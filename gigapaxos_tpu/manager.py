@@ -58,6 +58,7 @@ from .ops.engine import (
     EngineState,
     init_state,
     StepOutputs,
+    blob_vec_len,
     make_blob,
     pack_blob,
     split_blob_vec,
@@ -80,7 +81,7 @@ from .utils.profiler import DelayProfiler
 # backends without donation support ignore it.  The Blob-exchange tick
 # (_tick_locked, the test-cluster harness) uses a donate=False instance:
 # that harness caches blob views aliasing the live state across ticks.
-_pack_blob_jit = jax.jit(pack_blob)
+_publish_vec_jit = jax.jit(lambda state: pack_blob(make_blob(state)))
 # Blob of [R, ...] leaves -> [R, NB] packed rows (Blob._fields order, C
 # ravel per leaf — each row identical to pack_blob of that replica's
 # blob); the Blob-exchange tick packs its gathered blobs through this to
@@ -461,6 +462,22 @@ class PaxosManager:
         # duplicate decisions of one logical request are legal but wasteful,
         # and post-jump replicas can't dedup them (no cache entry yet)
         self.inflight: Dict[int, int] = {}  # request_id -> queued vid
+        # ...and when it was proposed here.  The dedup must not outlive
+        # the proposal: a coordinator deposed while its proposal was
+        # accepted by itself alone keeps it in its ring until ANOTHER
+        # value decides that slot (ops/engine.py retires and re-proposes
+        # only then), so with no other traffic for the name every
+        # retransmission was swallowed here and the request hung for
+        # good (seen on the chip: 36 of 1,000 single writes, PR 22).  A
+        # retransmission that finds its proposal older than the failure
+        # detector's timeout — time enough for an election to have moved
+        # the coordinator — and no longer queued here is proposed ANEW;
+        # execution dedups by request id on every replica, so a second
+        # decision of the same request is skipped, never re-executed.
+        self._inflight_since: Dict[int, float] = {}
+        self.repropose_after_s = Config.get_float(
+            PC.FAILURE_DETECTION_TIMEOUT_S
+        )
         self._next_counter = 1
         # node-minted request-id namespace: (boot nonce << 24) | counter,
         # < 2^61 (disjoint from reserved-bit-62 stop ids; client ids are
@@ -905,6 +922,43 @@ class PaxosManager:
             else self._recovery_stats.get("hot_hydrated", 0)
         )
         return out
+
+    def warm_engine(self) -> float:
+        """Compile what the serving path dispatches — the donated packed
+        step, the publish-vector pack and the single-row lifecycle
+        scatters — on a scratch state, BEFORE the node's listeners open;
+        returns the seconds it took.
+
+        The first dispatch otherwise compiles inside the tick thread,
+        which also sends this node's failure-detector pings: at the
+        deployed shape the chip's compiler takes longer than
+        FAILURE_DETECTION_TIMEOUT_S and REQUEST_TIMEOUT_S, so a cold
+        node would look dead to its peers and time out its first
+        creates.  Arguments are built exactly as ``step_dispatch``
+        builds them, so the real first dispatch is a jit-cache hit (the
+        retrace sentinel counts this compile as THE warm-up compile)."""
+        cfg = self.cfg
+        G, R = cfg.n_groups, cfg.n_replicas
+        t0 = time.monotonic()
+        one = np.array([0])
+        scratch = create_groups(
+            init_state(cfg), one, np.array([1]), np.array([0]),
+            my_id=self.my_id, version=0, tag=0,
+        )
+        scratch = kill_groups(scratch, one)
+        _publish_vec_jit(scratch)
+        req = np.full(
+            (self.steps_per_dispatch, G, cfg.req_lanes), NULL, np.int32
+        )
+        out = self._dispatch_step(
+            scratch,
+            jnp.asarray(np.zeros((R, blob_vec_len(cfg)), np.int32)),
+            jnp.asarray(np.zeros(R, bool)), jnp.asarray(req),
+            jnp.asarray(np.zeros((G,), bool)), jnp.int32(self.my_id),
+            jnp.zeros((G,), jnp.int32),
+        )
+        jax.block_until_ready(out)
+        return time.monotonic() - t0
 
     def mesh_info(self) -> Dict[str, Any]:
         """{n_devices, shape, platform} of the devices backing the engine
@@ -2304,9 +2358,8 @@ class PaxosManager:
             if request_id is not None and request_id in self.response_cache:
                 cached_hit = True
                 cached_response = self.response_cache[request_id][1]
-            elif (
-                request_id is not None
-                and self.inflight.get(request_id) in self.vid_meta
+            elif request_id is not None and self._awaits_decision_locked(
+                request_id, row, time.time()
             ):
                 # original proposal still live here: refresh the callback
                 # (the client re-registered) and wait for execution
@@ -2368,6 +2421,7 @@ class PaxosManager:
                     name, int(self._np("version")[row])
                 )
                 self.inflight[request_id] = vid
+                self._inflight_since[request_id] = time.time()
                 if callback is not None:
                     self.outstanding.put(request_id, callback)
                 self.queues.setdefault(row, []).append(vid)
@@ -2487,7 +2541,9 @@ class PaxosManager:
                         fired.append((cb, rid, resp))
                     results.append((rid, "cached", resp))
                     continue
-                if rid is not None and inflight.get(rid) in meta:
+                if rid is not None and self._awaits_decision_locked(
+                    rid, row, now
+                ):
                     if cb is not None:
                         self.outstanding.put(rid, cb)
                     results.append((rid, "inflight", None))
@@ -2508,6 +2564,7 @@ class PaxosManager:
                 meta[vid] = (entry, rid)
                 self.vid_scope[vid] = (name, int(versions[row]))
                 inflight[rid] = vid
+                self._inflight_since[rid] = now
                 if cb is not None:
                     self.outstanding.put_at(rid, cb, now)
                 self.queues.setdefault(row, []).append(vid)
@@ -2525,6 +2582,23 @@ class PaxosManager:
         for cb, rid, resp in fired:
             cb(rid, resp)
         return results
+
+    def _awaits_decision_locked(self, request_id: int, row: int,
+                                now: float) -> bool:
+        """In-flight dedup for a retransmitted request id: True while its
+        proposal is live here and worth waiting for — still queued at
+        this node, or younger than ``repropose_after_s``.  False lets the
+        caller mint a fresh proposal (see ``_inflight_since``)."""
+        vid = self.inflight.get(request_id)
+        if vid not in self.vid_meta:
+            return False
+        since = self._inflight_since.get(request_id, now)
+        if now - since < self.repropose_after_s:
+            return True
+        if vid in self.queues.get(row, ()):
+            return True
+        self.metrics.count("requests_reproposed")
+        return False
 
     def overloaded(self) -> bool:
         """Entry back-pressure: too many in-flight requests here."""
@@ -3353,6 +3427,10 @@ class PaxosManager:
             self.inflight = {
                 r: v for r, v in self.inflight.items() if v in self.vid_meta
             }
+            self._inflight_since = {
+                r: t for r, t in self._inflight_since.items()
+                if r in self.inflight
+            }
         self._maybe_checkpoint(last)
 
         # periodic full-baseline refresh: a dropped gossip frame must not
@@ -4110,7 +4188,7 @@ class PaxosManager:
         staleness by state identity without racing lifecycle ops."""
         with self._state_lock:
             state = self.state
-            return np.asarray(_pack_blob_jit(make_blob(state))), state
+            return np.asarray(_publish_vec_jit(state)), state
 
     def close(self) -> None:
         if self.hydrator is not None:

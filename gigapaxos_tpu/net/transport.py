@@ -26,6 +26,18 @@ _HDR = struct.Struct(">II")  # magic, payload length
 MAX_PAYLOAD = 256 * 1024 * 1024
 CONGESTION_LIMIT = 4096  # per-peer queued messages before drops (isCongested)
 
+
+
+class _Latest:
+    """Queue marker for :meth:`MessageTransport.send_latest_to_id`: the
+    sender takes whatever frame the slot holds when its turn comes."""
+
+    __slots__ = ("slot",)
+
+    def __init__(self, slot: str):
+        self.slot = slot
+
+
 # handler(payload: bytes, sender: (host, port), reply) -> None
 # ``reply(bytes)`` queues a frame back on the SAME connection (needed for
 # client request/response: clients don't listen on a port).
@@ -63,6 +75,9 @@ class MessageTransport:
         self._writers: Dict[Tuple[str, int], asyncio.StreamWriter] = {}
         self._queues: Dict[Tuple[str, int], asyncio.Queue] = {}
         self._senders: Dict[Tuple[str, int], asyncio.Task] = {}
+        # (addr, slot) -> newest unsent frame of a latest-wins slot
+        self._latest: Dict[Tuple[Tuple[str, int], str], bytes] = {}
+        self._latest_lock = threading.Lock()
         self._server: Optional[asyncio.AbstractServer] = None
         self._started = threading.Event()
         self._stopped = False
@@ -171,6 +186,27 @@ class MessageTransport:
             self.node_config.get_node_address(node_id), payload
         )
 
+    def send_latest_to_id(self, node_id: int, slot: str,
+                          payload: bytes) -> bool:
+        """Queue a frame that SUPERSEDES a still-unsent frame of the same
+        ``slot`` to that node — for frames that carry a whole state, where
+        only the newest matters (the consensus blob: the engine is built
+        for dropped and stale deliveries).  CONGESTION_LIMIT counts
+        frames, and a blob frame is 17.8 MB at the deployed 65,536 rows:
+        a tick loop that outruns a peer's reader would otherwise queue
+        gigabytes a minute.  At most one frame per (peer, slot) waits,
+        whatever the peer's pace."""
+        if node_id not in self.node_config:
+            return False
+        addr = self.node_config.get_node_address(node_id)
+        addr = (addr[0], int(addr[1]))
+        with self._latest_lock:
+            waiting = (addr, slot) in self._latest
+            self._latest[(addr, slot)] = payload
+        if waiting:
+            return True  # its marker is already queued
+        return self.send_to_address(addr, _Latest(slot))
+
     def send_to_address(self, addr: Tuple[str, int], payload: bytes,
                         delay: float = 0.0) -> bool:
         """Queue a frame; `delay` postpones the enqueue (chunk pacing /
@@ -196,6 +232,9 @@ class MessageTransport:
             self._senders[addr] = self._loop.create_task(self._sender(addr, q))
         if q.qsize() >= CONGESTION_LIMIT:
             self.n_dropped += 1  # congestion: drop, like the reference
+            if isinstance(payload, _Latest):
+                with self._latest_lock:  # the next frame queues anew
+                    self._latest.pop((addr, payload.slot), None)
             return
         q.put_nowait(payload)
 
@@ -212,6 +251,9 @@ class MessageTransport:
         writer: Optional[asyncio.StreamWriter] = None
         while not self._stopped:
             payload = await q.get()
+            if isinstance(payload, _Latest):
+                with self._latest_lock:
+                    payload = self._latest.pop((addr, payload.slot))
             for _attempt in (0, 1):
                 if writer is None:
                     try:

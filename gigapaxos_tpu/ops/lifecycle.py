@@ -12,6 +12,7 @@ from __future__ import annotations
 
 from typing import Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -43,6 +44,13 @@ def initial_coordinator(idx: np.ndarray, member_mask: np.ndarray) -> np.ndarray:
     return out
 
 
+# One dispatch per call, not one per touched leaf: op by op, a single-row
+# create is ~100 Python-level dispatches under the manager's state lock,
+# and at the deployed row count three actives creating names in one
+# process starve their own tick threads (whence pings and blobs) past the
+# failure-detection timeout.  ``my_id``/``version``/``tag`` are traced,
+# so there is one compile per (state shape, N).
+@jax.jit
 def create_groups(
     state: EngineState,
     idx: jnp.ndarray,          # [N] group indices to (re)create
@@ -92,6 +100,7 @@ def create_groups(
     )
 
 
+@jax.jit
 def kill_groups(state: EngineState, idx: jnp.ndarray) -> EngineState:
     """Batched kill: rows become inert (the Cremator analog,
     ``PaxosManager.java:2142-2205``)."""

@@ -106,6 +106,7 @@ class ActiveReplica:
 
         self.pause_option = Config.get_bool(PC.PAUSE_OPTION)
         self.deactivation_period_s = Config.get_float(PC.DEACTIVATION_PERIOD_S)
+        self.pause_batch_size = Config.get_int(PC.PAUSE_BATCH_SIZE)
         # probe backoff: (name, epoch) for pause records, or
         # ("pending", name, epoch, row) -> (next probe time, interval)
         self._probe_backoff: Dict[Tuple, Tuple[float, float]] = {}
@@ -304,7 +305,7 @@ class ActiveReplica:
         # coldest names go first, and a name with queued admissions or a
         # recent resume is never suggested ahead of a truly cold one
         for name, epoch in self.coordinator.eviction_candidates(
-            period, limit=Config.get_int(PC.PAUSE_BATCH_SIZE)
+            period, limit=self.pause_batch_size
         ):
             rc = self.rc_ids[hash(name) % len(self.rc_ids)]
             self.send(("RC", rc), "suggest_pause", {

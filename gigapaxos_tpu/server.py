@@ -183,6 +183,10 @@ class PaxosServer:
 
     # ---- lifecycle -----------------------------------------------------
     def start(self) -> None:
+        # compile before the listeners open: a peer or client that can
+        # reach this node must find its tick thread free to answer
+        warm_s = self.manager.warm_engine()
+        self.log.info("engine warm-up took %.2fs", warm_s)
         self.transport.start()
         if self.client_transport is not None:
             self.client_transport.start()
@@ -982,7 +986,7 @@ class PaxosServer:
             mx.count("blob_bytes_sent", len(blob_frame) * len(peers))
             mx.count("blob_frames_sent", len(peers))
             for r in peers:
-                self.transport.send_to_id(r, blob_frame)
+                self.transport.send_latest_to_id(r, "blob", blob_frame)
         if pub["delta"] is not None:
             frame = encode_json("payloads", self.my_id, pub["delta"])
             for r in peers:

@@ -34,13 +34,10 @@ def main(argv: Optional[List[str]] = None) -> None:
     import sys
 
     from ..net.node_config import NodeConfig
+    from ..utils.compile_cache import configure_compile_cache
     from ..utils.config import load_default_config_file
 
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
-
+    configure_compile_cache()
     argv = sys.argv[1:] if argv is None else argv
     load_default_config_file()
     rest = list(Config.register_args(argv))

@@ -25,16 +25,28 @@ import time
 from gigapaxos_tpu.testing.ports import free_ports
 
 
-def _probe_provenance() -> dict:
-    """Provenance stamp for capacity artifacts (obs/device.py): the
-    probe is a HOST-path measurement, so the stamp's platform/versions
-    say which host stack produced the number.  Never fails the probe."""
-    try:
+def _probe_provenance(in_process: bool, node_mesh: dict) -> dict:
+    """Provenance stamp for capacity artifacts: the platform it names is
+    the NODES' — ``node_mesh`` is the ``engine.mesh`` block of a node's
+    ``stats`` answer, the devices that back its engine arrays.  Only
+    with ``--in-process`` is this process a node, and only then does it
+    ask JAX itself; with child or attached nodes the parent stays off
+    JAX (a parent that has touched it holds the chip)."""
+    if in_process:
         from gigapaxos_tpu.obs.device import provenance
 
-        return provenance()
-    except Exception as e:  # noqa: BLE001
-        return {"error": repr(e)}
+        return provenance(extra={"nodes": node_mesh})
+    import platform as _platform
+    from importlib.metadata import version
+
+    return {
+        "jax": version("jax"),
+        "jaxlib": version("jaxlib"),
+        "platform": node_mesh.get("platform", "unknown"),
+        "n_devices": node_mesh.get("n_devices"),
+        "nodes": node_mesh,
+        "python": _platform.python_version(),
+    }
 
 
 def main() -> int:
@@ -154,6 +166,11 @@ def main() -> int:
     if args.attach:
         pass  # nothing to boot
     elif args.in_process:
+        from gigapaxos_tpu.utils.compile_cache import (
+            configure_compile_cache,
+        )
+
+        configure_compile_cache()
         from gigapaxos_tpu.models.apps import NoopPaxosApp
         from gigapaxos_tpu.ops.engine import EngineConfig
         from gigapaxos_tpu.reconfigurable_node import ReconfigurableNode
@@ -208,9 +225,9 @@ def main() -> int:
         props.close()
         env = dict(os.environ)
         env["GIGAPAXOS_CONFIG"] = props.name
-        # six node processes must not fight over one accelerator: the
-        # SYSTEM probe measures the host path, so children always run on
-        # CPU (bench.py owns the chip measurement)
+        # a chip belongs to one process, so six node processes run on
+        # the CPU; on a chip the six names boot in ONE process
+        # (chip_smoke.py, or --in-process here)
         env["JAX_PLATFORMS"] = "cpu"
         err_log = tempfile.NamedTemporaryFile(
             "w+", suffix=".nodes.log", delete=False
@@ -433,6 +450,7 @@ def main() -> int:
             }
 
         phases = {}
+        node_mesh = {}
         try:
             from gigapaxos_tpu.clients import PaxosClientAsync
 
@@ -443,7 +461,9 @@ def main() -> int:
                 st = stats_cli.admin_sync(0, {"op": "stats"}, timeout=5)
             finally:
                 stats_cli.close()
-            hists = ((st or {}).get("engine") or {}).get("hists") or {}
+            engine = (st or {}).get("engine") or {}
+            node_mesh = engine.get("mesh") or {}
+            hists = engine.get("hists") or {}
             for k in ("engine_step_s", "phase_ingress_s",
                       "phase_execute_s", "phase_flush_s",
                       "phase_publish_s", "pipeline_overlap_s"):
@@ -512,7 +532,9 @@ def main() -> int:
                 "protocol": summary["protocol"],
                 "phases": phases,
                 "warmup_s": summary["warmup_s"],
-                "provenance": _probe_provenance(),
+                "provenance": _probe_provenance(
+                    args.in_process, node_mesh
+                ),
             }
             with open(args.capacity_out, "w") as f:
                 json.dump(doc, f, indent=1, sort_keys=True)

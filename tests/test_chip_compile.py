@@ -1,0 +1,138 @@
+"""The serving path's jitted programs, compiled at real sizes for a
+DESCRIBED TPU v5e (no chip attached, nothing runs): what the chip's
+compiler refuses — a program that does not fit 16 GiB, a sharding it
+cannot partition — fails here, at no chip time.
+
+This is the only file that describes the chip.  The topology is
+described inside a module-scoped fixture, never at import: only one
+process may load the TPU's library, and every xdist worker imports every
+test file.  A compile that passes here is not a chip run.
+"""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from gigapaxos_tpu.ops.engine import EngineConfig, blob_vec_len, init_state
+from gigapaxos_tpu.ops.lifecycle import create_groups, restore_paused_rows
+from gigapaxos_tpu.parallel.mesh import GROUP_AXIS
+from gigapaxos_tpu.parallel.spmd import make_step
+
+HBM_BYTES = 16 * 2 ** 30  # one TPU v5e chip
+COLLECTIVES = ("all-gather", "all-reduce", "all-to-all",
+               "collective-permute", "reduce-scatter")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — whatever keeps libtpu away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _state_shapes(cfg, sharding):
+    """One replica's EngineState as shapes on ``sharding``."""
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        jax.eval_shape(lambda: init_state(cfg)),
+    )
+
+
+def _dispatch_bytes(compiled) -> int:
+    ma = compiled.memory_analysis()
+    return (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+
+
+@pytest.mark.parametrize("G,W,K", [
+    (65_536, 16, 8),       # the deployed default (what chip_smoke serves)
+    (1_048_576, 32, 16),   # the headline shape
+])
+def test_packed_host_step_compiles_and_fits_one_chip(one_chip, G, W, K):
+    """The step the manager dispatches (donated, heat-carrying): one
+    replica's dispatch must stay under one chip's 16 GiB."""
+    R = 3
+    cfg = EngineConfig(G, W, K, R)
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip
+    )
+    state = _state_shapes(cfg, one_chip)
+    step = make_step(cfg, None, 1, donate=True, io="packed_host", heat=True)
+    compiled = step.lower(
+        state, sds((R, blob_vec_len(cfg)), jnp.int32), sds((R,), jnp.bool_),
+        sds((1, G, K), jnp.int32), sds((G,), jnp.bool_),
+        sds((), jnp.int32), sds((G,), jnp.int32),
+    ).compile()
+    need = _dispatch_bytes(compiled)
+    assert 0 < need < HBM_BYTES, need
+    # the state is donated: its buffers are aliased into the new state
+    assert compiled.memory_analysis().alias_size_in_bytes > 0
+
+
+def test_lifecycle_scatters_compile_at_deployed_rows(one_chip):
+    """create_groups + restore_paused_rows (the batched create / unpause
+    scatters) over a 65,536-row state, 1,000 rows at a time."""
+    G, W, N = 65_536, 16, 1000
+    cfg = EngineConfig(G, W, 8, 3)
+    sds = lambda shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                             sharding=one_chip)
+    state = _state_shapes(cfg, one_chip)
+
+    def create_then_restore(state, idx, mask, coord0, tag, exec_slot, bal,
+                            app_hash, n_execd, *windows):
+        state = create_groups(state, idx, mask, coord0, my_id=1,
+                              version=0, tag=tag)
+        return restore_paused_rows(state, idx, exec_slot, bal, app_hash,
+                                   n_execd, *windows)
+
+    compiled = jax.jit(create_then_restore, donate_argnums=(0,)).lower(
+        state, *[sds((N,))] * 8, *[sds((N, W))] * 5
+    ).compile()
+    assert "scatter" in compiled.as_text()
+    assert _dispatch_bytes(compiled) < HBM_BYTES
+
+
+def test_group_sharded_step_has_no_collectives_on_four_chips(topo):
+    """The ``('g',)``-sharded step on the four described devices
+    (``chip_smoke.py --chips 4``): groups are independent, so the
+    partitioned program holds no cross-device collective, and each
+    device holds a quarter of the unsharded dispatch."""
+    G, W, K, R = 1_048_576, 16, 8, 3
+    cfg = EngineConfig(G, W, K, R)
+    mesh = Mesh(np.array(topo.devices), (GROUP_AXIS,))
+    assert mesh.size == 4
+    on = lambda *spec: NamedSharding(mesh, P(*spec))
+    by_rank = {2: on(None, GROUP_AXIS), 3: on(None, GROUP_AXIS, None)}
+    state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(
+            (R,) + x.shape, x.dtype, sharding=by_rank[x.ndim + 1]
+        ),
+        jax.eval_shape(lambda: init_state(cfg)),
+    )
+    compiled = make_step(cfg, mesh, 1).lower(
+        state,
+        jax.ShapeDtypeStruct((R, G, K), jnp.int32, sharding=by_rank[3]),
+        jax.ShapeDtypeStruct((R, G), jnp.bool_, sharding=by_rank[2]),
+    ).compile()
+    text = compiled.as_text()
+    found = {c: len(re.findall(c, text)) for c in COLLECTIVES}
+    assert not any(found.values()), found
+    # memory_analysis() is per device: G/4 groups each
+    assert _dispatch_bytes(compiled) < HBM_BYTES // 4

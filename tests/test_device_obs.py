@@ -1,6 +1,6 @@
 """Device-plane observatory (obs/device.py + manager/server hooks).
 
-Covers the four tentpole instruments end to end:
+Covers the three tentpole instruments end to end:
 
 * the retrace/compile sentinel — counts compiles, flags shape-unstable
   steps as retraces after warmup, and the HARD invariant that the
@@ -12,23 +12,15 @@ Covers the four tentpole instruments end to end:
   bit-matches scalar observes;
 * cost attribution — ``step_cost`` AOT split, provenance JSON
   round-trip, the ``profile`` admin op writing into (and bounding) its
-  dump directory;
-* the perf-regression observatory — the committed PERF_BASELINE.json
-  stays structurally valid (``--check-only``; no wall-clock gates in
-  tier-1) and the validator actually rejects gutted documents.
+  dump directory.
 """
 
-import importlib.util
 import json
 import os
-import subprocess
-import sys
 import time
 
 import numpy as np
 import pytest
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # ---- retrace/compile sentinel ----------------------------------------
@@ -342,55 +334,3 @@ def test_slo_budget_parse_and_breach():
     over = tm.slo_breaches(trace, budgets)
     assert [b["phase"] for b in over] == ["ingress"]  # 60ms > 50ms
     assert not tm.slo_breaches(trace, {"consensus": 0.5})
-
-
-# ---- perf-regression observatory --------------------------------------
-
-def _load_perf_baseline_module():
-    spec = importlib.util.spec_from_file_location(
-        "perf_baseline", os.path.join(REPO, "scripts", "perf_baseline.py")
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_perf_baseline_committed_artifact_valid():
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts",
-                                      "perf_baseline.py"),
-         "--check-only"],
-        capture_output=True, text=True, timeout=60, cwd=REPO,
-    )
-    assert r.returncode == 0, r.stderr or r.stdout
-    doc = json.load(open(os.path.join(REPO, "PERF_BASELINE.json")))
-    series = doc["series"]["committed_decisions_per_s"]
-    # full committed bench series, split by platform, with bands
-    assert series["cpu"]["rounds"] == ["r01", "r02", "r03"]
-    assert series["tpu"]["rounds"] == ["r04", "r05"]
-    for s in series.values():
-        assert 0 < s["band"]["lower"] < min(s["values"])
-    assert doc["series"]["dispatch_ablation"]["rounds"] == ["r06"]
-    assert doc["fresh_check"]["in_band"] is True
-    assert doc["fresh_check"]["provenance"]["jax"]
-
-
-def test_perf_baseline_validator_rejects_gutted_doc():
-    mod = _load_perf_baseline_module()
-    doc = json.load(open(os.path.join(REPO, "PERF_BASELINE.json")))
-    assert mod.validate(doc) == []
-    broken = json.loads(json.dumps(doc))
-    del broken["series"]["committed_decisions_per_s"]
-    assert any("committed_decisions_per_s" in e
-               for e in mod.validate(broken))
-    below = json.loads(json.dumps(doc))
-    below["fresh_check"]["in_band"] = False
-    assert any("out of band" in e for e in mod.validate(below))
-    # a fresh value below the band is gated out
-    band = doc["series"]["committed_decisions_per_s"]["cpu"]["band"]
-    fc = mod.check_fresh(doc, {
-        "metric": "committed_decisions_per_s",
-        "value": band["lower"] * 0.5,
-        "unit": "decisions/s (8192 groups, 3 replicas, 1 chip, cpu)",
-    })
-    assert fc["in_band"] is False

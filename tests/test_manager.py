@@ -220,3 +220,55 @@ def test_retransmit_reproposes_after_row_killed():
     assert got.get("second") == "7", got
     assert all(mm.app.totals.get("acct", 0) == 7 for mm in c.managers)
     c.close()
+
+
+def test_retransmission_revives_a_request_stranded_by_an_election():
+    """A coordinator deposed while its proposal was accepted by itself
+    alone keeps that proposal in its ring until another value decides
+    the slot; with no other traffic for the name, the entry replica's
+    in-flight dedup used to swallow every retransmission and the request
+    hung for good (36 of 1,000 single writes on the chip, PR 22).  A
+    retransmission that finds its proposal older than the failure
+    detector's timeout, and no longer queued here, is proposed anew —
+    and executed exactly once."""
+    c = ManagerCluster(CFG, StatefulAdderApp)
+    c.create("acct")
+    row = c.managers[0].names["acct"]
+    old = c.managers[0].coordinator_of_row(row)
+    new = (old + 1) % 3
+    got = []
+    cb = lambda rid, resp: got.append(resp)
+    # the coordinator proposes and accepts alone: nobody hears it
+    cut = np.full((3, 3), DELIVER)
+    for r in range(3):
+        if r != old:
+            cut[r, old] = cut[old, r] = DROP
+    c.managers[old].propose("acct", "7", callback=cb, request_id=77)
+    c.step_all(delivery=cut)
+    c.step_all(delivery=cut)
+    # the others elect `new` without hearing the old coordinator
+    want = np.zeros(CFG.n_groups, bool)
+    want[row] = True
+    c.step_all(delivery=cut, want_coord={new: want})
+    for _ in range(4):
+        c.step_all(delivery=cut)
+    c.run(12)  # healed: everyone hears everyone, and still nothing decides
+    assert all(m.coordinator_of_row(row) == new for m in c.managers)
+    assert not got and all(
+        m.app.totals.get("acct", 0) == 0 for m in c.managers
+    ), "the stranded request was expected to hang"
+
+    entry = c.managers[old]
+    # a young proposal is still waited for: the retransmission is deduped
+    entry.propose("acct", "7", callback=cb, request_id=77)
+    c.run(8)
+    assert not got
+    # aged past the failure detector's timeout: proposed anew
+    entry._inflight_since[77] -= entry.repropose_after_s + 1
+    entry.propose("acct", "7", callback=cb, request_id=77)
+    c.run(16)
+    assert got == ["7"], got
+    # the revived original decides too (a later slot) and is skipped
+    assert [m.app.totals.get("acct") for m in c.managers] == [7, 7, 7]
+    assert entry.metrics.snapshot()["counters"]["requests_reproposed"] == 1
+    c.close()

@@ -349,3 +349,27 @@ def test_stranded_winning_row_confirm_heals_via_pending_probe():
         assert row not in m1.pending_rows, "lost confirm never re-sent"
     finally:
         c.close()
+
+
+def test_deactivator_sweep_pauses_a_name_that_sat_idle():
+    """The sweep itself, as a deployed active runs it from its tick: a
+    name idle past DEACTIVATION_PERIOD_S is suggested for pause, capped
+    at PAUSE_BATCH_SIZE per period (the cap's config read raised a
+    NameError in every deployed active after its first 60 s, PR 19-22)."""
+    import time
+
+    c = make_cluster()
+    create(c, "idle")
+    run_requests(c, "idle", ["a", "b"])
+    ar = c.active_replicas[0]
+    assert ar.pause_option and ar.pause_batch_size > 0
+    ar.deactivation_period_s = 0.05  # the period is also the idle bound
+    time.sleep(0.1)
+    ar._maybe_sweep()
+    for _ in range(80):
+        c.step()
+        rec = c.reconfigurators[0].rc_app.get_record("idle")
+        if rec is not None and rec.state is RCState.PAUSED:
+            break
+    assert rec.state is RCState.PAUSED, rec
+    assert all(m.names.get("idle") is None for m in c.ars.managers)

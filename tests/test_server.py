@@ -216,3 +216,48 @@ def test_overload_backpressure():
             client.close()
     finally:
         Config.clear()
+
+
+def test_latest_wins_send_keeps_one_frame_waiting_per_peer():
+    """A frame that carries a whole state (the consensus blob) supersedes
+    its unsent predecessor: a sender that outruns its peer's reader keeps
+    ONE frame waiting, not a queue of them (17.8 MB each at the deployed
+    65,536 rows), and the newest frame is the one that arrives."""
+    import threading
+
+    from gigapaxos_tpu.net.transport import MessageTransport
+
+    nc = NodeConfig({0: ("127.0.0.1", 0), 1: ("127.0.0.1", 0)})
+    release = threading.Event()
+    got, last = [], threading.Event()
+    n_frames, size = 40, 4 * 1024 * 1024  # outgrows the socket buffers
+
+    def slow_reader(payload, peer, reply):
+        release.wait(30)  # a peer that does not keep up
+        got.append(payload[:4])
+        if payload[:4] == (n_frames - 1).to_bytes(4, "big"):
+            last.set()
+
+    sender = MessageTransport(0, nc, lambda *a: None,
+                              listen_host="127.0.0.1", listen_port=0)
+    reader = MessageTransport(1, nc, slow_reader,
+                              listen_host="127.0.0.1", listen_port=0)
+    try:
+        for nid, t in ((0, sender), (1, reader)):
+            t.start()
+            nc.add(nid, "127.0.0.1", t.listen_port)
+        for i in range(n_frames):
+            frame = i.to_bytes(4, "big") + bytes(size)
+            assert sender.send_latest_to_id(1, "blob", frame)
+            time.sleep(0.005)
+            assert len(sender._latest) <= 1
+            assert sum(q.qsize() for q in sender._queues.values()) <= 1
+        release.set()
+        assert last.wait(30), "the newest frame never arrived"
+        assert len(got) < n_frames  # the superseded ones were never sent
+        assert got == sorted(got)  # and never out of order
+        assert not sender._latest
+    finally:
+        release.set()
+        sender.stop()
+        reader.stop()
