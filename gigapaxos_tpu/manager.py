@@ -32,6 +32,7 @@ The tick cycle (one call to :meth:`tick`):
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import json
 import threading
@@ -67,11 +68,11 @@ from .ops.engine import (
 from .parallel.spmd import make_step
 from .obs import gplog
 from .obs.flight import FlightRecorder
-from .obs.metrics import MetricsRegistry
+from .obs.metrics import TICK_BOUNDS, MetricsRegistry
 from .obs.reqtrace import RequestTracer
+from .obs.spans import span
 from .ops.lifecycle import create_groups, kill_groups, restore_paused_rows
 from .storage.logger import PaxosLogger
-from .utils.profiler import DelayProfiler
 
 # Every tick flavor steps through the ONE unified factory
 # (parallel/spmd.py:make_step, io="packed_host").  The dispatch path
@@ -197,22 +198,28 @@ class Outstanding:
         if timeout_s is None:
             timeout_s = Config.get_float(PC.REQUEST_TIMEOUT_S)
         self.timeout_s = timeout_s
-        self._map: Dict[int, Tuple[float, Callable]] = {}
+        # request id -> (time of the newest put, callback, time and
+        # manager tick of the FIRST put: a retransmission refreshes the
+        # callback and the TTL, not when the request entered)
+        self._map: Dict[int, Tuple[float, Callable, float, int]] = {}
 
-    def put(self, request_id: int, cb: Callable) -> None:
-        self._map[request_id] = (time.time(), cb)
+    def put(self, request_id: int, cb: Callable, tick: int,
+            t: Optional[float] = None) -> None:
+        """``t``: a caller-shared timestamp (batched ingress)."""
+        t = time.time() if t is None else t
+        old = self._map.get(request_id)
+        self._map[request_id] = (
+            (t, cb, t, tick) if old is None else (t, cb, old[2], old[3])
+        )
 
-    def put_at(self, request_id: int, cb: Callable, t: float) -> None:
-        """put() with a caller-shared timestamp (batched ingress)."""
-        self._map[request_id] = (t, cb)
-
-    def pop(self, request_id: int) -> Optional[Callable]:
+    def pop(self, request_id: int) -> Optional[Tuple[Callable, float, int]]:
+        """(callback, time and tick of the first put), or None."""
         ent = self._map.pop(request_id, None)
-        return ent[1] if ent else None
+        return ent[1:] if ent else None
 
     def gc(self) -> int:
         cut = time.time() - self.timeout_s
-        dead = [k for k, (t, _) in self._map.items() if t < cut]
+        dead = [k for k, ent in self._map.items() if ent[0] < cut]
         for k in dead:
             del self._map[k]
         return len(dead)
@@ -283,6 +290,7 @@ class PaxosManager:
             PaxosLogger(
                 my_id, log_dir, sync=sync_journal,
                 max_file_size=Config.get_int(PC.MAX_LOG_FILE_SIZE),
+                metrics=self.metrics,
             ) if log_dir else None
         )
         self.checkpoint_every = (
@@ -2364,7 +2372,8 @@ class PaxosManager:
                 # original proposal still live here: refresh the callback
                 # (the client re-registered) and wait for execution
                 if callback is not None:
-                    self.outstanding.put(request_id, callback)
+                    self.outstanding.put(request_id, callback,
+                                         self._tick_no)
                 return None
             elif (
                 self.emulate_unreplicated or self.lazy_propagation
@@ -2423,7 +2432,8 @@ class PaxosManager:
                 self.inflight[request_id] = vid
                 self._inflight_since[request_id] = time.time()
                 if callback is not None:
-                    self.outstanding.put(request_id, callback)
+                    self.outstanding.put(request_id, callback,
+                                         self._tick_no)
                 self.queues.setdefault(row, []).append(vid)
                 self.row_activity[row] = time.time()
                 self.demand_counts[name] = self.demand_counts.get(name, 0) + 1
@@ -2433,7 +2443,7 @@ class PaxosManager:
                     self.tracer.note(
                         request_id, "propose", name=name, node=self.my_id,
                         vid=vid, row=row, entry=entry, stop=bool(stop),
-                        force=trace_ctx is not None,
+                        tick=self._tick_no, force=trace_ctx is not None,
                         **self._tc_detail(trace_ctx),
                     )
         if emulated is not None:
@@ -2545,7 +2555,7 @@ class PaxosManager:
                     rid, row, now
                 ):
                     if cb is not None:
-                        self.outstanding.put(rid, cb)
+                        self.outstanding.put(rid, cb, self._tick_no)
                     results.append((rid, "inflight", None))
                     continue
                 if self._next_counter > VID_COUNTER_MASK:
@@ -2566,7 +2576,7 @@ class PaxosManager:
                 inflight[rid] = vid
                 self._inflight_since[rid] = now
                 if cb is not None:
-                    self.outstanding.put_at(rid, cb, now)
+                    self.outstanding.put(rid, cb, self._tick_no, now)
                 self.queues.setdefault(row, []).append(vid)
                 self.row_activity[row] = now
                 self.demand_counts[name] = self.demand_counts.get(name, 0) + 1
@@ -2577,6 +2587,7 @@ class PaxosManager:
                     self.tracer.note(
                         rid, "propose", name=name, node=self.my_id,
                         vid=vid, row=row, entry=entry, batch=True,
+                        tick=self._tick_no,
                         force=tc is not None, **self._tc_detail(tc),
                     )
         for cb, rid, resp in fired:
@@ -2662,13 +2673,12 @@ class PaxosManager:
                 # only the admitting coordinator persisted them, a
                 # coordinator-only crash could lose decided-but-unexecuted
                 # values for everyone
-                t_lp = time.monotonic()
-                self.logger.log_payloads(fresh, meta={
-                    k: self.vid_meta[k] for k in fresh if k in self.vid_meta
-                })
-                DelayProfiler.update_count(
-                    "t_log_payloads", time.monotonic() - t_lp
-                )
+                with span(self.metrics, "journal.gossip",
+                          node=self.my_id):
+                    self.logger.log_payloads(fresh, meta={
+                        k: self.vid_meta[k] for k in fresh
+                        if k in self.vid_meta
+                    })
             tcs = body.get("tc")
             if tcs:
                 # trace contexts ride the payload gossip so every replica
@@ -2717,7 +2727,7 @@ class PaxosManager:
                 self.tracer.note(
                     body.get("request_id"), "forward-in",
                     name=body["name"], node=self.my_id,
-                    entry=body.get("entry"),
+                    entry=body.get("entry"), tick=self._tick_no,
                     force=tc is not None, **self._tc_detail(tc),
                 )
             self.propose(
@@ -2752,6 +2762,7 @@ class PaxosManager:
                     if tr_on or tc is not None:
                         self.tracer.note(rid, "forward-in", name=name,
                                          node=self.my_id, entry=entry,
+                                         tick=self._tick_no,
                                          force=tc is not None,
                                          **self._tc_detail(tc))
             items = []
@@ -3015,11 +3026,10 @@ class PaxosManager:
         the same queues, arena, and vid tables this reads and rewrites.
         User callbacks collected during execution fire AFTER the lock is
         released (a blocking callback must not wedge transport threads)."""
-        with self._state_lock:
+        with self._step_locked():
             result = self._tick_locked(gathered, heard, want_coord)
             fired, self._fired_callbacks = self._fired_callbacks, []
-        for cb, rid, resp in fired:
-            cb(rid, resp)
+        self._fire(fired)
         return result
 
     def tick_host(
@@ -3036,12 +3046,114 @@ class PaxosManager:
         delta).  One device upload + two downloads per tick instead of
         ~50 per-leaf dispatches — at loopback scale the per-leaf dispatch
         overhead was most of a node's tick cost."""
-        with self._state_lock:
+        with self._step_locked():
             result = self._tick_host_locked(gathered_vec, heard, want_coord)
             fired, self._fired_callbacks = self._fired_callbacks, []
-        for cb, rid, resp in fired:
-            cb(rid, resp)
+        self._fire(fired)
         return result
+
+    # ------------------------------------------------------------------
+    # the tick's spans (obs/spans.py): every flavor of tick — pipelined
+    # step_dispatch/step_complete, serial tick_host, the test clusters'
+    # tick — is built from these helpers, so each phase is timed once,
+    # in one place
+    # ------------------------------------------------------------------
+    def _span(self, phase: str, cpu: bool = True, record: bool = True):
+        """A span of the thread that ticks this node (the server's tick
+        loop uses it too).  The thread's CPU time is taken for the
+        phases that last a millisecond or more; the short ones pass
+        ``cpu=False``, because on the chip's host two reads of that clock
+        cost more than such a phase itself (obs/spans.py)."""
+        return span(self.metrics, phase, cpu=cpu, record=record,
+                    node=self.my_id, tick=self._tick_no)
+
+    @contextlib.contextmanager
+    def _step_locked(self):
+        """Hold ``_state_lock`` with no step in flight; the wait for
+        both (transport threads admitting under the lock, the previous
+        step's completion) is the span."""
+        with self._span("step.lock_wait", cpu=False):
+            self._state_lock.acquire()
+            try:
+                self._await_step_locked()
+            except BaseException:
+                self._state_lock.release()
+                raise
+        try:
+            yield
+        finally:
+            self._state_lock.release()
+
+    def _dispatch_locked(self, step, gathered_vec, heard, want_coord,
+                         carry: bool = False):
+        """Lock held: admit into the request ring and fire ``step``
+        without waiting for the device.  Returns (out_vec, blob_vec,
+        t0) with ``self.state`` already the in-flight result."""
+        with self._span("step.ring_build", cpu=False):
+            req = self.build_request_ring(self.steps_per_dispatch)
+            wc = (
+                np.zeros((self.cfg.n_groups,), bool) if want_coord is None
+                else np.asarray(want_coord, bool)
+            )
+            old_state = self.state
+            carried = self._carried_leaves(old_state) if carry else None
+        with self._span("step.dispatch"):
+            t0 = time.monotonic()
+            new_state, out_vec, blob_vec, new_heat = step(
+                old_state, jnp.asarray(gathered_vec), jnp.asarray(heard),
+                jnp.asarray(req), jnp.asarray(wc), jnp.int32(self.my_id),
+                self._heat_dev,
+            )
+        self.state = new_state
+        self._heat_dev = new_heat
+        if carry:
+            self._np_cache = carried
+            self._np_cache_state = new_state
+        return out_vec, blob_vec, t0
+
+    def _carried_leaves(self, old_state) -> Dict[str, np.ndarray]:
+        """The lifecycle-owned leaves' host cache, carried across the
+        state swap: the step passes version/member_mask/majority/tag
+        through UNCHANGED (ops/engine.py keeps them), and the
+        transport-thread propose/admission path reads them during the
+        overlap window — a cache miss there would block on the device
+        sync and re-serialize exactly what the pipeline exists to
+        overlap.  Copies are taken BEFORE the jit call: the step donates
+        old_state's buffers."""
+        carry: Dict[str, np.ndarray] = {}
+        if self._np_cache_state is old_state:
+            for leaf in ("version", "member_mask", "majority", "tag"):
+                arr = self._np_cache.get(leaf)
+                if arr is not None:
+                    carry[leaf] = arr
+        for leaf in ("version", "member_mask"):
+            if leaf not in carry:
+                arr = np.asarray(getattr(old_state, leaf))
+                carry[leaf] = arr.copy() if arr.base is not None else arr
+        return carry
+
+    def _device_wait(self, out_vec, blob_vec):
+        """The step's outputs on the host: two transfers, the first
+        forces the sync.  Its annotation wraps JAX's own host events, so
+        an idle gap of the device under it keeps their names."""
+        with self._span("step.device_wait"):
+            return np.asarray(out_vec), np.asarray(blob_vec)
+
+    def _complete_locked(self, out_np_vec, t0: float) -> Dict:
+        """Lock held, outputs on the host: close the ``engine_step_s``
+        envelope and run the post-step host cycle."""
+        self.last_engine_step_s = time.monotonic() - t0
+        with self._span("post_step"):
+            outs = [split_out_vec(row, self.cfg) for row in out_np_vec]
+            return self._post_step_locked(outs)
+
+    def _fire(self, fired) -> None:
+        """User callbacks, after the lock is released."""
+        if not fired:
+            return
+        with self._span("callbacks", cpu=False):
+            for cb, rid, resp in fired:
+                cb(rid, resp)
 
     # ------------------------------------------------------------------
     # double-buffered dispatch (the serving pipeline's step entry):
@@ -3080,49 +3192,16 @@ class PaxosManager:
         reader that np.asarray's it simply blocks until the device is
         done, which is correct but serializing; the hot propose path
         avoids that via the carried lifecycle-leaf cache below)."""
-        with self._state_lock:
-            self._await_step_locked()  # single-depth pipeline
-            cfg = self.cfg
-            G = cfg.n_groups
-            req = self.build_request_ring(self.steps_per_dispatch)
-            wc = (
-                np.zeros((G,), bool) if want_coord is None
-                else np.asarray(want_coord, bool)
+        with self._step_locked():  # single-depth pipeline
+            out_vec, blob_vec, t0 = self._dispatch_locked(
+                self._dispatch_step, gathered_vec, heard, want_coord,
+                carry=True,
             )
-            old_state = self.state
-            # Carry the lifecycle-owned leaves' host cache across the
-            # swap: the step passes version/member_mask/majority/tag
-            # through UNCHANGED (ops/engine.py keeps them), and the
-            # transport-thread propose/admission path reads them during
-            # the overlap window — a cache miss there would block on the
-            # device sync and re-serialize exactly what the pipeline
-            # exists to overlap.  Copies are taken BEFORE the jit call:
-            # the step donates old_state's buffers.
-            carry: Dict[str, np.ndarray] = {}
-            if self._np_cache_state is old_state:
-                for leaf in ("version", "member_mask", "majority", "tag"):
-                    arr = self._np_cache.get(leaf)
-                    if arr is not None:
-                        carry[leaf] = arr
-            for leaf in ("version", "member_mask"):
-                if leaf not in carry:
-                    arr = np.asarray(getattr(old_state, leaf))
-                    carry[leaf] = arr.copy() if arr.base is not None else arr
-            t0 = time.monotonic()
-            new_state, out_vec, blob_vec, new_heat = self._dispatch_step(
-                old_state, jnp.asarray(gathered_vec), jnp.asarray(heard),
-                jnp.asarray(req), jnp.asarray(wc), jnp.int32(self.my_id),
-                self._heat_dev,
-            )
-            self.state = new_state
-            self._heat_dev = new_heat
-            self._np_cache = carry
-            self._np_cache_state = new_state
             self._step_inflight = True
             self._step_thread = threading.get_ident()
             return {
                 "out_vec": out_vec, "blob_vec": blob_vec,
-                "state": new_state, "t0": t0,
+                "state": self.state, "t0": t0,
             }
 
     def step_complete(
@@ -3134,25 +3213,21 @@ class PaxosManager:
         # device sync OUTSIDE the lock: np.asarray blocks with the GIL
         # released, so transport threads run the ingress/codec path
         # against the still-valid carried caches while the device works
-        out_np_vec = np.asarray(pend["out_vec"])
-        blob_vec = np.asarray(pend["blob_vec"])
-        t0 = pend["t0"]
-        with self._state_lock:
+        out_np_vec, blob_vec = self._device_wait(
+            pend["out_vec"], pend["blob_vec"])
+        with self._span("post_step.lock_wait", cpu=False):
+            self._state_lock.acquire()
+        try:
             try:
-                DelayProfiler.update_delay("engine_step", t0)
-                self.last_engine_step_s = time.monotonic() - t0
-                DelayProfiler.update_count(
-                    "t_engine_step", self.last_engine_step_s
-                )
-                outs = [split_out_vec(row, self.cfg) for row in out_np_vec]
-                host_delta = self._post_step_locked(outs)
+                host_delta = self._complete_locked(out_np_vec, pend["t0"])
             finally:
                 self._step_inflight = False
                 self._step_thread = None
                 self._step_cv.notify_all()
             fired, self._fired_callbacks = self._fired_callbacks, []
-        for cb, rid, resp in fired:
-            cb(rid, resp)
+        finally:
+            self._state_lock.release()
+        self._fire(fired)
         return blob_vec, pend["state"], host_delta
 
     def _tick_host_locked(
@@ -3160,30 +3235,12 @@ class PaxosManager:
         gathered_vec: np.ndarray,
         heard: np.ndarray,
         want_coord: Optional[np.ndarray],
-    ) -> Tuple[np.ndarray, Dict]:
-        self._await_step_locked()
-        cfg = self.cfg
-        G = cfg.n_groups
-        req = self.build_request_ring(self.steps_per_dispatch)
-        wc = (
-            np.zeros((G,), bool) if want_coord is None
-            else np.asarray(want_coord, bool)
-        )
-        t0 = time.monotonic()
-        new_state, out_vec, blob_vec, new_heat = self._dispatch_step(
-            self.state, jnp.asarray(gathered_vec), jnp.asarray(heard),
-            jnp.asarray(req), jnp.asarray(wc), jnp.int32(self.my_id),
-            self._heat_dev,
-        )
-        self.state = new_state
-        self._heat_dev = new_heat
-        out_np_vec = np.asarray(out_vec)  # one transfer; forces the sync
-        DelayProfiler.update_delay("engine_step", t0)
-        self.last_engine_step_s = time.monotonic() - t0
-        DelayProfiler.update_count("t_engine_step", self.last_engine_step_s)
-        outs = [split_out_vec(row, cfg) for row in out_np_vec]
-        host_delta = self._post_step_locked(outs)
-        return np.asarray(blob_vec), new_state, host_delta
+    ) -> Tuple[np.ndarray, "EngineState", Dict]:
+        out_vec, blob_vec, t0 = self._dispatch_locked(
+            self._dispatch_step, gathered_vec, heard, want_coord)
+        new_state = self.state
+        out_np_vec, blob_np = self._device_wait(out_vec, blob_vec)
+        return blob_np, new_state, self._complete_locked(out_np_vec, t0)
 
     def _tick_locked(
         self,
@@ -3191,36 +3248,15 @@ class PaxosManager:
         heard: np.ndarray,
         want_coord: Optional[np.ndarray] = None,
     ) -> Tuple[Blob, Dict]:
-        self._await_step_locked()
-        cfg = self.cfg
-        G = cfg.n_groups
-        req = self.build_request_ring(self.steps_per_dispatch)
-        wc = (
-            np.zeros((G,), bool) if want_coord is None
-            else np.asarray(want_coord, bool)
-        )
         # the Blob-of-leaves exchange reaches the unified packed step as
         # one [R, NB] matrix (each row == pack_blob of that replica);
         # donate=False — the test-cluster harness caches blob views that
         # alias the live state across ticks
-        gvec = _pack_rows_jit(gathered)
-        t0 = time.monotonic()
-        new_state, out_vec, blob_vec, new_heat = self._tick_step(
-            self.state, gvec, jnp.asarray(heard),
-            jnp.asarray(req), jnp.asarray(wc), jnp.int32(self.my_id),
-            self._heat_dev,
-        )
-        self.state = new_state
-        self._heat_dev = new_heat
-        out_np_vec = np.asarray(out_vec)  # one transfer; forces the sync
-        # update_delay takes the START time (it computes monotonic()-t0)
-        DelayProfiler.update_delay("engine_step", t0)
-        self.last_engine_step_s = time.monotonic() - t0
-        DelayProfiler.update_count("t_engine_step", self.last_engine_step_s)
-
-        outs = [split_out_vec(row, cfg) for row in out_np_vec]
-        host_delta = self._post_step_locked(outs)
-        return split_blob_vec(np.asarray(blob_vec), cfg), host_delta
+        out_vec, blob_vec, t0 = self._dispatch_locked(
+            self._tick_step, _pack_rows_jit(gathered), heard, want_coord)
+        out_np_vec, blob_np = self._device_wait(out_vec, blob_vec)
+        host_delta = self._complete_locked(out_np_vec, t0)
+        return split_blob_vec(blob_np, self.cfg), host_delta
 
     def _post_step_locked(self, outs) -> Dict:
         """Shared post-engine host work (requeue, watermarks, journaling,
@@ -3289,14 +3325,11 @@ class PaxosManager:
         mx.gauge("inflight_requests", len(self.inflight))
         mx.gauge("arena_payloads", len(self.arena))
         mx.observe("engine_step_s", self.last_engine_step_s)
-        # residency plane: steps amortized per host dispatch, staged
-        # device-ring depth, and the per-substep amortized host cost
+        # residency plane: steps amortized per host dispatch and the
+        # staged device-ring depth
         mx.count("host_dispatches")
         mx.gauge("dispatch_steps_per_host", n_sub)
         mx.gauge("device_queue_depth", self._last_ring_depth)
-        mx.observe(
-            "dispatch_amortized_s", self.last_engine_step_s / n_sub
-        )
         # retrace sentinel: fold the shared sentinels' totals into this
         # node's counters as deltas (attribute reads only — no device
         # traffic), and mark them warm after the first completed
@@ -3382,12 +3415,13 @@ class PaxosManager:
         # log-before-send: persist the promise + accept delta before the
         # blob leaves (bare promises too — a ballot that rose with no
         # accept must survive a crash, ADVICE r1 high / handlePrepare's
-        # LogMessagingTask rule).  The whole tick's blocks (including the
-        # decision log inside _execute) leave as ONE group commit
-        # (BatchedLogger analog) — flushed before this function returns,
-        # so log-before-send still holds for the published blob.
+        # LogMessagingTask rule).  The whole tick's blocks (every
+        # substep's decision log included) leave as ONE group commit
+        # (BatchedLogger analog) — written before anything executes and
+        # before this function returns, so log-before-send still holds
+        # for the published blob, and the `journal` span holds the write.
         if self.logger is not None:
-            with self.logger.batch():
+            with self._span("journal", cpu=False), self.logger.batch():
                 pg = np.nonzero(bal_rose)[0]
                 if len(pg):
                     bal_np = self._np("bal")
@@ -3415,10 +3449,9 @@ class PaxosManager:
                 if payload_delta:
                     self.logger.log_payloads(payload_delta, meta=meta_delta)
                 for o in outs:
-                    self._execute(o)
-        else:
-            for o in outs:
-                self._execute(o)
+                    self._log_decisions(o)
+        for o in outs:
+            self._execute(o)
         self._maybe_request_state(last)
         self.outstanding.gc()
         if self._tick_no % 64 == 0 and self.inflight:
@@ -3460,22 +3493,25 @@ class PaxosManager:
     # ------------------------------------------------------------------
     # execution (EEC analog, PaxosInstanceStateMachine.java:1511-1734)
     # ------------------------------------------------------------------
+    def _log_decisions(self, out_np) -> None:
+        """One substep's decisions into the open journal batch."""
+        committed = np.nonzero(out_np.n_committed)[0]
+        if not len(committed):
+            return
+        rows, slots, vids = [], [], []
+        for g in committed:
+            base = int(out_np.exec_base[g])
+            for o in range(int(out_np.n_committed[g])):
+                rows.append(g)
+                slots.append(base + o)
+                vids.append(int(out_np.exec_vid[g, o]))
+        self.logger.log_decisions(
+            np.array(rows, np.int32), np.array(slots, np.int32),
+            np.array(vids, np.int32),
+        )
+
     def _execute(self, out_np) -> None:
         committed = np.nonzero(out_np.n_committed)[0]
-        if self.logger is not None and len(committed):
-            t_j = time.monotonic()
-            rows, slots, vids = [], [], []
-            for g in committed:
-                base = int(out_np.exec_base[g])
-                for o in range(int(out_np.n_committed[g])):
-                    rows.append(g)
-                    slots.append(base + o)
-                    vids.append(int(out_np.exec_vid[g, o]))
-            self.logger.log_decisions(
-                np.array(rows, np.int32), np.array(slots, np.int32),
-                np.array(vids, np.int32),
-            )
-            DelayProfiler.update_count("t_journal", time.monotonic() - t_j)
         if len(committed):
             self.row_activity[committed] = time.time()
         tr = self.tracer
@@ -3502,18 +3538,15 @@ class PaxosManager:
                     tr.note(
                         key, "decide", name=self.row_name.get(int(g)),
                         node=self.my_id, row=int(g), slot=base + o,
-                        vid=vid, ballot=bal_g,
+                        vid=vid, ballot=bal_g, tick=self._tick_no,
                         force=tc is not None, **self._tc_detail(tc),
                     )
-        t_exec = time.monotonic()
-        missing = self._drain_pending_exec()
-        DelayProfiler.update_delay("app_execute", t_exec)
-        dt_exec = time.monotonic() - t_exec
-        DelayProfiler.update_count("t_app_execute", dt_exec)
-        if len(committed):
-            # per-phase latency distribution (SLO surface): the decided-
-            # slot execution leg of a tick, exported via /metrics + stats
-            self.metrics.observe("phase_execute_s", dt_exec)
+        # per-phase latency distribution (SLO surface): the decided-
+        # slot execution leg of a tick, recorded when something was
+        # decided, exported via /metrics + stats
+        with self._span("execute", cpu=False,
+                        record=bool(len(committed))):
+            missing = self._drain_pending_exec()
         if missing:
             self.forward_out.append(
                 (-1, "need_payloads", SyncDecisionsPacket(
@@ -3588,8 +3621,8 @@ class PaxosManager:
         1647-1734``): a deterministic app must eventually execute a decided
         request — giving up would silently skip a slot and diverge the
         RSM, so the only alternatives are retry or wedge.  Backoff grows
-        1ms -> 100ms; sustained failure surfaces loudly (DelayProfiler
-        counter at /stats + a periodic WARNING log line) instead of
+        1ms -> 100ms; sustained failure surfaces loudly (the
+        ``app_execute_retries`` counter + a periodic WARNING log line) instead of
         raising into the tick loop."""
         delay = 0.001
         attempt = 0
@@ -3600,7 +3633,7 @@ class PaxosManager:
             except Exception:
                 pass
             attempt += 1
-            DelayProfiler.update_count("app_execute_retries")
+            self.metrics.count("app_execute_retries")
             if attempt in (10, 100) or attempt % 1000 == 0:
                 self.log.warning(
                     "app refusing to execute %s#%s (%d attempts); "
@@ -3645,6 +3678,21 @@ class PaxosManager:
         for rid in list(itertools.islice(self.response_cache, n)):
             del self.response_cache[rid]
 
+    def _answer(self, request_id: int, response: Optional[str]) -> None:
+        """Entry replica, lock held: queue the waiting client callback
+        (fired after the lock) and record how long the commit took on
+        this node's own clocks — manager ticks and seconds from the
+        request's first put to now."""
+        ent = self.outstanding.pop(request_id)
+        if ent is None:
+            return
+        cb, t_put, tick_put = ent
+        self._fired_callbacks.append((cb, request_id, response))
+        mx = self.metrics
+        mx.observe("commit_ticks", self._tick_no - tick_put,
+                   bounds=TICK_BOUNDS)
+        mx.observe("commit_entry_s", time.time() - t_put)
+
     def _execute_one(self, name: Optional[str], g: int, slot: int, vid: int) -> bool:
         if vid == 0:  # NOOP hole-filler: nothing to execute
             return True
@@ -3669,11 +3717,7 @@ class PaxosManager:
             for request_id, entry, value in decode_batch(payload):
                 if request_id in rc:
                     if entry == my:
-                        cb = self.outstanding.pop(request_id)
-                        if cb is not None:
-                            self._fired_callbacks.append(
-                                (cb, request_id, rc[request_id][1])
-                            )
+                        self._answer(request_id, rc[request_id][1])
                     continue
                 req = SlimRequest(nm, request_id, value)
                 self._app_execute_retrying(req, do_not_reply=(entry != my))
@@ -3683,6 +3727,7 @@ class PaxosManager:
                 if tr_on or tc is not None:
                     self.tracer.note(request_id, "execute", name=nm,
                                      node=my, row=g, slot=slot, batch=True,
+                                     tick=self._tick_no,
                                      force=tc is not None,
                                      **self._tc_detail(tc))
                 self.inflight.pop(request_id, None)
@@ -3690,11 +3735,7 @@ class PaxosManager:
                 if self._cacheable(req):
                     rc[request_id] = (now, response, nm)
                 if entry == my:
-                    cb = self.outstanding.pop(request_id)
-                    if cb is not None:
-                        self._fired_callbacks.append(
-                            (cb, request_id, response)
-                        )
+                    self._answer(request_id, response)
             if len(rc) > self.response_cache_cap:
                 self._evict_response_cache()
             self._slots_since_ckpt += 1
@@ -3707,11 +3748,8 @@ class PaxosManager:
             # EVERY replica — deterministic, since all see the same
             # decided sequence and the same earlier execution.
             if entry == self.my_id:
-                cb = self.outstanding.pop(request_id)
-                if cb is not None:
-                    self._fired_callbacks.append(
-                        (cb, request_id, self.response_cache[request_id][1])
-                    )
+                self._answer(request_id,
+                             self.response_cache[request_id][1])
             self.retained[vid] = (g, slot)
             return True
         req = SlimRequest(
@@ -3723,7 +3761,7 @@ class PaxosManager:
         if self.tracer.enabled or tc is not None:
             self.tracer.note(request_id, "execute", name=name or "",
                              node=self.my_id, row=g, slot=slot,
-                             stop=bool(vid & STOP_BIT),
+                             stop=bool(vid & STOP_BIT), tick=self._tick_no,
                              force=tc is not None, **self._tc_detail(tc))
         self._slots_since_ckpt += 1
         self.inflight.pop(request_id, None)
@@ -3743,9 +3781,7 @@ class PaxosManager:
             except Exception:
                 pass  # reconfiguration-layer hook must not wedge execution
         if entry == self.my_id:
-            cb = self.outstanding.pop(request_id)
-            if cb is not None:
-                self._fired_callbacks.append((cb, request_id, response))
+            self._answer(request_id, response)
         self.retained[vid] = (g, slot)  # keep for straggler pulls
         return True
 
@@ -4085,10 +4121,8 @@ class PaxosManager:
             # snapshots must capture a COMPLETED tick (engine arrays and
             # host cursors from the same cycle)
             self._await_step_locked()
-        t_ck = time.monotonic()
-        self._checkpoint_now_inner()
-        DelayProfiler.update_delay("checkpoint", t_ck)
-        DelayProfiler.update_count("t_checkpoint", time.monotonic() - t_ck)
+        with self._span("checkpoint", cpu=False):
+            self._checkpoint_now_inner()
 
     def _checkpoint_now_inner(self) -> None:
         # _np returns donation-safe PRIVATE host arrays (never zero-copy

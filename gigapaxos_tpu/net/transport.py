@@ -21,6 +21,8 @@ import struct
 import threading
 from typing import Callable, Dict, Optional, Tuple
 
+from ..obs.spans import span
+
 MAGIC = 0x47503270  # "GP2p"
 _HDR = struct.Struct(">II")  # magic, payload length
 MAX_PAYLOAD = 256 * 1024 * 1024
@@ -55,8 +57,19 @@ class MessageTransport:
         ssl_context=None,
         ssl_server_context=None,
         ssl_client_context=None,
+        metrics=None,
     ):
         self.my_id = int(my_id)
+        # the owning node's MetricsRegistry (None: a transport outside
+        # any node counts nothing): the latest-wins frames' accounting
+        # and the blob.send span
+        self.metrics = metrics
+        if metrics is not None:
+            # registered at 0: a snapshot shows a counter that never
+            # fired apart from a program that has no such counter
+            for key in ("blob_frames_superseded", "blob_frames_written",
+                        "blob_bytes_written"):
+                metrics.count(key, 0)
         self.node_config = node_config
         self.handler = handler
         if listen_host is None or listen_port is None:
@@ -204,7 +217,11 @@ class MessageTransport:
             waiting = (addr, slot) in self._latest
             self._latest[(addr, slot)] = payload
         if waiting:
-            return True  # its marker is already queued
+            # its marker is already queued; the frame it replaced never
+            # leaves (the blob is the one latest-wins slot in use)
+            if self.metrics is not None:
+                self.metrics.count("blob_frames_superseded")
+            return True
         return self.send_to_address(addr, _Latest(slot))
 
     def send_to_address(self, addr: Tuple[str, int], payload: bytes,
@@ -251,7 +268,8 @@ class MessageTransport:
         writer: Optional[asyncio.StreamWriter] = None
         while not self._stopped:
             payload = await q.get()
-            if isinstance(payload, _Latest):
+            latest = isinstance(payload, _Latest)
+            if latest:
                 with self._latest_lock:
                     payload = self._latest.pop((addr, payload.slot))
             for _attempt in (0, 1):
@@ -266,8 +284,18 @@ class MessageTransport:
                         await asyncio.sleep(0.05)
                         continue
                 try:
-                    writer.write(_HDR.pack(MAGIC, len(payload)) + payload)
-                    await writer.drain()
+                    if latest:
+                        # the span crosses awaits, so it carries no CPU
+                        # time: other tasks of this loop run meanwhile
+                        with span(self.metrics, "blob.send",
+                                  node=self.my_id):
+                            await self._write(writer, payload)
+                        if self.metrics is not None:  # it has left
+                            self.metrics.count("blob_frames_written")
+                            self.metrics.count("blob_bytes_written",
+                                               len(payload))
+                    else:
+                        await self._write(writer, payload)
                     self.n_sent += 1
                     break
                 except (ConnectionError, OSError):
@@ -276,3 +304,8 @@ class MessageTransport:
                     except Exception:
                         pass
                     writer = None  # retry once with a fresh connection
+
+    @staticmethod
+    async def _write(writer, payload: bytes) -> None:
+        writer.write(_HDR.pack(MAGIC, len(payload)) + payload)
+        await writer.drain()

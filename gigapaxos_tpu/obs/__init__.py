@@ -16,6 +16,9 @@ package recreates all three for the array-world runtime:
   per-step engine aggregates (decisions, preempts, coordinator flips,
   frontier stalls, blob bytes), complementing the EWMA-only
   :class:`~gigapaxos_tpu.utils.profiler.DelayProfiler`.
+* :mod:`.spans` — the one span primitive: a timed phase observed into
+  the node's registry (``phase_<phase>_s``, with the thread's CPU time
+  beside it) and annotated onto the profiler's clock (``gp.<phase>``).
 * :mod:`.device` — the device-plane observatory: the retrace/compile
   sentinel every ``make_step`` instance is wrapped in, group-heat
   analysis for the on-device activity accumulator, AOT cost
@@ -39,3 +42,4 @@ from .device import (  # noqa: F401
 from .gplog import configure, get_logger, node_logger, warn_once  # noqa: F401
 from .metrics import Histogram, MetricsRegistry  # noqa: F401
 from .reqtrace import RequestTracer, trace_enabled  # noqa: F401
+from .spans import span  # noqa: F401

@@ -10,15 +10,14 @@ p99 stall that actually wedges a tick loop).
 
 One registry per node (``PaxosManager.metrics``), surfaced three ways:
 
-* the ``stats`` admin op (``server._on_admin``) returns ``snapshot()``
-  alongside the DelayProfiler dump;
+* the ``stats`` admin op (``server._on_admin``) returns ``snapshot()``;
 * ``GET /metrics`` on the active-replica HTTP front renders ``render()``
   (Prometheus-style text lines);
 * the server's periodic INFO stats line logs ``summary_line()``.
 
-Updates are per-STEP aggregates, not per-request — a few numpy
-reductions per tick against an engine step that costs ~1ms, so the
-registry stays on unconditionally (like DelayProfiler); only per-request
+Updates are per-STEP aggregates and per-phase spans (``obs/spans.py``),
+not per-request — a few numpy reductions and some thirty observations
+per tick — so the registry stays on unconditionally; only per-request
 tracing is gated.
 """
 
@@ -33,6 +32,9 @@ from typing import Dict, List, Optional, Sequence, Tuple
 DEFAULT_BOUNDS = (
     0.0001, 0.0003, 0.001, 0.003, 0.01, 0.03, 0.1, 0.3, 1.0, 3.0, 10.0,
 )
+# bounds for histograms whose unit is TICKS (commit_ticks, blob_age_ticks):
+# the protocol's 5-6 legs sit in the middle
+TICK_BOUNDS = (1, 2, 3, 4, 6, 8, 12, 16, 24, 32, 64)
 
 
 class Histogram:

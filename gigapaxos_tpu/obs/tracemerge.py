@@ -53,9 +53,14 @@ def merge_node_dumps(dumps: Dict) -> List[Dict]:
     the shape ``RequestTracer.export`` / the ``trace_dump`` admin op
     produce.  Returns one dict per request/trace, ordered by first
     event: ``{"trace_id", "keys", "events": [{t, node, event, detail}],
-    "hops": [{phase, dt_s, from_node, to_node, from_event, to_event}],
-    "total_s"}``.  Per-hop ``dt_s`` is clamped non-negative (clock
-    skew)."""
+    "hops": [{phase, dt_s, dticks, from_node, to_node, from_event,
+    to_event}], "total_s"}``.  Per-hop ``dt_s`` is clamped non-negative
+    (clock skew).  ``dticks`` counts ticks on the clock of the hop's
+    ``to_node`` (every node has its own tick counter): that node's tick
+    at the hop's end less its tick at its previous event of this trace,
+    None where either is unknown.  So the ``dticks`` of the hops that
+    end on the entry replica sum to its ``commit_ticks`` for the
+    request (propose to respond-flush)."""
     # pass 1: learn each key's trace id (any node's event may carry it)
     key_tid: Dict[str, int] = {}
     for by_key in dumps.values():
@@ -86,13 +91,21 @@ def merge_node_dumps(dumps: Dict) -> List[Dict]:
         # cross-host skew is absorbed by the dt clamp below
         evs.sort(key=lambda e: (e["t"], e["detail"].get("hop", 0)))
         hops = []
-        for a, b in zip(evs, evs[1:]):
+        last_tick: Dict = {}  # node -> its tick at its last ticked event
+        for a, b in zip([None] + evs, evs):
+            tick, seen = b["detail"].get("tick"), last_tick.get(b["node"])
+            if tick is not None:
+                last_tick[b["node"]] = tick
+            if a is None:
+                continue
             pair = (a["event"], b["event"])
             hops.append({
                 "phase": PHASE_LABELS.get(
                     pair, f"{a['event']}->{b['event']}"
                 ),
                 "dt_s": max(0.0, b["t"] - a["t"]),
+                "dticks": None if tick is None or seen is None
+                else tick - seen,
                 "from_node": a["node"], "to_node": b["node"],
                 "from_event": a["event"], "to_event": b["event"],
             })
@@ -118,6 +131,18 @@ def phase_totals(trace: Dict) -> Dict[str, float]:
     acc: Dict[str, float] = {}
     for hop in trace["hops"]:
         acc[hop["phase"]] = acc.get(hop["phase"], 0.0) + hop["dt_s"]
+    return acc
+
+
+def node_ticks(trace: Dict) -> Dict:
+    """Per node, the ticks it ran between its first and its last event
+    of this trace that carry one: the ``dticks`` of the hops ending
+    there, summed.  The entry replica's is the request's
+    ``commit_ticks``."""
+    acc: Dict = {}
+    for hop in trace["hops"]:
+        if hop.get("dticks") is not None:
+            acc[hop["to_node"]] = acc.get(hop["to_node"], 0) + hop["dticks"]
     return acc
 
 
@@ -188,19 +213,28 @@ def render_trace(trace: Dict) -> str:
         head += f" tid=0x{trace['trace_id']:x}"
     lines = [f"{head} total={trace['total_s'] * 1e3:.3f}ms"]
     t0 = evs[0]["t"]
-    for e in evs:
+    # the hop that ENDS at event i says how many ticks that event's node
+    # ran since its previous event of this trace
+    dticks = [None] + [h.get("dticks") for h in trace["hops"]]
+    for e, dt in zip(evs, dticks):
         tail = " ".join(
             f"{k}={v}" for k, v in e["detail"].items() if k != "tid"
         )
         lines.append(
             f"  +{(e['t'] - t0) * 1e3:9.3f}ms {e['event']:<14}"
-            f" @ node {e['node']}" + (f" [{tail}]" if tail else "")
+            f" @ node {e['node']}" + (f" +{dt}t" if dt is not None else "")
+            + (f" [{tail}]" if tail else "")
         )
     tot = phase_totals(trace)
     if tot:
         lines.append("  phases: " + " ".join(
             f"{ph}={dt * 1e3:.3f}ms"
             for ph, dt in sorted(tot.items(), key=lambda kv: -kv[1])
+        ))
+    ticks = node_ticks(trace)
+    if ticks:
+        lines.append("  ticks: " + " ".join(
+            f"node{n}={k}" for n, k in sorted(ticks.items(), key=str)
         ))
     return "\n".join(lines)
 

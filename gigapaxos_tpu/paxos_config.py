@@ -94,8 +94,8 @@ class PC(FlagEnum):
     RESPONSE_CACHE_TTL_S = 60.0          # exactly-once retransmit cache TTL
 
     # ---- observability (obs/: gplog + reqtrace + metrics + flight) ----
-    # cadence of the server's INFO stats line (engine counters +
-    # DelayProfiler); the line only renders when gp.server is at INFO
+    # cadence of the server's INFO stats line (the registry's
+    # summary); the line only renders when gp.server is at INFO
     # (GP_LOG=server:INFO), so the default deployment pays a level check
     STATS_LOG_PERIOD_S = 10.0
     # black-box flight recorder (obs/flight.py; always on): ring sizes

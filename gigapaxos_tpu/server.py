@@ -36,14 +36,14 @@ from .net.codec import (
     encode_json,
     extract_trace,
 )
-from .obs.metrics import collect_process_gauges
+from .obs.metrics import TICK_BOUNDS, collect_process_gauges
 from .net.node_config import NodeConfig
 from .net.transport import MessageTransport
 from .obs import gplog
+from .obs.spans import span
 from .ops.engine import EngineConfig
 from .paxos_config import PC
 from .utils.config import Config
-from .utils.profiler import DelayProfiler
 
 
 class PaxosServer:
@@ -77,6 +77,7 @@ class PaxosServer:
         self.transport = MessageTransport(
             my_id, node_config, self._on_message,
             ssl_server_context=ssl_server, ssl_client_context=ssl_client,
+            metrics=self.manager.metrics,
         )
         # per-plane port split (PaxosConfig.java:219-224): when
         # CLIENT_SSL_MODE is set, clients speak to a SEPARATE listener at
@@ -106,6 +107,18 @@ class PaxosServer:
         self._batching = Config.get_bool(PC.BATCHING_ENABLED)
         self._batch_sleep_s = Config.get_float(PC.BATCH_SLEEP_MS) / 1000.0
         self._peer_blobs: Dict[int, np.ndarray] = {}  # packed [N] vectors
+        # per peer, under _blob_lock: the sender's tick in the blob held,
+        # whether a dispatch has folded it yet, and the sender's tick in
+        # the blob the last dispatch folded (the blob accounting:
+        # blob_frames_replaced_unread, ticks_without_fresh_blob,
+        # blob_age_ticks)
+        self._peer_blob_tick: Dict[int, int] = {}
+        self._peer_blob_unread: Dict[int, bool] = {}
+        self._peer_blob_folded: Dict[int, int] = {}
+        for key in ("blob_frames_received", "blob_frames_replaced_unread",
+                    "ticks", "ticks_noprog", "ticks_inflight_noprog",
+                    "ticks_without_fresh_blob"):
+            self.manager.metrics.count(key, 0)  # present from the start
         self._blob_lock = threading.Lock()
         self._my_blob_vec: Optional[np.ndarray] = None
         self._my_blob_state = None
@@ -167,7 +180,7 @@ class PaxosServer:
         self.CHUNK_PACE_S = 0.002  # per-chunk stagger: lets other frames in
         self._xfer_seq = 0
         self._schema_skew_warned: set = set()
-        # periodic INFO stats line (the reference's DelayProfiler dump
+        # periodic INFO stats line (the reference's stats-dump
         # cadence): emitted only when gp.server is at INFO, so a default
         # deployment stays silent and pays one level check per period
         self._stats_period_s = Config.get_float(PC.STATS_LOG_PERIOD_S)
@@ -270,12 +283,20 @@ class PaxosServer:
                 )
             return
         if kind == "D":
-            sender, _tick, vec = decode_blob_vec(payload, self.cfg)
-            with self._blob_lock:
-                self._peer_blobs[sender] = vec
-                self._blob_dirty = True
-            self.fd.heard_from(sender)
             m = self.manager
+            mx = m.metrics
+            with span(mx, "blob.decode", node=self.my_id):
+                sender, tick, vec = decode_blob_vec(payload, self.cfg)
+            with self._blob_lock:
+                replaced = self._peer_blob_unread.get(sender, False)
+                self._peer_blobs[sender] = vec
+                self._peer_blob_tick[sender] = tick
+                self._peer_blob_unread[sender] = True
+                self._blob_dirty = True
+            mx.count("blob_frames_received")
+            if replaced:
+                mx.count("blob_frames_replaced_unread")
+            self.fd.heard_from(sender)
             # with idle-skip below, peers only publish blobs when THEY
             # have work — so a new blob is itself a new-work signal and
             # wakes the loop, unless this node has been stalled in flight
@@ -415,11 +436,15 @@ class PaxosServer:
             if not self._resp_buf:
                 return
             bufs, self._resp_buf = self._resp_buf, {}
-        t0 = time.monotonic()
+        with self.manager._span("flush", cpu=False):
+            self._ship_responses(bufs)
+
+    def _ship_responses(self, bufs) -> None:
         tr = self.tracer
         m = self.manager
         mx = m.metrics
         tcm = m.trace_ctx
+        tick = m._tick_no
         n_items = 0
         for reply, items, binary in bufs.values():
             for item in items:
@@ -433,7 +458,7 @@ class PaxosServer:
                     tr.note(
                         rid, "respond-flush",
                         name=item.get("name"), node=self.my_id,
-                        error=item.get("error"),
+                        error=item.get("error"), tick=tick,
                         force=tc is not None, **m._tc_detail(tc),
                     )
             n_items += len(items)
@@ -452,18 +477,10 @@ class PaxosServer:
         if n_items:
             mx.count("responses_flushed", n_items)
             mx.count("response_frames_sent", len(bufs))
-        dt = time.monotonic() - t0
-        DelayProfiler.update_count("t_flush", dt)
-        mx.observe("phase_flush_s", dt)
 
     def _on_client_request(self, body: Dict, reply) -> None:
-        t0 = time.monotonic()
-        try:
+        with span(self.manager.metrics, "ingress", node=self.my_id):
             self._on_client_request_inner(body, reply)
-        finally:
-            DelayProfiler.update_count(
-                "t_ingress", time.monotonic() - t0
-            )
 
     def _maybe_local_read(self, name: str, value: str, request_id,
                           cb) -> bool:
@@ -495,7 +512,10 @@ class PaxosServer:
         peel off to their own paths; everything else amortizes the
         lock/clock per frame).  ``reqs``: [(request_id, name, value,
         stop)] — traced items are 5-tuples carrying (tid, origin, hop)."""
-        t0 = time.monotonic()
+        with span(self.manager.metrics, "ingress", node=self.my_id):
+            self._admit_client_items(reqs, reply, binary)
+
+    def _admit_client_items(self, reqs, reply, binary: bool) -> None:
         m = self.manager
         tr = self.tracer
         overloaded = m.overloaded()
@@ -546,9 +566,6 @@ class PaxosServer:
                         "request_id": rid, "response": None,
                         "name": name, "error": "exhausted",
                     }, binary)
-        dt = time.monotonic() - t0
-        DelayProfiler.update_count("t_ingress", dt)
-        m.metrics.observe("phase_ingress_s", dt)
 
     def _on_client_request_inner(self, body: Dict, reply) -> None:
         request_id = int(body["request_id"])
@@ -627,7 +644,7 @@ class PaxosServer:
                 "op": op, "name": body["name"], "ok": bool(ok),
             }))
         elif op == "stats":
-            # engine counters + DelayProfiler snapshot over the admin
+            # engine counters over the admin
             # plane — the deployed analog of the AR HTTP /stats page,
             # reachable wherever the binary protocol is.  Layered roles
             # (ReconfiguratorServer) ride their own plane stats along
@@ -670,8 +687,6 @@ class PaxosServer:
                 # paused-on-disk (+ the spill store's segment/compaction
                 # internals) — the density campaign's operator view
                 "residency": residency,
-                "profiler": DelayProfiler.get_snapshot(),
-                "profiler_line": DelayProfiler.get_stats(),
             }
             # transaction plane (txn/app.py): live lock/staged/record
             # counts — a stuck in-doubt transaction shows up here long
@@ -791,6 +806,7 @@ class PaxosServer:
                 )
             sleep = interval - dt
             if sleep > 0:
+                t_idle = time.perf_counter()
                 if backlog:
                     # batch aging is KICK-PROOF under backlog: a kick per
                     # arriving frame would collapse the window back to
@@ -802,6 +818,9 @@ class PaxosServer:
                     time.sleep(sleep)
                 else:
                     self._kick.wait(sleep)
+                self.manager.metrics.observe(
+                    "tick_idle_s", time.perf_counter() - t_idle
+                )
             self._kick.clear()
 
     def _should_tick(self) -> bool:
@@ -824,21 +843,37 @@ class PaxosServer:
         """Host housekeeping between engine ticks: FD pings, layered
         protocol-task timers, callback GC.  Runs at the loop cadence so
         liveness machinery never depends on consensus traffic."""
+        t0 = time.perf_counter()
         self._publish_pending()  # a staged tick must never strand idle
         self._drain_self_msgs()
+        # no span here: an idle node runs this a hundred times a second,
+        # and a span's two thread-CPU-clock reads cost 11-150 us on the
+        # chip's host (PERF.md, PR 25) — with six nodes on one interpreter
+        # lock that was 5 % of the saturated cell's throughput
         self._maybe_ping()
         self.manager.outstanding.gc()
         self._layer_tick()
         self._flush_responses()
+        self.manager.metrics.observe(
+            "idle_cycle_s", time.perf_counter() - t0
+        )
 
     def tick_once(self) -> None:
-        t0 = time.monotonic()
+        """One engine tick.  Its envelope is a histogram only
+        (``tick_s``): the spans inside tile it, and none encloses it
+        (obs/spans.py says why)."""
+        t0 = time.perf_counter()
         try:
             self._tick_once_inner()
         finally:
-            DelayProfiler.update_count("t_tick", time.monotonic() - t0)
+            self.manager.metrics.observe(
+                "tick_s", time.perf_counter() - t0
+            )
 
-    def _tick_once_inner(self) -> None:
+    def _gather(self):
+        """This dispatch's inputs: the [R, N] stack of packed blobs (my
+        own cached row, each peer's newest), who was heard, and the
+        failure detector's election mask."""
         R = self.cfg.n_replicas
         # packed exchange: peer frames already ARE the [N] vectors, my
         # previous tick's publish vector is cached, and the whole [R, N]
@@ -854,9 +889,23 @@ class PaxosServer:
                 self.manager.publish_snapshot()
             )
         my_vec = self._my_blob_vec
+        mx = self.manager.metrics
         with self._blob_lock:
             peer_vecs = dict(self._peer_blobs)
             self._blob_dirty = False
+            # a blob is fresh when its sender's tick is past the one the
+            # last dispatch folded from that sender
+            ages = [
+                tick - self._peer_blob_folded.get(r, tick - 1)
+                for r, tick in self._peer_blob_tick.items()
+                if self._peer_blob_unread.get(r)
+            ]
+            self._peer_blob_folded.update(self._peer_blob_tick)
+            self._peer_blob_unread.clear()
+        for age in ages:  # 1 = this node saw every tick of that peer
+            mx.observe("blob_age_ticks", age, bounds=TICK_BOUNDS)
+        if not ages:
+            mx.count("ticks_without_fresh_blob")
         rows, heard = [], np.zeros(R, bool)
         for r in range(R):
             if r == self.my_id:
@@ -873,7 +922,12 @@ class PaxosServer:
             self.manager._np("member_mask"),
             R,
         )
+        return gathered, heard, want
+
+    def _tick_once_inner(self) -> None:
         m = self.manager
+        with m._span("tick.gather"):
+            gathered, heard, want = self._gather()
         if self._pipeline:
             # double-buffered dispatch: fire step N and, while the device
             # computes it, do tick N-1's host-side codec/publish work
@@ -883,22 +937,17 @@ class PaxosServer:
             # NOTHING in the overlap window may call a manager op that
             # waits on step completion (same thread completes the step).
             pend = m.step_dispatch(gathered, heard, want)
-            t_overlap = time.monotonic()
+            t_overlap = time.perf_counter()
             self._publish_pending()
             self._flush_responses()
-            overlap_s = time.monotonic() - t_overlap
+            overlap_s = time.perf_counter() - t_overlap
             blob_vec, blob_state, delta = m.step_complete(pend)
-            mx = m.metrics
-            mx.observe("pipeline_overlap_s", overlap_s)
-            step_s = m.last_engine_step_s
-            mx.gauge(
-                "pipeline_overlap_ratio",
-                min(1.0, overlap_s / step_s) if step_s > 0 else 0.0,
-            )
+            m.metrics.observe("pipeline_overlap_s", overlap_s)
         else:
             blob_vec, blob_state, delta = m.tick_host(gathered, heard, want)
-        self._finish_tick(blob_vec, blob_state, delta)
-        self._drain_self_msgs()
+        with m._span("tick.finish"):
+            self._finish_tick(blob_vec, blob_state, delta)
+            self._drain_self_msgs()
         if not self._pipeline or not m.has_backlog():
             # serial mode publishes its own tick immediately (the
             # pre-pipeline behavior, exactly); pipelined mode does too
@@ -906,11 +955,9 @@ class PaxosServer:
             # frames ship in the NEXT dispatch's overlap window, which
             # under backlog begins immediately
             self._publish_pending()
-
-        t_layer = time.monotonic()
-        self._maybe_ping()
-        self._layer_tick()
-        DelayProfiler.update_count("t_layer", time.monotonic() - t_layer)
+        with m._span("layer"):
+            self._maybe_ping()
+            self._layer_tick()
         self._flush_responses()  # callbacks fired by this tick's execution
 
     def _finish_tick(self, blob_vec, blob_state, delta) -> None:
@@ -925,11 +972,12 @@ class PaxosServer:
         # refreshed HERE (post-engine): gates blob-kick wakeups and the
         # idle skip until the next tick updates it
         self._in_flight = m.engine_work_in_flight()
-        DelayProfiler.update_count("n_ticks")
+        mx = m.metrics
+        mx.count("ticks")
         if not progressed:
-            DelayProfiler.update_count("n_ticks_noprog")
+            mx.count("ticks_noprog")
             if self._in_flight:
-                DelayProfiler.update_count("n_ticks_inflight_noprog")
+                mx.count("ticks_inflight_noprog")
         # publish gating decided NOW (at the tick that produced the
         # frames): publishing from a tick that neither progressed nor has
         # work in flight would re-trigger peers' blob-driven ticks and
@@ -974,39 +1022,41 @@ class PaxosServer:
             return
         peers = [r for r in self.node_config.get_node_ids()
                  if r != self.my_id]
-        m = self.manager
-        t_pub = time.monotonic()
-        if pub["blob_vec"] is not None:
-            self._last_publish = time.monotonic()
-            blob_frame = encode_blob_vec(
-                self.my_id, pub["tick"], pub["blob_vec"]
-            )
-            mx = m.metrics
-            mx.gauge("blob_frame_bytes", len(blob_frame))
-            mx.count("blob_bytes_sent", len(blob_frame) * len(peers))
-            mx.count("blob_frames_sent", len(peers))
-            for r in peers:
-                self.transport.send_latest_to_id(r, "blob", blob_frame)
-        if pub["delta"] is not None:
-            frame = encode_json("payloads", self.my_id, pub["delta"])
-            for r in peers:
-                self.transport.send_to_id(r, frame)
-        dt_pub = time.monotonic() - t_pub
-        DelayProfiler.update_count("t_publish", dt_pub)
-        m.metrics.observe("phase_publish_s", dt_pub)
-        for dst, k, body in pub["fwd"]:
-            frame = encode_json(k, self.my_id, body)
-            # send_frame_to_id streams oversize frames (a multi-MB
-            # state_reply must not monopolize the link)
-            if dst == -1:
+        with self.manager._span("publish"):
+            if pub["blob_vec"] is not None:
+                self._last_publish = time.monotonic()
+                blob_frame = encode_blob_vec(
+                    self.my_id, pub["tick"], pub["blob_vec"]
+                )
+                mx = self.manager.metrics
+                mx.gauge("blob_frame_bytes", len(blob_frame))
+                # counted as QUEUED: a frame superseded before it left is
+                # in these two (blob_frames_written / blob_bytes_written
+                # count what left, net/transport.py)
+                mx.count("blob_bytes_sent", len(blob_frame) * len(peers))
+                mx.count("blob_frames_sent", len(peers))
                 for r in peers:
-                    self.send_frame_to_id(r, frame)
-            elif dst == self.my_id:
-                # deferred: a self-destined host message may replace
-                # engine state and must not run in the overlap window
-                self._self_msgs.append((k, body))
-            else:
-                self.send_frame_to_id(dst, frame)
+                    self.transport.send_latest_to_id(r, "blob", blob_frame)
+            if pub["delta"] is not None:
+                frame = encode_json("payloads", self.my_id, pub["delta"])
+                for r in peers:
+                    self.transport.send_to_id(r, frame)
+        if not pub["fwd"]:
+            return
+        with self.manager._span("forward"):
+            for dst, k, body in pub["fwd"]:
+                frame = encode_json(k, self.my_id, body)
+                # send_frame_to_id streams oversize frames (a multi-MB
+                # state_reply must not monopolize the link)
+                if dst == -1:
+                    for r in peers:
+                        self.send_frame_to_id(r, frame)
+                elif dst == self.my_id:
+                    # deferred: a self-destined host message may replace
+                    # engine state and must not run in the overlap window
+                    self._self_msgs.append((k, body))
+                else:
+                    self.send_frame_to_id(dst, frame)
 
     def _heat_stats(self) -> Dict:
         """Group-heat block for the ``stats`` op — degrades to an empty
@@ -1018,13 +1068,20 @@ class PaxosServer:
             return {}
 
     def _maybe_stats_line(self) -> None:
-        """Periodic INFO stats line (engine counters + DelayProfiler) —
-        one `isEnabledFor` check per period when INFO is off."""
+        """Periodic INFO stats line (the registry's summary) — one
+        `isEnabledFor` check per period when INFO is off."""
         now = time.monotonic()
         elapsed = now - self._last_stats_line
         if elapsed < self._stats_period_s:
             return
         self._last_stats_line = now
+        # the loop's only work outside a tick or an idle cycle, and it
+        # holds a device sync (the heat pull): spanned, so that a stall
+        # of the loop that is in no tick can be told from one in here
+        with self.manager._span("stats", cpu=False):
+            self._stats_cycle(elapsed)
+
+    def _stats_cycle(self, elapsed: float) -> None:
         # per-process resource gauges (RSS / fds / GC / threads) refresh
         # at the stats cadence: slow leaks across a multi-hour soak (or a
         # SERVING_WORKERS parent) become visible on /metrics and the
@@ -1057,9 +1114,8 @@ class PaxosServer:
             )
             self.log.info(
                 "stats tick=%d dispatch_rate=%.1f/s engine_compiles=%d "
-                "engine_retraces=%d %s %s", self._tick, rate, n_comp,
+                "engine_retraces=%d %s", self._tick, rate, n_comp,
                 n_retr, self.manager.metrics.summary_line(),
-                DelayProfiler.get_stats(),
             )
 
     def _maybe_ping(self) -> None:

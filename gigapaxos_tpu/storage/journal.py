@@ -134,8 +134,17 @@ class Journal:
         directory: str,
         max_file_size: int = 64 * 1024 * 1024,  # MAX_LOG_FILE_SIZE analog
         sync: bool = False,                      # FLUSH/SYNC flag analog
+        metrics=None,
     ):
         self.dir = directory
+        # the node's MetricsRegistry, or None: journal_writes (one per
+        # write made: a native append, a writev, or the Python path's
+        # buffered write + flush), journal_bytes_written, journal_fsyncs
+        self.metrics = metrics
+        if metrics is not None:
+            for key in ("journal_writes", "journal_bytes_written",
+                        "journal_fsyncs"):
+                metrics.count(key, 0)  # present from the start
         self.max_file_size = max_file_size
         self.sync = sync
         # append/position/gc are serialized: the async checkpoint
@@ -201,6 +210,7 @@ class Journal:
             )
             if wrote >= 0:
                 self._pos += int(wrote)
+                self._count_write(int(wrote))
                 if self._pos >= self.max_file_size:
                     self._rotate()
                     return (self._cur_idx, 0)
@@ -218,10 +228,19 @@ class Journal:
         self._pos += len(hdr) + len(payload)
         if self.sync:
             os.fsync(self._fh.fileno())
+        self._count_write(len(hdr) + len(payload))
         if self._pos >= self.max_file_size:
             self._rotate()
             return (self._cur_idx, 0)
         return (self._cur_idx, self._pos)
+
+    def _count_write(self, n_bytes: int) -> None:
+        mx = self.metrics
+        if mx is not None:
+            mx.count("journal_writes")
+            mx.count("journal_bytes_written", n_bytes)
+            if self.sync:
+                mx.count("journal_fsyncs")
 
     def _repair_to_pos(self) -> None:
         """Truncate torn partial bytes back to the last good block
@@ -297,6 +316,7 @@ class Journal:
                 lib = None  # retired by _repair_to_pos
                 continue
             self._pos += int(wrote)
+            self._count_write(int(wrote))
             if self._pos >= self.max_file_size:
                 self._rotate()
             pos = self.position

@@ -78,10 +78,12 @@ class PaxosLogger:
         directory: str,
         sync: bool = False,
         max_file_size: int = 64 * 1024 * 1024,
+        metrics=None,
     ):
         self.node_id = node_id
         self.dir = directory
-        self.journal = Journal(directory, max_file_size=max_file_size, sync=sync)
+        self.journal = Journal(directory, max_file_size=max_file_size,
+                               sync=sync, metrics=metrics)
         # open group-commit batch (BatchedLogger analog): log_* calls
         # buffer here and leave in ONE writev/fsync at scope exit
         self._batch: Optional[List] = None

@@ -542,11 +542,12 @@ def main() -> int:
                 {"capacity_out": args.capacity_out, "label": label}
             ), flush=True)
         if args.in_process:
-            # per-segment attribution (this process hosts the nodes, so
-            # the global DelayProfiler aggregates all six tick loops)
-            from gigapaxos_tpu.utils.profiler import DelayProfiler
-
-            print("stats:", DelayProfiler.get_stats(), flush=True)
+            # per-segment attribution, node by node: each registry's
+            # counters and every span's count, mean and maximum
+            for n in nodes:
+                for srv in n.servers:
+                    print(f"stats node {srv.my_id}:",
+                          srv.manager.metrics.summary_line(), flush=True)
     finally:
         client.close()
         for n in nodes:

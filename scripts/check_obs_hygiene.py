@@ -18,7 +18,13 @@ Second pass (the inventory gate): every metric name registered in code
 appear in ``METRICS.md``, and every name documented there must exist in
 code.  Dynamically-labeled series (f-strings like
 ``probe_rtt_ms_active_{id}``) are documented with a ``*`` wildcard
-(``probe_rtt_ms_active_*``) and matched by their literal prefix.
+(``probe_rtt_ms_active_*``) and matched by their literal prefix.  A span
+(``span(registry, "step.dispatch", ...)`` of ``obs/spans.py``, or a
+node's ``self._span("step.dispatch")``) registers the histogram
+``phase_step_dispatch_s``, which needs its own row, and with CPU time
+(``cpu=True``; a ``_span`` without ``cpu=False``)
+``phase_step_dispatch_cpu_s``, which the one family row
+``phase_*_cpu_s`` documents.
 
 Third pass (the hot-path pull gate): ``_np("leaf")`` device pulls
 inside the tick/dispatch hot path — the functions named in
@@ -79,7 +85,8 @@ HOT_NP_ALLOW = {
         {"bal", "member_mask", "acc_slot", "acc_bal", "acc_vid"}
     ),
     ("server.py", "_should_tick"): frozenset({"bal", "member_mask"}),
-    ("server.py", "_tick_once_inner"): frozenset({"bal", "member_mask"}),
+    ("server.py", "_tick_once_inner"): frozenset(),
+    ("server.py", "_gather"): frozenset({"bal", "member_mask"}),
     # stats-cadence hook: the ONE sanctioned group-heat drain (runs at
     # STATS_LOG_PERIOD_S inside the tick loop, not per tick)
     ("server.py", "_maybe_stats_line"): frozenset({GROUP_HEAT_LEAF}),
@@ -116,17 +123,41 @@ def iter_violations(pkg_root: pathlib.Path) -> Iterator[Tuple[str, int, str]]:
                        "use gigapaxos_tpu.obs.gplog")
 
 
+def _span_names(node: ast.Call) -> Set[str]:
+    """The histograms a span call registers (obs/spans.py): the phase
+    is the second argument of ``span(...)``, the first of a node's
+    ``self._span(...)``, which takes CPU time too unless told not to."""
+    func = node.func
+    if isinstance(func, ast.Name) and func.id == "span":
+        arg = node.args[1] if len(node.args) > 1 else None
+        cpu = any(k.arg == "cpu" and isinstance(k.value, ast.Constant)
+                  and k.value.value is True for k in node.keywords)
+    elif isinstance(func, ast.Attribute) and func.attr == "_span":
+        arg = node.args[0] if node.args else None
+        cpu = not any(k.arg == "cpu" and isinstance(k.value, ast.Constant)
+                      and k.value.value is False for k in node.keywords)
+    else:
+        return set()
+    if not (isinstance(arg, ast.Constant) and isinstance(arg.value, str)):
+        return set()
+    stem = "phase_" + arg.value.replace(".", "_")
+    return {stem + "_s", stem + "_cpu_s"} if cpu else {stem + "_s"}
+
+
 def collect_metric_names(pkg_root: pathlib.Path) -> Tuple[Set[str], Set[str]]:
     """Scan registration sites: returns (literal names, f-string
     prefixes).  Only string-literal / f-string FIRST arguments to
     ``.count/.gauge/.observe`` count — a non-string first arg (e.g. the
-    sim checker's ``observe(i, …)``) is not a metric registration."""
+    sim checker's ``observe(i, …)``) is not a metric registration.
+    Span calls register their ``phase_*`` histograms."""
     literals: Set[str] = set()
     prefixes: Set[str] = set()
     for path in sorted(pkg_root.rglob("*.py")):
         tree = ast.parse(path.read_text(encoding="utf-8"),
                          filename=str(path))
         for node in ast.walk(tree):
+            if isinstance(node, ast.Call):
+                literals |= _span_names(node)
             if not (isinstance(node, ast.Call) and node.args
                     and isinstance(node.func, ast.Attribute)
                     and node.func.attr in METRIC_METHODS):
@@ -147,25 +178,34 @@ def collect_metric_names(pkg_root: pathlib.Path) -> Tuple[Set[str], Set[str]]:
     return literals, prefixes
 
 
-def parse_metrics_doc(doc_path: pathlib.Path) -> Tuple[Set[str], Set[str]]:
+def parse_metrics_doc(
+    doc_path: pathlib.Path,
+) -> Tuple[Set[str], Set[Tuple[str, str]]]:
     """Inventory rows in METRICS.md — the backticked name leading a
-    table row (``| `name` | …``): (exact names, wildcard prefixes — a
-    trailing ``*`` documents a dynamically-labeled family).  Backticked
-    words in prose are NOT inventory entries."""
+    table row (``| `name` | …``): (exact names, families — one ``*``
+    stands for the varying part, kept as (prefix, suffix):
+    ``probe_rtt_ms_active_*``, ``phase_*_cpu_s``).  Backticked words in
+    prose are NOT inventory entries."""
     exact: Set[str] = set()
-    wild: Set[str] = set()
+    wild: Set[Tuple[str, str]] = set()
     if not doc_path.exists():
         return exact, wild
     for line in doc_path.read_text().splitlines():
-        m = re.match(r"^\|\s*`([a-z0-9_]+\*?)`\s*\|", line)
+        m = re.match(r"^\|\s*`([a-z0-9_]+(?:\*[a-z0-9_]*)?)`\s*\|", line)
         if not m:
             continue
         name = m.group(1)
-        if name.endswith("*"):
-            wild.add(name[:-1])
+        if "*" in name:
+            wild.add(tuple(name.split("*")))
         else:
             exact.add(name)
     return exact, wild
+
+
+def _in_family(name: str, family: Tuple[str, str]) -> bool:
+    pre, suf = family
+    return len(name) > len(pre) + len(suf) and name.startswith(pre) \
+        and name.endswith(suf)
 
 
 def iter_inventory_violations(
@@ -178,12 +218,12 @@ def iter_inventory_violations(
     literals, prefixes = collect_metric_names(pkg_root)
     exact, wild = parse_metrics_doc(doc_path)
     for name in sorted(literals):
-        if name in exact or any(name.startswith(w) for w in wild):
+        if name in exact or any(_in_family(name, w) for w in wild):
             continue
         yield (f"metric {name!r} registered in code but absent from "
                f"{doc_path.name}")
     for pre in sorted(prefixes):
-        if pre in wild or pre in exact:
+        if (pre, "") in wild or pre in exact:
             continue
         yield (f"dynamic metric family {pre + '*'!r} registered in code "
                f"but absent from {doc_path.name}")
@@ -193,9 +233,10 @@ def iter_inventory_violations(
         yield (f"{doc_path.name} documents {name!r} but no code "
                "registers it")
     for w in sorted(wild):
-        if w in prefixes or any(n.startswith(w) for n in literals):
+        if (w[0] in prefixes and not w[1]) \
+                or any(_in_family(n, w) for n in literals):
             continue
-        yield (f"{doc_path.name} documents family {w + '*'!r} but no "
+        yield (f"{doc_path.name} documents family {'*'.join(w)!r} but no "
                "code registers it")
 
 

@@ -311,6 +311,46 @@ def test_capture_profile_writes_and_bounds_dump_dir(tmp_path):
     assert cap["seconds"] < 1.0
 
 
+def test_span_leaves_a_gp_event_in_the_host_plane(tmp_path):
+    """Inside a ``jax.profiler`` session the span's annotation lies in
+    the ``/host:CPU`` plane of the same ``.xplane.pb`` that holds the
+    device's operations, named ``gp.<phase>`` with node and tick as
+    stats (not as part of the name); outside a session nothing is
+    written and the histograms are observed all the same."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from gigapaxos_tpu.obs.metrics import MetricsRegistry
+    from gigapaxos_tpu.obs.spans import span
+
+    reg = MetricsRegistry(node=2)
+    with span(reg, "step.device_wait", cpu=True, node=2, tick=6):
+        pass                                   # no session: histogram only
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for tick in (7, 8):
+            with span(reg, "step.device_wait", cpu=True, node=2,
+                      tick=tick):
+                time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    assert reg.snapshot()["hists"]["phase_step_device_wait_s"]["count"] == 3
+    path = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                         "*.xplane.pb"))[0]
+    found = [
+        (e.name, dict(e.stats))
+        for plane in ProfileData.from_file(path).planes
+        if plane.name == "/host:CPU"
+        for line in plane.lines for e in line.events
+        if e.name.startswith("gp.")
+    ]
+    assert [n for n, _ in found] == ["gp.step.device_wait"] * 2
+    assert [st["tick"] for _, st in found] == [7, 8]
+    assert all(st["node"] == 2 for _, st in found)
+
+
 # ---- SLO gate ---------------------------------------------------------
 
 def test_slo_budget_parse_and_breach():

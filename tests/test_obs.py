@@ -7,8 +7,11 @@ import io
 import logging
 import subprocess
 import sys
+import threading
 import time
 from pathlib import Path
+
+import pytest
 
 from gigapaxos_tpu.obs import gplog
 from gigapaxos_tpu.obs.metrics import Histogram, MetricsRegistry
@@ -246,8 +249,12 @@ def test_stats_admin_roundtrip_and_unknown_op():
         assert isinstance(eng["mesh"]["shape"], dict)
         # blob publishing happened, so the wire-cost counters are live
         assert eng["counters"].get("blob_bytes_sent", 0) > 0
-        assert "profiler" in r and "counts" in r["profiler"]
-        assert r["profiler_line"].startswith("[")
+        # the tick accounts for itself through the same registry: its
+        # envelope, its spans and its counts ride the same stats op
+        assert {"tick_s", "phase_tick_gather_s", "phase_post_step_s",
+                "phase_step_device_wait_cpu_s"} <= set(eng["hists"])
+        assert eng["counters"].get("ticks", 0) >= 1
+        assert "profiler" not in r  # the process-global EWMA dump is gone
     finally:
         client.close()
         for s in servers:
@@ -450,3 +457,257 @@ def test_obs_hygiene_gate():
     )
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert "metric inventory" in proc.stdout
+
+
+# ---- the span primitive and what the tick counts ----------------------
+@pytest.mark.parametrize("cpu, record, expect", [
+    (True, True, {"phase_a_b_s", "phase_a_b_cpu_s"}),
+    (False, True, {"phase_a_b_s"}),
+    (True, False, set()),     # a phase recorded only when it had work
+])
+def test_span_observes_wall_and_cpu_into_the_named_histograms(
+        cpu, record, expect):
+    from gigapaxos_tpu.obs.spans import span
+
+    reg = MetricsRegistry(node=4)
+    with span(reg, "a.b", cpu=cpu, record=record, node=4, tick=9):
+        c_end = time.thread_time() + 0.02
+        while time.thread_time() < c_end:    # 20 ms on the CPU, however
+            pass                             # long that takes under load
+        time.sleep(0.03)                     # and 30 ms off it
+    hists = reg.snapshot()["hists"]
+    assert set(hists) == expect
+    if record:
+        wall = hists["phase_a_b_s"]
+        assert wall["count"] == 1 and 0.05 <= wall["sum"] < 1.0
+    if cpu and record:
+        # the spin is CPU time, the sleep is not: wall minus CPU is the
+        # time the thread was not running
+        assert 0.019 <= hists["phase_a_b_cpu_s"]["sum"] <= wall["sum"] - 0.025
+
+
+def test_span_without_a_registry_is_the_annotation_alone():
+    from gigapaxos_tpu.obs.spans import span
+
+    with span(None, "nowhere", node=0):
+        pass
+
+
+@pytest.fixture(scope="module")
+def ticked_cluster(tmp_path_factory):
+    """Three journaled servers on loopback, four sampled writes through a
+    NON-coordinator entry (so forwards are on the path), then the
+    nodes' registries, the entry's ticks and the merged request
+    timelines — one boot for the tests below."""
+    import os
+
+    from gigapaxos_tpu.clients import PaxosClientAsync
+    from gigapaxos_tpu.models import StatefulAdderApp
+    from gigapaxos_tpu.net.node_config import NodeConfig
+    from gigapaxos_tpu.obs import tracemerge
+    from gigapaxos_tpu.ops.engine import EngineConfig
+    from gigapaxos_tpu.server import PaxosServer
+    from gigapaxos_tpu.testing.ports import free_ports
+
+    old = os.environ.get("GP_TRACE_SAMPLE")
+    os.environ["GP_TRACE_SAMPLE"] = "1"
+    cfg = EngineConfig(n_groups=8, window=8, req_lanes=4, n_replicas=3)
+    ports = free_ports(3)
+    nc = NodeConfig({i: ("127.0.0.1", p) for i, p in enumerate(ports)})
+    logs = tmp_path_factory.mktemp("ticked")
+    servers = [PaxosServer(i, nc, StatefulAdderApp(), cfg,
+                           log_dir=str(logs / f"n{i}"),
+                           tick_interval=0.01) for i in range(3)]
+    for s in servers:
+        s.start()
+    client = PaxosClientAsync([("127.0.0.1", p) for p in ports])
+    try:
+        assert client.create_paxos_instance("tk0", [0, 1, 2], timeout=30)
+        m0 = servers[0].manager
+        entry = (m0.coordinator_of_row(m0.names["tk0"]) + 1) % 3
+        for k in range(4):
+            assert client.send_request_sync(
+                "tk0", "1", timeout=30, server=entry) == str(k + 1)
+        deadline = time.time() + 30
+        while time.time() < deadline and not all(
+                s.manager.app.totals.get("tk0") == 4 for s in servers):
+            time.sleep(0.05)
+        time.sleep(0.3)  # a few more ticks: the last flush is recorded
+        snaps = [s.manager.metrics.snapshot() for s in servers]
+        traces = tracemerge.merge_node_dumps(
+            {s.my_id: s.tracer.export() for s in servers})
+        yield {"entry": entry, "snaps": snaps, "traces": traces}
+    finally:
+        client.close()
+        for s in servers:
+            s.stop()
+        if old is None:
+            os.environ.pop("GP_TRACE_SAMPLE", None)
+        else:
+            os.environ["GP_TRACE_SAMPLE"] = old
+
+
+def test_commit_ticks_equal_the_dticks_of_the_entrys_hops(ticked_cluster):
+    """Per write: at least one tick from registration to answer, and the
+    ticks the entry replica counted (``commit_ticks``) are the ``dticks``
+    of the merged timeline's hops that end on the entry, summed."""
+    entry = ticked_cluster["entry"]
+    traces = ticked_cluster["traces"]
+    assert len(traces) == 4
+    per_trace = []
+    for tr in traces:
+        hops = [h for h in tr["hops"] if h["to_node"] == entry
+                and h["dticks"] is not None]
+        assert hops and all(h["dticks"] >= 0 for h in hops)
+        assert [e["node"] for e in tr["events"]
+                if e["event"] == "respond-flush"] == [entry]
+        per_trace.append(sum(h["dticks"] for h in hops))
+        # every leg the issue names carries the tick it happened in
+        for e in tr["events"]:
+            if e["event"] in ("propose", "forward-in", "decide",
+                              "execute", "respond-flush"):
+                assert "tick" in e["detail"], e
+    assert min(per_trace) >= 1
+    hist = ticked_cluster["snaps"][entry]["hists"]["commit_ticks"]
+    assert hist["count"] == 4 and hist["min"] >= 1
+    assert hist["sum"] == sum(per_trace)
+    assert ticked_cluster["snaps"][entry]["hists"]["commit_entry_s"][
+        "count"] == 4
+    from gigapaxos_tpu.obs import tracemerge
+
+    # what scripts/gp_trace.py prints: ticks per leg, and per node
+    assert tracemerge.node_ticks(traces[0])[entry] == per_trace[0]
+    text = tracemerge.render_trace(traces[0])
+    assert "ticks: " in text and f"node{entry}={per_trace[0]}" in text
+    assert any(" @ node %d +" % entry in line
+               for line in text.splitlines())
+
+
+TICK_THREAD_TOP_LEVEL = (
+    "tick_gather", "step_lock_wait", "step_ring_build", "step_dispatch",
+    "publish", "forward", "flush", "step_device_wait",
+    "post_step_lock_wait", "post_step", "callbacks", "tick_finish",
+    "layer",
+)
+
+
+def test_top_level_spans_tile_the_tick(ticked_cluster):
+    """After N ticks the tick thread's top-level spans sum to the ticks'
+    own time within a stated margin: at least 80 % of ``tick_s`` on the
+    CPU's millisecond ticks (the chip's ticks are a hundred times longer
+    and the share is a benchmark metric), and no more than ``tick_s`` +
+    ``idle_cycle_s`` (publish and flush also run in idle cycles; spans
+    do not overlap: 2 % for clock reads)."""
+    for snap in ticked_cluster["snaps"]:
+        hists = snap["hists"]
+        assert hists["tick_s"]["count"] == snap["counters"]["ticks"] >= 4
+        busy = hists["tick_s"]["sum"] + hists["idle_cycle_s"]["sum"]
+        spanned = sum(hists[f"phase_{k}_s"]["sum"]
+                      for k in TICK_THREAD_TOP_LEVEL
+                      if f"phase_{k}_s" in hists)
+        assert 0.8 * hists["tick_s"]["sum"] <= spanned <= 1.02 * busy, (
+            spanned, hists["tick_s"]["sum"], busy)
+        # children lie inside their parent
+        assert hists["phase_journal_s"]["sum"] \
+            + hists["phase_execute_s"]["sum"] \
+            <= hists["phase_post_step_s"]["sum"]
+        # CPU time never exceeds wall time, span by span
+        for k in TICK_THREAD_TOP_LEVEL:
+            if f"phase_{k}_cpu_s" in hists:
+                assert hists[f"phase_{k}_cpu_s"]["sum"] \
+                    <= hists[f"phase_{k}_s"]["sum"] * 1.05 + 1e-3
+
+
+def test_blob_accounting_adds_up(ticked_cluster):
+    """Per node: what was written is what was queued less what was
+    superseded (less at most one frame per peer still waiting); what a
+    dispatch folded plus what was replaced unread is what arrived; a
+    tick either folded a fresh blob or is counted as having none."""
+    snaps = ticked_cluster["snaps"]
+    for snap in snaps:
+        c, h = snap["counters"], snap["hists"]
+        written = c.get("blob_frames_written", 0)
+        superseded = c.get("blob_frames_superseded", 0)
+        assert 0 <= c["blob_frames_sent"] - superseded - written <= 2
+        assert c["blob_bytes_written"] == written * snap["gauges"][
+            "blob_frame_bytes"]
+        folded = h["blob_age_ticks"]["count"]
+        replaced = c.get("blob_frames_replaced_unread", 0)
+        assert 0 <= c["blob_frames_received"] - folded - replaced <= 2
+        assert h["blob_age_ticks"]["min"] >= 1
+        assert 0 <= c.get("ticks_without_fresh_blob", 0) <= c["ticks"]
+        assert c["journal_writes"] >= 4 and c["journal_bytes_written"] > 0
+        assert c.get("ticks_inflight_noprog", 0) \
+            <= c.get("ticks_noprog", 0) <= c["ticks"]
+    assert sum(s["counters"]["blob_frames_received"] for s in snaps) \
+        == sum(s["counters"].get("blob_frames_written", 0) for s in snaps)
+
+
+def test_superseded_blob_is_counted_and_never_as_written():
+    """A second blob queued before the first left supersedes it:
+    ``blob_frames_superseded`` grows, and ``blob_bytes_written`` counts
+    only the frames that reached the socket."""
+    from gigapaxos_tpu.net.node_config import NodeConfig
+    from gigapaxos_tpu.net.transport import MessageTransport
+
+    nc = NodeConfig({0: ("127.0.0.1", 0), 1: ("127.0.0.1", 0)})
+    reg = MetricsRegistry(node=0)
+    release, got, last = threading.Event(), [], threading.Event()
+    n_frames, size = 12, 4 * 1024 * 1024  # outgrows the socket buffers
+
+    def slow_reader(payload, peer, reply):
+        release.wait(30)
+        got.append(payload[:4])
+        if payload[:4] == (n_frames - 1).to_bytes(4, "big"):
+            last.set()
+
+    sender = MessageTransport(0, nc, lambda *a: None, metrics=reg,
+                              listen_host="127.0.0.1", listen_port=0)
+    reader = MessageTransport(1, nc, slow_reader,
+                              listen_host="127.0.0.1", listen_port=0)
+    try:
+        for nid, t in ((0, sender), (1, reader)):
+            t.start()
+            nc.add(nid, "127.0.0.1", t.listen_port)
+        for i in range(n_frames):
+            assert sender.send_latest_to_id(
+                1, "blob", i.to_bytes(4, "big") + bytes(size))
+            time.sleep(0.005)
+        release.set()
+        assert last.wait(30), "the newest frame never arrived"
+        deadline = time.time() + 10
+        while reg.get("blob_frames_written") < len(got) \
+                and time.time() < deadline:
+            time.sleep(0.01)
+        c = reg.snapshot()["counters"]
+        assert c["blob_frames_superseded"] >= 1
+        assert c["blob_frames_written"] == len(got) \
+            == n_frames - c["blob_frames_superseded"]
+        assert c["blob_bytes_written"] == len(got) * (size + 4)
+        assert reg.snapshot()["hists"]["phase_blob_send_s"]["count"] \
+            == len(got)
+    finally:
+        release.set()
+        sender.stop()
+        reader.stop()
+
+
+def test_journal_counts_where_the_write_is_made(tmp_path):
+    from gigapaxos_tpu.storage.journal import BlockType, Journal
+
+    reg = MetricsRegistry(node=0)
+    j = Journal(str(tmp_path), sync=True, metrics=reg)
+    try:
+        j.append(BlockType.PAYLOADS, b"x" * 100)
+        j.append_many([(BlockType.PAYLOADS, b"y" * 10, 0),
+                       (BlockType.KILL, b"z" * 8, 2)])
+        c = reg.snapshot()["counters"]
+        assert c["journal_bytes_written"] == j.position[1] \
+            == 3 * 17 + 118          # three 17-byte headers
+        # one write for the append; the batch is one writev natively,
+        # one write per block on the pure-Python path
+        assert c["journal_writes"] == (2 if j._native is not None else 3)
+        assert c["journal_fsyncs"] == c["journal_writes"]
+    finally:
+        j.close()
+
