@@ -16,19 +16,28 @@ and hand-rolled byte layouts on one NIO channel,
   fixed-layout frame from a different schema must be dropped by kind,
   never parsed misaligned — a mixed-version node fails loudly instead
   of feeding misparsed ballots into consensus.
+* ``d`` frames — row deltas of a ``D`` blob against the vector the
+  sender last wrote to THIS connection (the base, named by its tick):
+  sender + tick + base tick + row count, the int32 row indices, then each
+  leaf's changed rows in ``Blob._fields`` order.  The receiver patches
+  them into the vector it holds of that sender, which then equals the
+  sender's publish vector at ``tick`` bit for bit.  A connection's first
+  blob, and any blob whose delta would be no smaller, is a ``D`` frame.
 """
 
 from __future__ import annotations
 
+import itertools
 import json
 import struct
-from typing import Dict, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
 from ..ops.engine import Blob, EngineConfig, _leaf_shapes, blob_vec_len
 
 _BHDR = struct.Struct(">cIQ")  # kind, sender, tick
+_DHDR = struct.Struct(">cIQQI")  # kind, sender, tick, base tick, rows
 
 # cross-node trace context (Dapper-style, obs/reqtrace.py): an OPTIONAL
 # ``"tc": [trace_id, origin_node, hop]`` field on J-frame request bodies
@@ -119,6 +128,88 @@ def decode_blob_vec(
             f"{_BHDR.size + 4 * n} (peer blob-schema/config mismatch)"
         )
     return sender, tick, np.frombuffer(payload, np.int32, offset=_BHDR.size)
+
+
+def _row_blocks(vec: np.ndarray, cfg: EngineConfig,
+                rows: Optional[int] = None) -> List[np.ndarray]:
+    """A packed vector of ``rows`` engine rows (all of them by default)
+    as its runs of same-shaped leaves, each a ``[leaves, rows, words]``
+    view in wire order — the four ``[G]`` leaves are one block, the four
+    ``[G, W]`` leaves another, so a pass over the rows is two numpy calls
+    and not eight (each gives up the interpreter lock and queues for it
+    again; six tick threads and the loops contend for it)."""
+    rows = cfg.n_groups if rows is None else rows
+    shapes = [s for _name, s in _leaf_shapes(Blob._fields, cfg)]
+    blocks, off = [], 0
+    for shape, run in itertools.groupby(shapes):
+        leaves, words = len(list(run)), int(np.prod(shape[1:]))
+        n = leaves * rows * words
+        blocks.append(vec[off:off + n].reshape(leaves, rows, words))
+        off += n
+    return blocks
+
+
+def encode_blob_frame(
+    sender: int, cfg: EngineConfig,
+    item: Tuple[int, np.ndarray],
+    base: Optional[Tuple[int, np.ndarray]],
+) -> Tuple[bytes, Optional[int]]:
+    """The blob frame for one peer connection, encoded when its turn to
+    be written comes: ``item`` is the newest (tick, publish vector),
+    ``base`` the pair last written and drained on that connection (None
+    on a new one).  -> (frame, rows in the delta; None for a full ``D``
+    frame).  The rule is what the two vectors show, nothing else: a
+    delta wherever it is smaller than the whole vector."""
+    tick, vec = item
+    if base is not None:
+        differs = vec != base[1]
+        changed = np.zeros(cfg.n_groups, bool)
+        for block in _row_blocks(differs, cfg):
+            changed |= block.any(axis=(0, 2))
+        rows = np.flatnonzero(changed).astype(np.int32)
+        n = int(rows.size)
+        if _DHDR.size + 4 * n * (1 + vec.size // cfg.n_groups) \
+                < _BHDR.size + 4 * vec.size:
+            parts = [_DHDR.pack(b"d", sender, tick, base[0], n),
+                     rows.tobytes()]
+            parts += [block[:, rows].tobytes()
+                      for block in _row_blocks(vec, cfg)]
+            return b"".join(parts), n
+    return encode_blob_vec(sender, tick, vec), None
+
+
+def decode_blob_delta(
+    payload: bytes, cfg: EngineConfig
+) -> Tuple[int, int, int, np.ndarray, List[np.ndarray]]:
+    """-> (sender, tick, base tick, row indices [n], the rows' new
+    values as :func:`_row_blocks` of n rows).  Same refusals as
+    :func:`decode_blob_vec`: a frame of another schema or shape is never
+    parsed misaligned, and a row index outside the engine raises."""
+    kind, sender, tick, base_tick, n = _DHDR.unpack_from(payload, 0)
+    if kind != b"d":
+        raise ValueError(
+            f"blob delta schema {kind!r} != expected b'd' "
+            "(mixed-version peer; refusing to parse)"
+        )
+    row_words = blob_vec_len(cfg) // cfg.n_groups
+    if len(payload) != _DHDR.size + 4 * n * (1 + row_words):
+        raise ValueError(
+            f"blob delta size {len(payload)} != expected "
+            f"{_DHDR.size + 4 * n * (1 + row_words)} for {n} rows "
+            "(peer blob-schema/config mismatch)"
+        )
+    rows = np.frombuffer(payload, np.int32, count=n, offset=_DHDR.size)
+    if n and not (0 <= rows.min() and rows.max() < cfg.n_groups):
+        raise ValueError("blob delta names a row outside the engine")
+    body = np.frombuffer(payload, np.int32, offset=_DHDR.size + 4 * n)
+    return sender, tick, base_tick, rows, _row_blocks(body, cfg, n)
+
+
+def patch_blob_vec(vec: np.ndarray, rows: np.ndarray,
+                   blocks: List[np.ndarray], cfg: EngineConfig) -> None:
+    """Write a decoded delta's rows into the held (mutable) vector."""
+    for held, new in zip(_row_blocks(vec, cfg), blocks):
+        held[:, rows] = new
 
 
 def decode_blob(payload: bytes, cfg: EngineConfig) -> Tuple[int, int, Blob]:

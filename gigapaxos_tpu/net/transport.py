@@ -19,8 +19,9 @@ from __future__ import annotations
 import asyncio
 import struct
 import threading
-from typing import Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
+from ..obs.metrics import ROW_BOUNDS
 from ..obs.spans import span
 
 MAGIC = 0x47503270  # "GP2p"
@@ -32,13 +33,21 @@ CONGESTION_LIMIT = 4096  # per-peer queued messages before drops (isCongested)
 
 class _Latest:
     """Queue marker for :meth:`MessageTransport.send_latest_to_id`: the
-    sender takes whatever frame the slot holds when its turn comes."""
+    sender encodes whatever item the slot holds when its turn comes."""
 
     __slots__ = ("slot",)
 
     def __init__(self, slot: str):
         self.slot = slot
 
+
+# latest_encoder(item, base) -> (frame, rows): called in ``_sender`` when a
+# latest-wins slot's turn comes.  ``item`` is whatever
+# ``send_latest_to_id`` was last given for that peer; ``base`` is the item
+# last written AND drained on the connection that is open now, None on a
+# new connection or after ``forget_latest_base``.  ``rows`` is None for a
+# frame that stands alone, else the rows of a delta against ``base``.
+LatestEncoder = Callable[[Any, Any], Tuple[bytes, Optional[int]]]
 
 # handler(payload: bytes, sender: (host, port), reply) -> None
 # ``reply(bytes)`` queues a frame back on the SAME connection (needed for
@@ -58,18 +67,23 @@ class MessageTransport:
         ssl_server_context=None,
         ssl_client_context=None,
         metrics=None,
+        latest_encoder: Optional[LatestEncoder] = None,
     ):
         self.my_id = int(my_id)
         # the owning node's MetricsRegistry (None: a transport outside
         # any node counts nothing): the latest-wins frames' accounting
-        # and the blob.send span
+        # and the blob.encode / blob.send spans
         self.metrics = metrics
         if metrics is not None:
             # registered at 0: a snapshot shows a counter that never
             # fired apart from a program that has no such counter
             for key in ("blob_frames_superseded", "blob_frames_written",
-                        "blob_bytes_written"):
+                        "blob_bytes_written", "blob_bytes_sent",
+                        "blob_frames_delta", "blob_frames_full"):
                 metrics.count(key, 0)
+        # frames of a latest-wins slot are encoded at the sender's turn,
+        # against what THIS connection last carried (see LatestEncoder)
+        self._latest_encoder = latest_encoder
         self.node_config = node_config
         self.handler = handler
         if listen_host is None or listen_port is None:
@@ -88,9 +102,12 @@ class MessageTransport:
         self._writers: Dict[Tuple[str, int], asyncio.StreamWriter] = {}
         self._queues: Dict[Tuple[str, int], asyncio.Queue] = {}
         self._senders: Dict[Tuple[str, int], asyncio.Task] = {}
-        # (addr, slot) -> newest unsent frame of a latest-wins slot
-        self._latest: Dict[Tuple[Tuple[str, int], str], bytes] = {}
+        # (addr, slot) -> newest unsent item of a latest-wins slot
+        self._latest: Dict[Tuple[Tuple[str, int], str], Any] = {}
         self._latest_lock = threading.Lock()
+        # peers that said they do not hold the base of what they were
+        # sent: their next latest-wins frame stands alone
+        self._base_forgotten: set = set()
         self._server: Optional[asyncio.AbstractServer] = None
         self._started = threading.Event()
         self._stopped = False
@@ -199,23 +216,27 @@ class MessageTransport:
             self.node_config.get_node_address(node_id), payload
         )
 
-    def send_latest_to_id(self, node_id: int, slot: str,
-                          payload: bytes) -> bool:
-        """Queue a frame that SUPERSEDES a still-unsent frame of the same
+    def send_latest_to_id(self, node_id: int, slot: str, item) -> bool:
+        """Queue an item that SUPERSEDES a still-unsent item of the same
         ``slot`` to that node — for frames that carry a whole state, where
         only the newest matters (the consensus blob: the engine is built
-        for dropped and stale deliveries).  CONGESTION_LIMIT counts
-        frames, and a blob frame is 17.8 MB at the deployed 65,536 rows:
-        a tick loop that outruns a peer's reader would otherwise queue
-        gigabytes a minute.  At most one frame per (peer, slot) waits,
-        whatever the peer's pace."""
+        for dropped and stale deliveries).  The item is turned into bytes
+        by ``latest_encoder`` when its turn to be written comes, against
+        what that connection last carried, so a peer that keeps up gets
+        what changed and a new connection gets the whole; the caller must
+        not write to the item afterwards.  CONGESTION_LIMIT counts
+        frames, and a whole blob is 17.8 MB at the deployed 65,536 rows:
+        at most one item per (peer, slot) waits, whatever the peer's
+        pace."""
+        if self._latest_encoder is None:
+            raise ValueError("this transport was given no latest_encoder")
         if node_id not in self.node_config:
             return False
         addr = self.node_config.get_node_address(node_id)
         addr = (addr[0], int(addr[1]))
         with self._latest_lock:
             waiting = (addr, slot) in self._latest
-            self._latest[(addr, slot)] = payload
+            self._latest[(addr, slot)] = item
         if waiting:
             # its marker is already queued; the frame it replaced never
             # leaves (the blob is the one latest-wins slot in use)
@@ -223,6 +244,13 @@ class MessageTransport:
                 self.metrics.count("blob_frames_superseded")
             return True
         return self.send_to_address(addr, _Latest(slot))
+
+    def forget_latest_base(self, node_id: int) -> None:
+        """That peer does not hold the base its deltas name (it said so):
+        its next latest-wins frame is encoded against nothing."""
+        if node_id in self.node_config:
+            addr = self.node_config.get_node_address(node_id)
+            self._base_forgotten.add((addr[0], int(addr[1])))
 
     def send_to_address(self, addr: Tuple[str, int], payload: bytes,
                         delay: float = 0.0) -> bool:
@@ -266,14 +294,19 @@ class MessageTransport:
     async def _sender(self, addr: Tuple[str, int], q: asyncio.Queue) -> None:
         """Per-peer writer with auto-reconnect (pending-writes analog)."""
         writer: Optional[asyncio.StreamWriter] = None
+        # slot -> the item last written and drained on `writer`: what the
+        # peer's reader holds once it has read this connection that far
+        bases: Dict[str, Any] = {}
         while not self._stopped:
             payload = await q.get()
-            latest = isinstance(payload, _Latest)
-            if latest:
+            item = None
+            if isinstance(payload, _Latest):
+                slot = payload.slot
                 with self._latest_lock:
-                    payload = self._latest.pop((addr, payload.slot))
+                    item = self._latest.pop((addr, slot))
             for _attempt in (0, 1):
                 if writer is None:
+                    bases.clear()
                     try:
                         _r, writer = await asyncio.open_connection(
                             addr[0], addr[1], ssl=self._ssl_client
@@ -284,16 +317,9 @@ class MessageTransport:
                         await asyncio.sleep(0.05)
                         continue
                 try:
-                    if latest:
-                        # the span crosses awaits, so it carries no CPU
-                        # time: other tasks of this loop run meanwhile
-                        with span(self.metrics, "blob.send",
-                                  node=self.my_id):
-                            await self._write(writer, payload)
-                        if self.metrics is not None:  # it has left
-                            self.metrics.count("blob_frames_written")
-                            self.metrics.count("blob_bytes_written",
-                                               len(payload))
+                    if item is not None:
+                        await self._write_latest(writer, addr, slot, item,
+                                                 bases)
                     else:
                         await self._write(writer, payload)
                     self.n_sent += 1
@@ -304,6 +330,35 @@ class MessageTransport:
                     except Exception:
                         pass
                     writer = None  # retry once with a fresh connection
+
+    async def _write_latest(self, writer, addr, slot: str, item,
+                            bases: Dict[str, Any]) -> None:
+        """Encode a latest-wins item against this connection's base, write
+        and drain it; only then is it the base of the next."""
+        # until the drain returns the peer may hold either: no base
+        base = bases.pop(slot, None)
+        if addr in self._base_forgotten:
+            self._base_forgotten.discard(addr)
+            base = None
+        with span(self.metrics, "blob.encode", node=self.my_id):
+            frame, rows = self._latest_encoder(item, base)
+        # the span crosses awaits, so it carries no CPU time: other tasks
+        # of this loop run meanwhile
+        with span(self.metrics, "blob.send", node=self.my_id):
+            await self._write(writer, frame)
+        bases[slot] = item
+        mx = self.metrics
+        if mx is None:
+            return
+        mx.count("blob_frames_written")  # it has left
+        mx.count("blob_bytes_written", len(frame))
+        mx.count("blob_bytes_sent", len(frame))
+        mx.gauge("blob_frame_bytes", len(frame))
+        if rows is None:
+            mx.count("blob_frames_full")
+        else:
+            mx.count("blob_frames_delta")
+            mx.observe("blob_delta_rows", rows, bounds=ROW_BOUNDS)
 
     @staticmethod
     async def _write(writer, payload: bytes) -> None:

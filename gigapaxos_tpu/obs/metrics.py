@@ -35,6 +35,9 @@ DEFAULT_BOUNDS = (
 # bounds for histograms whose unit is TICKS (commit_ticks, blob_age_ticks):
 # the protocol's 5-6 legs sit in the middle
 TICK_BOUNDS = (1, 2, 3, 4, 6, 8, 12, 16, 24, 32, 64)
+# bounds for histograms whose unit is engine ROWS (blob_delta_rows): none,
+# one, and powers of four up to the deployed 65,536
+ROW_BOUNDS = (0, 1, 4, 16, 64, 256, 1024, 4096, 16384, 65536)
 
 
 class Histogram:

@@ -17,6 +17,7 @@ transport).  Each server runs:
 
 from __future__ import annotations
 
+import functools
 import logging
 import threading
 import time
@@ -29,19 +30,21 @@ from .failure_detection import FailureDetector
 from .manager import PaxosManager, execute_uncoordinated
 from .net import hot_codec
 from .net.codec import (
+    decode_blob_delta,
     decode_blob_vec,
     decode_json,
     decode_kind,
-    encode_blob_vec,
+    encode_blob_frame,
     encode_json,
     extract_trace,
+    patch_blob_vec,
 )
 from .obs.metrics import TICK_BOUNDS, collect_process_gauges
 from .net.node_config import NodeConfig
 from .net.transport import MessageTransport
 from .obs import gplog
 from .obs.spans import span
-from .ops.engine import EngineConfig
+from .ops.engine import EngineConfig, blob_vec_len
 from .paxos_config import PC
 from .utils.config import Config
 
@@ -78,6 +81,10 @@ class PaxosServer:
             my_id, node_config, self._on_message,
             ssl_server_context=ssl_server, ssl_client_context=ssl_client,
             metrics=self.manager.metrics,
+            # a blob is encoded when its turn to be written comes, as the
+            # rows that differ from what that connection last carried
+            latest_encoder=functools.partial(
+                encode_blob_frame, self.my_id, cfg),
         )
         # per-plane port split (PaxosConfig.java:219-224): when
         # CLIENT_SSL_MODE is set, clients speak to a SEPARATE listener at
@@ -106,7 +113,22 @@ class PaxosServer:
         # trade the reference's sleep tuning makes
         self._batching = Config.get_bool(PC.BATCHING_ENABLED)
         self._batch_sleep_s = Config.get_float(PC.BATCH_SLEEP_MS) / 1000.0
-        self._peer_blobs: Dict[int, np.ndarray] = {}  # packed [N] vectors
+        # packed [N] vectors, one per peer and MUTABLE: a full frame
+        # replaces a peer's, a delta frame patches rows into it, and
+        # _gather copies it, all three under _blob_lock — a reader never
+        # sees a row mixed from two ticks
+        self._peer_blobs: Dict[int, np.ndarray] = {}
+        # when each peer was last asked for a full frame (base mismatch)
+        self._resync_asked: Dict[int, float] = {}
+        # the [R, N] stack a dispatch uploads, two of them taken in turn:
+        # a tick's step (and its upload) has completed before the tick
+        # after next gathers into the same one.  Kept, not made anew each
+        # tick: a fresh 53 MB at the deployed 65,536 rows is 13,000 page
+        # faults, 55 ms on the chip's host (PERF.md, PR 26)
+        self._gather_bufs = [
+            np.empty((cfg.n_replicas, blob_vec_len(cfg)), np.int32)
+            for _ in range(2)
+        ]
         # per peer, under _blob_lock: the sender's tick in the blob held,
         # whether a dispatch has folded it yet, and the sender's tick in
         # the blob the last dispatch folded (the blob accounting:
@@ -116,8 +138,8 @@ class PaxosServer:
         self._peer_blob_unread: Dict[int, bool] = {}
         self._peer_blob_folded: Dict[int, int] = {}
         for key in ("blob_frames_received", "blob_frames_replaced_unread",
-                    "ticks", "ticks_noprog", "ticks_inflight_noprog",
-                    "ticks_without_fresh_blob"):
+                    "blob_base_mismatch", "ticks", "ticks_noprog",
+                    "ticks_inflight_noprog", "ticks_without_fresh_blob"):
             self.manager.metrics.count(key, 0)  # present from the start
         self._blob_lock = threading.Lock()
         self._my_blob_vec: Optional[np.ndarray] = None
@@ -267,7 +289,7 @@ class PaxosServer:
         if kind == "R":  # binary client request batch (hot path)
             self._on_binary_requests(payload, reply)
             return
-        if kind not in ("D", "J"):
+        if kind not in ("D", "d", "J"):
             # frame from a DIFFERENT schema (pre-tag "B", pre-compact "C",
             # or anything newer): parsing a fixed-layout blob misaligned
             # would feed garbage ballots into consensus, so drop it LOUDLY
@@ -278,32 +300,13 @@ class PaxosServer:
                 self._schema_skew_warned.add(kind)
                 self.log.warning(
                     "dropping frame of unrecognized schema %r (this node "
-                    "speaks 'D'/'J'; a mixed-version peer must be upgraded)",
+                    "speaks 'D'/'d'/'J'; a mixed-version peer must be "
+                    "upgraded)",
                     kind,
                 )
             return
-        if kind == "D":
-            m = self.manager
-            mx = m.metrics
-            with span(mx, "blob.decode", node=self.my_id):
-                sender, tick, vec = decode_blob_vec(payload, self.cfg)
-            with self._blob_lock:
-                replaced = self._peer_blob_unread.get(sender, False)
-                self._peer_blobs[sender] = vec
-                self._peer_blob_tick[sender] = tick
-                self._peer_blob_unread[sender] = True
-                self._blob_dirty = True
-            mx.count("blob_frames_received")
-            if replaced:
-                mx.count("blob_frames_replaced_unread")
-            self.fd.heard_from(sender)
-            # with idle-skip below, peers only publish blobs when THEY
-            # have work — so a new blob is itself a new-work signal and
-            # wakes the loop, unless this node has been stalled in flight
-            # for a long time (wedged minority: fall back to the timer
-            # instead of busy-spinning at the peer's pace)
-            if m._tick_no - m.last_progress_tick < self.STALL_TICKS:
-                self._kick.set()
+        if kind != "J":
+            self._on_blob(kind, payload)
             return
         k, sender, body = decode_json(payload)
         if sender >= 0:
@@ -313,6 +316,58 @@ class PaxosServer:
             # every non-ping J frame is (or may carry) new work: requests,
             # forwards, payload gossip, epoch-plane control.  Control
             # traffic is low-rate, so the over-approximation is cheap.
+            self._kick.set()
+
+    def _on_blob(self, kind: str, payload: bytes) -> None:
+        """A peer's blob: a full ``D`` frame replaces the vector held of
+        that sender, a ``d`` frame patches the rows it names into it —
+        if the vector held IS the base the frame names.  Either way the
+        copy then equals the sender's publish vector at the frame's tick."""
+        m = self.manager
+        mx = m.metrics
+        with span(mx, "blob.decode", node=self.my_id):
+            if kind == "D":
+                sender, tick, vec = decode_blob_vec(payload, self.cfg)
+                vec, rows = vec.copy(), None  # the frame is read-only
+            else:
+                sender, tick, base_tick, rows, blocks = decode_blob_delta(
+                    payload, self.cfg)
+            with self._blob_lock:
+                if rows is None:
+                    self._peer_blobs[sender] = vec
+                    accepted = True
+                else:
+                    accepted = self._peer_blob_tick.get(sender) == base_tick
+                    if accepted:
+                        patch_blob_vec(self._peer_blobs[sender], rows,
+                                       blocks, self.cfg)
+                if accepted:
+                    replaced = self._peer_blob_unread.get(sender, False)
+                    self._peer_blob_tick[sender] = tick
+                    self._peer_blob_unread[sender] = True
+                    self._blob_dirty = True
+        self.fd.heard_from(sender)
+        if not accepted:
+            # no base to patch (a frame was lost, or came over a
+            # connection the sender has since replaced): what is held
+            # stays, and the sender is told to send the whole vector
+            mx.count("blob_base_mismatch")
+            now = time.monotonic()
+            if now - self._resync_asked.get(sender, 0.0) \
+                    > self.IDLE_REPUBLISH_S:
+                self._resync_asked[sender] = now
+                self.transport.send_to_id(
+                    sender, encode_json("blob_resync", self.my_id, {}))
+            return
+        mx.count("blob_frames_received")
+        if replaced:
+            mx.count("blob_frames_replaced_unread")
+        # with idle-skip below, peers only publish blobs when THEY
+        # have work — so a new blob is itself a new-work signal and
+        # wakes the loop, unless this node has been stalled in flight
+        # for a long time (wedged minority: fall back to the timer
+        # instead of busy-spinning at the peer's pace)
+        if m._tick_no - m.last_progress_tick < self.STALL_TICKS:
             self._kick.set()
 
     def _on_json(self, k: str, sender: int, body: Dict, reply) -> bool:
@@ -326,6 +381,9 @@ class PaxosServer:
             self._on_chunk(sender, body, reply)
         elif k == "fd_ping":
             pass  # hearing it is the point (any traffic counts as alive)
+        elif k == "blob_resync":
+            # that peer dropped a delta frame for want of its base
+            self.transport.forget_latest_base(sender)
         elif k == "client_request":
             # singleton frames only arrive at low rate (the client
             # aggregates under load), so the immediate flush is cheap
@@ -890,8 +948,16 @@ class PaxosServer:
             )
         my_vec = self._my_blob_vec
         mx = self.manager.metrics
+        self._gather_bufs.reverse()
+        gathered = self._gather_bufs[0]
+        heard = np.zeros(R, bool)
         with self._blob_lock:
-            peer_vecs = dict(self._peer_blobs)
+            # copied under the lock: a delta frame patches these vectors
+            # in place, and a row must come whole from one tick
+            for r, vec in self._peer_blobs.items():
+                if r != self.my_id and 0 <= r < R:
+                    gathered[r] = vec
+                    heard[r] = True
             self._blob_dirty = False
             # a blob is fresh when its sender's tick is past the one the
             # last dispatch folded from that sender
@@ -906,17 +972,9 @@ class PaxosServer:
             mx.observe("blob_age_ticks", age, bounds=TICK_BOUNDS)
         if not ages:
             mx.count("ticks_without_fresh_blob")
-        rows, heard = [], np.zeros(R, bool)
-        for r in range(R):
-            if r == self.my_id:
-                rows.append(my_vec)
-                heard[r] = True
-            elif r in peer_vecs:
-                rows.append(peer_vecs[r])
-                heard[r] = True
-            else:
-                rows.append(my_vec)
-        gathered = np.stack(rows)
+        for r in np.flatnonzero(~heard):  # my own row, and the unheard
+            gathered[r] = my_vec
+        heard[self.my_id] = True
         want = self.fd.want_coord(
             self.manager._np("bal"),
             self.manager._np("member_mask"),
@@ -1025,18 +1083,13 @@ class PaxosServer:
         with self.manager._span("publish"):
             if pub["blob_vec"] is not None:
                 self._last_publish = time.monotonic()
-                blob_frame = encode_blob_vec(
-                    self.my_id, pub["tick"], pub["blob_vec"]
-                )
-                mx = self.manager.metrics
-                mx.gauge("blob_frame_bytes", len(blob_frame))
-                # counted as QUEUED: a frame superseded before it left is
-                # in these two (blob_frames_written / blob_bytes_written
-                # count what left, net/transport.py)
-                mx.count("blob_bytes_sent", len(blob_frame) * len(peers))
-                mx.count("blob_frames_sent", len(peers))
+                # counted as QUEUED, one per peer: a vector superseded
+                # before its turn is in here (its bytes exist only once a
+                # frame is encoded, and are counted there: net/transport.py)
+                self.manager.metrics.count("blob_frames_sent", len(peers))
+                item = (pub["tick"], pub["blob_vec"])
                 for r in peers:
-                    self.transport.send_latest_to_id(r, "blob", blob_frame)
+                    self.transport.send_latest_to_id(r, "blob", item)
             if pub["delta"] is not None:
                 frame = encode_json("payloads", self.my_id, pub["delta"])
                 for r in peers:

@@ -244,6 +244,54 @@ def test_wire_frame_roundtrip_and_version_skew():
     with pytest.raises(ValueError, match="schema"):
         decode_blob(b"B" + encode_blob(1, 9, blob)[1:], CFG)
 
+    # the row-delta frame is a kind of its own ('d': rows of a 'D'
+    # vector): each decoder refuses the other's frames by kind, and a
+    # delta of another schema is refused like a full frame of one
+    from gigapaxos_tpu.net.codec import decode_blob_delta, encode_blob_frame
+
+    vec2 = vec.copy()
+    vec2[1] += 1  # row 1 of the first [G] leaf
+    delta, rows = encode_blob_frame(2, CFG, (12, vec2), (11, vec))
+    assert delta[:1] == b"d" and rows == 1
+    s3, t3, base_tick, idx, _leaves = decode_blob_delta(delta, CFG)
+    assert (s3, t3, base_tick, idx.tolist()) == (2, 12, 11, [1])
+    with pytest.raises(ValueError, match="schema"):
+        decode_blob_vec(delta, CFG)
+    with pytest.raises(ValueError, match="schema"):
+        decode_blob_delta(encode_blob_vec(2, 11, vec), CFG)
+    with pytest.raises(ValueError, match="schema"):
+        decode_blob_delta(b"e" + delta[1:], CFG)
+
+
+@pytest.mark.parametrize("kind", [b"B", b"C", b"e", b"E"])
+def test_server_drops_blob_frames_of_another_schema_by_kind(kind):
+    """The ingress speaks 'D' (whole vector), 'd' (row delta) and 'J':
+    a blob of any other kind byte is dropped before it is parsed, warned
+    about once, and leaves nothing held of that sender."""
+    from gigapaxos_tpu.net.codec import encode_blob_frame, encode_blob_vec
+    from tests.test_blob_delta import receiver
+
+    srv, _sent = receiver(CFG)
+    try:
+        vec = np.asarray(pack_blob(make_blob(
+            random_state(np.random.default_rng(4)))))
+        full = encode_blob_vec(0, 5, vec)
+        delta, _rows = encode_blob_frame(0, CFG, (6, vec.copy()), (5, vec))
+        for frame in (full, delta, full):
+            srv._on_message(kind + frame[1:], ("127.0.0.1", 0),
+                            lambda b: None)
+        assert 0 not in srv._peer_blobs
+        assert srv.manager.metrics.get("blob_frames_received") == 0
+        assert srv.manager.metrics.get("blob_base_mismatch") == 0
+        assert srv._schema_skew_warned == {kind.decode()}
+        # and the two kinds it speaks get through
+        for frame in (full, delta):
+            srv._on_message(frame, ("127.0.0.1", 0), lambda b: None)
+        assert srv._peer_blob_tick[0] == 6
+        assert srv.manager.metrics.get("blob_frames_received") == 2
+    finally:
+        srv.manager.close()
+
 
 def test_footprint_reduction_at_headline_shape():
     """The acceptance-criterion assert: compact blob bytes/replica at the

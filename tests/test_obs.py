@@ -536,7 +536,8 @@ def ticked_cluster(tmp_path_factory):
         snaps = [s.manager.metrics.snapshot() for s in servers]
         traces = tracemerge.merge_node_dumps(
             {s.my_id: s.tracer.export() for s in servers})
-        yield {"entry": entry, "snaps": snaps, "traces": traces}
+        yield {"entry": entry, "snaps": snaps, "traces": traces,
+               "cfg": cfg}
     finally:
         client.close()
         for s in servers:
@@ -623,14 +624,24 @@ def test_blob_accounting_adds_up(ticked_cluster):
     superseded (less at most one frame per peer still waiting); what a
     dispatch folded plus what was replaced unread is what arrived; a
     tick either folded a fresh blob or is counted as having none."""
+    from gigapaxos_tpu.ops.engine import blob_vec_len
+
     snaps = ticked_cluster["snaps"]
     for snap in snaps:
         c, h = snap["counters"], snap["hists"]
         written = c.get("blob_frames_written", 0)
         superseded = c.get("blob_frames_superseded", 0)
         assert 0 <= c["blob_frames_sent"] - superseded - written <= 2
-        assert c["blob_bytes_written"] == written * snap["gauges"][
-            "blob_frame_bytes"]
+        # a frame is a delta or a full one; full ones open a connection
+        # (two peers) and deltas are smaller, by the size rule
+        full = 13 + 4 * blob_vec_len(ticked_cluster["cfg"])
+        assert c["blob_frames_delta"] + c["blob_frames_full"] == written
+        assert 1 <= c["blob_frames_full"] <= 2 < c["blob_frames_delta"]
+        assert h["blob_delta_rows"]["count"] == c["blob_frames_delta"]
+        assert c["blob_bytes_sent"] == c["blob_bytes_written"] \
+            < written * full
+        assert snap["gauges"]["blob_frame_bytes"] <= full
+        assert c["blob_base_mismatch"] == 0
         folded = h["blob_age_ticks"]["count"]
         replaced = c.get("blob_frames_replaced_unread", 0)
         assert 0 <= c["blob_frames_received"] - folded - replaced <= 2
@@ -662,7 +673,8 @@ def test_superseded_blob_is_counted_and_never_as_written():
             last.set()
 
     sender = MessageTransport(0, nc, lambda *a: None, metrics=reg,
-                              listen_host="127.0.0.1", listen_port=0)
+                              listen_host="127.0.0.1", listen_port=0,
+                              latest_encoder=lambda item, base: (item, None))
     reader = MessageTransport(1, nc, slow_reader,
                               listen_host="127.0.0.1", listen_port=0)
     try:
@@ -683,9 +695,13 @@ def test_superseded_blob_is_counted_and_never_as_written():
         assert c["blob_frames_superseded"] >= 1
         assert c["blob_frames_written"] == len(got) \
             == n_frames - c["blob_frames_superseded"]
-        assert c["blob_bytes_written"] == len(got) * (size + 4)
-        assert reg.snapshot()["hists"]["phase_blob_send_s"]["count"] \
-            == len(got)
+        assert c["blob_bytes_written"] == c["blob_bytes_sent"] \
+            == len(got) * (size + 4)
+        assert c["blob_frames_full"] == len(got)
+        assert c["blob_frames_delta"] == 0
+        hists = reg.snapshot()["hists"]
+        assert hists["phase_blob_send_s"]["count"] \
+            == hists["phase_blob_encode_s"]["count"] == len(got)
     finally:
         release.set()
         sender.stop()

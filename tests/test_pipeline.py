@@ -165,7 +165,7 @@ def test_flush_coalescing_metrics():
     """A loopback round trip populates the flush metrics (one frame per
     peer per cycle: responses_flushed counter + flush_batch_size hist),
     and the stats admin op reports the live codec + pipeline mode."""
-    from tests.test_server import boot_cluster
+    from tests.test_server import boot_cluster, wait_until
 
     servers, client, _ = boot_cluster()
     try:
@@ -176,7 +176,10 @@ def test_flush_coalescing_metrics():
             ) is not None
         mx = [s.manager.metrics for s in servers]
         # the client randomizes entry replicas — count across the cluster
-        assert sum(m.get("responses_flushed") for m in mx) >= 4
+        # (a flush counts AFTER it hands the frame over, so the client
+        # can hold the fourth reply a moment before the counter moves)
+        assert wait_until(lambda: sum(
+            m.get("responses_flushed") for m in mx) >= 4, timeout=5)
         assert any(
             "flush_batch_size" in m.snapshot()["hists"] for m in mx
         )
