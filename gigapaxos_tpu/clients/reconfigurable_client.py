@@ -19,6 +19,7 @@ from __future__ import annotations
 import random
 import threading
 import time
+import uuid
 from typing import Callable, Dict, List, Optional, Tuple
 
 from ..net.codec import decode_json, decode_kind, encode_json
@@ -349,9 +350,15 @@ class ReconfigurableAppClient(AsyncFrameClient):
     def reconfigure(
         self, name: str, new_actives: List[int], timeout: float = 15.0
     ) -> Optional[Dict]:
+        """One epoch change; the acknowledgement carries the name's new
+        ``epoch``.  Every retransmission of this call carries the same
+        ``rid``: the reconfigurators start one epoch change for it however
+        often it arrives (with RECONFIGURE_IN_PLACE the target set cannot
+        tell a retransmission from a second request)."""
         return self._rc_op_sync(
             "reconfigure", "reconfigure_ack", name,
-            {"name": name, "new_actives": list(new_actives)}, timeout,
+            {"name": name, "new_actives": list(new_actives),
+             "rid": uuid.uuid4().hex}, timeout,
         )
 
     def request_actives(

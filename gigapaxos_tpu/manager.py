@@ -250,6 +250,7 @@ class PaxosManager:
         self.log = gplog.node_logger("manager", my_id)
         self.tracer = RequestTracer(my_id)
         self.metrics = MetricsRegistry(node=my_id)
+        self.metrics.count("requests_carried_over", 0)  # present from the start
         # black-box flight recorder (obs/flight.py): always-on bounded
         # rings of per-step engine summaries + last-K decided
         # (group, slot, ballot, vid), dumped on divergence/exception/
@@ -401,6 +402,21 @@ class PaxosManager:
         # (the reconfiguration layer captures the final state here);
         # signature: (name, row, epoch)
         self.on_stop_executed: Optional[Callable[[str, int, int], None]] = None
+        # epoch changes seen from the request path.  A write never sees
+        # one except as latency: what is queued and unadmitted when the
+        # next epoch's row is created follows the name there; a forward
+        # that crosses the change is taken into the epoch that is; and a
+        # write DECIDED behind the stop is executed nowhere in the old
+        # epoch (the final state was captured at the stop) and proposed
+        # again, under its request id, by the node that minted it.
+        # rows whose epoch-final stop has executed at the app:
+        self._stop_executed_rows: set = set()
+        # name -> [(request id, entry replica, value)] decided behind the
+        # stop here, waiting for the next epoch's row:
+        self._epoch_carry: Dict[str, List[Tuple[int, int, str]]] = {}
+        # name -> when its stop executed here (histogram epoch_gap_s:
+        # until the next epoch's row admits a proposal)
+        self._stop_exec_t: Dict[str, float] = {}
         # residency (pause/unpause, PaxosManager.java:2264-2392 analog):
         # paused groups' snapshots, keyed (name, epoch) — their rows are
         # freed for reuse; reactivation restores at a freshly probed row.
@@ -1038,6 +1054,16 @@ class PaxosManager:
         return {
             "dispatch": self._dispatch_step.stats(),
             "tick": self._tick_step.stats(),
+            # the lifecycle scatters are jitted once per (state shape,
+            # rows touched); ``warm_engine`` compiles the single-row
+            # shapes an epoch change uses, so growth of these caches
+            # after boot is a compile under the state lock, in traffic
+            "lifecycle": {
+                "label": "create_groups+kill_groups",
+                "compiles": create_groups._cache_size()
+                + kill_groups._cache_size(),
+                "retraces": 0,
+            },
         }
 
     def local_read_ok(self, name: str) -> bool:
@@ -1103,7 +1129,7 @@ class PaxosManager:
         not adopted) skip-executes decisions the app state does not
         contain and diverges the RSM (chaos seed 662625602)."""
         with self._state_lock:
-            self._await_step_locked()
+            self._await_step_lifecycle_locked()
             return self._create_locked(
                 name, members, initial_state, version, row, pending,
                 dedup=dedup,
@@ -1153,6 +1179,29 @@ class PaxosManager:
                 # (PaxosManager's paxosID+version instance keying analog).
                 if not int(self._np("stopped")[cur_row]):
                     return False  # old epoch must stop before the next starts
+                if row is not None and int(row) in self.row_name:
+                    # the probed row is taken: refuse BEFORE the name lets
+                    # go of its old row — a name that maps to no row
+                    # answers its writers "unknown_name" until the
+                    # reconfigurator's next probe lands
+                    raise RuntimeError(
+                        f"row {int(row)} already hosts "
+                        f"{self.row_name[int(row)]!r} (epoch {version} of "
+                        f"{name!r} must probe another)"
+                    )
+                # what is queued here and was never admitted (the engine
+                # admits nothing behind a stop) follows the name into the
+                # new epoch, in order; the old epoch's stop stays behind
+                old_queue = self.queues.get(cur_row) or []
+                held_vids = [v for v in old_queue if not v & STOP_BIT]
+                if held_vids:
+                    self.queues[cur_row] = [
+                        v for v in old_queue if v & STOP_BIT
+                    ]
+                    self.metrics.count(
+                        "requests_carried_over",
+                        sum(self._n_requests(v) for v in held_vids),
+                    )
                 self.old_epochs[(name, cur_ver)] = cur_row
                 # row_name keeps the REAL name (occupancy only needs the key);
                 # trailing executions of the old row must see the true
@@ -1161,7 +1210,15 @@ class PaxosManager:
                 # The new epoch's initial state (the stop-time final state)
                 # subsumes any of the old row's decided-but-unexecuted slots;
                 # executing them after the restore would double-apply them.
-                self.pending_exec.pop(cur_row, None)
+                # Where the stop has executed here, what is still pending
+                # was decided BEHIND it and is in no final state: carried.
+                dropped = self.pending_exec.pop(cur_row, None) or {}
+                if cur_row in self._stop_executed_rows:
+                    for slot_, vid_ in sorted(dropped.items()):
+                        if vid_:
+                            self._carry_behind_stop(
+                                name, cur_row, slot_, vid_)
+                self._stop_executed_rows.add(cur_row)
                 self._payload_blocked.pop(cur_row, None)
                 self._stall_since[cur_row] = -1
                 self._stall_slot[cur_row] = -1
@@ -1186,6 +1243,7 @@ class PaxosManager:
             )
         self.names[name] = row
         self.row_name[row] = name
+        self._stop_executed_rows.discard(row)
         if pending:
             self.pending_rows.add(row)
         mask = 0
@@ -1214,6 +1272,8 @@ class PaxosManager:
         self.row_activity[row] = time.time()
         if held_vids:
             self.queues[row] = held_vids
+        if not pending:
+            self._note_writable_locked(name)
         if self.logger:
             self.logger.log_create(
                 np.array([row]), np.array([mask]),
@@ -1230,7 +1290,34 @@ class PaxosManager:
             # through the epoch hold
             if dedup:
                 self.install_dedup(dedup)
+        self._repropose_locked(name, self._epoch_carry.pop(name, None))
         return True
+
+    def _repropose_locked(self, name: str, items) -> None:
+        """Writes decided behind the previous epoch's stop, as (request
+        id, entry replica, value): proposed again into ``name``'s current
+        row under their ids (their callbacks wait at their entry
+        replicas, whichever those are)."""
+        if items:
+            self.propose_batch([
+                (name, value, rid, None, entry)
+                for rid, entry, value in items
+            ])
+
+    def _n_requests(self, vid: int) -> int:
+        """Client requests one queued vid stands for."""
+        if not vid & BATCH_BIT:
+            return 1
+        payload = self.arena.get(vid)
+        return len(decode_batch(payload)) if payload else 0
+
+    def _note_writable_locked(self, name: Optional[str]) -> None:
+        """``name``'s current row admits proposals: if its previous epoch
+        stopped here, the time since is how long the name took no write
+        on this node (histogram ``epoch_gap_s``)."""
+        t0 = self._stop_exec_t.pop(name, None) if name else None
+        if t0 is not None:
+            self.metrics.observe("epoch_gap_s", time.monotonic() - t0)
 
     def create_paxos_batch(
         self,
@@ -1250,7 +1337,7 @@ class PaxosManager:
         for mem in members:
             mask |= 1 << mem
         with self._state_lock:
-            self._await_step_locked()
+            self._await_step_lifecycle_locked()
             rows, coords, tags, fresh = [], [], [], []
             try:
                 for name in names:
@@ -1320,6 +1407,7 @@ class PaxosManager:
 
     def _unpend_locked(self, row: int) -> None:
         self.pending_rows.discard(row)
+        self._note_writable_locked(self.row_name.get(row))
         if self.logger:
             self.logger.log_unpend(np.array([row]))
 
@@ -1352,7 +1440,7 @@ class PaxosManager:
 
     def kill(self, name: str) -> bool:
         with self._state_lock:
-            self._await_step_locked()
+            self._await_step_lifecycle_locked()
             return self._kill_locked(name)
 
     def _kill_locked(self, name: str, release_queue: bool = True) -> bool:
@@ -1363,6 +1451,10 @@ class PaxosManager:
         if row is None:
             return False
         del self.row_name[row]
+        self._stop_executed_rows.discard(row)
+        if release_queue:  # a true kill, not a pause or a re-home
+            self._stop_exec_t.pop(name, None)
+            self._epoch_carry.pop(name, None)
         self.pending_rows.discard(row)
         self.hydrating_rows.discard(row)  # killed cold name: state is moot
         self._payload_blocked.pop(row, None)
@@ -1384,7 +1476,7 @@ class PaxosManager:
         the reconfigurator garbage-collects the old epoch once the new one
         is running)."""
         with self._state_lock:
-            self._await_step_locked()
+            self._await_step_lifecycle_locked()
             # a paused group being deleted has no row — drop the record
             # with a journal tombstone (else the PAUSE block resurrects it
             # on recovery, and a later re-created incarnation of the name
@@ -1412,6 +1504,7 @@ class PaxosManager:
                     return False  # never kill a live, unstopped group
                 return self._kill_locked(name)
             del self.row_name[row]
+            self._stop_executed_rows.discard(row)
             self.pending_rows.discard(row)
             self._payload_blocked.pop(row, None)
             self._stall_since[row] = -1
@@ -1455,7 +1548,7 @@ class PaxosManager:
         cancelled).  `force` carries window remnants into the record (used
         by re-homing, where quiescence can't be awaited)."""
         with self._state_lock:
-            self._await_step_locked()
+            self._await_step_lifecycle_locked()
             row = self.names.get(name)
             if row is None:
                 return "ok" if (name, int(epoch)) in self.paused else "unknown"
@@ -1554,7 +1647,7 @@ class PaxosManager:
         `row` is occupied by another group (-> collision NACK)."""
         epoch = int(epoch)
         with self._state_lock:
-            self._await_step_locked()
+            self._await_step_lifecycle_locked()
             cur = self.names.get(name)
             if cur is not None:
                 cur_ver = int(self._np("version")[cur])
@@ -1771,7 +1864,7 @@ class PaxosManager:
         n_fast = 0
         deferred: List[Tuple[str, int, List[int], int, bool]] = []
         with self._state_lock:
-            self._await_step_locked()
+            self._await_step_lifecycle_locked()
             fast: List[Tuple[str, int, List[int], int, bool]] = []
             claimed: set = set()
             for name, epoch, members, row, pending in items:
@@ -2713,13 +2806,10 @@ class PaxosManager:
                     np.maximum(arr, np.asarray(cursors, np.int64), out=arr)
         elif kind == "forward":  # a peer forwards a proposal to me
             fwd_epoch = body.get("epoch")
-            if fwd_epoch is not None and (
-                self.current_epoch(body["name"]) != int(fwd_epoch)
-            ):
-                # a DELAYED forward from a superseded epoch must not be
-                # injected into the current one — an old epoch's stop
-                # executing in the new epoch diverges the RSM (chaos
-                # soak); genuine client requests retransmit
+            cur = self.current_epoch(body["name"])
+            if fwd_epoch is not None and cur != int(fwd_epoch) \
+                    and not self._write_crosses_epoch_locked(
+                        cur, int(fwd_epoch), 0 if body.get("stop") else 1):
                 return
             tc = body.get("tc")
             tc = None if not tc else (int(tc[0]), int(tc[1]), int(tc[2]))
@@ -2744,9 +2834,15 @@ class PaxosManager:
             # before a stop flush BEFORE the stop is proposed (proposing
             # the stop first would decide it ahead of requests that
             # preceded it, and the epoch bump would drop them as stale).
-            if self.current_epoch(body["name"]) != int(body["epoch"]):
-                return
             name = body["name"]
+            cur = self.current_epoch(name)
+            if cur != int(body["epoch"]):
+                # across an epoch change only the writes come along
+                writes = [r for r in body["reqs"] if not r[3]]
+                if not self._write_crosses_epoch_locked(
+                        cur, int(body["epoch"]), len(writes)):
+                    return
+                body = dict(body, reqs=writes)
             tcs = body.get("tc") or {}
 
             def _tc_of(rid):
@@ -3164,6 +3260,14 @@ class PaxosManager:
     # and admit batch N+1 throughout (the lock is free during the sync).
     # Step-for-step state-identical to tick_host (tests/test_pipeline.py).
     # ------------------------------------------------------------------
+    def _await_step_lifecycle_locked(self) -> None:
+        """:meth:`_await_step_locked` for the ops that rewrite rows
+        (create, kill, pause, resume), with the wait as a span: an epoch
+        change pays it twice on every active, against a step that a
+        loaded node has in flight nearly always."""
+        with span(self.metrics, "lifecycle.await_step", node=self.my_id):
+            self._await_step_locked()
+
     def _await_step_locked(self) -> None:
         """Wait (lock held; CV releases it) until no step is in flight.
         Called at the TOP of every op that replaces engine state or
@@ -3696,6 +3800,15 @@ class PaxosManager:
     def _execute_one(self, name: Optional[str], g: int, slot: int, vid: int) -> bool:
         if vid == 0:  # NOOP hole-filler: nothing to execute
             return True
+        if g in self._stop_executed_rows:
+            # decided behind the epoch-final stop (a second coordinator
+            # that had not learnt of it): the final state was captured AT
+            # the stop, so executing this here would be lost with the old
+            # row on some replicas and kept on others.  Every replica
+            # skips it — they all see the same decided sequence — and the
+            # node that minted the vid proposes it again in the next epoch
+            self._carry_behind_stop(name, g, slot, vid)
+            return True
         payload = self.arena.get(vid)
         if payload is None:
             return False
@@ -3774,6 +3887,10 @@ class PaxosManager:
         # one epoch-final stop id)
         if self._cacheable(req):
             self._cache_response(request_id, response, name or "")
+        if vid & STOP_BIT:
+            self._stop_executed_rows.add(g)
+            if name and self.names.get(name) == g:
+                self._stop_exec_t[name] = time.monotonic()
         if (vid & STOP_BIT) and self.on_stop_executed is not None and name:
             epoch = int(self._np("version")[g])
             try:
@@ -3784,6 +3901,58 @@ class PaxosManager:
             self._answer(request_id, response)
         self.retained[vid] = (g, slot)  # keep for straggler pulls
         return True
+
+    def _write_crosses_epoch_locked(self, cur: Optional[int],
+                                    fwd_epoch: int, n_writes: int) -> bool:
+        """A forward as of ``fwd_epoch`` for a name whose epoch here is
+        ``cur``, another: an epoch change lies between sender and receiver.  An
+        old epoch's STOP must never be injected into another epoch (it
+        would stop the live one, and a member whose dedup entry for it
+        expired executes it: RSM divergence, chaos soak), so stops are
+        not taken.  A write crosses rightfully: the app state carries
+        over and its request id dedups it.  From a sender that is behind
+        it goes straight into the epoch that is; from one that is ahead
+        it queues on the old row here and follows the name when this node
+        starts the next epoch."""
+        if cur is None or not n_writes:
+            return False
+        if fwd_epoch < cur:
+            self.metrics.count("requests_carried_over", n_writes)
+        return True
+
+    def _carry_behind_stop(self, name: Optional[str], g: int, slot: int,
+                           vid: int) -> None:
+        """Lock held: ``vid`` was decided on row ``g`` behind its stop.
+        If this node minted it, its requests go into the name's next
+        epoch — now, if that epoch's row is already here, else when it is
+        created (:meth:`_create_locked`).  A stale stop is dropped."""
+        if vid in self.retained:
+            return
+        self.retained[vid] = (g, slot)  # retention GC owns the payload
+        if vid & STOP_BIT or ((vid >> VID_NODE_SHIFT) & 31) != self.my_id \
+                or not name:
+            return
+        payload = self.arena.get(vid)
+        if payload is None:
+            return
+        if vid & BATCH_BIT:
+            items = list(decode_batch(payload))
+        else:
+            entry, rid = self.vid_meta.get(vid, (self.my_id, vid))
+            items = [(rid, entry, payload)]
+        # under their own ids: not answered from the cache, not taken for
+        # a proposal still in flight
+        items = [it for it in items if it[0] not in self.response_cache]
+        for rid, _entry, _value in items:
+            self.inflight.pop(rid, None)
+        if not items:
+            return
+        self.metrics.count("requests_carried_over", len(items))
+        cur = self.names.get(name)
+        if cur is not None and cur != g:
+            self._repropose_locked(name, items)
+        else:
+            self._epoch_carry.setdefault(name, []).extend(items)
 
     # ------------------------------------------------------------------
     # THE data-plane straggler sync protocol — the one heal path for

@@ -41,6 +41,16 @@ def _names(phase: str):
     return "gp." + phase, stem + "_s", stem + "_cpu_s"
 
 
+def observe_interval(registry, phase: str, seconds: float) -> None:
+    """A phase that begins with one message and ends with another, on
+    whatever threads carry them (a reconfigurator's intent to its
+    COMPLETE, an active's stop_epoch to the stop's execution): no
+    ``with`` block can hold it, so it has no host event — only its
+    histogram, named as :class:`span` names one."""
+    if registry is not None:
+        registry.observe(_names(phase)[1], seconds)
+
+
 class span:
     """Context manager; see the module docstring.  ``registry`` may be
     None (a transport outside any node): the annotation alone is made.

@@ -21,6 +21,8 @@ import json
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from ..obs.metrics import MetricsRegistry
+from ..obs.spans import observe_interval, span
 from ..protocoltask import ProtocolExecutor, ProtocolTask
 from .coordinator import AbstractReplicaCoordinator
 
@@ -141,6 +143,16 @@ class ActiveReplica:
         # hook the coordinator's stop-execution signal (fires on execution
         # AND on a checkpoint jump that lands past the stop)
         coordinator.set_stop_callback(self._on_stop_executed)
+        # the epoch plane's own account, in the node's registry
+        # (obs/spans.py; a coordinator that keeps none gets a private one)
+        self.metrics = coordinator.metrics or MetricsRegistry(node=self.my_id)
+        self.metrics.count("epochs_started", 0)  # present from the start
+        self.metrics.count("epochs_stopped", 0)
+        self.metrics.count("epochs_dropped", 0)
+        # (name, epoch) -> when its first stop_epoch arrived here
+        # (reconf.stop: until the stop executed, the final state was
+        # captured and the acknowledgement left)
+        self._stop_asked_at: Dict[Tuple[str, int], float] = {}
 
     # ------------------------------------------------------------------
     # epoch-op handlers (dispatch table)
@@ -333,9 +345,7 @@ class ActiveReplica:
         if fs_key in self.final_states:
             # I was in the previous epoch and hold the final state locally
             # (my own dedup entries are already in my cache)
-            self._ack_start(
-                body, self._create(body, self.final_states[fs_key]["state"])
-            )
+            self._start_next_epoch(body, self.final_states[fs_key]["state"])
             return
         # fetch the previous epoch's final state from its actives; the task
         # is keyed by the PREVIOUS epoch (what is being fetched)
@@ -346,8 +356,22 @@ class ActiveReplica:
 
     def _finish_start_epoch(self, body: Dict, state: Optional[str],
                             dedup: Optional[Dict] = None):
-        self._ack_start(body, self._create(body, state, dedup))
+        self._start_next_epoch(body, state, dedup)
         return ()
+
+    def _start_next_epoch(self, body: Dict, state: Optional[str],
+                          dedup: Optional[Dict] = None) -> None:
+        """The previous epoch's final state is in hand: create the next
+        epoch's row, restore the state, acknowledge (span
+        ``reconf.start``; a retransmitted start_epoch for an epoch that
+        is already here passes through it again and starts nothing)."""
+        name, epoch = body["name"], int(body["epoch"])
+        with span(self.metrics, "reconf.start", node=self.my_id):
+            fresh = self.coordinator.current_epoch(name) != epoch
+            outcome = self._create(body, state, dedup)
+            if fresh and outcome == "ok":
+                self.metrics.count("epochs_started")
+            self._ack_start(body, outcome)
 
     def _create(self, body: Dict, state: Optional[str],
                 dedup: Optional[Dict] = None) -> str:
@@ -457,6 +481,7 @@ class ActiveReplica:
             return
         if cur_epoch < epoch:
             return  # start_epoch for this epoch hasn't landed yet; retransmit finds us later
+        self._stop_asked_at.setdefault((name, epoch), time.monotonic())
         self._pending_stop_acks.setdefault((name, epoch), [])
         if rc not in self._pending_stop_acks[(name, epoch)]:
             self._pending_stop_acks[(name, epoch)].append(rc)
@@ -479,13 +504,19 @@ class ActiveReplica:
         node adds later (executing in the NEXT epoch) must not ride with
         the previous epoch's state — they describe executions the fetched
         state does not contain."""
-        self.final_states[(name, epoch)] = {
-            "state": self.coordinator.app.checkpoint(name),
-            "dedup": self.coordinator.dedup_for_name(name),
-            "t": time.time(),
-        }
-        for rc in self._pending_stop_acks.pop((name, epoch), []):
-            self._ack_stop(rc, name, epoch)
+        with span(self.metrics, "reconf.stop.capture", node=self.my_id):
+            self.final_states[(name, epoch)] = {
+                "state": self.coordinator.app.checkpoint(name),
+                "dedup": self.coordinator.dedup_for_name(name),
+                "t": time.time(),
+            }
+            for rc in self._pending_stop_acks.pop((name, epoch), []):
+                self._ack_stop(rc, name, epoch)
+        self.metrics.count("epochs_stopped")
+        asked = self._stop_asked_at.pop((name, epoch), None)
+        if asked is not None:
+            observe_interval(self.metrics, "reconf.stop",
+                             time.monotonic() - asked)
 
     def _ack_stop(self, rc: Addr, name: str, epoch: int) -> None:
         self.send(rc, "ack_stop_epoch", {
@@ -536,6 +567,10 @@ class ActiveReplica:
 
     # ---- drop (handleDropEpochFinalState, :968) ------------------------
     def _handle_drop_epoch(self, body: Dict) -> None:
+        with span(self.metrics, "reconf.drop", node=self.my_id):
+            self._drop_epoch(body)
+
+    def _drop_epoch(self, body: Dict) -> None:
         name, epoch = body["name"], int(body["epoch"])
         if self.coordinator.hosts_epoch(name, epoch):
             if not self.coordinator.delete_replica_group(name, epoch):
@@ -543,7 +578,9 @@ class ActiveReplica:
                 # execution): stay silent, the drop task's retransmit will
                 # find us once the stop lands — never kill a live group
                 return
+            self.metrics.count("epochs_dropped")
         self.final_states.pop((name, epoch), None)
+        self._stop_asked_at.pop((name, epoch), None)
         self.send(tuple(body["rc"]), "ack_drop_epoch", {
             "name": name, "epoch": epoch, "from": self.my_id,
         })

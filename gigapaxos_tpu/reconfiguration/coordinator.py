@@ -24,6 +24,10 @@ from ..manager import PaxosManager
 class AbstractReplicaCoordinator:
     """Coordination SPI (``AbstractReplicaCoordinator.java:78``)."""
 
+    # the node's MetricsRegistry, where the coordinator has one: the
+    # epoch plane's spans and counters go there (None: not kept)
+    metrics = None
+
     def __init__(self, app: Replicable):
         self.app = app
 
@@ -174,6 +178,7 @@ class PaxosReplicaCoordinator(AbstractReplicaCoordinator):
     def __init__(self, app: Replicable, manager: PaxosManager):
         super().__init__(app)
         self.manager = manager
+        self.metrics = manager.metrics
 
     def coordinate_request(
         self,

@@ -161,7 +161,9 @@ class RCRecordsApp(Replicable):
         if rec is None or rec.deleted:
             return False
         if kind == RECONFIGURE_INTENT:
-            return rec.start_reconfigure(list(op["new_actives"]), int(op["new_row"]))
+            return rec.start_reconfigure(
+                list(op["new_actives"]), int(op["new_row"]), op.get("rid")
+            )
         if kind == STOP_DONE:
             return rec.stop_done()
         if kind == COMPLETE:

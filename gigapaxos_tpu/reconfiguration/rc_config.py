@@ -16,6 +16,15 @@ class RC(FlagEnum):
     # ---- placement (ref: ReconfigurationConfig.java DEFAULT_NUM_REPLICAS)
     DEFAULT_NUM_REPLICAS = 3
 
+    # ---- in-place reconfiguration (ref: ReconfigurationConfig.java:268
+    # RECONFIGURE_IN_PLACE, default false).  False: a reconfigure whose
+    # target equals the name's current replica set is acknowledged without
+    # an epoch change.  True: it runs the whole protocol (intent, stop,
+    # final state, start at a fresh row, drop) on the same members — what
+    # upstream's reconfiguration-rate test (TESTReconfigurationClient
+    # test04) and a deployment whose actives are all of its nodes need
+    RECONFIGURE_IN_PLACE = False
+
     # ---- demand-driven reconfiguration (ref: DEMAND_PROFILE_TYPE,
     # AbstractDemandProfile SPI) — the dotted path of the profile class
     DEMAND_PROFILE_TYPE = (

@@ -28,6 +28,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 from ..manager import PaxosManager
 from ..obs import gplog
 from ..obs.reqtrace import RequestTracer
+from ..obs.spans import observe_interval
 from ..protocoltask import ProtocolExecutor, ProtocolTask, ThresholdProtocolTask
 from ..utils.config import Config
 from .active_replica import stop_request_id
@@ -77,6 +78,7 @@ class StartEpochTask(ProtocolTask):
         self.attempt = int(op.get("attempt", 0))
         self.acked: set = set()
         self.majority = len(op["actives"]) // 2 + 1
+        self.t0 = time.monotonic()
 
     @property
     def row(self) -> int:
@@ -130,6 +132,8 @@ class StartEpochTask(ProtocolTask):
         self.acked.add(int(body["from"]))
         if len(self.acked) >= self.majority:
             self.done = True
+            observe_interval(self.rcf.metrics, "rc.start_round",
+                             time.monotonic() - self.t0)
             # commit COMPLETE (with the row that won) through RC paxos;
             # prev-epoch info rides along so the applied callback can GC
             # it, and the ack set so laggards get a late-start retransmit
@@ -313,6 +317,7 @@ class StopEpochTask(ThresholdProtocolTask):
         self.epoch = epoch
         self.row = row
         self._on_stopped = on_stopped
+        self.t0 = time.monotonic()
 
     def send_to(self, node):
         return (("AR", node), "stop_epoch", {
@@ -327,6 +332,8 @@ class StopEpochTask(ThresholdProtocolTask):
         return None
 
     def on_threshold(self):
+        observe_interval(self.rcf.metrics, "rc.stop_round",
+                         time.monotonic() - self.t0)
         self._on_stopped()
         return ()
 
@@ -464,6 +471,7 @@ class Reconfigurator:
             if default_replicas is None else int(default_replicas)
         )
         self.REDRIVE_EVERY = Config.get_int(RC.REDRIVE_EVERY)
+        self.reconfigure_in_place = Config.get_bool(RC.RECONFIGURE_IN_PLACE)
         self.MAX_REDROPS = Config.get_int(RC.MAX_REDROPS)
         # elastic membership: the replicated AR set (rc_app.ar_nodes) wins
         # over the boot configuration once any add/remove has committed
@@ -503,6 +511,14 @@ class Reconfigurator:
         self.tasks = ProtocolExecutor(send=lambda m: self.send(m[0], m[1], m[2]))
         # client replies owed on COMPLETE / DELETE_FINAL: name -> client addr
         self._pending_clients: Dict[str, Any] = {}
+        # the reconfiguration layer's own account (obs/spans.py), in this
+        # node's registry: when the record's primary proposed a name's
+        # intent, until its COMPLETE executes here (rc.intent_to_complete
+        # for an epoch change, rc.create_to_complete for a create; the
+        # stop and start rounds inside are timed by their tasks)
+        self.metrics = rc_manager.metrics
+        self._epoch_change_t0: Dict[str, float] = {}
+        self._create_t0: Dict[str, float] = {}
         # epochs whose drop expired with unreached stragglers: re-dropped
         # periodically so a long-partitioned active doesn't leak the row
         # forever (MAX_FINAL_STATE_AGE re-drop analog)
@@ -747,6 +763,7 @@ class Reconfigurator:
         )
         if self._bad_actives(actives):
             return {"ok": False, "reason": "bad-actives"}
+        self._create_t0.setdefault(name, time.monotonic())
         self.propose_op({
             "op": CREATE_INTENT, "name": name, "epoch": 0,
             "actives": actives, "row": row_for(name, 0, 0, self.n_groups),
@@ -784,6 +801,7 @@ class Reconfigurator:
         if ent is None:
             ent = self._batches[batch_id] = {
                 "client": body.get("client"), "pending": set(), "results": {},
+                "t0": time.monotonic(),
             }
         elif body.get("client") is not None:
             ent["client"] = body["client"]  # retransmit re-registers
@@ -831,6 +849,8 @@ class Reconfigurator:
         if ent is None or ent["pending"]:
             return
         del self._batches[bid]
+        observe_interval(self.metrics, "rc.create_batch",
+                         time.monotonic() - ent["t0"])
         client = ent.get("client")
         if client is not None:
             # "name" carries the batch id: the client's waiter table keys
@@ -871,20 +891,28 @@ class Reconfigurator:
             self._reply(body, "reconfigure_ack", name, ok=False,
                         reason="bad-actives")
             return
-        if sorted(rec.actives) == sorted(body["new_actives"]):
-            # already at the target set: a completed migration's delayed
-            # retransmit must NOT start a redundant epoch bump (the
-            # reference skips same-set reconfigurations unless
-            # RECONFIGURE_IN_PLACE, ReconfigurationConfig.java:268)
+        rid = body.get("rid")
+        if (rid is not None and rid == rec.reconf_rid) or (
+            not self.reconfigure_in_place
+            and sorted(rec.actives) == sorted(body["new_actives"])
+        ):
+            # nothing to do: either this very request already made the
+            # record's last epoch change and is here again (a delayed
+            # retransmission must not start a second one — recognised by
+            # the request's id, because with RECONFIGURE_IN_PLACE the set
+            # is the same before and after), or the name is at the target
+            # set and RECONFIGURE_IN_PLACE is off (the reference skips
+            # same-set reconfigurations then, ReconfigurationConfig.java:268)
             self._reply(body, "reconfigure_ack", name, ok=True,
                         actives=rec.actives, epoch=rec.epoch)
             return
         new_actives = body["new_actives"]
         if body.get("client") is not None:
             self._pending_clients[name] = body["client"]
+        self._epoch_change_t0.setdefault(name, time.monotonic())
         self.propose_op({
             "op": RECONFIGURE_INTENT, "name": name,
-            "new_actives": new_actives,
+            "new_actives": new_actives, "rid": rid,
             "new_row": row_for(name, rec.epoch + 1, 0, self.n_groups),
         })
 
@@ -1278,6 +1306,11 @@ class Reconfigurator:
         if rec.state is not RCState.READY:
             return
         target = prof.reconfigure(list(rec.actives), sorted(self.ar_ids))
+        # a PROFILE that names the current set asks for an epoch change in
+        # place where RECONFIGURE_IN_PLACE is on (as upstream); the default
+        # profile names nothing, and the balance fallback's same set means
+        # "stay", flag or no flag
+        in_place = bool(target) and self.reconfigure_in_place
         if not target:
             # the locality profile declined: the placement policy may
             # still spread a hot name onto less-loaded actives
@@ -1285,10 +1318,12 @@ class Reconfigurator:
             target = self.placement.rebalance(
                 name, prof, list(rec.actives), sorted(self.ar_ids)
             )
-        if not target or sorted(target) == sorted(rec.actives) or \
-                self._bad_actives(target):
+        if not target or self._bad_actives(target) or (
+            sorted(target) == sorted(rec.actives) and not in_place
+        ):
             return
         prof.just_reconfigured()
+        self._epoch_change_t0.setdefault(name, time.monotonic())
         self.propose_op({
             "op": RECONFIGURE_INTENT, "name": name,
             "new_actives": list(target),
@@ -1610,10 +1645,17 @@ class Reconfigurator:
                 )
             return
         name = op["name"]
-        if not op.get("applied") or not self.is_primary(name):
+        kind = op["op"]
+        if not op.get("applied"):
+            # a refused intent ends nothing that could be timed
+            if kind == RECONFIGURE_INTENT:
+                self._epoch_change_t0.pop(name, None)
+            elif kind == CREATE_INTENT:
+                self._create_t0.pop(name, None)
+            return
+        if not self.is_primary(name):
             return
         rec = self.rc_app.get_record(name)
-        kind = op["op"]
         if kind == CREATE_INTENT:
             skey = f"start:{name}:{int(op.get('epoch', 0))}"
             self.tasks.spawn_if_not_running(
@@ -1651,6 +1693,16 @@ class Reconfigurator:
         elif kind == COMPLETE:
             assert rec is not None
             was_create = not op.get("prev_actives")
+            if was_create:
+                t0 = self._create_t0.pop(name, None)
+                if t0 is not None and not op.get("resume"):
+                    observe_interval(self.metrics, "rc.create_to_complete",
+                                     time.monotonic() - t0)
+            else:
+                t0 = self._epoch_change_t0.pop(name, None)
+                if t0 is not None:
+                    observe_interval(self.metrics, "rc.intent_to_complete",
+                                     time.monotonic() - t0)
             client = self._pending_clients.pop(name, None)
             if client is not None:
                 self.send(tuple(client),

@@ -56,6 +56,11 @@ class ReconfigurationRecord:
     # a reactivation start round keeps the SAME epoch (the group is not
     # migrating, just re-homing to a fresh row after pause)
     resuming: bool = False
+    # the client's id of the reconfigure request behind the last epoch
+    # change (None: started by the reconfigurators themselves, or by a
+    # client that sent none).  A retransmission is recognised by it, not by
+    # its target set: in place, the set is the same before and after
+    reconf_rid: Optional[str] = None
 
     def to_json(self) -> Dict[str, Any]:
         return {
@@ -66,6 +71,7 @@ class ReconfigurationRecord:
             "pending_drop_epoch": self.pending_drop_epoch,
             "pending_drop_actives": self.pending_drop_actives,
             "resuming": self.resuming,
+            "reconf_rid": self.reconf_rid,
         }
 
     @classmethod
@@ -79,13 +85,20 @@ class ReconfigurationRecord:
             pending_drop_epoch=d.get("pending_drop_epoch"),
             pending_drop_actives=list(d.get("pending_drop_actives") or []),
             resuming=bool(d.get("resuming", False)),
+            reconf_rid=d.get("reconf_rid"),
         )
 
     # ---- transitions (setState analog, ReconfigurationRecord.java:466+) --
-    def start_reconfigure(self, new_actives: List[int], new_row: int) -> bool:
-        """INTENT: begin epoch e -> e+1 (READY -> WAIT_ACK_STOP)."""
+    def start_reconfigure(self, new_actives: List[int], new_row: int,
+                          rid: Optional[str] = None) -> bool:
+        """INTENT: begin epoch e -> e+1 (READY -> WAIT_ACK_STOP).  An
+        intent under the id of the request that made the LAST epoch
+        change is that request sent again: refused."""
         if self.state is not RCState.READY or self.deleted:
             return False
+        if rid is not None and rid == self.reconf_rid:
+            return False
+        self.reconf_rid = rid
         self.new_actives = list(new_actives)
         self.new_row = int(new_row)
         self.state = RCState.WAIT_ACK_STOP

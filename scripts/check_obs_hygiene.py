@@ -132,6 +132,10 @@ def _span_names(node: ast.Call) -> Set[str]:
         arg = node.args[1] if len(node.args) > 1 else None
         cpu = any(k.arg == "cpu" and isinstance(k.value, ast.Constant)
                   and k.value.value is True for k in node.keywords)
+    elif isinstance(func, ast.Name) and func.id == "observe_interval":
+        # a phase between two messages: the histogram alone (obs/spans.py)
+        arg = node.args[1] if len(node.args) > 1 else None
+        cpu = False
     elif isinstance(func, ast.Attribute) and func.attr == "_span":
         arg = node.args[0] if node.args else None
         cpu = not any(k.arg == "cpu" and isinstance(k.value, ast.Constant)
