@@ -133,6 +133,7 @@ class _ReplicaArm:
         pack = jax.jit(lambda s: pack_blob(make_blob(s)))
         self.blobs = [pack(s) for s in self.states]
         self.heat = [self.put(jnp.zeros((G,), jnp.int32)) for _ in range(R)]
+        self.digests = [None] * R  # the last step's, per replica
 
     def step(self, req, want, heard):
         import jax.numpy as jnp
@@ -142,12 +143,13 @@ class _ReplicaArm:
         ring = put(req[None])
         outs, blobs = [], []
         for r in range(self.cfg.n_replicas):
-            self.states[r], out, blob, self.heat[r] = self.step_fn(
+            self.states[r], out, blob, self.heat[r], digest = self.step_fn(
                 self.states[r], gathered, put(heard[r]), ring,
                 put(want[r]), put(np.int32(r)), self.heat[r],
             )
             outs.append(out)
             blobs.append(blob)
+            self.digests[r] = digest
         self.blobs = blobs
         return outs
 
@@ -160,6 +162,7 @@ class _ReplicaArm:
                 yield f"r{r}.state.{name}", leaf
             yield f"r{r}.blob", blob
             yield f"r{r}.heat", heat
+            yield f"r{r}.digest", self.digests[r]
 
 
 def _assert_same(name: str, got, want) -> None:

@@ -58,17 +58,20 @@ from .ops.engine import (
     EngineConfig,
     EngineState,
     init_state,
-    StepOutputs,
+    StepDigest,
     blob_vec_len,
+    digest_from_planes,
+    digest_rows,
     make_blob,
     pack_blob,
     split_blob_vec,
+    split_digest_vec,
     split_out_vec,
 )
 from .parallel.spmd import make_step
 from .obs import gplog
 from .obs.flight import FlightRecorder
-from .obs.metrics import TICK_BOUNDS, MetricsRegistry
+from .obs.metrics import ROW_BOUNDS, TICK_BOUNDS, MetricsRegistry
 from .obs.reqtrace import RequestTracer
 from .obs.spans import span
 from .ops.lifecycle import create_groups, kill_groups, restore_paused_rows
@@ -90,6 +93,34 @@ _publish_vec_jit = jax.jit(lambda state: pack_blob(make_blob(state)))
 _pack_rows_jit = jax.jit(
     lambda b: jnp.concatenate([x.reshape(x.shape[0], -1) for x in b], axis=1)
 )
+
+
+def _committed_rows(digest: StepDigest) -> List[Tuple[int, int, int]]:
+    """(index into the digest's planes, row, slots committed) of the rows
+    that executed something in this substep, rows ascending."""
+    n_committed = digest.n_committed[digest.rows]
+    return [
+        (int(k), int(digest.rows[k]), int(n_committed[k]))
+        for k in np.flatnonzero(n_committed)
+    ]
+
+
+def _accepted_lanes(digests: List[StepDigest]):
+    """The lanes some substep of the dispatch newly accepted, each once,
+    rows then lanes ascending, with their slot, ballot and value in the
+    dispatch-final state: (rows, acc_slot, acc_bal, acc_vid), all [n].
+    Every digest carries the FINAL state's accept columns, so a row that
+    several substeps touched reads the same from each."""
+    rows = np.unique(np.concatenate([d.rows for d in digests]))
+    W = digests[0].acc_new.shape[1]
+    acc_any = np.zeros((len(rows), W), bool)
+    cols = np.empty((3, len(rows), W), np.int32)
+    for d in digests:
+        at = np.searchsorted(rows, d.rows)
+        acc_any[at] |= d.acc_new != 0
+        cols[:, at] = (d.acc_slot, d.acc_bal, d.acc_vid)
+    ks, lanes = np.nonzero(acc_any)
+    return (rows[ks].astype(np.int32),) + tuple(cols[:, ks, lanes])
 
 
 def _mix32(h: int, vid: int) -> int:
@@ -250,7 +281,11 @@ class PaxosManager:
         self.log = gplog.node_logger("manager", my_id)
         self.tracer = RequestTracer(my_id)
         self.metrics = MetricsRegistry(node=my_id)
-        self.metrics.count("requests_carried_over", 0)  # present from the start
+        # present from the start: a snapshot shows a counter that never
+        # fired apart from a program that has no such counter
+        for key in ("requests_carried_over", "step_digest_dispatches",
+                    "step_digest_overflows"):
+            self.metrics.count(key, 0)
         # black-box flight recorder (obs/flight.py): always-on bounded
         # rings of per-step engine summaries + last-K decided
         # (group, slot, ballot, vid), dumped on divergence/exception/
@@ -368,6 +403,12 @@ class PaxosManager:
         # the hot dispatch never retraces after warmup)
         self._compile_seen = 0
         self._retrace_seen = 0
+        # what the post-step reads of a dispatch is the step's digest
+        # (ops/engine.py:make_digest); a substep with more busy rows than
+        # the digest holds has its whole planes pulled instead
+        self._digest_rows = digest_rows(cfg)
+        # the work-in-flight flag of the last completed step's new state
+        self._work_in_flight = False
         # device-resident [G] group-activity accumulator + the host-side
         # cumulative view refreshed by pull_group_heat at stats cadence
         self._heat_dev = jnp.zeros((G,), jnp.int32)
@@ -606,16 +647,22 @@ class PaxosManager:
         later tick overwrites in place.  Device backends already transfer
         into a fresh host buffer (`.base` None)."""
         with self._state_lock:
-            if self._np_cache_state is not self.state:
-                self._np_cache = {}
-                self._np_cache_state = self.state
-            arr = self._np_cache.get(leaf)
+            cache = self._np_cache_locked()
+            arr = cache.get(leaf)
             if arr is None:
                 arr = np.asarray(getattr(self.state, leaf))
                 if arr.base is not None:
                     arr = arr.copy()
-                self._np_cache[leaf] = arr
+                cache[leaf] = arr
             return arr
+
+    def _np_cache_locked(self) -> Dict[str, np.ndarray]:
+        """Lock held: the host cache of the CURRENT state object's
+        leaves (emptied when the state was replaced since)."""
+        if self._np_cache_state is not self.state:
+            self._np_cache = {}
+            self._np_cache_state = self.state
+        return self._np_cache
 
     # ------------------------------------------------------------------
     # recovery (initiateRecovery analog, PaxosManager.java:1832-2035)
@@ -2732,17 +2779,12 @@ class PaxosManager:
         outstanding coordinator proposals.  Drives the server's
         event-kicked tick (a blob arriving mid-round should be consumed
         NOW, not a full tick quantum later — per-hop quantum delays are
-        what made the socket path's round trip ~10x the engine's)."""
-        with self._state_lock:
-            acc_slot = self._np("acc_slot")
-            acc_vid = self._np("acc_vid")
-            exec_slot = self._np("exec_slot")
-            prop = self._np("c_prop_vid")
-        live = (
-            (acc_slot != NULL) & (acc_vid != NULL)
-            & (acc_slot >= exec_slot[:, None])
-        )
-        return bool(live.any() or (prop != NULL).any())
+        what made the socket path's round trip ~10x the engine's).
+
+        The flag of the last completed step, worked out on the device
+        from that step's new state (ops/engine.py:work_in_flight): the
+        tick loop reads it right after ``step_complete``."""
+        return self._work_in_flight
 
     # ------------------------------------------------------------------
     # host channel ingress (payload replication + forwarded proposals)
@@ -3183,8 +3225,10 @@ class PaxosManager:
     def _dispatch_locked(self, step, gathered_vec, heard, want_coord,
                          carry: bool = False):
         """Lock held: admit into the request ring and fire ``step``
-        without waiting for the device.  Returns (out_vec, blob_vec,
-        t0) with ``self.state`` already the in-flight result."""
+        without waiting for the device.  Returns the pending handle of
+        device values (``out_vec`` stays on the device unless a substep's
+        digest overflows) with ``self.state`` already the in-flight
+        result."""
         with self._span("step.ring_build", cpu=False):
             req = self.build_request_ring(self.steps_per_dispatch)
             wc = (
@@ -3195,7 +3239,7 @@ class PaxosManager:
             carried = self._carried_leaves(old_state) if carry else None
         with self._span("step.dispatch"):
             t0 = time.monotonic()
-            new_state, out_vec, blob_vec, new_heat = step(
+            new_state, out_vec, blob_vec, new_heat, digest_vec = step(
                 old_state, jnp.asarray(gathered_vec), jnp.asarray(heard),
                 jnp.asarray(req), jnp.asarray(wc), jnp.int32(self.my_id),
                 self._heat_dev,
@@ -3205,7 +3249,10 @@ class PaxosManager:
         if carry:
             self._np_cache = carried
             self._np_cache_state = new_state
-        return out_vec, blob_vec, t0
+        return {
+            "out_vec": out_vec, "blob_vec": blob_vec,
+            "digest_vec": digest_vec, "state": new_state, "t0": t0,
+        }
 
     def _carried_leaves(self, old_state) -> Dict[str, np.ndarray]:
         """The lifecycle-owned leaves' host cache, carried across the
@@ -3228,20 +3275,48 @@ class PaxosManager:
                 carry[leaf] = arr.copy() if arr.base is not None else arr
         return carry
 
-    def _device_wait(self, out_vec, blob_vec):
-        """The step's outputs on the host: two transfers, the first
-        forces the sync.  Its annotation wraps JAX's own host events, so
-        an idle gap of the device under it keeps their names."""
+    def _device_wait(self, pend: Dict):
+        """The step's digests and blob on the host: two transfers, the
+        first forces the sync.  Its annotation wraps JAX's own host
+        events, so an idle gap of the device under it keeps their
+        names."""
         with self._span("step.device_wait"):
-            return np.asarray(out_vec), np.asarray(blob_vec)
+            return np.asarray(pend["digest_vec"]), np.asarray(pend["blob_vec"])
 
-    def _complete_locked(self, out_np_vec, t0: float) -> Dict:
-        """Lock held, outputs on the host: close the ``engine_step_s``
-        envelope and run the post-step host cycle."""
-        self.last_engine_step_s = time.monotonic() - t0
+    def _complete_locked(self, pend: Dict, digest_np, blob_np) -> Dict:
+        """Lock held, digests and blob on the host: close the
+        ``engine_step_s`` envelope and run the post-step host cycle."""
+        self.last_engine_step_s = time.monotonic() - pend["t0"]
         with self._span("post_step"):
-            outs = [split_out_vec(row, self.cfg) for row in out_np_vec]
-            return self._post_step_locked(outs)
+            if pend["state"] is self.state:
+                # the new state's ballots and frontiers are in the blob,
+                # unmasked (ops/engine.py:make_blob): the tick path reads
+                # them from here and not from the device
+                blob = split_blob_vec(blob_np, self.cfg)
+                self._np_cache_locked().update(
+                    bal=blob.bal, exec_slot=blob.exec_slot)
+            mx = self.metrics
+            digests = []
+            for i, row in enumerate(digest_np):
+                digest, n_busy = split_digest_vec(row, self.cfg)
+                mx.count("step_digest_dispatches")
+                mx.observe("step_digest_rows", n_busy, bounds=ROW_BOUNDS)
+                if n_busy > self._digest_rows:
+                    mx.count("step_digest_overflows")
+                    digest = self._whole_planes_locked(
+                        pend["out_vec"][i], digest.live)
+                digests.append(digest)
+            self._work_in_flight = digests[-1].live
+            return self._post_step_locked(digests)
+
+    def _whole_planes_locked(self, out_vec_row, live: bool) -> StepDigest:
+        """A substep whose busy rows overflowed the device's digest: its
+        whole output planes and the new state's accept columns, pulled
+        and reduced on the host to the same form."""
+        return digest_from_planes(
+            split_out_vec(out_vec_row, self.cfg), self._np("acc_slot"),
+            self._np("acc_bal"), self._np("acc_vid"), live,
+        )
 
     def _fire(self, fired) -> None:
         """User callbacks, after the lock is released."""
@@ -3297,16 +3372,13 @@ class PaxosManager:
         done, which is correct but serializing; the hot propose path
         avoids that via the carried lifecycle-leaf cache below)."""
         with self._step_locked():  # single-depth pipeline
-            out_vec, blob_vec, t0 = self._dispatch_locked(
+            pend = self._dispatch_locked(
                 self._dispatch_step, gathered_vec, heard, want_coord,
                 carry=True,
             )
             self._step_inflight = True
             self._step_thread = threading.get_ident()
-            return {
-                "out_vec": out_vec, "blob_vec": blob_vec,
-                "state": self.state, "t0": t0,
-            }
+            return pend
 
     def step_complete(
         self, pend: Dict
@@ -3317,13 +3389,12 @@ class PaxosManager:
         # device sync OUTSIDE the lock: np.asarray blocks with the GIL
         # released, so transport threads run the ingress/codec path
         # against the still-valid carried caches while the device works
-        out_np_vec, blob_vec = self._device_wait(
-            pend["out_vec"], pend["blob_vec"])
+        digest_np, blob_vec = self._device_wait(pend)
         with self._span("post_step.lock_wait", cpu=False):
             self._state_lock.acquire()
         try:
             try:
-                host_delta = self._complete_locked(out_np_vec, pend["t0"])
+                host_delta = self._complete_locked(pend, digest_np, blob_vec)
             finally:
                 self._step_inflight = False
                 self._step_thread = None
@@ -3340,11 +3411,11 @@ class PaxosManager:
         heard: np.ndarray,
         want_coord: Optional[np.ndarray],
     ) -> Tuple[np.ndarray, "EngineState", Dict]:
-        out_vec, blob_vec, t0 = self._dispatch_locked(
+        pend = self._dispatch_locked(
             self._dispatch_step, gathered_vec, heard, want_coord)
-        new_state = self.state
-        out_np_vec, blob_np = self._device_wait(out_vec, blob_vec)
-        return blob_np, new_state, self._complete_locked(out_np_vec, t0)
+        digest_np, blob_np = self._device_wait(pend)
+        return blob_np, pend["state"], self._complete_locked(
+            pend, digest_np, blob_np)
 
     def _tick_locked(
         self,
@@ -3356,23 +3427,25 @@ class PaxosManager:
         # one [R, NB] matrix (each row == pack_blob of that replica);
         # donate=False — the test-cluster harness caches blob views that
         # alias the live state across ticks
-        out_vec, blob_vec, t0 = self._dispatch_locked(
+        pend = self._dispatch_locked(
             self._tick_step, _pack_rows_jit(gathered), heard, want_coord)
-        out_np_vec, blob_np = self._device_wait(out_vec, blob_vec)
-        host_delta = self._complete_locked(out_np_vec, t0)
+        digest_np, blob_np = self._device_wait(pend)
+        host_delta = self._complete_locked(pend, digest_np, blob_np)
         return split_blob_vec(blob_np, self.cfg), host_delta
 
     def _post_step_locked(self, outs) -> Dict:
         """Shared post-engine host work (requeue, watermarks, journaling,
         execution, state pulls, gossip delta) for every tick flavor.
 
-        ``outs`` is the dispatch's LIST of per-substep StepOutputs (a
-        bare StepOutputs is accepted as a 1-list) — one host cycle per
-        dispatch covers all N device-resident substeps: per-substep work
-        (decision logging, execution, preempt requeue) runs in substep
-        order; per-dispatch work (ballot pull, watermarks, checkpoint
-        cadence, gossip delta) runs once against the final state."""
-        if isinstance(outs, StepOutputs):
+        ``outs`` is the dispatch's LIST of per-substep StepDigests (a
+        bare one is accepted as a 1-list): the [G] output leaves whole,
+        the [G, W] planes as their busy rows only, in row order — one
+        host cycle per dispatch covers all N device-resident substeps:
+        per-substep work (decision logging, execution, preempt requeue)
+        runs in substep order; per-dispatch work (ballot flips,
+        watermarks, checkpoint cadence, gossip delta) runs once against
+        the final state."""
+        if isinstance(outs, StepDigest):
             outs = [outs]
         last = outs[-1]
         n_sub = len(outs)
@@ -3389,11 +3462,11 @@ class PaxosManager:
         # slab bookkeeping of substeps > i
         preempt_requeue = []
         for o in outs:
-            pre_g, pre_l = np.nonzero(o.preempted_vid != NULL)
-            for g_, l_ in zip(pre_g, pre_l):
-                vid = int(o.preempted_vid[g_, l_])
+            pre_k, pre_l = np.nonzero(o.preempted_vid != NULL)
+            for k_, l_ in zip(pre_k, pre_l):
+                vid = int(o.preempted_vid[k_, l_])
                 if vid in self.arena and vid not in self.retained:
-                    preempt_requeue.append((int(g_), vid))
+                    preempt_requeue.append((int(o.rows[k_]), vid))
         # per-step engine metrics: aggregate counters reduced from the
         # vectorized step outputs — a few O(G) numpy sums per DISPATCH
         # (the engine step itself is ~1ms), never per-request host work
@@ -3411,10 +3484,10 @@ class PaxosManager:
             bal_rose = bal_rose | o.bal_new
         flips = rises = 0
         if bal_rose.any():
-            # coordinator flips: `bal` is only pulled host-side on the
-            # rare dispatches where a promised ballot rose (elections),
-            # and only the risen rows are compared against the cached
-            # view; the pull reflects the dispatch-final state
+            # coordinator flips: only on the rare dispatches where a
+            # promised ballot rose (elections), and only the risen rows
+            # are compared against the cached view; `bal` is the
+            # dispatch-final state's, seeded from the blob
             pg_m = np.nonzero(bal_rose)[0]
             bal_host = self._np("bal")
             self._bal_host = bal_host.copy()
@@ -3536,20 +3609,9 @@ class PaxosManager:
                 # within this dispatch, and that decision is journaled
                 # per substep by _execute below — so the final lane view
                 # plus the per-substep decision log loses nothing
-                acc_any = outs[0].acc_new
-                for o in outs[1:]:
-                    acc_any = acc_any | o.acc_new
-                gs, lanes = np.nonzero(acc_any)
+                gs, acc_slot, acc_bal, acc_vid = _accepted_lanes(outs)
                 if len(gs):
-                    acc_slot = self._np("acc_slot")
-                    acc_bal = self._np("acc_bal")
-                    acc_vid = self._np("acc_vid")
-                    self.logger.log_accepts(
-                        gs.astype(np.int32),
-                        acc_slot[gs, lanes],
-                        acc_bal[gs, lanes],
-                        acc_vid[gs, lanes],
-                    )
+                    self.logger.log_accepts(gs, acc_slot, acc_bal, acc_vid)
                 if payload_delta:
                     self.logger.log_payloads(payload_delta, meta=meta_delta)
                 for o in outs:
@@ -3597,27 +3659,26 @@ class PaxosManager:
     # ------------------------------------------------------------------
     # execution (EEC analog, PaxosInstanceStateMachine.java:1511-1734)
     # ------------------------------------------------------------------
-    def _log_decisions(self, out_np) -> None:
+    def _log_decisions(self, out_np: StepDigest) -> None:
         """One substep's decisions into the open journal batch."""
-        committed = np.nonzero(out_np.n_committed)[0]
-        if not len(committed):
-            return
         rows, slots, vids = [], [], []
-        for g in committed:
+        for k, g, n in _committed_rows(out_np):
             base = int(out_np.exec_base[g])
-            for o in range(int(out_np.n_committed[g])):
+            for o in range(n):
                 rows.append(g)
                 slots.append(base + o)
-                vids.append(int(out_np.exec_vid[g, o]))
+                vids.append(int(out_np.exec_vid[k, o]))
+        if not rows:
+            return
         self.logger.log_decisions(
             np.array(rows, np.int32), np.array(slots, np.int32),
             np.array(vids, np.int32),
         )
 
-    def _execute(self, out_np) -> None:
-        committed = np.nonzero(out_np.n_committed)[0]
-        if len(committed):
-            self.row_activity[committed] = time.time()
+    def _execute(self, out_np: StepDigest) -> None:
+        committed = _committed_rows(out_np)
+        if committed:
+            self.row_activity[[g for _k, g, _n in committed]] = time.time()
         tr = self.tracer
         tcm = self.trace_ctx
         # ballot attribution for decide events + the flight recorder's
@@ -3625,14 +3686,14 @@ class PaxosManager:
         # pulling `bal` from the device per commit tick costs a sync
         # that measurably perturbs soak timing
         bal_np = self._bal_host
-        for g in committed:
+        for k, g, n in committed:
             base = int(out_np.exec_base[g])
             bal_g = int(bal_np[g])
-            pend = self.pending_exec.setdefault(int(g), {})
-            for o in range(int(out_np.n_committed[g])):
-                vid = int(out_np.exec_vid[g, o])
+            pend = self.pending_exec.setdefault(g, {})
+            for o in range(n):
+                vid = int(out_np.exec_vid[k, o])
                 pend[base + o] = vid
-                self.flight.record_decided(int(g), base + o, bal_g, vid)
+                self.flight.record_decided(g, base + o, bal_g, vid)
                 if vid == 0:
                     continue
                 meta = self.vid_meta.get(vid)
@@ -3640,16 +3701,15 @@ class PaxosManager:
                 tc = tcm.get(key) if tcm else None
                 if tr.enabled or tc is not None:
                     tr.note(
-                        key, "decide", name=self.row_name.get(int(g)),
-                        node=self.my_id, row=int(g), slot=base + o,
+                        key, "decide", name=self.row_name.get(g),
+                        node=self.my_id, row=g, slot=base + o,
                         vid=vid, ballot=bal_g, tick=self._tick_no,
                         force=tc is not None, **self._tc_detail(tc),
                     )
         # per-phase latency distribution (SLO surface): the decided-
         # slot execution leg of a tick, recorded when something was
         # decided, exported via /metrics + stats
-        with self._span("execute", cpu=False,
-                        record=bool(len(committed))):
+        with self._span("execute", cpu=False, record=bool(committed)):
             missing = self._drain_pending_exec()
         if missing:
             self.forward_out.append(

@@ -867,6 +867,11 @@ def unpack_gathered(gvec: jnp.ndarray, cfg: EngineConfig) -> Blob:
     return _unpack(gvec, Blob._fields, cfg, Blob, batched=True)
 
 
+def unpack_out(vec: jnp.ndarray, cfg: EngineConfig) -> StepOutputs:
+    """[M] packed step outputs -> StepOutputs (inside jit)."""
+    return _unpack(vec, StepOutputs._fields, cfg, StepOutputs, batched=False)
+
+
 def split_out_vec(vec: np.ndarray, cfg: EngineConfig) -> StepOutputs:
     """Host-side: one transferred [M] vector -> StepOutputs of np views."""
     return _unpack(
@@ -877,6 +882,149 @@ def split_out_vec(vec: np.ndarray, cfg: EngineConfig) -> StepOutputs:
 def split_blob_vec(vec: np.ndarray, cfg: EngineConfig) -> Blob:
     return _unpack(
         np.asarray(vec), Blob._fields, cfg, Blob, batched=False
+    )
+
+
+# ---------------------------------------------------------------------------
+# The step digest: what the host's post-step reads, reduced on the device
+# to the rows that have something in them.  The six [G] output leaves come
+# whole (the watermark and the state-pull detectors read every row); of the
+# [G, W] planes only the BUSY rows come — those with a commit, a newly
+# accepted lane or a preempted proposal — each with its lanes of the NEW
+# state's accept columns (the journal's log-before-send rows), plus one
+# flag: does any row still hold consensus work.  A dispatch with more busy
+# rows than the digest holds reports its count, and the host pulls the
+# whole planes for that one (``digest_from_planes``).
+#
+# Vector layout (int32): the six [G] leaves in StepOutputs order, n_busy,
+# live, rows [M], then the six [M, W] planes in StepDigest order.
+# ---------------------------------------------------------------------------
+
+class StepDigest(NamedTuple):
+    """One substep's results as the host reads them: the [G] leaves of
+    :class:`StepOutputs` whole, ``rows`` the busy rows in ascending order,
+    and every plane [len(rows), W] — row ``k`` of a plane is row
+    ``rows[k]`` of the [G, W] plane it was gathered from."""
+
+    n_committed: np.ndarray    # [G]
+    exec_base: np.ndarray      # [G]
+    n_admitted: np.ndarray     # [G]
+    maj_exec: np.ndarray       # [G]
+    app_hash: np.ndarray       # [G]
+    bal_new: np.ndarray        # [G]
+    live: bool                 # the new state holds work in flight
+    rows: np.ndarray           # [n] busy rows, ascending
+    exec_vid: np.ndarray       # [n, W] of StepOutputs
+    acc_new: np.ndarray        # [n, W] of StepOutputs
+    preempted_vid: np.ndarray  # [n, W] of StepOutputs
+    acc_slot: np.ndarray       # [n, W] of the NEW state
+    acc_bal: np.ndarray        # [n, W] of the NEW state
+    acc_vid: np.ndarray        # [n, W] of the NEW state
+
+
+_DIGEST_G_LEAVES = StepDigest._fields[:6]
+_DIGEST_OUT_PLANES = ("exec_vid", "acc_new", "preempted_vid")
+_DIGEST_STATE_PLANES = ("acc_slot", "acc_bal", "acc_vid")
+
+
+def digest_rows(cfg: EngineConfig) -> int:
+    """M, the busy rows one digest holds: a sixteenth of the rows, at
+    least 1,024, at most all of them (4,096 of 65,536; 64 of 64)."""
+    return min(cfg.n_groups, max(1024, cfg.n_groups // 16))
+
+
+@functools.lru_cache(maxsize=None)
+def digest_vec_len(cfg: EngineConfig) -> int:
+    M = digest_rows(cfg)
+    return 6 * cfg.n_groups + 2 + M + 6 * M * cfg.window
+
+
+def work_in_flight(state: EngineState) -> jnp.ndarray:
+    """Scalar bool: some row holds consensus work that the next peer
+    blob can advance — an accepted lane at or past the execute frontier,
+    or an outstanding coordinator proposal."""
+    lanes = (
+        (state.acc_slot != NULL) & (state.acc_vid != NULL)
+        & (state.acc_slot >= state.exec_slot[:, None])
+    )
+    return lanes.any() | (state.c_prop_vid != NULL).any()
+
+
+def _busy_rows(out) -> "jnp.ndarray | np.ndarray":
+    """[G] bool: the rows whose [G, W] output planes are not all empty."""
+    return (
+        (out.n_committed > 0)
+        | (out.acc_new != 0).any(-1)
+        | (out.preempted_vid != NULL).any(-1)
+    )
+
+
+_DIGEST_CHUNK = 256  # rows gathered per pass of make_digest's loop
+
+
+def make_digest(out: StepOutputs, state: EngineState,
+                cfg: EngineConfig) -> jnp.ndarray:
+    """Inside jit: the digest vector of one substep's ``out`` against
+    the dispatch's NEW ``state``.  The device's work follows the busy
+    rows: one sort of [G] keys names them, and their lanes are gathered
+    a chunk of rows at a time, as many chunks as hold them (rows of the
+    planes past the last chunk stay 0; the host reads none of them)."""
+    G, W, M = cfg.n_groups, cfg.window, digest_rows(cfg)
+    C = min(_DIGEST_CHUNK, M)
+    busy = _busy_rows(out)
+    n_busy = busy.sum(dtype=jnp.int32)
+    # the first M busy rows, ascending; G past the last of them
+    rows = jnp.sort(jnp.where(busy, jnp.arange(G, dtype=jnp.int32), G))[:M]
+    at = jnp.minimum(rows, G - 1)
+    sources = [getattr(out, f) for f in _DIGEST_OUT_PLANES] \
+        + [getattr(state, f) for f in _DIGEST_STATE_PLANES]
+
+    def gather_chunk(i, planes):
+        # a chunk that would run past M is read and written C rows back
+        # from the end: dynamic_slice clamps both the same way
+        chunk = lax.dynamic_slice(at, (i * C,), (C,))
+        return lax.dynamic_update_slice(
+            planes, jnp.stack([src[chunk] for src in sources]), (0, i * C, 0)
+        )
+
+    planes = lax.fori_loop(
+        0, (jnp.minimum(n_busy, M) + C - 1) // C, gather_chunk,
+        jnp.zeros((6, M, W), jnp.int32),
+    )
+    head = jnp.stack([n_busy, work_in_flight(state).astype(jnp.int32)])
+    return jnp.concatenate(
+        [getattr(out, f) for f in _DIGEST_G_LEAVES]
+        + [head, rows, jnp.ravel(planes)]
+    )
+
+
+def split_digest_vec(vec: np.ndarray, cfg: EngineConfig):
+    """Host-side: one transferred digest vector -> (:class:`StepDigest`
+    of np views, n_busy).  Where ``n_busy`` exceeds the digest's rows
+    the planes hold only the first of them: the caller pulls the whole
+    planes instead (``digest_from_planes``)."""
+    G, W, M = cfg.n_groups, cfg.window, digest_rows(cfg)
+    vec = np.asarray(vec)
+    g_leaves = vec[:6 * G].reshape(6, G)
+    n_busy, live = int(vec[6 * G]), bool(vec[6 * G + 1])
+    n = min(n_busy, M)
+    off = 6 * G + 2
+    rows = vec[off:off + n]
+    planes = vec[off + M:].reshape(6, M, W)[:, :n]
+    return StepDigest(*g_leaves, live, rows, *planes), n_busy
+
+
+def digest_from_planes(out: StepOutputs, acc_slot: np.ndarray,
+                       acc_bal: np.ndarray, acc_vid: np.ndarray,
+                       live: bool) -> StepDigest:
+    """Host-side: the digest of whole pulled planes, however many rows
+    are busy — the path of a dispatch that overflowed the device's
+    digest, and what the tests hold the device's digest against."""
+    rows = np.flatnonzero(_busy_rows(out)).astype(np.int32)
+    return StepDigest(
+        *[getattr(out, f) for f in _DIGEST_G_LEAVES], live, rows,
+        *[getattr(out, f)[rows] for f in _DIGEST_OUT_PLANES],
+        acc_slot[rows], acc_bal[rows], acc_vid[rows],
     )
 
 

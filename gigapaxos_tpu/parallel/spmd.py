@@ -34,10 +34,15 @@ Two I/O flavors:
 * ``io="packed_host"`` — the deployed-runtime face (one replica's state,
   peers' blobs arriving as the packed ``[R, NB]`` gathered matrix == the
   ``D`` wire-frame bodies): returns ``(state', out_rings [N, M],
-  blob_vec)``.  Substep 0 consumes the gathered rows exactly as passed;
-  substeps >= 1 refresh only MY row from the advancing state while
-  peers' rows stay frozen — the semantics of N serial host ticks during
-  which no new peer frame lands.
+  blob_vec)``, and with ``heat`` also the heat accumulator and
+  ``digests [N, L]`` — per substep what the host's post-step reads
+  (``ops/engine.py:make_digest``: the [G] output leaves, the busy rows
+  of the [G, W] planes with their accept lanes of the new state, a
+  work-in-flight flag), so that ``out_rings`` can stay on the device.
+  Substep 0 consumes the gathered rows exactly as passed; substeps >= 1
+  refresh only MY row from the advancing state while peers' rows stay
+  frozen — the semantics of N serial host ticks during which no new
+  peer frame lands.
 
 The three pre-factory entry points (``single_chip_step``, ``spmd_step``,
 ``group_sharded_step``) survive as thin deprecated aliases over the
@@ -65,10 +70,12 @@ from ..ops.engine import (
     EngineState,
     StepOutputs,
     make_blob,
+    make_digest,
     out_vec_len,
     pack_blob,
     step,
     unpack_gathered,
+    unpack_out,
 )
 from .mesh import GROUP_AXIS, REPLICA_AXIS
 
@@ -252,6 +259,7 @@ def _build_packed(cfg: EngineConfig, mesh: Optional[Mesh], n_steps: int,
             return (
                 _constrain(mesh, new_state, GROUP_AXIS),
                 out_rings, blob_vec, heat_acc,
+                make_digest(out, new_state, cfg)[None],
             )
     else:
         def _core(state, gvec, heard, req_ring, want_coord, my_id,
@@ -291,9 +299,14 @@ def _build_packed(cfg: EngineConfig, mesh: Optional[Mesh], n_steps: int,
                 0, n_steps, body, (state, out0, heat_acc)
             )
             blob_vec = pack_blob(make_blob(new_state))
+            # one digest row per substep, each against the dispatch's
+            # final state: the journal values an accepted lane from it
+            digests = jax.vmap(
+                lambda row: make_digest(unpack_out(row, cfg), new_state, cfg)
+            )(out_rings)
             return (
                 _constrain(mesh, new_state, GROUP_AXIS), out_rings,
-                blob_vec, _constrain(mesh, heat_acc, GROUP_AXIS),
+                blob_vec, _constrain(mesh, heat_acc, GROUP_AXIS), digests,
             )
 
     if heat:
@@ -310,7 +323,7 @@ def _build_packed(cfg: EngineConfig, mesh: Optional[Mesh], n_steps: int,
 
     @partial(jax.jit, donate_argnums=(0,) if donate else ())
     def run(state, gvec, heard, req_ring, want_coord, my_id):
-        new_state, out_rings, blob_vec, _ = _core(
+        new_state, out_rings, blob_vec, _, _ = _core(
             state, gvec, heard, req_ring, want_coord, my_id,
             jnp.zeros((cfg.n_groups,), jnp.int32),
         )
@@ -377,9 +390,10 @@ def make_step(cfg: EngineConfig, mesh: Optional[Mesh] = None,
         activity accumulator through the dispatch — the step takes it
         as a trailing argument and returns ``heat + n_committed +
         n_admitted`` folded across every substep inside the device
-        loop.  The host pulls it at the STATS cadence (obs/device.py
-        heat analysis), never per tick.  ``False`` keeps the exact
-        legacy signatures.
+        loop, then the per-substep digests (the manager's face).  The
+        host pulls the heat at the STATS cadence (obs/device.py heat
+        analysis), never per tick.  ``False`` keeps the exact legacy
+        signatures.
 
     Instances are memoized: the same (cfg, mesh, N, donate, io, heat)
     returns the same callable, so jit caches are shared across

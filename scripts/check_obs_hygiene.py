@@ -81,12 +81,22 @@ HOT_NP_ALLOW = {
     ("manager.py", "_execute_one"): frozenset({"version"}),
     ("manager.py", "build_request_ring"): frozenset({"bal", "version"}),
     ("manager.py", "_filter_stale_vids"): frozenset({"version"}),
-    ("manager.py", "_post_step_locked"): frozenset(
-        {"bal", "member_mask", "acc_slot", "acc_bal", "acc_vid"}
+    # `bal` and `exec_slot` of a completed step are seeded into the host
+    # cache from its blob (_complete_locked), `member_mask` is carried
+    # across the swap: no pull below reaches the device on the tick path
+    ("manager.py", "_complete_locked"): frozenset(),
+    ("manager.py", "_post_step_locked"): frozenset({"bal", "member_mask"}),
+    ("manager.py", "_log_decisions"): frozenset(),
+    ("manager.py", "engine_work_in_flight"): frozenset(),
+    # the ONE place a [G, W] leaf crosses to the host on the tick path: a
+    # substep whose busy rows overflowed the step's digest
+    ("manager.py", "_whole_planes_locked"): frozenset(
+        {"acc_slot", "acc_bal", "acc_vid"}
     ),
     ("server.py", "_should_tick"): frozenset({"bal", "member_mask"}),
     ("server.py", "_tick_once_inner"): frozenset(),
     ("server.py", "_gather"): frozenset({"bal", "member_mask"}),
+    ("server.py", "_finish_tick"): frozenset(),
     # stats-cadence hook: the ONE sanctioned group-heat drain (runs at
     # STATS_LOG_PERIOD_S inside the tick loop, not per tick)
     ("server.py", "_maybe_stats_line"): frozenset({GROUP_HEAT_LEAF}),

@@ -12,6 +12,7 @@ import pytest
 from gigapaxos_tpu.manager import PaxosManager
 from gigapaxos_tpu.models.apps import HashChainApp
 from gigapaxos_tpu.ops.engine import EngineConfig
+from gigapaxos_tpu.utils.config import Config
 
 CFG = EngineConfig(n_groups=8, window=8, req_lanes=4, n_replicas=3)
 
@@ -78,11 +79,22 @@ class PackedCluster:
             m.close()
 
 
-def test_pipeline_state_parity():
+@pytest.mark.parametrize("steps_per_dispatch, whole_planes", [
+    (1, False), (4, False),
+    (1, True),   # the pipelined side pulls the whole planes every dispatch
+])
+def test_pipeline_state_parity(steps_per_dispatch, whole_planes):
     """Identical schedule through serial and pipelined dispatch: every
     engine leaf equal after every cluster step, and identical client
-    responses."""
+    responses — with one substep a dispatch and with four (each
+    substep's digest read in turn), and with the donated, pipelined side
+    forced down the digest's overflow path."""
+    Config.set("ENGINE_STEPS_PER_DISPATCH", str(steps_per_dispatch))
     serial, piped = PackedCluster(False), PackedCluster(True)
+    assert serial.managers[0].steps_per_dispatch == steps_per_dispatch
+    if whole_planes:
+        for m in piped.managers:
+            m._digest_rows = -1
     try:
         resp_s, resp_p = [], []
         names = ["pa", "pb", "pc"]
