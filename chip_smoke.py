@@ -191,10 +191,8 @@ def phase_engine_parity(n_groups: int, window: int, req_lanes: int,
     from gigapaxos_tpu.parallel.spmd import make_step
 
     cfg = EngineConfig(n_groups, window, req_lanes, n_replicas)
-    step_fn = make_step(cfg, None, 1, donate=True, io="packed_host",
-                        heat=True)
-    ref_fn = make_step(cfg, None, 1, donate=False, io="packed_host",
-                       heat=True)
+    step_fn = make_step(cfg, None, 1, donate=True, io="packed_host")
+    ref_fn = make_step(cfg, None, 1, donate=False, io="packed_host")
     arm = _ReplicaArm(cfg, device, step_fn)
     ref = _ReplicaArm(cfg, reference_device, ref_fn)
     decided = admitted = 0

@@ -54,7 +54,6 @@ from .paxos_config import PC
 from .utils.config import Config
 from .ops.engine import (
     STOP_BIT,
-    Blob,
     EngineConfig,
     EngineState,
     init_state,
@@ -77,22 +76,13 @@ from .obs.spans import span
 from .ops.lifecycle import create_groups, kill_groups, restore_paused_rows
 from .storage.logger import PaxosLogger
 
-# Every tick flavor steps through the ONE unified factory
-# (parallel/spmd.py:make_step, io="packed_host").  The dispatch path
-# donates the state: the manager owns it exclusively (every external view
-# is an identity check or a host-side numpy copy), so the old buffers may
-# be reused in place by the new state — on-device this halves state HBM;
-# backends without donation support ignore it.  The Blob-exchange tick
-# (_tick_locked, the test-cluster harness) uses a donate=False instance:
-# that harness caches blob views aliasing the live state across ticks.
+# Both ways to run a tick step through the ONE unified factory
+# (parallel/spmd.py:make_step, io="packed_host"), and it donates the
+# state: the manager owns it exclusively (every external view is an
+# identity check or a host-side numpy copy), so the old buffers may be
+# reused in place by the new state — on-device this halves state HBM;
+# backends without donation support ignore it.
 _publish_vec_jit = jax.jit(lambda state: pack_blob(make_blob(state)))
-# Blob of [R, ...] leaves -> [R, NB] packed rows (Blob._fields order, C
-# ravel per leaf — each row identical to pack_blob of that replica's
-# blob); the Blob-exchange tick packs its gathered blobs through this to
-# reach the unified packed step.
-_pack_rows_jit = jax.jit(
-    lambda b: jnp.concatenate([x.reshape(x.shape[0], -1) for x in b], axis=1)
-)
 
 
 def _committed_rows(digest: StepDigest) -> List[Tuple[int, int, int]]:
@@ -381,24 +371,20 @@ class PaxosManager:
             1, Config.get_int(PC.ENGINE_STEPS_PER_DISPATCH)
         )
         # the ONE unified step (parallel/spmd.py:make_step), packed-host
-        # flavor; instances are memoized by (cfg, N, donate, heat), so
-        # jit caches are shared across managers with the same shape.
-        # heat=True threads the [G] device-resident activity accumulator
-        # through every dispatch (decisions + admissions per group,
-        # folded across substeps inside the device loop); the host pulls
-        # it only at the stats cadence (pull_group_heat), never per tick
+        # flavor; instances are memoized by (cfg, N, donate), so the jit
+        # cache is shared across managers with the same shape.  It
+        # threads the [G] device-resident activity accumulator through
+        # every dispatch (decisions + admissions per group, folded
+        # across substeps inside the device loop); the host pulls it
+        # only at the stats cadence (pull_group_heat), never per tick
         self._dispatch_step = make_step(
             cfg, None, self.steps_per_dispatch, donate=True,
-            io="packed_host", heat=True,
+            io="packed_host",
         )
-        self._tick_step = make_step(
-            cfg, None, self.steps_per_dispatch, donate=False,
-            io="packed_host", heat=True,
-        )
-        # retrace sentinel bookkeeping (obs/device.py): the sentinels are
+        # retrace sentinel bookkeeping (obs/device.py): the sentinel is
         # SHARED across managers of the same shape, so per-node metrics
-        # count deltas against the last totals this manager saw; the
-        # sentinels are marked warm after this manager's first completed
+        # count deltas against the last totals this manager saw; it is
+        # marked warm after this manager's first completed
         # dispatch — any compile after that is a retrace (hard invariant:
         # the hot dispatch never retraces after warmup)
         self._compile_seen = 0
@@ -1096,11 +1082,10 @@ class PaxosManager:
 
     def engine_compile_stats(self) -> Dict:
         """The ``engine.compile`` stats block: compile/retrace counts of
-        this manager's two step instances (shared across same-shape
-        managers in-process) plus their last recorded events."""
+        this manager's step instance (shared across same-shape
+        managers in-process) plus its last recorded events."""
         return {
             "dispatch": self._dispatch_step.stats(),
-            "tick": self._tick_step.stats(),
             # the lifecycle scatters are jitted once per (state shape,
             # rows touched); ``warm_engine`` compiles the single-row
             # shapes an epoch change uses, so growth of these caches
@@ -3151,50 +3136,34 @@ class PaxosManager:
         self._last_ring_depth = staged
         return req
 
-    def tick(
-        self,
-        gathered: Blob,
-        heard: np.ndarray,
-        want_coord: Optional[np.ndarray] = None,
-    ) -> Tuple[Blob, Dict]:
-        """One full cycle; returns (my fresh blob, host-channel delta).
-
-        Holds the manager lock for the whole cycle: the transport-thread
-        entry points (propose / on_host_message / create / kill) mutate
-        the same queues, arena, and vid tables this reads and rewrites.
-        User callbacks collected during execution fire AFTER the lock is
-        released (a blocking callback must not wedge transport threads)."""
-        with self._step_locked():
-            result = self._tick_locked(gathered, heard, want_coord)
-            fired, self._fired_callbacks = self._fired_callbacks, []
-        self._fire(fired)
-        return result
-
     def tick_host(
         self,
         gathered_vec: np.ndarray,
         heard: np.ndarray,
         want_coord: Optional[np.ndarray] = None,
     ) -> Tuple[np.ndarray, "EngineState", Dict]:
-        """Packed-I/O tick for the deployed socket runtime: `gathered_vec`
-        is the [R, N] stack of packed peer blob vectors (== the `D` wire
-        frame bodies); returns (my fresh packed blob vector, the state it
-        reflects — for identity-based staleness checks, captured under
-        the lock so lifecycle ops can't mispair them — and the host
-        delta).  One device upload + two downloads per tick instead of
-        ~50 per-leaf dispatches — at loopback scale the per-leaf dispatch
-        overhead was most of a node's tick cost."""
+        """One full cycle, serially: :meth:`step_dispatch` and
+        :meth:`step_complete` back to back under ONE hold of the lock —
+        the reference the pipelined pair is held to
+        (tests/test_pipeline.py), and what the stepped harnesses call.
+        `gathered_vec` is the [R, N] stack of packed peer blob vectors
+        (== the `D` wire frame bodies); returns (my fresh packed blob
+        vector, the state it reflects, the host delta).  User callbacks
+        collected during execution fire AFTER the lock is released (a
+        blocking callback must not wedge transport threads)."""
         with self._step_locked():
-            result = self._tick_host_locked(gathered_vec, heard, want_coord)
+            pend = self._dispatch_locked(gathered_vec, heard, want_coord)
+            digest_np, blob_np = self._device_wait(pend)
+            host_delta = self._complete_locked(pend, digest_np, blob_np)
             fired, self._fired_callbacks = self._fired_callbacks, []
         self._fire(fired)
-        return result
+        return blob_np, pend["state"], host_delta
 
     # ------------------------------------------------------------------
-    # the tick's spans (obs/spans.py): every flavor of tick — pipelined
-    # step_dispatch/step_complete, serial tick_host, the test clusters'
-    # tick — is built from these helpers, so each phase is timed once,
-    # in one place
+    # the tick's spans (obs/spans.py): both ways to run a tick —
+    # pipelined step_dispatch/step_complete, serial tick_host — are
+    # built from these helpers, so each phase is timed once, in one
+    # place
     # ------------------------------------------------------------------
     def _span(self, phase: str, cpu: bool = True, record: bool = True):
         """A span of the thread that ticks this node (the server's tick
@@ -3222,9 +3191,9 @@ class PaxosManager:
         finally:
             self._state_lock.release()
 
-    def _dispatch_locked(self, step, gathered_vec, heard, want_coord,
+    def _dispatch_locked(self, gathered_vec, heard, want_coord,
                          carry: bool = False):
-        """Lock held: admit into the request ring and fire ``step``
+        """Lock held: admit into the request ring and fire the step
         without waiting for the device.  Returns the pending handle of
         device values (``out_vec`` stays on the device unless a substep's
         digest overflows) with ``self.state`` already the in-flight
@@ -3239,11 +3208,12 @@ class PaxosManager:
             carried = self._carried_leaves(old_state) if carry else None
         with self._span("step.dispatch"):
             t0 = time.monotonic()
-            new_state, out_vec, blob_vec, new_heat, digest_vec = step(
-                old_state, jnp.asarray(gathered_vec), jnp.asarray(heard),
-                jnp.asarray(req), jnp.asarray(wc), jnp.int32(self.my_id),
-                self._heat_dev,
-            )
+            new_state, out_vec, blob_vec, new_heat, digest_vec = \
+                self._dispatch_step(
+                    old_state, jnp.asarray(gathered_vec),
+                    jnp.asarray(heard), jnp.asarray(req), jnp.asarray(wc),
+                    jnp.int32(self.my_id), self._heat_dev,
+                )
         self.state = new_state
         self._heat_dev = new_heat
         if carry:
@@ -3373,8 +3343,7 @@ class PaxosManager:
         avoids that via the carried lifecycle-leaf cache below)."""
         with self._step_locked():  # single-depth pipeline
             pend = self._dispatch_locked(
-                self._dispatch_step, gathered_vec, heard, want_coord,
-                carry=True,
+                gathered_vec, heard, want_coord, carry=True,
             )
             self._step_inflight = True
             self._step_thread = threading.get_ident()
@@ -3405,48 +3374,18 @@ class PaxosManager:
         self._fire(fired)
         return blob_vec, pend["state"], host_delta
 
-    def _tick_host_locked(
-        self,
-        gathered_vec: np.ndarray,
-        heard: np.ndarray,
-        want_coord: Optional[np.ndarray],
-    ) -> Tuple[np.ndarray, "EngineState", Dict]:
-        pend = self._dispatch_locked(
-            self._dispatch_step, gathered_vec, heard, want_coord)
-        digest_np, blob_np = self._device_wait(pend)
-        return blob_np, pend["state"], self._complete_locked(
-            pend, digest_np, blob_np)
-
-    def _tick_locked(
-        self,
-        gathered: Blob,
-        heard: np.ndarray,
-        want_coord: Optional[np.ndarray] = None,
-    ) -> Tuple[Blob, Dict]:
-        # the Blob-of-leaves exchange reaches the unified packed step as
-        # one [R, NB] matrix (each row == pack_blob of that replica);
-        # donate=False — the test-cluster harness caches blob views that
-        # alias the live state across ticks
-        pend = self._dispatch_locked(
-            self._tick_step, _pack_rows_jit(gathered), heard, want_coord)
-        digest_np, blob_np = self._device_wait(pend)
-        host_delta = self._complete_locked(pend, digest_np, blob_np)
-        return split_blob_vec(blob_np, self.cfg), host_delta
-
     def _post_step_locked(self, outs) -> Dict:
         """Shared post-engine host work (requeue, watermarks, journaling,
-        execution, state pulls, gossip delta) for every tick flavor.
+        execution, state pulls, gossip delta) of a completed dispatch.
 
-        ``outs`` is the dispatch's LIST of per-substep StepDigests (a
-        bare one is accepted as a 1-list): the [G] output leaves whole,
-        the [G, W] planes as their busy rows only, in row order — one
+        ``outs`` is the dispatch's LIST of per-substep StepDigests: the
+        [G] output leaves whole, the [G, W] planes as their busy rows
+        only, in row order — one
         host cycle per dispatch covers all N device-resident substeps:
         per-substep work (decision logging, execution, preempt requeue)
         runs in substep order; per-dispatch work (ballot flips,
         watermarks, checkpoint cadence, gossip delta) runs once against
         the final state."""
-        if isinstance(outs, StepDigest):
-            outs = [outs]
         last = outs[-1]
         n_sub = len(outs)
         self._tick_no += 1
@@ -3507,14 +3446,14 @@ class PaxosManager:
         mx.count("host_dispatches")
         mx.gauge("dispatch_steps_per_host", n_sub)
         mx.gauge("device_queue_depth", self._last_ring_depth)
-        # retrace sentinel: fold the shared sentinels' totals into this
+        # retrace sentinel: fold the shared sentinel's totals into this
         # node's counters as deltas (attribute reads only — no device
-        # traffic), and mark them warm after the first completed
+        # traffic), and mark it warm after the first completed
         # dispatch.  A retrace after warmup is the recompile analog of a
         # stray hot-path _np pull: it still WORKS, ~100x slower — so it
         # is shouted into the log, not just a metric
-        n_c = self._dispatch_step.n_compiles + self._tick_step.n_compiles
-        n_r = self._dispatch_step.n_retraces + self._tick_step.n_retraces
+        n_c = self._dispatch_step.n_compiles
+        n_r = self._dispatch_step.n_retraces
         if n_c != self._compile_seen:
             mx.count("engine_compiles", n_c - self._compile_seen)
             self._compile_seen = n_c
@@ -3527,7 +3466,6 @@ class PaxosManager:
             )
         if not self._dispatch_step.warm:
             self._dispatch_step.mark_warm()
-            self._tick_step.mark_warm()
         # flight recorder: the per-step summary ring (always on; skips
         # pure-idle ticks internally so the ring spans real history)
         self.flight.record_step(
@@ -4434,10 +4372,6 @@ class PaxosManager:
         with self._state_lock:
             out, self.forward_out = self.forward_out, []
         return out
-
-    def blob(self) -> Blob:
-        """Current publishable snapshot (what peers gather)."""
-        return make_blob(self.state)
 
     def blob_vec(self) -> np.ndarray:
         """Packed publish vector for the current state (the wire body of

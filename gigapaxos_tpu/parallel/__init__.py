@@ -1,18 +1,9 @@
 from .mesh import make_mesh, pick_mesh_shape
-from .spmd import (
-    group_sharded_step,
-    make_step,
-    single_chip_step,
-    spmd_step,
-    stack_states,
-)
+from .spmd import make_step, stack_states
 
 __all__ = [
     "make_mesh",
     "pick_mesh_shape",
     "make_step",
-    "spmd_step",
-    "single_chip_step",
-    "group_sharded_step",
     "stack_states",
 ]

@@ -11,11 +11,11 @@ Two deployment shapes use these axes:
 
 * ``make_mesh(n_replicas, n_group_shards)`` — the 2-D acceptor-per-chip
   mesh: each chip holds ONE replica row of a group shard and the blob
-  exchange is an ``all_gather`` over 'r' (``spmd.spmd_step``).
+  exchange is an ``all_gather`` over 'r' (``spmd.make_step(cfg, mesh)``).
 * ``make_group_mesh(n_devices)`` — the 1-D group-sharded mesh: every chip
   holds ALL R replica rows for its G/n slice, so the exchange is the
   device-local stacked blobs and the step has ZERO cross-device
-  collectives (``spmd.group_sharded_step``).  This is the weak-scaling
+  collectives (``spmd.make_step`` over this mesh).  This is the weak-scaling
   shape: capacity and throughput both scale with the device count.
 """
 

@@ -33,20 +33,19 @@ Two I/O flavors:
 
 * ``io="packed_host"`` — the deployed-runtime face (one replica's state,
   peers' blobs arriving as the packed ``[R, NB]`` gathered matrix == the
-  ``D`` wire-frame bodies): returns ``(state', out_rings [N, M],
-  blob_vec)``, and with ``heat`` also the heat accumulator and
-  ``digests [N, L]`` — per substep what the host's post-step reads
-  (``ops/engine.py:make_digest``: the [G] output leaves, the busy rows
-  of the [G, W] planes with their accept lanes of the new state, a
-  work-in-flight flag), so that ``out_rings`` can stay on the device.
+  ``D`` wire-frame bodies, and a donated ``[G]`` int32 activity
+  accumulator as the trailing argument): returns ``(state', out_rings
+  [N, M], blob_vec, heat', digests [N, L])`` — ``heat'`` is the
+  accumulator plus ``n_committed + n_admitted`` of every substep (the
+  host pulls it at the stats cadence, never per tick), ``digests`` per
+  substep what the host's post-step reads (``ops/engine.py:make_digest``:
+  the [G] output leaves, the busy rows of the [G, W] planes with their
+  accept lanes of the new state, a work-in-flight flag), so that
+  ``out_rings`` can stay on the device.
   Substep 0 consumes the gathered rows exactly as passed; substeps >= 1
   refresh only MY row from the advancing state while peers' rows stay
   frozen — the semantics of N serial host ticks during which no new
   peer frame lands.
-
-The three pre-factory entry points (``single_chip_step``, ``spmd_step``,
-``group_sharded_step``) survive as thin deprecated aliases over the
-factory.
 
 Global array convention for SPMD: every state leaf gets a leading replica
 axis -> ``[R, G, ...]``; a ``(g, r)`` mesh constrains ``P('r', 'g')``, a
@@ -228,23 +227,17 @@ def _build_stacked(cfg: EngineConfig, mesh: Optional[Mesh], n_steps: int,
 
 
 def _build_packed(cfg: EngineConfig, mesh: Optional[Mesh], n_steps: int,
-                  donate: bool, heat: bool):
-    R = cfg.n_replicas
+                  donate: bool):
     M = out_vec_len(cfg)
 
     def _pack_out(out):
         return jnp.concatenate([jnp.ravel(leaf) for leaf in out])
 
-    # ONE traced core for both the plain and the heat-carrying entry:
-    # the core always folds the [G] activity accumulator (decisions +
-    # admissions per group, per substep); the plain entry simply drops
-    # that output, and XLA's dead-code elimination strips the adds, so
-    # heat=False still compiles the exact legacy program.
     if n_steps == 1:
         # the exact legacy step_host program (plus a trivial [1, M]
         # reshape): one upload, one step, two downloads
-        def _core(state, gvec, heard, req_ring, want_coord, my_id,
-                  heat_acc):
+        def run_heat(state, gvec, heard, req_ring, want_coord, my_id,
+                     heat_acc):
             state = _constrain(mesh, state, GROUP_AXIS)
             g = unpack_gathered(gvec, cfg)
             new_state, out = step(
@@ -262,8 +255,8 @@ def _build_packed(cfg: EngineConfig, mesh: Optional[Mesh], n_steps: int,
                 make_digest(out, new_state, cfg)[None],
             )
     else:
-        def _core(state, gvec, heard, req_ring, want_coord, my_id,
-                  heat_acc):
+        def run_heat(state, gvec, heard, req_ring, want_coord, my_id,
+                     heat_acc):
             state = _constrain(mesh, state, GROUP_AXIS)
             heat_acc = _constrain(mesh, heat_acc, GROUP_AXIS)
             gathered0 = unpack_gathered(gvec, cfg)
@@ -309,44 +302,21 @@ def _build_packed(cfg: EngineConfig, mesh: Optional[Mesh], n_steps: int,
                 blob_vec, _constrain(mesh, heat_acc, GROUP_AXIS), digests,
             )
 
-    if heat:
-        # heat-carrying face: the accumulator rides the dispatch like a
-        # state leaf (donated alongside it) and is pulled host-side only
-        # at the stats cadence — never per tick
-        @partial(jax.jit, donate_argnums=(0, 6) if donate else ())
-        def run_heat(state, gvec, heard, req_ring, want_coord, my_id,
-                     heat_acc):
-            return _core(state, gvec, heard, req_ring, want_coord,
-                         my_id, heat_acc)
-
-        return run_heat
-
-    @partial(jax.jit, donate_argnums=(0,) if donate else ())
-    def run(state, gvec, heard, req_ring, want_coord, my_id):
-        new_state, out_rings, blob_vec, _, _ = _core(
-            state, gvec, heard, req_ring, want_coord, my_id,
-            jnp.zeros((cfg.n_groups,), jnp.int32),
-        )
-        return new_state, out_rings, blob_vec
-
-    return run
+    # the accumulator rides the dispatch like a state leaf (donated
+    # alongside it) and is pulled host-side only at the stats cadence
+    return jax.jit(run_heat, donate_argnums=(0, 6) if donate else ())
 
 
 @functools.lru_cache(maxsize=None)
-def _make_step_cached(cfg, mesh, steps_per_dispatch, donate, io, heat):
+def _make_step_cached(cfg, mesh, steps_per_dispatch, donate, io):
     from ..obs.device import StepSentinel
 
     if steps_per_dispatch < 1:
         raise ValueError("steps_per_dispatch must be >= 1")
     if io == "stacked":
-        if heat:
-            raise ValueError(
-                "heat accumulation is a packed_host feature (the "
-                "stacked/SPMD face reads StepOutputs directly)"
-            )
         fn = _build_stacked(cfg, mesh, steps_per_dispatch, donate)
     elif io == "packed_host":
-        fn = _build_packed(cfg, mesh, steps_per_dispatch, donate, heat)
+        fn = _build_packed(cfg, mesh, steps_per_dispatch, donate)
     else:
         raise ValueError(f"unknown io flavor: {io!r}")
     # every factory instance leaves through the retrace/compile sentinel
@@ -358,7 +328,7 @@ def _make_step_cached(cfg, mesh, steps_per_dispatch, donate, io, heat):
     ) if mesh is not None else "none"
     label = (
         f"make_step[{io} N={steps_per_dispatch} donate={donate} "
-        f"heat={heat} mesh={mesh_tag} G={cfg.n_groups} "
+        f"mesh={mesh_tag} G={cfg.n_groups} "
         f"R={cfg.n_replicas} W={cfg.window} K={cfg.req_lanes}]"
     )
     return StepSentinel(fn, label=label)
@@ -366,7 +336,7 @@ def _make_step_cached(cfg, mesh, steps_per_dispatch, donate, io, heat):
 
 def make_step(cfg: EngineConfig, mesh: Optional[Mesh] = None,
               steps_per_dispatch: int = 1, *, donate: bool = True,
-              io: str = "stacked", heat: bool = False):
+              io: str = "stacked"):
     """Build THE consensus step: mesh-parameterized, N-steps-resident.
 
     Parameters
@@ -386,16 +356,8 @@ def make_step(cfg: EngineConfig, mesh: Optional[Mesh] = None,
     io : ``"stacked"`` ([R, ...] SPMD/bench face) or ``"packed_host"``
         (one replica + packed [R, NB] gathered vectors — the deployed
         runtime's face; see the module docstring for signatures).
-    heat : (``packed_host`` only) carry a donated ``[G]`` int32
-        activity accumulator through the dispatch — the step takes it
-        as a trailing argument and returns ``heat + n_committed +
-        n_admitted`` folded across every substep inside the device
-        loop, then the per-substep digests (the manager's face).  The
-        host pulls the heat at the STATS cadence (obs/device.py heat
-        analysis), never per tick.  ``False`` keeps the exact legacy
-        signatures.
 
-    Instances are memoized: the same (cfg, mesh, N, donate, io, heat)
+    Instances are memoized: the same (cfg, mesh, N, donate, io)
     returns the same callable, so jit caches are shared across
     managers.  Every instance is wrapped in a
     :class:`gigapaxos_tpu.obs.device.StepSentinel`, so compiles and
@@ -403,44 +365,7 @@ def make_step(cfg: EngineConfig, mesh: Optional[Mesh] = None,
     """
     return _make_step_cached(
         cfg, mesh, int(steps_per_dispatch), bool(donate), str(io),
-        bool(heat),
     )
-
-
-# ---------------------------------------------------------------------------
-# deprecated thin aliases over the factory (pre-factory entry points)
-# ---------------------------------------------------------------------------
-
-
-def single_chip_step(cfg: EngineConfig, donate: bool = True):
-    """Deprecated alias: ``make_step(cfg, None, 1, donate=donate)``.
-
-    All R replica states stacked on one device and advanced with vmap;
-    the "gather" is the stacked blobs (the loopback/bench mode — the
-    analog of the reference's N-nodes-in-one-JVM testing mode,
-    ``PaxosManager.java:108-111``)."""
-    return make_step(cfg, None, 1, donate=donate)
-
-
-def spmd_step(cfg: EngineConfig, mesh: Mesh):
-    """Deprecated alias: ``make_step(cfg, mesh, 1)`` over the (g, r)
-    mesh (acceptor-per-chip; blob exchange = all_gather over 'r').
-
-    Keeps the historical divisibility contract: the (g, r) deployment
-    pins G/gs groups per chip, so a non-divisible G is a config error
-    here (the factory itself accepts any G — GSPMD pads internally)."""
-    if cfg.n_groups % mesh.shape[GROUP_AXIS]:
-        raise ValueError("n_groups must divide evenly over the group axis")
-    return make_step(cfg, mesh, 1)
-
-
-def group_sharded_step(cfg: EngineConfig, mesh: Mesh, donate: bool = True):
-    """Deprecated alias: ``make_step(cfg, mesh, 1, donate=donate)`` over
-    the 1-D ('g',) mesh — G partitioned, R device-local, zero
-    cross-device collectives (the weak-scaling shape).  Pad G to a mesh
-    multiple first (``pad_group_states`` / ``shard_group_inputs``) to
-    keep per-device slices even."""
-    return make_step(cfg, mesh, 1, donate=donate)
 
 
 # ---------------------------------------------------------------------------

@@ -36,14 +36,6 @@ class PC(FlagEnum):
     MIN_PP_BATCH_SIZE = 3
 
     # ---- serving pipeline (host-path ceiling: dispatch/codec/sharding) -
-    # double-buffered dispatch: the jitted engine step for batch N runs
-    # asynchronously (dispatch-and-go) while transport threads frame,
-    # decode, and admit batch N+1 — the manager lock is NOT held across
-    # the device sync, so ingress/codec work overlaps the ~1ms step
-    # instead of following it.  False = serial tick (lock held across the
-    # whole step), the pre-pipeline behavior; the two are step-for-step
-    # state-identical (tests/test_pipeline.py pins it)
-    PIPELINE_DISPATCH = True
     # binary client hot-path frames ('R' request / 'S' response batches,
     # net/hot_codec.py): replaces per-request JSON on the client plane;
     # decode/encode run in the native layer when available (GP_NO_NATIVE

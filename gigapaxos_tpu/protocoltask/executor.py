@@ -123,6 +123,10 @@ class ProtocolExecutor:
     """
 
     MAX_TASKS = 10_000
+    #: read where a caller gives no ``now``; a stepped harness puts the
+    #: clock it passes to :meth:`tick` here, so that a task spawned
+    #: between two ticks is stamped on the clock its restarts count on
+    clock = staticmethod(time.time)
 
     def __init__(self, send: Optional[Callable[[MessagingTask], None]] = None):
         self._tasks: Dict[str, ProtocolTask] = {}
@@ -142,7 +146,7 @@ class ProtocolExecutor:
             return False
         if len(self._tasks) >= self.MAX_TASKS:
             raise RuntimeError("protocol task store full")
-        now = time.time() if now is None else now
+        now = self.clock() if now is None else now
         self._tasks[task.key] = task
         self._meta[task.key] = (now, now)
         self._emit(task.start())
@@ -176,7 +180,7 @@ class ProtocolExecutor:
 
     def tick(self, now: Optional[float] = None) -> None:
         """Run restarts/expiries due at `now` (call from the node loop)."""
-        now = time.time() if now is None else now
+        now = self.clock() if now is None else now
         for key in list(self._tasks.keys()):
             task = self._tasks.get(key)
             if task is None:

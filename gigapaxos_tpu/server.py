@@ -188,7 +188,6 @@ class PaxosServer:
         # serving pipeline: double-buffered dispatch (the engine step for
         # batch N computes while this thread frames/publishes tick N-1's
         # outputs and transport threads admit batch N+1)
-        self._pipeline = Config.get_bool(PC.PIPELINE_DISPATCH)
         self._pub: Optional[Dict] = None  # pending publish of last tick
         self._self_msgs: list = []  # self-destined forwards, post-overlap
         # large-message streaming (LargeCheckpointer analog,
@@ -722,9 +721,8 @@ class PaxosServer:
                 # serving-path configuration: which codec implementation
                 # is LIVE (a missing toolchain silently regressing to the
                 # Python path must be visible here, not discovered in a
-                # perf run) and whether dispatch is pipelined
+                # perf run)
                 "serving": {
-                    "pipeline_dispatch": self._pipeline,
                     "codec": hot_codec.status(),
                     "serving_workers": Config.get_int(PC.SERVING_WORKERS),
                 },
@@ -986,32 +984,27 @@ class PaxosServer:
         m = self.manager
         with m._span("tick.gather"):
             gathered, heard, want = self._gather()
-        if self._pipeline:
-            # double-buffered dispatch: fire step N and, while the device
-            # computes it, do tick N-1's host-side codec/publish work
-            # (blob frame encode, payload delta, forwards, response
-            # flush).  Transport threads admit batch N+1 throughout —
-            # the manager lock is free for the whole overlap window.
-            # NOTHING in the overlap window may call a manager op that
-            # waits on step completion (same thread completes the step).
-            pend = m.step_dispatch(gathered, heard, want)
-            t_overlap = time.perf_counter()
-            self._publish_pending()
-            self._flush_responses()
-            overlap_s = time.perf_counter() - t_overlap
-            blob_vec, blob_state, delta = m.step_complete(pend)
-            m.metrics.observe("pipeline_overlap_s", overlap_s)
-        else:
-            blob_vec, blob_state, delta = m.tick_host(gathered, heard, want)
+        # double-buffered dispatch: fire step N and, while the device
+        # computes it, do tick N-1's host-side codec/publish work (blob
+        # frame encode, payload delta, forwards, response flush).
+        # Transport threads admit batch N+1 throughout — the manager
+        # lock is free for the whole overlap window.  NOTHING in the
+        # overlap window may call a manager op that waits on step
+        # completion (same thread completes the step).
+        pend = m.step_dispatch(gathered, heard, want)
+        t_overlap = time.perf_counter()
+        self._publish_pending()
+        self._flush_responses()
+        overlap_s = time.perf_counter() - t_overlap
+        blob_vec, blob_state, delta = m.step_complete(pend)
+        m.metrics.observe("pipeline_overlap_s", overlap_s)
         with m._span("tick.finish"):
             self._finish_tick(blob_vec, blob_state, delta)
             self._drain_self_msgs()
-        if not self._pipeline or not m.has_backlog():
-            # serial mode publishes its own tick immediately (the
-            # pre-pipeline behavior, exactly); pipelined mode does too
-            # when the loop is about to go idle — otherwise this tick's
-            # frames ship in the NEXT dispatch's overlap window, which
-            # under backlog begins immediately
+        if not m.has_backlog():
+            # the loop is about to go idle: publish this tick now —
+            # otherwise its frames ship in the NEXT dispatch's overlap
+            # window, which under backlog begins immediately
             self._publish_pending()
         with m._span("layer"):
             self._maybe_ping()
@@ -1019,7 +1012,7 @@ class PaxosServer:
         self._flush_responses()  # callbacks fired by this tick's execution
 
     def _finish_tick(self, blob_vec, blob_state, delta) -> None:
-        """Post-step bookkeeping shared by both modes: stage this tick's
+        """Post-step bookkeeping: stage this tick's
         outbound frames (blob / payload delta / forwards) for
         :meth:`_publish_pending`."""
         self._my_blob_vec = blob_vec
@@ -1072,7 +1065,7 @@ class PaxosServer:
     def _publish_pending(self) -> None:
         """Ship the staged tick outputs (blob to every peer — the
         all_gather stand-in — plus the payload-delta frame and queued
-        forwards).  In pipelined mode this runs inside the NEXT tick's
+        forwards).  Under backlog this runs inside the NEXT tick's
         overlap window, so the frame encode + syscalls overlap the
         device step instead of following it."""
         pub, self._pub = self._pub, None
@@ -1159,12 +1152,8 @@ class PaxosServer:
             )
             self._last_stats_dispatches = disp
             cs = self.manager.engine_compile_stats()
-            n_comp = (
-                cs["dispatch"]["compiles"] + cs["tick"]["compiles"]
-            )
-            n_retr = (
-                cs["dispatch"]["retraces"] + cs["tick"]["retraces"]
-            )
+            n_comp = cs["dispatch"]["compiles"]
+            n_retr = cs["dispatch"]["retraces"]
             self.log.info(
                 "stats tick=%d dispatch_rate=%.1f/s engine_compiles=%d "
                 "engine_retraces=%d %s", self._tick, rate, n_comp,

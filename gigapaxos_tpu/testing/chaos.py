@@ -29,7 +29,7 @@ import random
 import shutil
 import tempfile
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 from ..models.apps import HashChainApp
 from ..ops.engine import EngineConfig
@@ -44,6 +44,30 @@ class SoakDivergence(AssertionError):
     def __init__(self, msg: str, diag: Optional[Dict] = None):
         super().__init__(msg if diag is None else f"{msg}: {diag}")
         self.diag = diag or {}
+
+
+# what one step of a reconfiguration soak stands for on the nodes' clocks:
+# the 30 ms a step of the stepped harness took when the seeds were pinned,
+# so the soaks' 0.05 s task period is a retransmit every second step on
+# any box, as the product's 1-2 s periods stand to its 60-480 ms ticks
+SOAK_STEP_S = 0.03
+
+
+def _stepper(c: ReconfigurableCluster) -> Callable[[], None]:
+    """``c.step`` on a clock that starts at the wall time and moves
+    :data:`SOAK_STEP_S` a step, however long the step took: each
+    retransmit draws from the shared fault rng, so on the wall clock a
+    seed's schedule depended on the box.  The nodes' task executors stamp
+    their spawns on the same clock."""
+    now = [time.time()]
+    for node in c.active_replicas + c.reconfigurators:
+        node.tasks.clock = lambda: now[0]
+
+    def step() -> None:
+        now[0] += SOAK_STEP_S
+        c.step(now[0])
+
+    return step
 
 
 def _soak_managers(c) -> List:
@@ -450,8 +474,10 @@ def run_soak(
             rc.placement.policy = MeasureOnlyPlacementPolicy(rc.placement)
         names = [f"n{i}" for i in range(n_names)]
 
+        advance = _stepper(c)
+
         def step():
-            c.step()
+            advance()
             probe_exactly_once(c, names)
 
         deleted: set = set()
@@ -605,9 +631,11 @@ def run_sharded_soak(
                 )
             return shards[w]
 
+        advance = [_stepper(c) for c in shards]
+
         def step_all():
-            for c in shards:
-                c.step()
+            for adv in advance:
+                adv()
             for c in shards:
                 probe_exactly_once(
                     c, [nm for nm in names if shards[owner[nm]] is c]
@@ -683,10 +711,8 @@ def run_sharded_soak(
                     "foreign names leaked across the worker-shard "
                     "boundary", {"shard": w, "names": foreign},
                 )
-            def step_one(c=c):
-                c.step()
             settle_iters += settle_and_audit(
-                c, mine, step_one, settle_budget_s
+                c, mine, advance[w], settle_budget_s
             )
         return {"seed": seed, "workers": workers,
                 "settle_iters": settle_iters}

@@ -1,24 +1,28 @@
 """Loopback manager cluster: N full PaxosManagers (engine + logger + app +
-callbacks) in one process, exchanging blobs and host-channel payloads with
-controllable delivery — the manager-level analog of :mod:`.sim` and of the
+callbacks) in one process, exchanging packed blob vectors (the `D` frame
+bodies a node serves) and host-channel payloads with controllable
+delivery — the manager-level analog of :mod:`.sim` and of the
 reference's N-nodes-in-one-JVM integration mode (``TESTPaxosNode.java:44``,
-``PaxosManager.java:108-111``)."""
+``PaxosManager.java:108-111``).  Each replica steps by one of the manager's
+two ways to run a tick, so a stepped test runs the program a node runs."""
 
 from __future__ import annotations
 
 from typing import Callable, Dict, List, Optional
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 from ..manager import PaxosManager
-from ..ops.engine import Blob, EngineConfig
+from ..ops.engine import EngineConfig, blob_vec_len
 
 DELIVER, DROP = 0, 1
 
 
 class ManagerCluster:
+    # False: each replica's tick is ``tick_host`` (the serial reference);
+    # True: ``step_dispatch`` then ``step_complete``, what a node serves
+    pipelined = False
+
     def __init__(
         self,
         cfg: EngineConfig,
@@ -44,7 +48,12 @@ class ManagerCluster:
             )
             for rid in range(R)
         ]
-        self.blobs: List[Blob] = [m.blob() for m in self.managers]
+        # what each replica last published, and the [R, N] stack a
+        # dispatch uploads: one, kept (a fresh one is 57 MB at 131,072
+        # rows) — either way to tick waits for the device before the
+        # next replica's rows are written into it
+        self.republish()
+        self._gathered = np.empty((R, blob_vec_len(cfg)), np.int32)
         # host-channel inboxes: (kind, body) per receiver
         self.inboxes: List[List] = [[] for _ in range(R)]
         # default election drive (the deployed server's FailureDetector)
@@ -69,6 +78,12 @@ class ManagerCluster:
             m.outstanding.timeout_s = float("inf")
 
     # ---- lifecycle across the cluster ---------------------------------
+    def republish(self) -> None:
+        """Take every replica's publish vector from its CURRENT state:
+        after a lifecycle op made outside :meth:`step_all`, the vectors
+        of the last round no longer describe the rows it rewrote."""
+        self.vecs = [m.blob_vec() for m in self.managers]
+
     def create(self, name: str, members: Optional[List[int]] = None,
                initial_state: Optional[str] = None) -> int:
         members = list(range(self.cfg.n_replicas)) if members is None else members
@@ -77,7 +92,7 @@ class ManagerCluster:
             m.create_paxos_instance(
                 name, members, initial_state=initial_state, row=row
             )
-        self.blobs = [m.blob() for m in self.managers]
+        self.republish()
         return row
 
     def restart(self, rid: int, hydrate: bool = True) -> PaxosManager:
@@ -105,7 +120,7 @@ class ManagerCluster:
         self.managers[rid] = m
         if hydrate:
             m.hydrate_all()
-        self.blobs[rid] = m.blob()
+        self.vecs[rid] = m.blob_vec()
         self.inboxes[rid] = []
         return m
 
@@ -130,26 +145,29 @@ class ManagerCluster:
             for kind, body in inbox:
                 self.managers[i].on_host_message(kind, body)
 
-        new_blobs: List[Blob] = list(self.blobs)
+        # every replica of a round steps against the vectors the
+        # PREVIOUS round published; an unheard peer's row is my own
+        new_vecs = list(self.vecs)
         deltas = []
-        for i in range(R):
+        gathered = self._gathered
+        for i, m in enumerate(self.managers):
             heard = np.zeros(R, bool)
-            rows = []
             for j in range(R):
-                live = i == j or delivery[i, j] == DELIVER
-                heard[j] = live
-                rows.append(self.blobs[j] if live else self.blobs[i])
-            gathered = jax.tree.map(lambda *xs: jnp.stack(xs), *rows)
+                heard[j] = i == j or delivery[i, j] == DELIVER
+                gathered[j] = self.vecs[j if heard[j] else i]
             want = want_coord.get(i)
             if want is None:
-                m = self.managers[i]
                 want = self._fds[i].want_coord(
                     m._np("bal"), m._np("member_mask"), R
                 )
-            blob, delta = self.managers[i].tick(gathered, heard, want)
-            new_blobs[i] = blob
+            if self.pipelined:
+                pend = m.step_dispatch(gathered, heard, want)
+                new_vecs[i], _state, delta = m.step_complete(pend)
+            else:
+                new_vecs[i], _state, delta = m.tick_host(
+                    gathered, heard, want)
             deltas.append(delta)
-        self.blobs = new_blobs
+        self.vecs = new_vecs
 
         # route host-channel traffic over live links for NEXT round
         for i in range(R):

@@ -73,7 +73,7 @@ def build_cluster(n_accounts: int, n_replicas: int):
         for m in c.managers:
             n = m.create_paxos_batch(chunk, members, initial_states=inits)
             assert n == len(chunk), (n, len(chunk))
-    c.blobs = [m.blob() for m in c.managers]
+    c.republish()
     return c, accounts
 
 

@@ -73,10 +73,11 @@ GROUP_HEAT_LEAF = "__group_heat__"
 # buffers).  A dynamic (non-literal) pull argument in any hot function
 # is always a violation.
 HOT_NP_ALLOW = {
+    # the two ways to run a tick, and the helper both dispatch through
     ("manager.py", "step_dispatch"): frozenset(),
     ("manager.py", "step_complete"): frozenset(),
-    ("manager.py", "_tick_host_locked"): frozenset(),
-    ("manager.py", "_tick_locked"): frozenset(),
+    ("manager.py", "tick_host"): frozenset(),
+    ("manager.py", "_dispatch_locked"): frozenset(),
     ("manager.py", "_execute"): frozenset(),
     ("manager.py", "_execute_one"): frozenset({"version"}),
     ("manager.py", "build_request_ring"): frozenset({"bal", "version"}),

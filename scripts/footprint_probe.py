@@ -23,7 +23,7 @@ assert proves it stays at the compact budget.
 Defaults are the headline bench shape (G=1,048,576, W=32, K=16, R=3).
 
 ``--sharded N`` adds the group-sharded SPMD deployment arithmetic
-(``parallel/spmd.py:group_sharded_step``): G pads up to a multiple of N,
+(``parallel/spmd.py:make_step`` over a ``('g',)`` mesh): G pads up to a multiple of N,
 each device hosts padded_G/N groups x all R replica rows, and the
 per-device peak is exactly the single-chip model at the local group
 count.  The mode ASSERTS the per-device blob cost per hosted group stays

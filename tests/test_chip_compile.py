@@ -74,7 +74,7 @@ def test_packed_host_step_compiles_and_fits_one_chip(one_chip, G, W, K):
         shape, dtype, sharding=one_chip
     )
     state = _state_shapes(cfg, one_chip)
-    step = make_step(cfg, None, 1, donate=True, io="packed_host", heat=True)
+    step = make_step(cfg, None, 1, donate=True, io="packed_host")
     compiled = step.lower(
         state, sds((R, blob_vec_len(cfg)), jnp.int32), sds((R,), jnp.bool_),
         sds((1, G, K), jnp.int32), sds((G,), jnp.bool_),
@@ -109,7 +109,7 @@ def test_lifecycle_scatters_compile_at_deployed_rows(one_chip):
     assert _dispatch_bytes(compiled) < HBM_BYTES
 
 
-def test_group_sharded_step_has_no_collectives_on_four_chips(topo):
+def test_group_sharded_has_no_collectives_on_four_chips(topo):
     """The ``('g',)``-sharded step on the four described devices
     (``chip_smoke.py --chips 4``): groups are independent, so the
     partitioned program holds no cross-device collective, and each

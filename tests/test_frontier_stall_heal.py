@@ -45,7 +45,7 @@ def test_small_gap_straggler_heals_after_majority_resume():
     for m in (c.managers[0], c.managers[1]):
         assert m.pause_group("svc", epoch, force=True) == "ok"
         assert m.resume_group("svc", epoch, [0, 1, 2], row, pending=False)
-    c.blobs = [m.blob() for m in c.managers]
+    c.republish()
 
     # reconnect member 2: it sits 2 slots behind (< W=8, < jump horizon);
     # the frontier-stall heal must pull it up to the majority frontier
@@ -114,7 +114,7 @@ def test_majority_behind_single_ahead_member_heals():
         assert m.kill("svc")
         assert m.create_paxos_instance("svc", [0, 1, 2], row=row,
                                        version=epoch)
-    c.blobs = [m.blob() for m in c.managers]
+    c.republish()
 
     import numpy as np
 

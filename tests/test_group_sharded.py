@@ -25,11 +25,10 @@ from gigapaxos_tpu.parallel.mesh import (
 )
 from gigapaxos_tpu.parallel.spmd import (
     build_replica_states,
-    group_sharded_step,
+    make_step,
     pad_group_states,
     padded_group_count,
     shard_group_inputs,
-    single_chip_step,
     strip_group_pad,
 )
 
@@ -67,8 +66,8 @@ def _parity_schedule(cfg):
 def _assert_parity(cfg, n_devices):
     mesh = make_group_mesh(n_devices)
     Gp = padded_group_count(cfg.n_groups, n_devices)
-    vm = single_chip_step(cfg)
-    gs = group_sharded_step(cfg, mesh)
+    vm = make_step(cfg)
+    gs = make_step(cfg, mesh)
 
     states_v = build_replica_states(cfg)
     R, G, K = cfg.n_replicas, cfg.n_groups, cfg.req_lanes
@@ -114,7 +113,7 @@ def _assert_parity(cfg, n_devices):
 
 
 def test_group_sharded_parity_8dev():
-    """Bit-identical to single_chip_step over 4 steps on the 8-device
+    """Bit-identical to the unsharded step over 4 steps on the 8-device
     virtual mesh — every leaf, every output field, every step."""
     cfg = EngineConfig(n_groups=16, window=8, req_lanes=4, n_replicas=3)
     states = _assert_parity(cfg, 8)
@@ -140,7 +139,7 @@ def test_group_sharded_commits_end_to_end():
     schedules)."""
     cfg = EngineConfig(n_groups=8, window=8, req_lanes=2, n_replicas=3)
     mesh = make_group_mesh(8)
-    fn = group_sharded_step(cfg, mesh)
+    fn = make_step(cfg, mesh)
     R, G, K = 3, 8, 2
     states, _r, _w = shard_group_inputs(
         mesh, cfg, build_replica_states(cfg),

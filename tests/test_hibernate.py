@@ -41,7 +41,7 @@ def test_hibernate_restore(tmp_path):
             assert m.names.get("svc") is None
             assert ("svc", 0) in m.paused
             assert m.paused.n_in_memory == 0  # sleeping on disk
-        c.blobs = [m.blob() for m in c.managers]
+        c.republish()
         c.run(3)
 
         # a second hibernate (unknown name now) reports failure
@@ -51,7 +51,7 @@ def test_hibernate_restore(tmp_path):
         for m in c.managers:
             assert m.restore("svc")
             assert m.names.get("svc") is not None
-        c.blobs = [m.blob() for m in c.managers]
+        c.republish()
         c.run(5)
         assert _converged(c, "svc") == h0
         rows = {m.names["svc"] for m in c.managers}

@@ -17,36 +17,49 @@ from gigapaxos_tpu.testing.cluster import ManagerCluster
 
 def tick_host_cost(G, n_ticks=12, warmup=3):
     """Median host-side tick cost (total tick minus the jitted engine
-    steps, measured per tick) for idle managers with a few live groups."""
+    steps, measured per tick) for idle managers with a few live groups,
+    and the median of an array-speed yardstick timed beside each tick:
+    the round's three blob vectors copied into a kept ``[3, N]`` array
+    (kept, as the harness keeps its own: a fresh one would time the
+    allocator's page faults, not the copy)."""
     cfg = EngineConfig(n_groups=G, window=8, req_lanes=4, n_replicas=3)
     c = ManagerCluster(cfg, NoopPaxosApp)
     for i in range(8):
         c.create(f"g{i}", members=[0, 1, 2])
     c.run(warmup)
-    host_costs = []
+    into = np.zeros((3, c.vecs[0].shape[0]), np.int32)
+    host_costs, yardsticks = [], []
     for _ in range(n_ticks):
         t0 = time.perf_counter()
         c.step_all()
         total = time.perf_counter() - t0
         engine = sum(m.last_engine_step_s for m in c.managers)
         host_costs.append(total - engine)
+        t0 = time.perf_counter()
+        for row, vec in zip(into, c.vecs):
+            np.copyto(row, vec)
+        yardsticks.append(time.perf_counter() - t0)
     c.close()
-    host_costs.sort()
-    return host_costs[len(host_costs) // 2]  # median
+    return np.median(host_costs), np.median(yardsticks)
 
 
 def test_idle_group_host_cost_is_array_speed():
     """Idle groups must cost ARRAY speed on the host, not Python speed.
 
     The tick's host side legitimately moves O(G*W) bytes (the blob
-    exchange IS the state transfer in host-exchange mode), so the bound
-    is per-group cost: numpy-batch work runs ~1-2us/group for the whole
-    3-replica round; per-group Python loops or per-call device syncs run
-    5-10us+/group and blow the budget immediately."""
-    per_group = tick_host_cost(131_072) / 131_072
-    assert per_group < 4e-6, (
-        f"host tick cost {per_group * 1e6:.2f}us/group at G=131k — "
-        "something walks idle groups in Python"
+    exchange IS the state transfer in host-exchange mode: each of the
+    three replicas gathers three 19 MB rows), so the bound is a multiple
+    of moving those bytes once, timed in this process at this moment — a
+    loaded box slows both sides alike.  The round's numpy-batch work
+    reads 7.0-7.2 of these yardsticks on an idle box, 7.3 beside six
+    soaks and 7.8-9.5 beside five copies of itself; per-group Python
+    loops or per-call device syncs run 5-10us+/group, 75-150 yardsticks,
+    and blow the budget immediately (1 us a group already reads 21)."""
+    host, yardstick = tick_host_cost(131_072)
+    assert host < 20 * yardstick, (
+        f"host tick cost {host * 1e3:.1f} ms at G=131k against "
+        f"{yardstick * 1e3:.1f} ms for one copy of the round's blob "
+        "vectors — something walks idle groups in Python"
     )
 
 

@@ -128,7 +128,7 @@ def test_pending_row_gates_admission_until_commit():
     row = c.managers[0].default_row_for("pend")
     for m in c.managers:
         m.create_paxos_instance("pend", [0, 1, 2], row=row, pending=True)
-    c.blobs = [m.blob() for m in c.managers]
+    c.republish()
     got = {}
     c.submit("pend", "v0", entry=0, callback=lambda rid, resp: got.update(r=resp))
     c.run(8)
@@ -153,7 +153,7 @@ def test_pending_row_move_carries_held_queue():
         assert m.create_paxos_instance("mv", [0, 1, 2], row=3, pending=True)
         assert m.names["mv"] == 3
         m.commit_row("mv", 0)
-    c.blobs = [m.blob() for m in c.managers]
+    c.republish()
     c.run(10)
     assert got.get("r") == "noop-ack"
     c.close()

@@ -12,71 +12,18 @@ import pytest
 from gigapaxos_tpu.manager import PaxosManager
 from gigapaxos_tpu.models.apps import HashChainApp
 from gigapaxos_tpu.ops.engine import EngineConfig
+from gigapaxos_tpu.testing.cluster import ManagerCluster
 from gigapaxos_tpu.utils.config import Config
 
 CFG = EngineConfig(n_groups=8, window=8, req_lanes=4, n_replicas=3)
 
 
-class PackedCluster:
-    """Three managers exchanging PACKED blob vectors (the socket
-    runtime's wire path), steppable in serial or pipelined mode."""
-
-    def __init__(self, pipelined: bool):
-        self.pipelined = pipelined
-        self.managers = [
-            PaxosManager(r, HashChainApp(), CFG) for r in range(3)
-        ]
-        for m in self.managers:
-            m.outstanding.timeout_s = float("inf")
-        self.vecs = [m.blob_vec() for m in self.managers]
-        self.inboxes = [[] for _ in range(3)]
-
-    def create(self, name):
-        row = self.managers[0].default_row_for(name)
-        for m in self.managers:
-            m.create_paxos_instance(name, [0, 1, 2], row=row)
-        self.vecs = [m.blob_vec() for m in self.managers]
-        return row
-
-    def step_all(self):
-        for i, m in enumerate(self.managers):
-            inbox, self.inboxes[i] = self.inboxes[i], []
-            for kind, body in inbox:
-                m.on_host_message(kind, body)
-        heard = np.ones(3, bool)
-        new_vecs = list(self.vecs)
-        deltas = []
-        for i, m in enumerate(self.managers):
-            gathered = np.stack(
-                [self.vecs[j] for j in range(3)]
-            )
-            if self.pipelined:
-                pend = m.step_dispatch(gathered, heard)
-                vec, _state, delta = m.step_complete(pend)
-            else:
-                vec, _state, delta = m.tick_host(gathered, heard)
-            new_vecs[i] = vec
-            deltas.append(delta)
-        self.vecs = new_vecs
-        for i, delta in enumerate(deltas):
-            ae = delta.get("app_exec")
-            if delta["arena"] or (ae and ae[1]):
-                for j in range(3):
-                    if j != i:
-                        self.inboxes[j].append(("payloads", delta))
-            for dst, kind, body in self.managers[i].drain_forward_out():
-                if dst == i:
-                    self.managers[i].on_host_message(kind, body)
-                elif dst == -1:
-                    for j in range(3):
-                        if j != i:
-                            self.inboxes[j].append((kind, body))
-                else:
-                    self.inboxes[dst].append((kind, body))
-
-    def close(self):
-        for m in self.managers:
-            m.close()
+def stepped_cluster(pipelined: bool) -> ManagerCluster:
+    """Three managers exchanging packed blob vectors, each stepped by
+    ``tick_host`` (serial) or by dispatch/complete (pipelined)."""
+    c = ManagerCluster(CFG, HashChainApp)
+    c.pipelined = pipelined
+    return c
 
 
 @pytest.mark.parametrize("steps_per_dispatch, whole_planes", [
@@ -90,7 +37,7 @@ def test_pipeline_state_parity(steps_per_dispatch, whole_planes):
     substep's digest read in turn), and with the donated, pipelined side
     forced down the digest's overflow path."""
     Config.set("ENGINE_STEPS_PER_DISPATCH", str(steps_per_dispatch))
-    serial, piped = PackedCluster(False), PackedCluster(True)
+    serial, piped = stepped_cluster(False), stepped_cluster(True)
     assert serial.managers[0].steps_per_dispatch == steps_per_dispatch
     if whole_planes:
         for m in piped.managers:
@@ -142,6 +89,34 @@ def test_pipeline_state_parity(steps_per_dispatch, whole_planes):
         piped.close()
 
 
+def test_stepped_cluster_and_served_node_share_one_step_instance():
+    """The stepped harness runs the program a node serves: one compiled
+    step instance per shape, whoever calls it and by which entry point,
+    and one face of it (no ``heat`` flavour to ask for)."""
+    from gigapaxos_tpu.parallel.spmd import make_step
+
+    cfg = EngineConfig(n_groups=12, window=8, req_lanes=4, n_replicas=3)
+    sentinel = make_step(cfg, None, 1, donate=True, io="packed_host")
+    with pytest.raises(TypeError):
+        make_step(cfg, None, 1, io="packed_host", heat=True)
+    c = ManagerCluster(cfg, HashChainApp)
+    m = PaxosManager(0, HashChainApp(), cfg)
+    try:
+        for x in c.managers + [m]:
+            assert x._dispatch_step is sentinel
+            assert set(x.engine_compile_stats()) == {"dispatch", "lifecycle"}
+        c.create("one")
+        c.run(20)
+        vec = m.blob_vec()
+        m.step_complete(m.step_dispatch(
+            np.stack([vec, vec, vec]), np.array([True, False, False])))
+        assert sentinel.n_compiles == 1, sentinel.stats()
+        assert sentinel.n_retraces == 0, sentinel.stats()
+    finally:
+        c.close()
+        m.close()
+
+
 def test_lifecycle_waits_for_inflight_step():
     """A state-replacing op (create) arriving during the in-flight
     window must WAIT for step_complete — interleaving would let the
@@ -176,7 +151,7 @@ def test_lifecycle_waits_for_inflight_step():
 def test_flush_coalescing_metrics():
     """A loopback round trip populates the flush metrics (one frame per
     peer per cycle: responses_flushed counter + flush_batch_size hist),
-    and the stats admin op reports the live codec + pipeline mode."""
+    and the stats admin op reports the live codec."""
     from tests.test_server import boot_cluster, wait_until
 
     servers, client, _ = boot_cluster()
@@ -198,7 +173,6 @@ def test_flush_coalescing_metrics():
         st = client.admin_sync(0, {"op": "stats"}, timeout=10)
         assert st and st["ok"]
         serving = st["serving"]
-        assert serving["pipeline_dispatch"] is True
         assert serving["codec"]["binary_frames"] is True
         assert serving["codec"]["impl"] in ("gp_codec.so", "python-struct")
         assert serving["serving_workers"] == 1
@@ -210,14 +184,13 @@ def test_flush_coalescing_metrics():
 
 @pytest.mark.timeout(120)
 def test_pipelined_loopback_under_overlap():
-    """Sanity: with pipelining ON (the default), concurrent client load
-    through real sockets stays correct — responses arrive and replicas
+    """Sanity: concurrent client load through real sockets stays correct
+    under the pipelined tick loop — responses arrive and replicas
     converge (the overlap window is exercised by the live tick loop)."""
     from tests.test_server import boot_cluster, wait_until
 
     servers, client, _ = boot_cluster()
     try:
-        assert servers[0]._pipeline is True
         assert client.create_paxos_instance("ov", [0, 1, 2], timeout=30)
         total = 0
         for i in range(8):

@@ -20,8 +20,7 @@ from gigapaxos_tpu.ops.lifecycle import initial_coordinator
 from gigapaxos_tpu.parallel.mesh import make_mesh
 from gigapaxos_tpu.parallel.spmd import (
     build_replica_states,
-    single_chip_step,
-    spmd_step,
+    make_step,
 )
 from gigapaxos_tpu.testing.sim import DELIVER, DROP, SimCluster
 
@@ -100,7 +99,7 @@ def test_single_chip_faults_match_host_sim():
     schedule = _schedule()
     sim = _run_sim(schedule)
 
-    fn = single_chip_step(CFG)
+    fn = make_step(CFG)
     states = build_replica_states(CFG, coord0=_coord0())
     for delivery, req, want in schedule:
         states, _ = fn(
@@ -133,7 +132,7 @@ def test_spmd_faults_match_host_sim():
     sim = _run_sim(schedule)
 
     mesh = make_mesh(n_replicas=R, n_group_shards=2)
-    fn = spmd_step(CFG, mesh)
+    fn = make_step(CFG, mesh)
     states = build_replica_states(CFG, coord0=_coord0())
     for delivery, req, want in schedule:
         states, _ = fn(
@@ -155,7 +154,7 @@ def test_spmd_partition_heals():
     sim = SimCluster(CFG)
     sim.create_all_groups()
     mesh = make_mesh(n_replicas=R, n_group_shards=2)
-    fn = spmd_step(CFG, mesh)
+    fn = make_step(CFG, mesh)
     states = build_replica_states(CFG, coord0=_coord0())
 
     coord0 = np.asarray(_coord0())

@@ -21,7 +21,7 @@ from gigapaxos_tpu.ops.ballot import NULL
 from gigapaxos_tpu.ops.engine import EngineConfig
 from gigapaxos_tpu.ops.lifecycle import create_groups, jump_rows, kill_groups
 from gigapaxos_tpu.parallel.mesh import make_mesh
-from gigapaxos_tpu.parallel.spmd import build_replica_states, spmd_step
+from gigapaxos_tpu.parallel.spmd import build_replica_states, make_step
 
 R, G, K, W = 4, 8, 4, 8
 CFG = EngineConfig(n_groups=G, window=W, req_lanes=K, n_replicas=R)
@@ -64,7 +64,7 @@ def test_epoch_upgrade_and_tag_guard_through_shard_map():
     tag guard, a chaos-soak find on the host path)."""
     mesh = _mesh_or_skip()
     states = build_replica_states(CFG)
-    step_fn = spmd_step(CFG, mesh)
+    step_fn = make_step(CFG, mesh)
     row = 3
 
     # epoch 0: everyone commits something on the row
@@ -111,7 +111,7 @@ def test_pause_resume_jump_through_shard_map():
     with full agreement."""
     mesh = _mesh_or_skip()
     states = build_replica_states(CFG)
-    step_fn = spmd_step(CFG, mesh)
+    step_fn = make_step(CFG, mesh)
     row = 2
 
     states = _drive(step_fn, states, row, [41, 42, 43])

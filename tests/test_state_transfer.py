@@ -72,7 +72,7 @@ def _jump_body(tmp_path):
 
     # node 2 restarts from its own (stale) journal and rejoins
     c.managers[2] = PaxosManager(2, HashChainApp(), cfg, log_dir=dirs[2])
-    c.blobs[2] = c.managers[2].blob()
+    c.republish()
     for _ in range(80):
         c.step_all()
         if int(np.asarray(c.managers[2].state.exec_slot)[row]) >= live_exec:

@@ -208,7 +208,7 @@ def test_overflow_takes_whole_planes_and_decides_the_same():
         names = [f"ov{i}" for i in range(cfg.n_groups)]
         for m in c.managers:
             assert m.create_paxos_batch(names, [0, 1, 2]) == cfg.n_groups
-        c.blobs = [m.blob() for m in c.managers]
+        c.republish()
         done = {}
         for i, nm in enumerate(names):
             coord = m0.coordinator_of_row(m0.names[nm])

@@ -200,9 +200,9 @@ def test_packed_flavor_frozen_peer_parity():
     golden_blob = np.asarray(pack_blob(make_blob(st)))
 
     fn = make_step(cfg, None, N, donate=False, io="packed_host")
-    st_u, out_rings, blob_vec = fn(
+    st_u, out_rings, blob_vec, _heat, _digests = fn(
         per[my_id], gvec, heard, jnp.asarray(np.stack(reqs)), want,
-        jnp.int32(my_id),
+        jnp.int32(my_id), jnp.zeros((8,), jnp.int32),
     )
     _assert_trees_equal(st, st_u, "state")
     rows = np.asarray(out_rings)
