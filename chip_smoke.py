@@ -103,14 +103,19 @@ def make_trace(cfg, n_steps: int, seed: int):
 # phase: engine parity
 # ---------------------------------------------------------------------------
 class _ReplicaArm:
-    """R replica states on one device, stepped through the packed step
-    with the blobs handed from replica to replica directly."""
+    """R replica states on one device, each with its gathered stack,
+    stepped through the packed step; a replica's blob reaches the others
+    as a node's frames bring it (``net/gather.py``): whole the first
+    time and where more rows changed than an update holds, else as the
+    rows that changed, scattered by the step."""
 
     def __init__(self, cfg, device, step_fn):
         import jax
         import jax.numpy as jnp
 
-        from gigapaxos_tpu.ops.engine import init_state, make_blob, pack_blob
+        from gigapaxos_tpu.net.gather import GatherNews, empty_update_vec
+        from gigapaxos_tpu.ops.engine import (
+            init_stack, init_state, make_blob, pack_blob)
         from gigapaxos_tpu.ops.lifecycle import create_groups
 
         G, R = cfg.n_groups, cfg.n_replicas
@@ -132,19 +137,42 @@ class _ReplicaArm:
         ]
         pack = jax.jit(lambda s: pack_blob(make_blob(s)))
         self.blobs = [pack(s) for s in self.states]
+        self.stacks = [jax.tree.map(self.put, init_stack(cfg))
+                       for _ in range(R)]
+        self.held = [None] * R  # the vectors every stack holds
+        self.news = GatherNews(cfg)
+        self.no_rows = empty_update_vec(cfg)
+        self.scattered = self.whole = 0
         self.heat = [self.put(jnp.zeros((G,), jnp.int32)) for _ in range(R)]
         self.digests = [None] * R  # the last step's, per replica
 
     def step(self, req, want, heard):
         import jax.numpy as jnp
 
-        put = self.put
-        gathered = jnp.stack(self.blobs)
+        from gigapaxos_tpu.ops.engine import set_peer_rows
+
+        put, cfg = self.put, self.cfg
+        R = cfg.n_replicas
+        # every replica is told of every other (``heard`` masks what it
+        # may use): one update serves all R stacks, its row for a
+        # replica's own id overwritten by the step from that state
+        self.held = [
+            self.news.hear(j, np.asarray(blob), self.held[j])
+            for j, blob in enumerate(self.blobs)
+        ]
+        update = self.news.drain(dict(enumerate(self.held)))
+        self.whole += len(update.whole)
+        self.scattered += update.n_scattered
+        upd = put(self.no_rows if update.rows is None else update.rows)
         ring = put(req[None])
         outs, blobs = [], []
-        for r in range(self.cfg.n_replicas):
-            self.states[r], out, blob, self.heat[r], digest = self.step_fn(
-                self.states[r], gathered, put(heard[r]), ring,
+        for r in range(R):
+            for peer, vec in update.whole:
+                self.stacks[r] = set_peer_rows(
+                    self.stacks[r], put(vec), put(np.int32(peer)), cfg=cfg)
+            (self.states[r], self.stacks[r], out, blob, self.heat[r],
+             digest) = self.step_fn(
+                self.states[r], self.stacks[r], upd, put(heard[r]), ring,
                 put(want[r]), put(np.int32(r)), self.heat[r],
             )
             outs.append(out)
@@ -161,6 +189,8 @@ class _ReplicaArm:
             for name, leaf in state._asdict().items():
                 yield f"r{r}.state.{name}", leaf
             yield f"r{r}.blob", blob
+            for name, leaf in self.stacks[r]._asdict().items():
+                yield f"r{r}.stack.{name}", leaf
             yield f"r{r}.heat", heat
             yield f"r{r}.digest", self.digests[r]
 
@@ -231,6 +261,7 @@ def phase_engine_parity(n_groups: int, window: int, req_lanes: int,
         "leaves_compared": n_leaves,
         "decided": decided,
         "admitted": admitted,
+        "gather_updates": {"scattered": arm.scattered, "whole": arm.whole},
         "first_call_s": first_call_s,  # compile + one dispatch of R steps
         "steady_s_per_round": steady_s / max(1, n_steps - 1),
         "compile": compile_summary(step_fn),

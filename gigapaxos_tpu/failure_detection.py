@@ -46,6 +46,9 @@ class FailureDetector:
         )
         now = time.time()
         self.last_heard: Dict[int, float] = {int(n): now for n in node_ids}
+        # want_coord's last inputs (as bytes) and its answer to them
+        self._want_key = None
+        self._want: Optional[np.ndarray] = None
 
     @property
     def ping_period_s(self) -> float:
@@ -73,13 +76,28 @@ class FailureDetector:
         member_mask: np.ndarray,  # [G]
         n_replicas: int,
     ) -> np.ndarray:
-        """[G] bool: should THIS node start an election for each group."""
+        """[G] bool: should THIS node start an election for each group.
+        The answer is a function of who is up, the ballots and the
+        memberships, which stand still from tick to tick: while they do,
+        the last answer is handed back — the SAME array, read-only —
+        and the thirty [G] passes below do not run (each gives up the
+        interpreter lock and queues for it again)."""
         R = n_replicas
         up = np.array([self.is_node_up(r) for r in range(R)], bool)
         long_dead = np.array(
             [self.dead_for(r) > self.timeout_s * self.long_dead_factor
              for r in range(R)], bool,
         )
+        key = (up.tobytes(), long_dead.tobytes(),
+               np.asarray(bal).tobytes(), np.asarray(member_mask).tobytes())
+        if key == self._want_key:
+            return self._want
+        self._want_key, self._want = key, self._want_coord(
+            up, long_dead, bal, member_mask, R)
+        self._want.setflags(write=False)
+        return self._want
+
+    def _want_coord(self, up, long_dead, bal, member_mask, R) -> np.ndarray:
         coord = np.asarray(ballot_coord(np.asarray(bal))) % R
         mask = np.asarray(member_mask)
         # a coordinator that is alive but NOT a member of the group (left

@@ -61,12 +61,16 @@ from .ops.engine import (
     blob_vec_len,
     digest_from_planes,
     digest_rows,
+    gathered_matrix,
+    init_stack,
     make_blob,
     pack_blob,
+    set_peer_rows,
     split_blob_vec,
     split_digest_vec,
     split_out_vec,
 )
+from .net.gather import NO_NEWS, GatherUpdate, empty_update_vec
 from .parallel.spmd import make_step
 from .obs import gplog
 from .obs.flight import FlightRecorder
@@ -81,7 +85,9 @@ from .storage.logger import PaxosLogger
 # state: the manager owns it exclusively (every external view is an
 # identity check or a host-side numpy copy), so the old buffers may be
 # reused in place by the new state — on-device this halves state HBM;
-# backends without donation support ignore it.
+# backends without donation support ignore it.  The gathered stack (the
+# peers' blobs, ops/engine.py:init_stack) is donated with it and never
+# leaves the device: a dispatch sends up the rows its frames named.
 _publish_vec_jit = jax.jit(lambda state: pack_blob(make_blob(state)))
 
 
@@ -274,7 +280,8 @@ class PaxosManager:
         # present from the start: a snapshot shows a counter that never
         # fired apart from a program that has no such counter
         for key in ("requests_carried_over", "step_digest_dispatches",
-                    "step_digest_overflows"):
+                    "step_digest_overflows", "gather_updates_scattered",
+                    "gather_updates_whole", "gather_upload_bytes"):
             self.metrics.count(key, 0)
         # black-box flight recorder (obs/flight.py): always-on bounded
         # rings of per-step engine summaries + last-K decided
@@ -393,6 +400,22 @@ class PaxosManager:
         # (ops/engine.py:make_digest); a substep with more busy rows than
         # the digest holds has its whole planes pulled instead
         self._digest_rows = digest_rows(cfg)
+        # the gathered stack, and the update of a tick without a row
+        # (padding only; never donated, so one upload serves every such
+        # tick)
+        self._stack = init_stack(cfg)
+        self._no_rows = jnp.asarray(empty_update_vec(cfg))
+        # the dispatch's other inputs, kept on the device while the host
+        # value stands: my id; a ring with no request in it; who is heard
+        # (by its bytes); the election mask (by identity: the failure
+        # detector hands back the same read-only array until its answer
+        # changes).  Each upload spared is a call that gives up the
+        # interpreter lock and queues for it again
+        self._my_id_dev = jnp.int32(my_id)
+        self._null_ring = jnp.asarray(np.full(
+            (self.steps_per_dispatch, G, cfg.req_lanes), NULL, np.int32))
+        self._heard_dev = (None, None)
+        self._want_dev = (None, None)
         # the work-in-flight flag of the last completed step's new state
         self._work_in_flight = False
         # device-resident [G] group-activity accumulator + the host-side
@@ -982,8 +1005,9 @@ class PaxosManager:
 
     def warm_engine(self) -> float:
         """Compile what the serving path dispatches — the donated packed
-        step, the publish-vector pack and the single-row lifecycle
-        scatters — on a scratch state, BEFORE the node's listeners open;
+        step, the whole-row program of the gathered stack, the
+        publish-vector pack and the single-row lifecycle scatters — on a
+        scratch state and a scratch stack, BEFORE the node's listeners open;
         returns the seconds it took.
 
         The first dispatch otherwise compiles inside the tick thread,
@@ -1007,9 +1031,13 @@ class PaxosManager:
         req = np.full(
             (self.steps_per_dispatch, G, cfg.req_lanes), NULL, np.int32
         )
+        stack = set_peer_rows(
+            init_stack(cfg),
+            jnp.asarray(np.zeros(blob_vec_len(cfg), np.int32)),
+            jnp.int32(0), cfg=cfg,
+        )
         out = self._dispatch_step(
-            scratch,
-            jnp.asarray(np.zeros((R, blob_vec_len(cfg)), np.int32)),
+            scratch, stack, jnp.asarray(empty_update_vec(cfg)),
             jnp.asarray(np.zeros(R, bool)), jnp.asarray(req),
             jnp.asarray(np.zeros((G,), bool)), jnp.int32(self.my_id),
             jnp.zeros((G,), jnp.int32),
@@ -1094,6 +1122,13 @@ class PaxosManager:
                 "label": "create_groups+kill_groups",
                 "compiles": create_groups._cache_size()
                 + kill_groups._cache_size(),
+                "retraces": 0,
+            },
+            # the whole-row program of the gathered stack: one compile a
+            # shape, in warm-up; a new connection in traffic runs it
+            "gather": {
+                "label": "set_peer_rows",
+                "compiles": set_peer_rows._cache_size(),
                 "retraces": 0,
             },
         }
@@ -3138,7 +3173,7 @@ class PaxosManager:
 
     def tick_host(
         self,
-        gathered_vec: np.ndarray,
+        update: Optional[GatherUpdate],
         heard: np.ndarray,
         want_coord: Optional[np.ndarray] = None,
     ) -> Tuple[np.ndarray, "EngineState", Dict]:
@@ -3146,13 +3181,14 @@ class PaxosManager:
         :meth:`step_complete` back to back under ONE hold of the lock —
         the reference the pipelined pair is held to
         (tests/test_pipeline.py), and what the stepped harnesses call.
-        `gathered_vec` is the [R, N] stack of packed peer blob vectors
-        (== the `D` wire frame bodies); returns (my fresh packed blob
-        vector, the state it reflects, the host delta).  User callbacks
+        `update` is what came of the peers since the last dispatch
+        (net/gather.py: rows for the device's stack, whole vectors; None
+        where there are no peers or no news); returns (my fresh packed
+        blob vector, the state it reflects, the host delta).  User callbacks
         collected during execution fire AFTER the lock is released (a
         blocking callback must not wedge transport threads)."""
         with self._step_locked():
-            pend = self._dispatch_locked(gathered_vec, heard, want_coord)
+            pend = self._dispatch_locked(update, heard, want_coord)
             digest_np, blob_np = self._device_wait(pend)
             host_delta = self._complete_locked(pend, digest_np, blob_np)
             fired, self._fired_callbacks = self._fired_callbacks, []
@@ -3191,38 +3227,100 @@ class PaxosManager:
         finally:
             self._state_lock.release()
 
-    def _dispatch_locked(self, gathered_vec, heard, want_coord,
+    def _dispatch_locked(self, update, heard, want_coord,
                          carry: bool = False):
-        """Lock held: admit into the request ring and fire the step
-        without waiting for the device.  Returns the pending handle of
-        device values (``out_vec`` stays on the device unless a substep's
-        digest overflows) with ``self.state`` already the in-flight
-        result."""
+        """Lock held: admit into the request ring, send up the peers'
+        news (whole vectors through the whole-row program, rows with the
+        step) and fire the step without waiting for the device.
+        Returns the pending handle of device values (``out_vec`` stays
+        on the device unless a substep's digest overflows) with
+        ``self.state`` already the in-flight result."""
         with self._span("step.ring_build", cpu=False):
             req = self.build_request_ring(self.steps_per_dispatch)
-            wc = (
-                np.zeros((self.cfg.n_groups,), bool) if want_coord is None
-                else np.asarray(want_coord, bool)
-            )
             old_state = self.state
             carried = self._carried_leaves(old_state) if carry else None
         with self._span("step.dispatch"):
             t0 = time.monotonic()
-            new_state, out_vec, blob_vec, new_heat, digest_vec = \
-                self._dispatch_step(
-                    old_state, jnp.asarray(gathered_vec),
-                    jnp.asarray(heard), jnp.asarray(req), jnp.asarray(wc),
-                    jnp.int32(self.my_id), self._heat_dev,
+            # every upload is a temporary of this call: a device value
+            # made of host memory may hold its last reference until the
+            # step has read it, and that wait belongs to this span
+            new_state, self._stack, out_vec, blob_vec, new_heat, \
+                digest_vec = self._dispatch_step(
+                    old_state, *self._send_up_locked(update or NO_NEWS),
+                    self._heard_locked(heard),
+                    jnp.asarray(req) if self._last_ring_depth
+                    else self._null_ring,
+                    self._want_locked(want_coord),
+                    self._my_id_dev, self._heat_dev,
                 )
-        self.state = new_state
-        self._heat_dev = new_heat
-        if carry:
-            self._np_cache = carried
-            self._np_cache_state = new_state
+            # the donated buffers' last references go inside the span:
+            # where letting go of one waits for the step (the CPU
+            # backend), that wait is the dispatch's
+            self.state = new_state
+            self._heat_dev = new_heat
+            if carry:
+                self._np_cache = carried
+                self._np_cache_state = new_state
+            del old_state
         return {
             "out_vec": out_vec, "blob_vec": blob_vec,
             "digest_vec": digest_vec, "state": new_state, "t0": t0,
         }
+
+    def _heard_locked(self, heard):
+        """``heard`` on the device, sent up when it differs from the last."""
+        heard = np.asarray(heard, bool)
+        if heard.tobytes() != self._heard_dev[0]:
+            self._heard_dev = (heard.tobytes(), jnp.asarray(heard))
+        return self._heard_dev[1]
+
+    def _want_locked(self, want_coord):
+        """The election mask on the device.  None and a read-only array
+        (the failure detector's standing answer) are sent up once, by
+        identity; anything else every time."""
+        standing = want_coord is None or (
+            isinstance(want_coord, np.ndarray)
+            and not want_coord.flags.writeable)
+        if not standing:
+            return jnp.asarray(np.asarray(want_coord, bool))
+        if self._want_dev[1] is None or want_coord is not self._want_dev[0]:
+            self._want_dev = (want_coord, jnp.asarray(
+                np.zeros((self.cfg.n_groups,), bool)
+                if want_coord is None else want_coord))
+        return self._want_dev[1]
+
+    def _send_up_locked(self, update: GatherUpdate, count: bool = True):
+        """Lock held: the update's whole vectors over their rows of the
+        stack (the whole-row program); -> (the stack, the update's rows
+        as the device value the step scatters)."""
+        for peer, vec in update.whole:
+            self._stack = set_peer_rows(
+                self._stack, jnp.asarray(vec), jnp.int32(peer), cfg=self.cfg
+            )
+        if count:
+            mx = self.metrics
+            mx.count("gather_updates_whole", len(update.whole))
+            mx.count("gather_updates_scattered", update.n_scattered)
+            mx.observe("gather_update_rows", update.n_rows, bounds=ROW_BOUNDS)
+            mx.count("gather_upload_bytes", sum(
+                v.nbytes for _p, v in update.whole
+            ) + (0 if update.rows is None else update.rows.nbytes))
+        return self._stack, (self._no_rows if update.rows is None
+                             else jnp.asarray(update.rows))
+
+    def gathered_host(self, update: Optional[GatherUpdate] = None
+                      ) -> np.ndarray:
+        """The [R, N] matrix the next step would read given ``update``,
+        on the host (tests; a look at a node by hand).  The update's
+        whole vectors ARE applied, which a dispatch of the same update
+        then repeats to no effect; its rows are not."""
+        with self._state_lock:
+            self._await_step_locked()
+            return np.asarray(gathered_matrix(
+                self.state,
+                *self._send_up_locked(update or NO_NEWS, count=False),
+                self._my_id_dev, cfg=self.cfg,
+            ))
 
     def _carried_leaves(self, old_state) -> Dict[str, np.ndarray]:
         """The lifecycle-owned leaves' host cache, carried across the
@@ -3331,7 +3429,7 @@ class PaxosManager:
 
     def step_dispatch(
         self,
-        gathered_vec: np.ndarray,
+        update: Optional[GatherUpdate],
         heard: np.ndarray,
         want_coord: Optional[np.ndarray] = None,
     ) -> Dict:
@@ -3343,7 +3441,7 @@ class PaxosManager:
         avoids that via the carried lifecycle-leaf cache below)."""
         with self._step_locked():  # single-depth pipeline
             pend = self._dispatch_locked(
-                gathered_vec, heard, want_coord, carry=True,
+                update, heard, want_coord, carry=True,
             )
             self._step_inflight = True
             self._step_thread = threading.get_ident()
@@ -4375,17 +4473,10 @@ class PaxosManager:
 
     def blob_vec(self) -> np.ndarray:
         """Packed publish vector for the current state (the wire body of
-        a `D` frame); used by the socket runtime at boot and after
-        lifecycle ops, before the first packed tick returns one."""
-        return self.publish_snapshot()[0]
-
-    def publish_snapshot(self) -> Tuple[np.ndarray, EngineState]:
-        """(packed publish vector, the exact state it was computed from),
-        captured atomically — callers caching the pair can then detect
-        staleness by state identity without racing lifecycle ops."""
+        a `D` frame): what a stepped harness hands the peers before the
+        first tick returns one, and after a lifecycle op."""
         with self._state_lock:
-            state = self.state
-            return np.asarray(_publish_vec_jit(state)), state
+            return np.asarray(_publish_vec_jit(self.state))
 
     def close(self) -> None:
         if self.hydrator is not None:

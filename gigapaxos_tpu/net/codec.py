@@ -149,6 +149,22 @@ def _row_blocks(vec: np.ndarray, cfg: EngineConfig,
     return blocks
 
 
+def changed_rows(vec: np.ndarray, base: np.ndarray,
+                 cfg: EngineConfig) -> np.ndarray:
+    """The engine rows in which two packed vectors differ, ascending."""
+    changed = np.zeros(cfg.n_groups, bool)
+    for block in _row_blocks(vec != base, cfg):
+        changed |= block.any(axis=(0, 2))
+    return np.flatnonzero(changed).astype(np.int32)
+
+
+def rows_of(vec: np.ndarray, rows: np.ndarray,
+            cfg: EngineConfig) -> List[np.ndarray]:
+    """The named rows of a packed vector, as :func:`_row_blocks` of
+    ``len(rows)`` rows (what a ``d`` frame carries of them)."""
+    return [block[:, rows] for block in _row_blocks(vec, cfg)]
+
+
 def encode_blob_frame(
     sender: int, cfg: EngineConfig,
     item: Tuple[int, np.ndarray],
@@ -162,18 +178,13 @@ def encode_blob_frame(
     delta wherever it is smaller than the whole vector."""
     tick, vec = item
     if base is not None:
-        differs = vec != base[1]
-        changed = np.zeros(cfg.n_groups, bool)
-        for block in _row_blocks(differs, cfg):
-            changed |= block.any(axis=(0, 2))
-        rows = np.flatnonzero(changed).astype(np.int32)
+        rows = changed_rows(vec, base[1], cfg)
         n = int(rows.size)
         if _DHDR.size + 4 * n * (1 + vec.size // cfg.n_groups) \
                 < _BHDR.size + 4 * vec.size:
             parts = [_DHDR.pack(b"d", sender, tick, base[0], n),
                      rows.tobytes()]
-            parts += [block[:, rows].tobytes()
-                      for block in _row_blocks(vec, cfg)]
+            parts += [block.tobytes() for block in rows_of(vec, rows, cfg)]
             return b"".join(parts), n
     return encode_blob_vec(sender, tick, vec), None
 

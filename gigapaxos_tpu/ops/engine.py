@@ -867,6 +867,131 @@ def unpack_gathered(gvec: jnp.ndarray, cfg: EngineConfig) -> Blob:
     return _unpack(gvec, Blob._fields, cfg, Blob, batched=True)
 
 
+# ---------------------------------------------------------------------------
+# The gathered stack: every peer's blob as ONE device-resident Blob of
+# [R, ...] leaves, which the packed step is handed donated and hands back.
+# Its lane leaves are held [R, W, G], ROWS MINOR: that is how the chip lays
+# out a [G, W] plane (the step reads them with no relayout — the parent's
+# unpacking of an uploaded [R, N] matrix was 0.9 of its 2.18 ms), and in
+# that layout a row's W words are scattered one by one, in place; a
+# scatter of whole [W] windows would relayout every plane there and back
+# (+1.2 ms; PERF.md, PR 30).
+#
+# A tick sends up only what its frames brought: an UPDATE of fixed shape
+# (``update_vec_len``) — ``update_rows`` flat indices ``peer * G + row``,
+# ascending and unique, then those rows' words as a packed vector of that
+# many rows in the wire layout (``net/codec.py:_row_blocks`` cuts it the
+# same way).  An index at or past ``R * G`` pads the update and is dropped.
+# A peer whose news does not fit sends its whole vector (``set_peer_rows``).
+# ---------------------------------------------------------------------------
+
+def update_rows(cfg: EngineConfig) -> int:
+    """C, the rows one update holds: from the shape, as the digest's."""
+    return digest_rows(cfg)
+
+
+@functools.lru_cache(maxsize=None)
+def update_vec_len(cfg: EngineConfig) -> int:
+    C = update_rows(cfg)
+    return C + blob_vec_len(cfg._replace(n_groups=C))
+
+
+def _swap_lanes(blob: Blob) -> Blob:
+    """Lane leaves [..., G, W] <-> [..., W, G] (on the chip a bitcast)."""
+    return Blob(*[
+        leaf if name in _G_LEAVES else jnp.swapaxes(leaf, -1, -2)
+        for name, leaf in zip(Blob._fields, blob)
+    ])
+
+
+def init_stack(cfg: EngineConfig) -> Blob:
+    """The stack before any peer was heard: zeros, which no step reads
+    (``heard`` masks a row until its peer's first whole vector lands)."""
+    R = cfg.n_replicas
+    return _swap_lanes(Blob(*[
+        jnp.zeros((R,) + shape, jnp.int32)
+        for _name, shape in _leaf_shapes(Blob._fields, cfg)
+    ]))
+
+
+def stack_blob(stack: Blob) -> Blob:
+    """The stack as the step reads it: [R, G] and [R, G, W] leaves."""
+    return _swap_lanes(stack)
+
+
+_SCATTER_CHUNK = 256  # rows scattered per pass of scatter_update's loop
+
+
+def scatter_update(stack: Blob, upd: jnp.ndarray, cfg: EngineConfig) -> Blob:
+    """Inside jit: the update's rows written into the stack.  The
+    device's work follows the rows that are there: a chunk of them a
+    pass, as many passes as hold them (0.24 ms a pass at 65,536 rows)."""
+    G, W, R, C = cfg.n_groups, cfg.window, cfg.n_replicas, update_rows(cfg)
+    S = min(_SCATTER_CHUNK, C)
+    idx = upd[:C]
+    rows = _unpack(upd[C:], Blob._fields, cfg._replace(n_groups=C), Blob,
+                   batched=False)
+    n = (idx < R * G).sum(dtype=jnp.int32)
+    lanes = jnp.arange(W, dtype=jnp.int32)
+
+    def scatter_chunk(i, stack):
+        # a chunk that would run past C is read S rows back from the
+        # end (dynamic_slice clamps): rows written twice, to one value
+        at = lax.dynamic_slice(idx, (i * S,), (S,))
+        peer, row = at // G, at % G  # padding: peer >= R, dropped
+        plane = (peer[:, None] * W + lanes[None]).ravel()   # of [R * W, G]
+        col = jnp.repeat(row, W)
+        out = []
+        for leaf, new in zip(stack, rows):
+            new = lax.dynamic_slice_in_dim(new, i * S, S)
+            if leaf.ndim == 2:
+                out.append(leaf.at[peer, row].set(
+                    new, mode="drop", unique_indices=True,
+                    indices_are_sorted=True))
+            else:
+                out.append(leaf.reshape(R * W, G).at[plane, col].set(
+                    new.ravel(), mode="drop", unique_indices=True,
+                ).reshape(R, W, G))
+        return Blob(*out)
+
+    return lax.fori_loop(0, (n + S - 1) // S, scatter_chunk, stack)
+
+
+def _set_row(stack: Blob, blob: Blob, r) -> Blob:
+    """Row ``r`` of the stack := one replica's blob ([G], [G, W])."""
+    return Blob(*[
+        lax.dynamic_update_index_in_dim(leaf, row, r, 0)
+        for leaf, row in zip(stack, _swap_lanes(blob))
+    ])
+
+
+@functools.partial(jax.jit, static_argnames="cfg", donate_argnums=0)
+def set_peer_rows(stack: Blob, vec: jnp.ndarray, peer, *,
+                  cfg: EngineConfig) -> Blob:
+    """The whole-row program: one peer's packed [N] vector over its row
+    of the (donated) stack — a new connection's ``D`` frame, the answer
+    to a resync, news of more rows than an update holds."""
+    return _set_row(
+        stack, _unpack(vec, Blob._fields, cfg, Blob, batched=False), peer)
+
+
+def with_my_row(stack: Blob, state: EngineState, my_id) -> Blob:
+    """Inside jit: the stack with row ``my_id`` taken from the state."""
+    return _set_row(stack, make_blob(state), my_id)
+
+
+@functools.partial(jax.jit, static_argnames="cfg")
+def gathered_matrix(state: EngineState, stack: Blob, upd: jnp.ndarray,
+                    my_id, *, cfg: EngineConfig) -> jnp.ndarray:
+    """What the next step reads of (state, stack, update), as the packed
+    [R, N] matrix a host would have assembled; donates nothing (tests,
+    and a look at a node by hand)."""
+    g = with_my_row(scatter_update(stack, upd, cfg), state, my_id)
+    return jnp.concatenate(
+        [leaf.reshape(cfg.n_replicas, -1) for leaf in stack_blob(g)], axis=1
+    )
+
+
 def unpack_out(vec: jnp.ndarray, cfg: EngineConfig) -> StepOutputs:
     """[M] packed step outputs -> StepOutputs (inside jit)."""
     return _unpack(vec, StepOutputs._fields, cfg, StepOutputs, batched=False)
@@ -1026,21 +1151,3 @@ def digest_from_planes(out: StepOutputs, acc_slot: np.ndarray,
         *[getattr(out, f)[rows] for f in _DIGEST_OUT_PLANES],
         acc_slot[rows], acc_bal[rows], acc_vid[rows],
     )
-
-
-def step_host(
-    state: EngineState,
-    gvec: jnp.ndarray,       # [R, N] packed gathered blobs
-    heard: jnp.ndarray,
-    req_vid: jnp.ndarray,
-    want_coord: jnp.ndarray,
-    my_id: jnp.ndarray,
-    *,
-    cfg: EngineConfig,
-):
-    """One step over packed I/O: returns (state', out_vec, blob_vec)."""
-    g = unpack_gathered(gvec, cfg)
-    new_state, out = step(state, g, heard, req_vid, want_coord, my_id, cfg=cfg)
-    out_vec = jnp.concatenate([jnp.ravel(leaf) for leaf in out])
-    blob_vec = pack_blob(make_blob(new_state))
-    return new_state, out_vec, blob_vec

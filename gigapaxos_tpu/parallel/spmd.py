@@ -31,21 +31,26 @@ Two I/O flavors:
   substep and the blob exchange is re-read from the advancing states, so
   N stacked substeps are exactly N sequential stacked calls.
 
-* ``io="packed_host"`` — the deployed-runtime face (one replica's state,
-  peers' blobs arriving as the packed ``[R, NB]`` gathered matrix == the
-  ``D`` wire-frame bodies, and a donated ``[G]`` int32 activity
-  accumulator as the trailing argument): returns ``(state', out_rings
-  [N, M], blob_vec, heat', digests [N, L])`` — ``heat'`` is the
-  accumulator plus ``n_committed + n_admitted`` of every substep (the
-  host pulls it at the stats cadence, never per tick), ``digests`` per
-  substep what the host's post-step reads (``ops/engine.py:make_digest``:
-  the [G] output leaves, the busy rows of the [G, W] planes with their
-  accept lanes of the new state, a work-in-flight flag), so that
-  ``out_rings`` can stay on the device.
-  Substep 0 consumes the gathered rows exactly as passed; substeps >= 1
-  refresh only MY row from the advancing state while peers' rows stay
-  frozen — the semantics of N serial host ticks during which no new
-  peer frame lands.
+* ``io="packed_host"`` — the deployed-runtime face: one replica's state,
+  the gathered STACK (every peer's blob as a device-resident ``Blob`` of
+  ``[R, ...]`` leaves, rows minor — ``ops/engine.py:init_stack`` —
+  donated and handed back), the tick's news as one
+  fixed-shape update (``ops/engine.py:scatter_update``: the rows the
+  peers' ``d`` frames named, scattered into the stack before it is
+  read), and a donated ``[G]`` int32 activity accumulator as the
+  trailing argument.  Returns ``(state', stack', out_rings [N, M],
+  blob_vec, heat', digests [N, L])`` — ``heat'`` is the accumulator plus
+  ``n_committed + n_admitted`` of every substep (the host pulls it at
+  the stats cadence, never per tick), ``digests`` per substep what the
+  host's post-step reads (``ops/engine.py:make_digest``: the [G] output
+  leaves, the busy rows of the [G, W] planes with their accept lanes of
+  the new state, a work-in-flight flag), so that ``out_rings`` can stay
+  on the device.
+  Row ``my_id`` of the stack is never sent up: every substep takes it
+  from the state it steps (at entry that is the row a host would have
+  gathered, after a lifecycle operation included); peers' rows stay
+  frozen for the dispatch — the semantics of N serial host ticks during
+  which no new peer frame lands.
 
 Global array convention for SPMD: every state leaf gets a leading replica
 axis -> ``[R, G, ...]``; a ``(g, r)`` mesh constrains ``P('r', 'g')``, a
@@ -72,9 +77,11 @@ from ..ops.engine import (
     make_digest,
     out_vec_len,
     pack_blob,
+    scatter_update,
+    stack_blob,
     step,
-    unpack_gathered,
     unpack_out,
+    with_my_row,
 )
 from .mesh import GROUP_AXIS, REPLICA_AXIS
 
@@ -234,14 +241,15 @@ def _build_packed(cfg: EngineConfig, mesh: Optional[Mesh], n_steps: int,
         return jnp.concatenate([jnp.ravel(leaf) for leaf in out])
 
     if n_steps == 1:
-        # the exact legacy step_host program (plus a trivial [1, M]
-        # reshape): one upload, one step, two downloads
-        def run_heat(state, gvec, heard, req_ring, want_coord, my_id,
+        # one scatter of the tick's news, one step, three downloads
+        def run_heat(state, stack, upd, heard, req_ring, want_coord, my_id,
                      heat_acc):
             state = _constrain(mesh, state, GROUP_AXIS)
-            g = unpack_gathered(gvec, cfg)
+            stack = with_my_row(
+                scatter_update(stack, upd, cfg), state, my_id)
             new_state, out = step(
-                state, g, heard, req_ring[0], want_coord, my_id, cfg=cfg
+                state, stack_blob(stack), heard, req_ring[0], want_coord,
+                my_id, cfg=cfg,
             )
             heat_acc = _constrain(
                 mesh, heat_acc + out.n_committed + out.n_admitted,
@@ -250,46 +258,38 @@ def _build_packed(cfg: EngineConfig, mesh: Optional[Mesh], n_steps: int,
             out_rings = _pack_out(out)[None]
             blob_vec = pack_blob(make_blob(new_state))
             return (
-                _constrain(mesh, new_state, GROUP_AXIS),
+                _constrain(mesh, new_state, GROUP_AXIS), stack,
                 out_rings, blob_vec, heat_acc,
                 make_digest(out, new_state, cfg)[None],
             )
     else:
-        def run_heat(state, gvec, heard, req_ring, want_coord, my_id,
+        def run_heat(state, stack, upd, heard, req_ring, want_coord, my_id,
                      heat_acc):
             state = _constrain(mesh, state, GROUP_AXIS)
             heat_acc = _constrain(mesh, heat_acc, GROUP_AXIS)
-            gathered0 = unpack_gathered(gvec, cfg)
+            stack = scatter_update(stack, upd, cfg)
             out0 = jnp.zeros((n_steps, M), jnp.int32)
 
             def body(i, carry):
-                st, outs, ht = carry
-                # substeps >= 1 refresh MY gathered row from the
-                # advancing state; peers' rows stay frozen for the whole
-                # dispatch — exactly N serial ticks during which no peer
-                # frame lands.  Substep 0 consumes gvec verbatim
-                # (bit-parity with N=1 even when the caller's self row
-                # is stale).
-                g = jax.tree.map(
-                    lambda gl, bl: jnp.where(
-                        i > 0, gl.at[my_id].set(bl), gl
-                    ),
-                    gathered0, make_blob(st),
-                )
+                st, g, outs, ht = carry
+                # every substep takes MY row from the advancing state;
+                # peers' rows stay frozen for the whole dispatch —
+                # exactly N serial ticks during which no peer frame lands
+                g = with_my_row(g, st, my_id)
                 req_i = lax.dynamic_index_in_dim(
                     req_ring, i, axis=0, keepdims=False
                 )
                 want_i = want_coord & (i == 0)
-                st, out = step(st, g, heard, req_i, want_i, my_id,
-                               cfg=cfg)
+                st, out = step(st, stack_blob(g), heard, req_i, want_i,
+                               my_id, cfg=cfg)
                 outs = lax.dynamic_update_index_in_dim(
                     outs, _pack_out(out), i, axis=0
                 )
                 ht = ht + out.n_committed + out.n_admitted
-                return st, outs, ht
+                return st, g, outs, ht
 
-            new_state, out_rings, heat_acc = lax.fori_loop(
-                0, n_steps, body, (state, out0, heat_acc)
+            new_state, stack, out_rings, heat_acc = lax.fori_loop(
+                0, n_steps, body, (state, stack, out0, heat_acc)
             )
             blob_vec = pack_blob(make_blob(new_state))
             # one digest row per substep, each against the dispatch's
@@ -298,13 +298,14 @@ def _build_packed(cfg: EngineConfig, mesh: Optional[Mesh], n_steps: int,
                 lambda row: make_digest(unpack_out(row, cfg), new_state, cfg)
             )(out_rings)
             return (
-                _constrain(mesh, new_state, GROUP_AXIS), out_rings,
+                _constrain(mesh, new_state, GROUP_AXIS), stack, out_rings,
                 blob_vec, _constrain(mesh, heat_acc, GROUP_AXIS), digests,
             )
 
-    # the accumulator rides the dispatch like a state leaf (donated
-    # alongside it) and is pulled host-side only at the stats cadence
-    return jax.jit(run_heat, donate_argnums=(0, 6) if donate else ())
+    # the gathered stack and the accumulator ride the dispatch like
+    # state leaves (donated alongside them); the accumulator is pulled
+    # host-side only at the stats cadence, the stack never
+    return jax.jit(run_heat, donate_argnums=(0, 1, 7) if donate else ())
 
 
 @functools.lru_cache(maxsize=None)
@@ -354,7 +355,7 @@ def make_step(cfg: EngineConfig, mesh: Optional[Mesh] = None,
         (halves state HBM — the G=2M capacity lever); pass ``False``
         when input states must stay valid across calls.
     io : ``"stacked"`` ([R, ...] SPMD/bench face) or ``"packed_host"``
-        (one replica + packed [R, NB] gathered vectors — the deployed
+        (one replica + the device-resident gathered stack — the deployed
         runtime's face; see the module docstring for signatures).
 
     Instances are memoized: the same (cfg, mesh, N, donate, io)

@@ -40,11 +40,12 @@ from .net.codec import (
     patch_blob_vec,
 )
 from .obs.metrics import TICK_BOUNDS, collect_process_gauges
+from .net.gather import GatherNews
 from .net.node_config import NodeConfig
 from .net.transport import MessageTransport
 from .obs import gplog
 from .obs.spans import span
-from .ops.engine import EngineConfig, blob_vec_len
+from .ops.engine import EngineConfig
 from .paxos_config import PC
 from .utils.config import Config
 
@@ -114,21 +115,16 @@ class PaxosServer:
         self._batching = Config.get_bool(PC.BATCHING_ENABLED)
         self._batch_sleep_s = Config.get_float(PC.BATCH_SLEEP_MS) / 1000.0
         # packed [N] vectors, one per peer and MUTABLE: a full frame
-        # replaces a peer's, a delta frame patches rows into it, and
-        # _gather copies it, all three under _blob_lock — a reader never
-        # sees a row mixed from two ticks
+        # replaces a peer's, a delta frame patches rows into it (the base
+        # the next delta is checked against).  The stack the step reads
+        # is the manager's, on the device: what a frame brought — its
+        # rows, or "whole" — is queued beside the patch, and _gather
+        # drains the queue into the dispatch's one update, both under
+        # _blob_lock, so a row comes whole from one tick
         self._peer_blobs: Dict[int, np.ndarray] = {}
+        self._peer_news = GatherNews(cfg)
         # when each peer was last asked for a full frame (base mismatch)
         self._resync_asked: Dict[int, float] = {}
-        # the [R, N] stack a dispatch uploads, two of them taken in turn:
-        # a tick's step (and its upload) has completed before the tick
-        # after next gathers into the same one.  Kept, not made anew each
-        # tick: a fresh 53 MB at the deployed 65,536 rows is 13,000 page
-        # faults, 55 ms on the chip's host (PERF.md, PR 26)
-        self._gather_bufs = [
-            np.empty((cfg.n_replicas, blob_vec_len(cfg)), np.int32)
-            for _ in range(2)
-        ]
         # per peer, under _blob_lock: the sender's tick in the blob held,
         # whether a dispatch has folded it yet, and the sender's tick in
         # the blob the last dispatch folded (the blob accounting:
@@ -142,8 +138,6 @@ class PaxosServer:
                     "ticks_inflight_noprog", "ticks_without_fresh_blob"):
             self.manager.metrics.count(key, 0)  # present from the start
         self._blob_lock = threading.Lock()
-        self._my_blob_vec: Optional[np.ndarray] = None
-        self._my_blob_state = None
         self._tick = 0
         self._last_ping = 0.0
         self._stop = threading.Event()
@@ -331,15 +325,20 @@ class PaxosServer:
             else:
                 sender, tick, base_tick, rows, blocks = decode_blob_delta(
                     payload, self.cfg)
+            if not (0 <= sender < self.cfg.n_replicas) \
+                    or sender == self.my_id:
+                return  # no row of the stack is this sender's
             with self._blob_lock:
                 if rows is None:
                     self._peer_blobs[sender] = vec
+                    self._peer_news.whole(sender)
                     accepted = True
                 else:
                     accepted = self._peer_blob_tick.get(sender) == base_tick
                     if accepted:
                         patch_blob_vec(self._peer_blobs[sender], rows,
                                        blocks, self.cfg)
+                        self._peer_news.rows(sender, rows, blocks)
                 if accepted:
                     replaced = self._peer_blob_unread.get(sender, False)
                     self._peer_blob_tick[sender] = tick
@@ -927,35 +926,19 @@ class PaxosServer:
             )
 
     def _gather(self):
-        """This dispatch's inputs: the [R, N] stack of packed blobs (my
-        own cached row, each peer's newest), who was heard, and the
-        failure detector's election mask."""
+        """This dispatch's inputs: what the frames since the last one
+        brought of the peers (the update the step scatters into the
+        manager's stack; my own row the step takes from its state), who
+        was heard, and the failure detector's election mask."""
         R = self.cfg.n_replicas
-        # packed exchange: peer frames already ARE the [N] vectors, my
-        # previous tick's publish vector is cached, and the whole [R, N]
-        # gather uploads as ONE device put inside the packed step (the
-        # per-leaf dispatch path cost ~3x the engine step at small G)
-        if self._my_blob_state is not self.manager.state:
-            # state changed outside the tick (create/kill/resume/recover):
-            # the cached publish vector is stale — my own gathered row
-            # must reflect the CURRENT state (tags/membership included).
-            # The pair is captured atomically under the manager lock, so
-            # a concurrent lifecycle op can never mispair them.
-            self._my_blob_vec, self._my_blob_state = (
-                self.manager.publish_snapshot()
-            )
-        my_vec = self._my_blob_vec
         mx = self.manager.metrics
-        self._gather_bufs.reverse()
-        gathered = self._gather_bufs[0]
         heard = np.zeros(R, bool)
         with self._blob_lock:
-            # copied under the lock: a delta frame patches these vectors
-            # in place, and a row must come whole from one tick
-            for r, vec in self._peer_blobs.items():
-                if r != self.my_id and 0 <= r < R:
-                    gathered[r] = vec
-                    heard[r] = True
+            # drained under the lock: a frame that lands while a tick
+            # gathers waits for the next, rows and accounting alike
+            update = self._peer_news.drain(self._peer_blobs)
+            for r in self._peer_blobs:
+                heard[r] = True
             self._blob_dirty = False
             # a blob is fresh when its sender's tick is past the one the
             # last dispatch folded from that sender
@@ -970,20 +953,20 @@ class PaxosServer:
             mx.observe("blob_age_ticks", age, bounds=TICK_BOUNDS)
         if not ages:
             mx.count("ticks_without_fresh_blob")
-        for r in np.flatnonzero(~heard):  # my own row, and the unheard
-            gathered[r] = my_vec
+        # an unheard peer's row of the stack holds what it held: the
+        # step masks it by ``heard`` (tests/test_gather_device.py)
         heard[self.my_id] = True
         want = self.fd.want_coord(
             self.manager._np("bal"),
             self.manager._np("member_mask"),
             R,
         )
-        return gathered, heard, want
+        return update, heard, want
 
     def _tick_once_inner(self) -> None:
         m = self.manager
         with m._span("tick.gather"):
-            gathered, heard, want = self._gather()
+            update, heard, want = self._gather()
         # double-buffered dispatch: fire step N and, while the device
         # computes it, do tick N-1's host-side codec/publish work (blob
         # frame encode, payload delta, forwards, response flush).
@@ -991,15 +974,22 @@ class PaxosServer:
         # lock is free for the whole overlap window.  NOTHING in the
         # overlap window may call a manager op that waits on step
         # completion (same thread completes the step).
-        pend = m.step_dispatch(gathered, heard, want)
+        try:
+            pend = m.step_dispatch(update, heard, want)
+        except BaseException:
+            # the drained update reached no step: the stack may lack its
+            # rows, so every peer heard so far goes up whole next tick
+            with self._blob_lock:
+                self._peer_news.all_whole(self._peer_blobs)
+            raise
         t_overlap = time.perf_counter()
         self._publish_pending()
         self._flush_responses()
         overlap_s = time.perf_counter() - t_overlap
-        blob_vec, blob_state, delta = m.step_complete(pend)
+        blob_vec, _state, delta = m.step_complete(pend)
         m.metrics.observe("pipeline_overlap_s", overlap_s)
         with m._span("tick.finish"):
-            self._finish_tick(blob_vec, blob_state, delta)
+            self._finish_tick(blob_vec, delta)
             self._drain_self_msgs()
         if not m.has_backlog():
             # the loop is about to go idle: publish this tick now —
@@ -1011,12 +1001,10 @@ class PaxosServer:
             self._layer_tick()
         self._flush_responses()  # callbacks fired by this tick's execution
 
-    def _finish_tick(self, blob_vec, blob_state, delta) -> None:
+    def _finish_tick(self, blob_vec, delta) -> None:
         """Post-step bookkeeping: stage this tick's
         outbound frames (blob / payload delta / forwards) for
         :meth:`_publish_pending`."""
-        self._my_blob_vec = blob_vec
-        self._my_blob_state = blob_state
         self._tick += 1
         m = self.manager
         progressed = m.last_progress_tick == m._tick_no

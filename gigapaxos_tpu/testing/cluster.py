@@ -1,7 +1,8 @@
 """Loopback manager cluster: N full PaxosManagers (engine + logger + app +
-callbacks) in one process, exchanging packed blob vectors (the `D` frame
-bodies a node serves) and host-channel payloads with controllable
-delivery — the manager-level analog of :mod:`.sim` and of the
+callbacks) in one process, exchanging packed blob vectors (whole the
+first time, then the rows that changed, as a node's `D` and `d` frames
+carry them) and host-channel payloads with controllable delivery —
+the manager-level analog of :mod:`.sim` and of the
 reference's N-nodes-in-one-JVM integration mode (``TESTPaxosNode.java:44``,
 ``PaxosManager.java:108-111``).  Each replica steps by one of the manager's
 two ways to run a tick, so a stepped test runs the program a node runs."""
@@ -13,7 +14,8 @@ from typing import Callable, Dict, List, Optional
 import numpy as np
 
 from ..manager import PaxosManager
-from ..ops.engine import EngineConfig, blob_vec_len
+from ..net.gather import GatherNews
+from ..ops.engine import EngineConfig
 
 DELIVER, DROP = 0, 1
 
@@ -48,12 +50,15 @@ class ManagerCluster:
             )
             for rid in range(R)
         ]
-        # what each replica last published, and the [R, N] stack a
-        # dispatch uploads: one, kept (a fresh one is 57 MB at 131,072
-        # rows) — either way to tick waits for the device before the
-        # next replica's rows are written into it
+        # what each replica last published; and per receiver what a
+        # node keeps of its peers (server.py): the vector each peer's
+        # deliveries add up to (None before the first) and the news of
+        # them that no dispatch has taken yet
         self.republish()
-        self._gathered = np.empty((R, blob_vec_len(cfg)), np.int32)
+        self._held: List[List[Optional[np.ndarray]]] = [
+            [None] * R for _ in range(R)
+        ]
+        self._news = [GatherNews(cfg) for _ in range(R)]
         # host-channel inboxes: (kind, body) per receiver
         self.inboxes: List[List] = [[] for _ in range(R)]
         # default election drive (the deployed server's FailureDetector)
@@ -122,7 +127,11 @@ class ManagerCluster:
             m.hydrate_all()
         self.vecs[rid] = m.blob_vec()
         self.inboxes[rid] = []
+        # a fresh manager's stack holds nothing: every peer whole again
+        self._held[rid] = [None] * self.cfg.n_replicas
+        self._news[rid] = GatherNews(self.cfg)
         return m
+
 
     # ---- client entry ---------------------------------------------------
     def submit(self, name: str, value: str, entry: int = 0,
@@ -146,26 +155,29 @@ class ManagerCluster:
                 self.managers[i].on_host_message(kind, body)
 
         # every replica of a round steps against the vectors the
-        # PREVIOUS round published; an unheard peer's row is my own
+        # PREVIOUS round published; an unheard peer's row of its stack
+        # keeps what it held, masked by ``heard``
         new_vecs = list(self.vecs)
         deltas = []
-        gathered = self._gathered
         for i, m in enumerate(self.managers):
             heard = np.zeros(R, bool)
             for j in range(R):
                 heard[j] = i == j or delivery[i, j] == DELIVER
-                gathered[j] = self.vecs[j if heard[j] else i]
+                if heard[j] and i != j:  # whole, then what changed
+                    self._held[i][j] = self._news[i].hear(
+                        j, self.vecs[j], self._held[i][j])
+            update = self._news[i].drain(self._held[i])
             want = want_coord.get(i)
             if want is None:
                 want = self._fds[i].want_coord(
                     m._np("bal"), m._np("member_mask"), R
                 )
             if self.pipelined:
-                pend = m.step_dispatch(gathered, heard, want)
+                pend = m.step_dispatch(update, heard, want)
                 new_vecs[i], _state, delta = m.step_complete(pend)
             else:
                 new_vecs[i], _state, delta = m.tick_host(
-                    gathered, heard, want)
+                    update, heard, want)
             deltas.append(delta)
         self.vecs = new_vecs
 

@@ -96,6 +96,7 @@ HOT_NP_ALLOW = {
     ),
     ("server.py", "_should_tick"): frozenset({"bal", "member_mask"}),
     ("server.py", "_tick_once_inner"): frozenset(),
+    # no blob vector is pulled or copied here: the stack is the device's
     ("server.py", "_gather"): frozenset({"bal", "member_mask"}),
     ("server.py", "_finish_tick"): frozenset(),
     # stats-cadence hook: the ONE sanctioned group-heat drain (runs at

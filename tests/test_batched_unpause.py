@@ -22,8 +22,7 @@ NAMES = [f"par{i}" for i in range(8)]
 
 def ticks(m, n=3):
     for _ in range(n):
-        vec, _st = m.publish_snapshot()
-        m.tick_host(np.stack([vec]), np.array([True]))
+        m.tick_host(None, np.array([True]))
 
 
 def _mk(tmp_path, tag, G=64, W=8):

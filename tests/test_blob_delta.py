@@ -206,8 +206,9 @@ def test_delta_without_its_base_is_dropped_counted_and_healed():
 
 # ---- (3) a reader racing the patch ------------------------------------
 def test_reader_racing_the_patch_never_sees_a_row_of_two_ticks():
-    """Every word of a row carries the tick that wrote it; the gather's
-    copy of the peer's vector must show each row uniform."""
+    """Every word of a row carries the tick that wrote it; the stack
+    the step would read of the gather's update must show each of the
+    peer's rows uniform."""
     srv, _ = receiver()
     enc = functools.partial(encode_blob_frame, 0, CFG)
     stop = threading.Event()
@@ -235,8 +236,9 @@ def test_reader_racing_the_patch_never_sees_a_row_of_two_ticks():
         assert wait_until(lambda: n_ticks[0] > 2)
         seen = set()
         for _ in range(300):
-            gathered, heard, _want = srv._gather()
+            update, heard, _want = srv._gather()
             assert heard[0]
+            gathered = srv.manager.gathered_host(update)
             by_row = np.concatenate([
                 leaf.reshape(CFG.n_groups, -1)
                 for leaf in split_blob_vec(gathered[0], CFG)], axis=1)
@@ -337,8 +339,8 @@ def test_three_nodes_commit_over_delta_frames():
         assert wait_until(lambda: all(
             s.manager.app.totals.get("dl0") == 20 for s in servers))
         for s in servers:
-            c = s.manager.metrics.snapshot()["counters"]
-            h = s.manager.metrics.snapshot()["hists"]
+            snap = s.manager.metrics.snapshot()  # one: the nodes run on
+            c, h = snap["counters"], snap["hists"]
             # one full frame per connection opened (one to each peer)
             assert c["blob_frames_full"] == len(s.transport._writers) == 2
             assert c["blob_frames_delta"] > 20

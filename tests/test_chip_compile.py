@@ -19,7 +19,14 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from gigapaxos_tpu.ops.engine import EngineConfig, blob_vec_len, init_state
+from gigapaxos_tpu.ops.engine import (
+    EngineConfig,
+    blob_vec_len,
+    init_stack,
+    init_state,
+    set_peer_rows,
+    update_vec_len,
+)
 from gigapaxos_tpu.ops.lifecycle import create_groups, restore_paused_rows
 from gigapaxos_tpu.parallel.mesh import GROUP_AXIS
 from gigapaxos_tpu.parallel.spmd import make_step
@@ -55,6 +62,15 @@ def _state_shapes(cfg, sharding):
     )
 
 
+def _stack_shapes(cfg, sharding):
+    """The gathered stack as shapes on ``sharding``, and its bytes."""
+    stack = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        jax.eval_shape(lambda: init_stack(cfg)),
+    )
+    return stack, 4 * cfg.n_replicas * blob_vec_len(cfg)
+
+
 def _dispatch_bytes(compiled) -> int:
     ma = compiled.memory_analysis()
     return (ma.argument_size_in_bytes + ma.output_size_in_bytes
@@ -66,24 +82,49 @@ def _dispatch_bytes(compiled) -> int:
     (1_048_576, 32, 16),   # the headline shape
 ])
 def test_packed_host_step_compiles_and_fits_one_chip(one_chip, G, W, K):
-    """The step the manager dispatches (donated, heat-carrying): one
-    replica's dispatch must stay under one chip's 16 GiB."""
+    """The step the manager dispatches (state, gathered stack and heat
+    donated; the tick's news as one fixed-shape update): one replica's
+    dispatch must stay under one chip's 16 GiB, and the stack comes back
+    in the buffers it went in."""
     R = 3
     cfg = EngineConfig(G, W, K, R)
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(
         shape, dtype, sharding=one_chip
     )
     state = _state_shapes(cfg, one_chip)
+    stack, stack_bytes = _stack_shapes(cfg, one_chip)
     step = make_step(cfg, None, 1, donate=True, io="packed_host")
-    compiled = step.lower(
-        state, sds((R, blob_vec_len(cfg)), jnp.int32), sds((R,), jnp.bool_),
+    args = (
+        sds((update_vec_len(cfg),), jnp.int32), sds((R,), jnp.bool_),
         sds((1, G, K), jnp.int32), sds((G,), jnp.bool_),
         sds((), jnp.int32), sds((G,), jnp.int32),
-    ).compile()
+    )
+    compiled = step.lower(state, stack, *args).compile()
     need = _dispatch_bytes(compiled)
     assert 0 < need < HBM_BYTES, need
-    # the state is donated: its buffers are aliased into the new state
-    assert compiled.memory_analysis().alias_size_in_bytes > 0
+    # state, stack and heat are donated: their buffers are aliased into
+    # the results — the stack's share is its whole size
+    aliased = compiled.memory_analysis().alias_size_in_bytes
+    state_bytes = sum(
+        4 * int(np.prod(x.shape)) for x in jax.tree.leaves(state))
+    assert aliased >= state_bytes + stack_bytes, (aliased, stack_bytes)
+
+
+@pytest.mark.parametrize("G,W,K", [(65_536, 16, 8), (1_048_576, 32, 16)])
+def test_whole_row_program_compiles_and_aliases_the_stack(one_chip, G, W, K):
+    """``set_peer_rows``: a peer's whole vector over its row of the
+    donated stack, in place."""
+    cfg = EngineConfig(G, W, K, 3)
+    stack, stack_bytes = _stack_shapes(cfg, one_chip)
+    compiled = set_peer_rows.lower(
+        stack,
+        jax.ShapeDtypeStruct((blob_vec_len(cfg),), jnp.int32,
+                             sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip), cfg=cfg,
+    ).compile()
+    assert _dispatch_bytes(compiled) < HBM_BYTES
+    # at least: the [R, G] leaves are laid out with R padded to a tile
+    assert compiled.memory_analysis().alias_size_in_bytes >= stack_bytes
 
 
 def test_lifecycle_scatters_compile_at_deployed_rows(one_chip):

@@ -38,7 +38,9 @@ def test_engine_parity_phase_cpu_vs_cpu(smoke):
     )
     json.dumps(line)  # every phase prints its line as JSON
     assert line["bit_exact"] and line["decided"] > 0
-    assert line["leaves_compared"] == 3 * (19 + 3)  # state, blob, heat, digest
+    # state, blob, heat, digest, and the eight leaves of the stack
+    assert line["leaves_compared"] == 3 * (19 + 3 + 8)
+    assert line["gather_updates"]["whole"] >= 3
     assert line["compile"]["retraces"] == 0
 
 

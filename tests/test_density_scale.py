@@ -19,8 +19,7 @@ WINDOW = 256  # awake working set per churn round
 
 def _ticks(m, n=3):
     for _ in range(n):
-        vec, _st = m.publish_snapshot()
-        m.tick_host(np.stack([vec]), np.array([True]))
+        m.tick_host(None, np.array([True]))
 
 
 @pytest.mark.slow

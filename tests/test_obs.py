@@ -511,7 +511,11 @@ def ticked_cluster(tmp_path_factory):
 
     old = os.environ.get("GP_TRACE_SAMPLE")
     os.environ["GP_TRACE_SAMPLE"] = "1"
-    cfg = EngineConfig(n_groups=8, window=8, req_lanes=4, n_replicas=3)
+    # 2,048 rows, not 8: a tick's spans then weigh milliseconds against
+    # the glue between them (since PR 30 a tick of 8 rows copies and
+    # uploads nothing, and under six test workers the glue's waits for
+    # the interpreter lock came to a fifth of it)
+    cfg = EngineConfig(n_groups=2048, window=8, req_lanes=4, n_replicas=3)
     ports = free_ports(3)
     nc = NodeConfig({i: ("127.0.0.1", p) for i, p in enumerate(ports)})
     logs = tmp_path_factory.mktemp("ticked")

@@ -104,12 +104,12 @@ def test_stepped_cluster_and_served_node_share_one_step_instance():
     try:
         for x in c.managers + [m]:
             assert x._dispatch_step is sentinel
-            assert set(x.engine_compile_stats()) == {"dispatch", "lifecycle"}
+            assert set(x.engine_compile_stats()) == {
+                "dispatch", "lifecycle", "gather"}
         c.create("one")
         c.run(20)
-        vec = m.blob_vec()
         m.step_complete(m.step_dispatch(
-            np.stack([vec, vec, vec]), np.array([True, False, False])))
+            None, np.array([True, False, False])))
         assert sentinel.n_compiles == 1, sentinel.stats()
         assert sentinel.n_retraces == 0, sentinel.stats()
     finally:
@@ -125,9 +125,8 @@ def test_lifecycle_waits_for_inflight_step():
     m = PaxosManager(0, HashChainApp(), CFG)
     try:
         m.create_paxos_instance("x", [0])
-        vec = m.blob_vec()
         heard = np.array([True, False, False])
-        pend = m.step_dispatch(np.stack([vec, vec, vec]), heard)
+        pend = m.step_dispatch(None, heard)
         done = threading.Event()
 
         def create_side():

@@ -25,9 +25,11 @@ from gigapaxos_tpu.ops.engine import (
     make_blob,
     pack_blob,
     split_out_vec,
+    stack_blob,
     step,
     unpack_gathered,
 )
+from gigapaxos_tpu.net.gather import empty_update_vec
 from gigapaxos_tpu.parallel.mesh import make_group_mesh, make_mesh
 from gigapaxos_tpu.parallel.spmd import build_replica_states, make_step
 from gigapaxos_tpu.utils.config import Config
@@ -164,10 +166,10 @@ def test_stacked_multistep_equals_sequential():
 
 def test_packed_flavor_frozen_peer_parity():
     """packed_host at N=4 == 4 serial legacy host ticks during which no
-    peer frame lands: substep 0 consumes the gathered matrix verbatim,
-    substeps >= 1 refresh only MY row from the advancing state.  Checks
+    peer frame lands: every substep takes MY row of the stack from the
+    advancing state (whatever stood in it), the peers' rows stay.  Checks
     the final state, every per-substep out-ring row (field-by-field via
-    split_out_vec), and the returned blob_vec."""
+    split_out_vec), the returned blob_vec, and the stack handed back."""
     cfg = EngineConfig(n_groups=8, window=8, req_lanes=4, n_replicas=3)
     N, my_id = 4, 0
     states = build_replica_states(cfg)
@@ -191,7 +193,7 @@ def test_packed_flavor_frozen_peer_parity():
     g0 = unpack_gathered(gvec, cfg)
     golden_rows = []
     for i in range(N):
-        g = g0 if i == 0 else jax.tree.map(
+        g = jax.tree.map(
             lambda gl, bl: gl.at[my_id].set(bl), g0, make_blob(st)
         )
         st, out = step(st, g, heard, jnp.asarray(reqs[i]), want,
@@ -199,12 +201,17 @@ def test_packed_flavor_frozen_peer_parity():
         golden_rows.append(out)
     golden_blob = np.asarray(pack_blob(make_blob(st)))
 
+    # the stack: the peers' rows as gathered, garbage where mine goes
+    # (held rows minor, as ops/engine.py:init_stack lays it out)
+    stack = stack_blob(jax.tree.map(lambda leaf: leaf.at[my_id].set(12345), g0))
     fn = make_step(cfg, None, N, donate=False, io="packed_host")
-    st_u, out_rings, blob_vec, _heat, _digests = fn(
-        per[my_id], gvec, heard, jnp.asarray(np.stack(reqs)), want,
+    st_u, stack_u, out_rings, blob_vec, _heat, _digests = fn(
+        per[my_id], stack, jnp.asarray(empty_update_vec(cfg)), heard,
+        jnp.asarray(np.stack(reqs)), want,
         jnp.int32(my_id), jnp.zeros((8,), jnp.int32),
     )
     _assert_trees_equal(st, st_u, "state")
+    _assert_trees_equal(g, stack_blob(stack_u), "stack")  # the last substep's
     rows = np.asarray(out_rings)
     assert rows.shape[0] == N
     for i, g_out in enumerate(golden_rows):
