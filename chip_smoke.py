@@ -181,6 +181,49 @@ class _ReplicaArm:
         self.blobs = blobs
         return outs
 
+    def pause(self, rows):
+        """What ``manager.pause_group`` does to the device, on every
+        replica: the rows' records read off the state (frontier, ballot,
+        hash, the lanes at or past the frontier), the rows freed."""
+        from gigapaxos_tpu.ops.ballot import NULL
+        from gigapaxos_tpu.ops.lifecycle import kill_groups
+
+        self.records = []
+        for r, state in enumerate(self.states):
+            rec = {k: np.asarray(getattr(state, k))[rows] for k in (
+                "exec_slot", "bal", "app_hash", "n_execd", "acc_bal",
+                "acc_vid", "acc_slot", "dec_vid", "dec_slot")}
+            for plane in ("acc", "dec"):
+                gone = rec[plane + "_slot"] < rec["exec_slot"][:, None]
+                for leaf in ("_bal", "_vid", "_slot"):
+                    if plane + leaf in rec:
+                        rec[plane + leaf] = np.where(
+                            gone, NULL, rec[plane + leaf]).astype(np.int32)
+            self.records.append(rec)
+            self.states[r] = kill_groups(state, rows)
+
+    def resume(self, rows):
+        """What ``manager.resume_group_batch`` does to the device: ONE
+        ``create_groups`` and ONE ``restore_paused_rows`` for all of
+        ``rows``, on every replica."""
+        from gigapaxos_tpu.ops.ballot import encode_ballot
+        from gigapaxos_tpu.ops.lifecycle import (
+            create_groups, restore_paused_rows)
+
+        R = self.cfg.n_replicas
+        masks = np.full(len(rows), (1 << R) - 1, np.int32)
+        coord0 = (rows % R).astype(np.int32)
+        zeros = np.zeros(len(rows), np.int32)
+        for r, rec in enumerate(self.records):
+            created = create_groups(self.states[r], rows, masks, coord0,
+                                    my_id=r, version=zeros, tag=zeros)
+            bal = np.maximum(np.asarray(encode_ballot(zeros, coord0)),
+                             rec["bal"]).astype(np.int32)
+            self.states[r] = restore_paused_rows(
+                created, rows, rec["exec_slot"], bal, rec["app_hash"],
+                rec["n_execd"], rec["acc_bal"], rec["acc_vid"],
+                rec["acc_slot"], rec["dec_vid"], rec["dec_slot"])
+
     def leaves(self):
         """Everything the phase compares at the end, as (name, array)."""
         for r, (state, blob, heat) in enumerate(
@@ -227,7 +270,16 @@ def phase_engine_parity(n_groups: int, window: int, req_lanes: int,
     ref = _ReplicaArm(cfg, reference_device, ref_fn)
     decided = admitted = 0
     first_call_s = steady_s = 0.0
+    # residency in the trace: eight rows are paused a third of the way
+    # in (freed on every replica, their records kept) and come back two
+    # thirds in through one batched restore; both arms do the same, each
+    # from its own device's state, and every step between is compared
+    slept = (np.arange(8, dtype=np.int32) * 37 + 5) % n_groups
+    pause_at, resume_at = n_steps // 3, (2 * n_steps) // 3
     for t, (req, want, heard) in enumerate(make_trace(cfg, n_steps, seed)):
+        if t in (pause_at, resume_at):
+            for side in (arm, ref):
+                (side.pause if t == pause_at else side.resume)(slept)
         t0 = time.perf_counter()
         outs = arm.step(req, want, heard)
         outs_np = [np.asarray(o) for o in outs]  # waits for the device
@@ -262,6 +314,10 @@ def phase_engine_parity(n_groups: int, window: int, req_lanes: int,
         "decided": decided,
         "admitted": admitted,
         "gather_updates": {"scattered": arm.scattered, "whole": arm.whole},
+        "residency": {"rows": int(slept.size), "paused_at_step": pause_at,
+                      "resumed_at_step": resume_at,
+                      "executed_while_awake": int(
+                          arm.records[0]["n_execd"].sum())},
         "first_call_s": first_call_s,  # compile + one dispatch of R steps
         "steady_s_per_round": steady_s / max(1, n_steps - 1),
         "compile": compile_summary(step_fn),

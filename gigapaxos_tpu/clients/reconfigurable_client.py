@@ -57,6 +57,9 @@ class ReconfigurableAppClient(AsyncFrameClient):
         self._callbacks: Dict[int, Tuple[float, Callable, Optional[int], int]] = {}
         # rc-op waiters: (ack_kind, name) -> (event, box)
         self._rc_waiters: Dict[Tuple[str, str], Tuple[threading.Event, Dict]] = {}
+        # admin waiters: tag echoed as the reply's name -> (event, box)
+        self._admin_waiters: Dict[str, Tuple[threading.Event, Dict]] = {}
+        self._admin_seq = 0
 
     @classmethod
     def from_properties(cls) -> "ReconfigurableAppClient":
@@ -380,6 +383,24 @@ class ReconfigurableAppClient(AsyncFrameClient):
             self._actives_cache[name] = (now + self.cache_ttl, acts)
         return acts
 
+    def admin_sync(self, active: int, body: Dict,
+                   timeout: float = 5.0) -> Optional[Dict]:
+        """One admin op (``{"op": "stats"}``, ...) to one active, and its
+        answer or None.  An op that names no ``name`` is told apart from
+        others in flight by a tag of this client's, which the node's
+        answer echoes in that field."""
+        ev, box = threading.Event(), {}
+        with self._lock:
+            self._admin_seq += 1
+            tag = str(body.get("name") or f"#{self.my_tag}:{self._admin_seq}")
+            self._admin_waiters[tag] = (ev, box)
+        self.send_frame(tuple(self.actives[active]), encode_json(
+            "admin", self.my_tag, {**body, "name": tag}))
+        ev.wait(timeout)
+        with self._lock:
+            self._admin_waiters.pop(tag, None)
+        return box.get("resp")
+
     def invalidate(self, name: str) -> None:
         with self._lock:
             self._actives_cache.pop(name, None)
@@ -563,6 +584,12 @@ class ReconfigurableAppClient(AsyncFrameClient):
         elif k == "client_response_batch":
             for sub in body.get("resps", ()):
                 self._on_response(sub, sender)
+        elif k == "admin_response":
+            with self._lock:
+                ent = self._admin_waiters.get(str(body.get("name")))
+            if ent:
+                ent[1]["resp"] = body
+                ent[0].set()
         elif k == "rc_client_reply":
             kind = body.get("kind")
             b = body.get("body") or {}

@@ -76,7 +76,7 @@ from .obs import gplog
 from .obs.flight import FlightRecorder
 from .obs.metrics import ROW_BOUNDS, TICK_BOUNDS, MetricsRegistry
 from .obs.reqtrace import RequestTracer
-from .obs.spans import span
+from .obs.spans import observe_interval, span
 from .ops.lifecycle import create_groups, kill_groups, restore_paused_rows
 from .storage.logger import PaxosLogger
 
@@ -117,6 +117,15 @@ def _accepted_lanes(digests: List[StepDigest]):
         cols[:, at] = (d.acc_slot, d.acc_bal, d.acc_vid)
     ks, lanes = np.nonzero(acc_any)
     return (rows[ks].astype(np.int32),) + tuple(cols[:, ks, lanes])
+
+
+def _padded_chunks(n: int, size: int):
+    """Index arrays over ``range(n)``, ``size`` long each, the last one
+    filled by repeating index n - 1: a batched lifecycle program is
+    compiled for ONE row count (in warm-up), and writing a row's words
+    to it twice changes nothing."""
+    for i in range(0, n, size):
+        yield np.minimum(np.arange(i, i + size), n - 1)
 
 
 def _mix32(h: int, vid: int) -> int:
@@ -497,6 +506,18 @@ class PaxosManager:
         # find a hibernated name's epochs without an O(paused) key scan
         # (the paused table holds the COLD tail — millions of names)
         self._paused_by_name: Dict[str, set] = {}
+        # wake on write (upstream's message-triggered unpause,
+        # PaxosManager.java:2350): a write or a forward for a name that
+        # sleeps HERE (a pause record, no row) is held under its request
+        # id, in arrival order, and proposed when the resume round has
+        # brought the row back; the ActiveReplica layer asks the name's
+        # reconfigurator for that round (:meth:`drain_wake_requests`).
+        # name -> {"items": {request id: (entry replica, value)}, in
+        #          arrival order, "t0": first hold, "asked": last ask}
+        self._wake_held: Dict[str, Dict[str, Any]] = {}
+        for key in ("writes_held_for_wake", "names_woken",
+                    "names_woken_batched"):
+            self.metrics.count(key, 0)  # present from the start
         # name -> wall time of last resume/create activity relevant to
         # eviction hysteresis (a just-woken name must not be re-paused
         # by the next sweep even if its traffic burst already ended)
@@ -1027,6 +1048,20 @@ class PaxosManager:
             my_id=self.my_id, version=0, tag=0,
         )
         scratch = kill_groups(scratch, one)
+        # a name's wake, alone (N = 1) and in a burst (N = RESUME_CHUNK),
+        # with the arguments ``resume_group_batch`` and
+        # ``_install_chunk_locked`` build
+        C, W = self.RESUME_CHUNK, cfg.window
+        z = np.zeros(C, np.int32)
+        scratch = create_groups(scratch, z, z + 1, z, my_id=self.my_id,
+                                version=z, tag=z)
+        for n in (1, C):
+            nullw = np.full((n, W), NULL, np.int32)
+            scratch = restore_paused_rows(
+                scratch, z[:n], z[:n], z[:n], z[:n], z[:n],
+                nullw, nullw, nullw, nullw, nullw)
+        # a sweep's burst of pauses frees its rows PAUSE_CHUNK at a time
+        kill_groups(scratch, np.zeros(self.PAUSE_CHUNK, np.int32))
         _publish_vec_jit(scratch)
         req = np.full(
             (self.steps_per_dispatch, G, cfg.req_lanes), NULL, np.int32
@@ -1119,9 +1154,10 @@ class PaxosManager:
             # shapes an epoch change uses, so growth of these caches
             # after boot is a compile under the state lock, in traffic
             "lifecycle": {
-                "label": "create_groups+kill_groups",
+                "label": "create_groups+kill_groups+restore_paused_rows",
                 "compiles": create_groups._cache_size()
-                + kill_groups._cache_size(),
+                + kill_groups._cache_size()
+                + restore_paused_rows._cache_size(),
                 "retraces": 0,
             },
             # the whole-row program of the gathered stack: one compile a
@@ -1366,10 +1402,15 @@ class PaxosManager:
         row under their ids (their callbacks wait at their entry
         replicas, whichever those are)."""
         if items:
-            self.propose_batch([
+            results = self.propose_batch([
                 (name, value, rid, None, entry)
                 for rid, entry, value in items
             ])
+            for (rid, entry, _v), (_r, outcome, resp) in zip(items, results):
+                # executed meanwhile under the same id (a retransmission
+                # that entered elsewhere): its writer here is answered
+                if outcome == "cached" and entry == self.my_id:
+                    self._answer(rid, resp)
 
     def _n_requests(self, vid: int) -> int:
         """Client requests one queued vid stands for."""
@@ -1554,6 +1595,7 @@ class PaxosManager:
                 # those request ids re-propose into the next incarnation
                 for vid in prec.get("held_vids") or []:
                     self._release_vid(vid)
+                self._wake_held.pop(name, None)
                 if self.logger:
                     self.logger.log_pause({
                         "name": name, "epoch": int(epoch), "dropped": True,
@@ -1607,6 +1649,81 @@ class PaxosManager:
                     del self._paused_by_name[key[0]]
         return rec
 
+    # ---- wake on write ---------------------------------------------------
+    WAKE_RETRY_S = 5.0  # a wake still unanswered is asked for again
+    RESUME_CHUNK = 8    # rows a batched restore's device programs take
+    PAUSE_CHUNK = 64    # rows a batched pause's ``kill_groups`` takes
+
+    def sleeps_here(self, name: str) -> bool:
+        """True while ``name`` has a pause record and no row on this node."""
+        return name in self._paused_by_name and name not in self.names
+
+    def _hold_for_wake_locked(
+        self, name: str, value: str, request_id: Optional[int],
+        callback: Optional[Callable], entry: int,
+    ) -> Optional[Tuple[int, str, Optional[str]]]:
+        """Lock held; ``name`` has no row here.  If it sleeps here, the
+        write is held under its request id and ``(id, "held", None)``
+        comes back — or ``"inflight"`` for an id held already (one
+        execution: the callback is registered again), or ``"cached"``
+        with the answer of an id executed before the name fell asleep.
+        None: no pause record either, the name is unknown here."""
+        if not self.sleeps_here(name):
+            return None
+        if request_id is None:
+            if self._next_counter > VID_COUNTER_MASK:
+                raise RuntimeError("vid counter space exhausted")
+            request_id = (self._rid_nonce << 24) | self._next_counter
+            self._next_counter += 1
+        cached = self.response_cache.get(request_id)
+        if cached is not None:
+            return request_id, "cached", cached[1]
+        if callback is not None:
+            self.outstanding.put(request_id, callback, self._tick_no)
+        ent = self._wake_held.get(name)
+        if ent is None:
+            ent = self._wake_held[name] = {
+                "items": {}, "t0": time.monotonic(), "asked": None,
+            }
+        if request_id in ent["items"]:
+            return request_id, "inflight", None
+        ent["items"][request_id] = (entry, value)
+        self.metrics.count("writes_held_for_wake")
+        self.demand_counts[name] = self.demand_counts.get(name, 0) + 1
+        self.demand_backlog += 1
+        return request_id, "held", None
+
+    def drain_wake_requests(self) -> List[Tuple[str, int]]:
+        """(name, epoch) of every sleeping name with writes held whose
+        resume has not been asked for yet — once a sleep, and again
+        every ``WAKE_RETRY_S`` while it is unanswered.  The ActiveReplica
+        layer sends these to the names' reconfigurators."""
+        if not self._wake_held:
+            return []
+        out = []
+        now = time.monotonic()
+        with self._state_lock:
+            for name, ent in self._wake_held.items():
+                eps = self._paused_by_name.get(name)
+                if not eps or (ent["asked"] is not None
+                               and now - ent["asked"] < self.WAKE_RETRY_S):
+                    continue
+                ent["asked"] = now
+                out.append((name, max(eps)))
+        return out
+
+    def _wake_release_locked(self, name: str) -> None:
+        """``name``'s row is back: what was held for it is proposed, in
+        arrival order, under the ids it came with (interval histogram
+        ``phase_wake_hold_s``: first write held -> proposed)."""
+        ent = self._wake_held.pop(name, None)
+        if ent is not None:
+            observe_interval(self.metrics, "wake.hold",
+                             time.monotonic() - ent["t0"])
+            self._repropose_locked(name, [
+                (rid, entry, value)
+                for rid, (entry, value) in ent["items"].items()])
+
     def pause_group(self, name: str, epoch: int, force: bool = False) -> str:
         """Free (name, epoch)'s row, snapshotting its state to the journal
         and `self.paused`.  Returns "ok", "unknown" (not hosted here — an
@@ -1628,14 +1745,7 @@ class PaxosManager:
                 # snapshot would capture the pre-restore blank.  Busy is
                 # transient: background hydration clears it
                 return "busy"
-            exec_now = int(self._np("exec_slot")[row])
-            quiescent = (
-                not self.queues.get(row)
-                and not self.pending_exec.get(row)
-                and int(self.app_exec_slot[row]) == exec_now
-                and int(self._np("acc_slot")[row].max()) < exec_now
-            )
-            if not quiescent and not force:
+            if not force and not self._quiescent_locked(row):
                 return "busy"
             rec = self._extract_record(name, int(epoch), row)
             held = list(self.queues.get(row, []))
@@ -1660,6 +1770,94 @@ class PaxosManager:
                 # (forced ones are re-homes/hibernates, not evictions)
                 self.metrics.count("pause_evictions")
             return "ok"
+
+    def _quiescent_locked(self, row: int) -> bool:
+        """Nothing queued, decided-unexecuted or accepted past the
+        frontier on ``row``: a pause there loses no work in flight."""
+        exec_now = int(self._np("exec_slot")[row])
+        return (
+            not self.queues.get(row)
+            and not self.pending_exec.get(row)
+            and int(self.app_exec_slot[row]) == exec_now
+            and int(self._np("acc_slot")[row].max()) < exec_now
+        )
+
+    def pause_group_batch(
+        self, items: List[Tuple[str, int]]
+    ) -> Dict[Tuple[str, int], str]:
+        """The pause rounds of a sweep's burst, together: per (name,
+        epoch) what :meth:`pause_group` answers without ``force`` ("ok",
+        "unknown", "busy"), at one wait for the step in flight, one pull
+        of each leaf for all the rows (every single pause makes a new
+        state, and the leaf cache would then fetch each [G, W] plane —
+        21 MB at 65,536 rows — again for the next name), one pass over
+        the response cache for their dedup entries, and the rows freed
+        ``PAUSE_CHUNK`` at a time.  Each record is journaled before its
+        row is freed."""
+        out: Dict[Tuple[str, int], str] = {}
+        with self._state_lock:
+            self._await_step_lifecycle_locked()
+            jobs: List[Tuple[str, int, int]] = []
+            for name, epoch in items:
+                epoch = int(epoch)
+                row = self.names.get(name)
+                if row is None:
+                    out[(name, epoch)] = (
+                        "ok" if (name, epoch) in self.paused else "unknown")
+                elif int(self._np("version")[row]) != epoch:
+                    out[(name, epoch)] = "unknown"
+                elif int(self._np("stopped")[row]) \
+                        or row in self.hydrating_rows \
+                        or not self._quiescent_locked(row):
+                    out[(name, epoch)] = "busy"
+                elif (name, epoch) not in out:
+                    out[(name, epoch)] = "ok"
+                    jobs.append((name, epoch, row))
+            if not jobs:
+                return out
+            dedup_by_name = self._dedup_by_name_locked(
+                {name for name, _e, _r in jobs})
+            for name, epoch, row in jobs:
+                rec = self._extract_record(
+                    name, epoch, row, dedup=dedup_by_name.get(name, {}))
+                if self.logger:
+                    self.logger.log_pause(rec)
+                self._paused_put((name, epoch), rec)
+            rows = np.array([row for _n, _e, row in jobs], np.int32)
+            for pad in _padded_chunks(len(rows), self.PAUSE_CHUNK):
+                self.state = kill_groups(self.state, rows[pad])
+            if self.logger:
+                self.logger.log_kill(rows)
+            for name, _epoch, row in jobs:
+                self._forget_row_locked(name, row)
+            self.metrics.count("pause_evictions", len(jobs))
+        return out
+
+    def _dedup_by_name_locked(self, wanted: set) -> Dict[str, Dict]:
+        """The exactly-once entries of every name in ``wanted``, by name,
+        from ONE pass over the response cache (:meth:`dedup_for_name`
+        scans it whole for each name)."""
+        out: Dict[str, Dict] = {}
+        for rid, (t, resp, nm) in self.response_cache.items():
+            if nm in wanted:
+                out.setdefault(nm, {})[str(rid)] = [t, resp, nm]
+        return out
+
+    def _forget_row_locked(self, name: str, row: int) -> None:
+        """Host side of freeing ``name``'s row for a pause, once the
+        device op and the journal entry are made: the scheduling state
+        of what was queued there survives (a record may carry it)."""
+        self.names.pop(name, None)
+        self.row_name.pop(row, None)
+        self._stop_executed_rows.discard(row)
+        self.pending_rows.discard(row)
+        self.hydrating_rows.discard(row)
+        self._payload_blocked.pop(row, None)
+        self._stall_since[row] = -1
+        self._stall_slot[row] = -1
+        self._needs_state.discard(row)
+        self.queues.pop(row, None)
+        self.pending_exec.pop(row, None)
 
     def _extract_record(
         self, name: str, epoch: int, row: int,
@@ -1768,6 +1966,8 @@ class PaxosManager:
                     # epoch's final state — this join is BLANK and must
                     # adopt a donor's state even at equal frontiers
                     self._needs_state.add(int(row))
+                if ok:
+                    self._wake_release_locked(name)
                 return ok
             t0 = time.monotonic()
             ok = self._create_locked(
@@ -1791,11 +1991,18 @@ class PaxosManager:
         self, batch: List[Tuple[int, Dict]]
     ) -> None:
         """Scatter N pause records' consensus remnants into rows JUST
-        created by ``create_groups`` — ONE fused device update (one
-        ``.at[rows].set`` per touched leaf) regardless of N.  The old
-        per-name install round-tripped the WHOLE state through host
-        numpy per resumed name; a 4096-name wake burst paid that 4096
-        times."""
+        created by ``create_groups`` — one fused device update (one
+        ``.at[rows].set`` per touched leaf) per ``RESUME_CHUNK`` records.
+        The old per-name install round-tripped the WHOLE state through
+        host numpy per resumed name; a 4096-name wake burst paid that
+        4096 times."""
+        if len(batch) > 1:  # N = 1 or RESUME_CHUNK, nothing else
+            for pad in _padded_chunks(len(batch), self.RESUME_CHUNK):
+                self._install_chunk_locked([batch[i] for i in pad])
+        else:
+            self._install_chunk_locked(batch)
+
+    def _install_chunk_locked(self, batch: List[Tuple[int, Dict]]) -> None:
         n = len(batch)
         W = self.cfg.window
         rows = np.empty(n, np.int32)
@@ -1872,11 +2079,11 @@ class PaxosManager:
         # the consensus remnants need the pause record on replay too
         if self.logger:
             self.logger.log_pause(rec)
-        held = rec.get("held_vids") or []
+        held = [v for v in rec.get("held_vids") or [] if v in self.arena]
         if held:
-            self.queues[r] = [v for v in held if v in self.arena]
+            self.queues[r] = held + self.queues.get(r, [])
             scopes = rec.get("held_scopes") or {}
-            for v in self.queues[r]:
+            for v in held:
                 sc = scopes.get(str(v))
                 # pre-scope records default to the resumed instance's
                 # own scope (they were queued on its row)
@@ -1910,6 +2117,8 @@ class PaxosManager:
         # sweep for PAUSE_EVICTION_HYSTERESIS_S even if its wake burst
         # already ended (pause/resume flap protection)
         self._resumed_at[name] = now
+        self.metrics.count("names_woken")
+        self._wake_release_locked(name)
 
     def resume_group_batch(
         self,
@@ -2000,19 +2209,19 @@ class PaxosManager:
                     batch.append((row, rec))
                 if batch:
                     rows_np = np.array(rows_l, np.int32)
-                    self.state = create_groups(
-                        self.state, rows_np,
-                        np.array(masks, np.int32),
-                        np.array(coords, np.int32),
-                        my_id=self.my_id,
-                        version=np.array(vers, np.int32),
-                        tag=np.array(tags, np.int32),
-                    )
+                    masks_np = np.array(masks, np.int32)
+                    coords_np = np.array(coords, np.int32)
+                    vers_np = np.array(vers, np.int32)
+                    tags_np = np.array(tags, np.int32)
+                    for pad in _padded_chunks(len(batch), self.RESUME_CHUNK):
+                        self.state = create_groups(
+                            self.state, rows_np[pad], masks_np[pad],
+                            coords_np[pad], my_id=self.my_id,
+                            version=vers_np[pad], tag=tags_np[pad],
+                        )
                     if self.logger:
                         self.logger.log_create(
-                            rows_np, np.array(masks, np.int32),
-                            np.array(vers, np.int32),
-                            np.array(coords, np.int32),
+                            rows_np, masks_np, vers_np, coords_np,
                             names=names_l,
                             inits=[rec.get("app_state") for rec in recs],
                             pendings=pendings,
@@ -2027,6 +2236,8 @@ class PaxosManager:
                         )
                         out[name] = True
                     n_fast = len(batch)
+                    if n_fast > 1:
+                        self.metrics.count("names_woken_batched", n_fast)
         if n_fast:
             dt = time.monotonic() - t0
             # every name in the burst became available when the batch
@@ -2094,15 +2305,8 @@ class PaxosManager:
                 jobs.append((name, int(versions[row]), row))
             if not jobs:
                 return 0
-            # ONE grouped pass over the response cache for every job's
-            # dedup entries (the per-name scan is O(cache) each)
-            wanted = {name for name, _e, _r in jobs}
-            dedup_by_name: Dict[str, Dict] = {}
-            for rid, (t, resp, nm) in self.response_cache.items():
-                if nm in wanted:
-                    dedup_by_name.setdefault(nm, {})[str(rid)] = [
-                        t, resp, nm
-                    ]
+            dedup_by_name = self._dedup_by_name_locked(
+                {name for name, _e, _r in jobs})
             rows_l: List[int] = []
             keys: List[Tuple[str, int]] = []
             for name, epoch, row in jobs:
@@ -2128,16 +2332,7 @@ class PaxosManager:
             for name, _epoch, row in jobs:
                 # host side of _kill_locked(release_queue=False), minus
                 # the per-name device op the fused kill replaced
-                self.names.pop(name, None)
-                self.row_name.pop(row, None)
-                self.pending_rows.discard(row)
-                self.hydrating_rows.discard(row)
-                self._payload_blocked.pop(row, None)
-                self._stall_since[row] = -1
-                self._stall_slot[row] = -1
-                self._needs_state.discard(row)
-                self.queues.pop(row, None)
-                self.pending_exec.pop(row, None)
+                self._forget_row_locked(name, row)
             # page the records out of RAM as one sequential append run
             if hasattr(self.paused, "demote_batch"):
                 self.paused.demote_batch(keys)
@@ -2280,6 +2475,10 @@ class PaxosManager:
     def drop_pause_record(self, name: str, epoch: int) -> None:
         with self._state_lock:
             self._paused_pop((name, int(epoch)))
+            if name not in self._paused_by_name:
+                # nothing left to wake: the writers' retransmissions
+                # find the name as it then is
+                self._wake_held.pop(name, None)
 
     def dedup_for_name(self, name: str) -> Dict[str, list]:
         """This name's exactly-once entries, for shipping WITH any app
@@ -2518,12 +2717,19 @@ class PaxosManager:
         emulated = None
         with self._state_lock:
             row = self.names.get(name)
-            if row is None:
-                return None
             entry = self.my_id if entry_replica is None else entry_replica
+            if row is None:
+                # asleep here: held for its wake (or answered from the
+                # cache below); a stop is the reconfigurator's own
+                held = None if stop else self._hold_for_wake_locked(
+                    name, request_value, request_id, callback, entry)
+                if held is None or held[1] != "cached":
+                    return None
+                request_id, cached_hit, cached_response = \
+                    held[0], True, held[2]
             # exactly-once fast path: a retransmitted request id is answered
             # from the response cache, not re-proposed
-            if request_id is not None and request_id in self.response_cache:
+            elif request_id is not None and request_id in self.response_cache:
                 cached_hit = True
                 cached_response = self.response_cache[request_id][1]
             elif request_id is not None and self._awaits_decision_locked(
@@ -2664,7 +2870,9 @@ class PaxosManager:
         Returns
         [(request_id, outcome, response)]: "queued", "cached" (callback
         already fired with the response), "inflight" (original still
-        live; callback re-registered), or "unknown" (name not here).
+        live; callback re-registered), "held" (the name sleeps here: the
+        write waits for its row, :meth:`_hold_for_wake_locked`), or
+        "unknown" (name not here).
         Emulation modes take the singleton path (they execute inline)."""
         if self.emulate_unreplicated or self.lazy_propagation:
             # singleton path per item (it executes inline); propose()
@@ -2703,7 +2911,14 @@ class PaxosManager:
                 tc = item[5] if len(item) > 5 else None
                 row = names.get(name)
                 if row is None:
-                    results.append((rid, "unknown", None))
+                    held = self._hold_for_wake_locked(
+                        name, value, rid, cb, entry)
+                    if held is None:
+                        results.append((rid, "unknown", None))
+                        continue
+                    if held[1] == "cached" and cb is not None:
+                        fired.append((cb, held[0], held[2]))
+                    results.append(held)
                     continue
                 if rid is not None and rid in cache:
                     resp = cache[rid][1]
@@ -2871,7 +3086,8 @@ class PaxosManager:
             cur = self.current_epoch(body["name"])
             if fwd_epoch is not None and cur != int(fwd_epoch) \
                     and not self._write_crosses_epoch_locked(
-                        cur, int(fwd_epoch), 0 if body.get("stop") else 1):
+                        body["name"], cur, int(fwd_epoch),
+                        0 if body.get("stop") else 1):
                 return
             tc = body.get("tc")
             tc = None if not tc else (int(tc[0]), int(tc[1]), int(tc[2]))
@@ -2902,7 +3118,7 @@ class PaxosManager:
                 # across an epoch change only the writes come along
                 writes = [r for r in body["reqs"] if not r[3]]
                 if not self._write_crosses_epoch_locked(
-                        cur, int(body["epoch"]), len(writes)):
+                        name, cur, int(body["epoch"]), len(writes)):
                     return
                 body = dict(body, reqs=writes)
             tcs = body.get("tc") or {}
@@ -3998,7 +4214,7 @@ class PaxosManager:
         self.retained[vid] = (g, slot)  # keep for straggler pulls
         return True
 
-    def _write_crosses_epoch_locked(self, cur: Optional[int],
+    def _write_crosses_epoch_locked(self, name: str, cur: Optional[int],
                                     fwd_epoch: int, n_writes: int) -> bool:
         """A forward as of ``fwd_epoch`` for a name whose epoch here is
         ``cur``, another: an epoch change lies between sender and receiver.  An
@@ -4009,9 +4225,13 @@ class PaxosManager:
         over and its request id dedups it.  From a sender that is behind
         it goes straight into the epoch that is; from one that is ahead
         it queues on the old row here and follows the name when this node
-        starts the next epoch."""
-        if cur is None or not n_writes:
+        starts the next epoch.  Where the name SLEEPS here (``cur`` None,
+        a pause record: the sender resumed first, or has not paused yet)
+        the write is taken too, and held until the row is back."""
+        if not n_writes:
             return False
+        if cur is None:
+            return self.sleeps_here(name)
         if fwd_epoch < cur:
             self.metrics.count("requests_carried_over", n_writes)
         return True

@@ -169,6 +169,7 @@ def jump_rows(
     )
 
 
+@jax.jit
 def restore_paused_rows(
     state: EngineState,
     idx: jnp.ndarray,        # [N] rows JUST created by create_groups
@@ -190,7 +191,11 @@ def restore_paused_rows(
     :func:`create_groups` (window lanes NULL, ballot at the initial
     (0, coord0)); the caller computes ``bal`` host-side as the max of
     that initial ballot and the record's promise, which is exactly the
-    per-name restore's ``max(bal0, rec.bal)``."""
+    per-name restore's ``max(bal0, rec.bal)``.  One program (jitted: a
+    wake in traffic must compile nothing, and ten eager scatters each
+    compiled where they first ran), one compile per (state shape, N):
+    the manager calls it with N = 1 and N = ``RESUME_CHUNK`` only, rows
+    repeated to fill a chunk, and warms both."""
     idx = jnp.asarray(idx, jnp.int32)
     as32 = lambda a: jnp.asarray(a, jnp.int32)
     return state._replace(

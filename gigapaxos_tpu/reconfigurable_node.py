@@ -152,6 +152,11 @@ class ActiveReplicaServer(PaxosServer):
         # stale load sample, never a crash
         return self.active_replica.load_summary()
 
+    def _layer_stats(self) -> Dict:
+        # scalar reads only, as above: where the Deactivator's sweep
+        # stands (the ``stats`` admin op's ``layer`` block)
+        return {"sweep": self.active_replica.sweep_stats()}
+
 
 class ReconfiguratorServer(PaxosServer):
     """A PaxosServer whose app is the RC-record RSM, plus the Reconfigurator

@@ -17,6 +17,7 @@ analog), retransmitting round-robin over the previous epoch's actives.
 from __future__ import annotations
 
 import hashlib
+import itertools
 import json
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -149,6 +150,16 @@ class ActiveReplica:
         self.metrics.count("epochs_started", 0)  # present from the start
         self.metrics.count("epochs_stopped", 0)
         self.metrics.count("epochs_dropped", 0)
+        self.metrics.count("wake_requests_sent", 0)
+        self.metrics.count("pause_evictions", 0)
+        # residency's rounds — ``pause_epoch``, and ``start_epoch`` with
+        # ``resume`` and no previous epoch to fetch — that arrived since
+        # the last drain, as (kind, body, arrival): a sweep's burst of
+        # pauses frees its rows together and the names a burst of first
+        # writes wakes are restored together, on the thread that ticks
+        # (no step in flight to wait for) and not one by one on the
+        # transport's (:meth:`drain_residency`)
+        self._residency_queue: List[Tuple[str, Dict, float]] = []
         # (name, epoch) -> when its first stop_epoch arrived here
         # (reconf.stop: until the stop executed, the final state was
         # captured and the acknowledgement left)
@@ -158,6 +169,13 @@ class ActiveReplica:
     # epoch-op handlers (dispatch table)
     # ------------------------------------------------------------------
     def handle_message(self, kind: str, body: Dict, frm: Optional[Addr] = None) -> None:
+        if kind == "pause_epoch" or (
+                kind == "start_epoch" and body.get("resume")
+                and not body.get("prev_actives")):
+            self._residency_queue.append((kind, body, time.monotonic()))
+            return
+        # whatever else comes finds the rounds that came before it done
+        self.drain_residency()
         if kind == "start_epoch":
             self._handle_start_epoch(body)
         elif kind == "stop_epoch":
@@ -172,8 +190,6 @@ class ActiveReplica:
             )
         elif kind == "epoch_commit":
             self._handle_epoch_commit(body)
-        elif kind == "pause_epoch":
-            self._handle_pause_epoch(body)
         elif kind == "echo":
             # active orientation (EchoRequest analog, Reconfigurator.
             # java:2420): bounce the prober's timestamp back so it can
@@ -203,6 +219,8 @@ class ActiveReplica:
                     self.final_states.pop((name, epoch), None)
 
     def tick(self, now: Optional[float] = None) -> None:
+        self.drain_residency()
+        self._request_wakes()
         self.tasks.tick(now)
         self._maybe_sweep(now)
         self._maybe_report_demand(now)
@@ -262,6 +280,16 @@ class ActiveReplica:
                       })
 
     # ---- Deactivator sweep (PaxosManager.java:2931,2786) ---------------
+    def sweep_stats(self, now: Optional[float] = None) -> Dict:
+        """Where the sweep stands: its period (also the idle bound), the
+        seconds since the last one, whether it suggests pauses."""
+        now = time.time() if now is None else now
+        return {
+            "period_s": self.deactivation_period_s,
+            "since_s": max(0.0, now - self._last_sweep),
+            "pause_option": bool(self.pause_option and self.rc_ids),
+        }
+
     def _maybe_sweep(self, now: Optional[float] = None) -> None:
         if not self.rc_ids:
             return
@@ -324,14 +352,80 @@ class ActiveReplica:
                 "name": name, "epoch": epoch, "from": self.my_id,
             })
 
-    # ---- pause (the RC-coordinated row free) ---------------------------
-    def _handle_pause_epoch(self, body: Dict) -> None:
-        name, epoch = body["name"], int(body["epoch"])
-        outcome = self.coordinator.pause_replica_group(name, epoch)
-        self.send(tuple(body["rc"]), "ack_pause_epoch", {
-            "name": name, "epoch": epoch, "from": self.my_id,
-            "ok": outcome in ("ok", "unknown"), "reason": outcome,
-        })
+    # ---- residency: the pause and resume rounds, and the wake request ---
+    def drain_residency(self) -> None:
+        """The rounds queued since the last drain, in the order they
+        came: each run of pauses and each run of resumes as one batch."""
+        if not self._residency_queue:
+            return
+        queue, self._residency_queue = self._residency_queue, []
+        for kind, run in itertools.groupby(queue, key=lambda e: e[0]):
+            run = [(body, t_in) for _k, body, t_in in run]
+            if kind == "pause_epoch":
+                self._pause_batch(run)
+            else:
+                self._resume_batch(run)
+
+    def _pause_batch(self, run: List[Tuple[Dict, float]]) -> None:
+        """Pause rounds (the RC-coordinated row free).  Interval
+        histogram ``phase_reconf_pause_s``: ``pause_epoch`` received ->
+        record journaled, row freed (or refused: busy), acknowledged;
+        the host event ``gp.reconf.pause`` holds the batch itself."""
+        with span(self.metrics, "reconf.pause", record=False,
+                  node=self.my_id, names=len(run)):
+            outcomes = self.coordinator.pause_replica_groups(
+                [(b["name"], int(b["epoch"])) for b, _t in run])
+            for body, t_in in run:
+                name, epoch = body["name"], int(body["epoch"])
+                outcome = outcomes[(name, epoch)]
+                self.send(tuple(body["rc"]), "ack_pause_epoch", {
+                    "name": name, "epoch": epoch, "from": self.my_id,
+                    "ok": outcome in ("ok", "unknown"), "reason": outcome,
+                })
+                observe_interval(self.metrics, "reconf.pause",
+                                 time.monotonic() - t_in)
+
+    def _request_wakes(self) -> None:
+        """A write waits here for a name that sleeps: ask the name's
+        reconfigurator for the resume round (once a sleep; the
+        coordinator repeats a request that stays unanswered)."""
+        if not self.rc_ids:
+            return
+        for name, epoch in self.coordinator.drain_wake_requests():
+            self.send(("RC", self.rc_ids[hash(name) % len(self.rc_ids)]),
+                      "reactivate_service", {
+                          "name": name, "epoch": epoch, "from": self.my_id,
+                      })
+            self.metrics.count("wake_requests_sent")
+
+    def _resume_batch(self, run: List[Tuple[Dict, float]]) -> None:
+        """Resume rounds.  Those that restore a local pause record go
+        through ONE batched restore when there are several; the rest (a
+        live re-home, a join without a record) and whatever the batch
+        refused take the per-name path, which knows a collision from a
+        transient refusal.  Interval histogram
+        ``phase_reconf_resume_s``: resume received -> row live,
+        acknowledged; the host event ``gp.reconf.resume`` holds the
+        restore itself."""
+        with span(self.metrics, "reconf.resume", record=False,
+                  node=self.my_id, names=len(run)):
+            fused = [b for b, _t in run if self.coordinator.has_pause_record(
+                b["name"], int(b["epoch"]))]
+            done: Dict[str, bool] = {}
+            if len(fused) > 1:
+                done = self.coordinator.resume_replica_groups([
+                    (b["name"], int(b["epoch"]), list(b["actives"]),
+                     int(b["row"]), not b.get("committed", False))
+                    for b in fused
+                ])
+            for body, t_in in run:
+                if done.get(body["name"]):
+                    outcome = "ok"
+                else:
+                    outcome = self._create(body, body.get("initial_state"))
+                self._ack_start(body, outcome)
+                observe_interval(self.metrics, "reconf.resume",
+                                 time.monotonic() - t_in)
 
     # ---- start (handleStartEpoch, ActiveReplica.java:796) --------------
     def _handle_start_epoch(self, body: Dict) -> None:

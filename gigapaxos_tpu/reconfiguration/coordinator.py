@@ -15,7 +15,7 @@ machinery — exactly the reference's intent.
 
 from __future__ import annotations
 
-from typing import Callable, List, Optional
+from typing import Callable, Dict, List, Optional
 
 from ..interfaces.app import Replicable
 from ..manager import PaxosManager
@@ -82,6 +82,32 @@ class AbstractReplicaCoordinator:
         collision, like create).  ``initial_state`` seeds a member with no
         local state joining a BIRTH epoch."""
         raise NotImplementedError
+
+    def pause_replica_groups(self, items) -> Dict:
+        """Residency: several pause rounds that arrived together, as
+        ``[(name, epoch)]`` -> ``{(name, epoch): "ok" | "unknown" |
+        "busy"}``.  Default: one by one."""
+        return {(name, int(epoch)): self.pause_replica_group(name, epoch)
+                for name, epoch in items}
+
+    def resume_replica_groups(self, items) -> Dict[str, bool]:
+        """Residency: several resumes that arrived together, as
+        ``[(name, epoch, members, row, pending)]`` — one fused restore
+        where the coordinator has one.  Default: one by one (a row
+        collision reads False, like a transient refusal)."""
+        out = {}
+        for name, epoch, members, row, pending in items:
+            try:
+                out[name] = self.resume_replica_group(
+                    name, epoch, members, row, pending=pending)
+            except RuntimeError:
+                out[name] = False
+        return out
+
+    def drain_wake_requests(self):
+        """(name, epoch) of names asleep here that a write is waiting
+        for: the layer asks their reconfigurator for the resume."""
+        return []
 
     def idle_groups(self, idle_s: float):
         """(name, epoch) pairs idle long enough for a Deactivator sweep."""
@@ -239,6 +265,15 @@ class PaxosReplicaCoordinator(AbstractReplicaCoordinator):
             name, epoch, members, row, pending=pending,
             initial_state=initial_state,
         )
+
+    def pause_replica_groups(self, items) -> Dict:
+        return self.manager.pause_group_batch(items)
+
+    def resume_replica_groups(self, items) -> Dict[str, bool]:
+        return self.manager.resume_group_batch(items)
+
+    def drain_wake_requests(self):
+        return self.manager.drain_wake_requests()
 
     def idle_groups(self, idle_s: float):
         return self.manager.idle_names(idle_s)

@@ -541,6 +541,8 @@ class Reconfigurator:
         # last row-probe attempt per name: an expired start task's re-drive
         # resumes probing here instead of restarting at attempt 0
         self._last_attempt: Dict[str, int] = {}
+        # name -> when its PAUSE_INTENT was proposed here, until applied
+        self._pause_suggested: Dict[str, float] = {}
         # batched creates (Reconfigurator.java:484-680 batch path):
         # batch_id -> {client, pending names, per-name results}; one
         # create_batch_ack per batch when every member settles.  In-memory
@@ -641,7 +643,7 @@ class Reconfigurator:
         elif kind == "epoch_probe":
             self._handle_epoch_probe(body)
         elif kind == "reactivate_service":
-            self.kick_reactivate(body["name"])
+            self._handle_reactivate_service(body)
         elif kind == "demand_report":
             self._handle_demand_report(body)
         elif kind == "echo_reply":
@@ -1413,6 +1415,8 @@ class Reconfigurator:
             )
 
     # ---- residency (suggest_pause / reactivate) ------------------------
+    SUGGEST_ONCE_S = 10.0  # a name's second and third suggestion: dropped
+
     def _handle_suggest_pause(self, body: Dict) -> None:
         name = body["name"]
         if not self.is_primary(name):
@@ -1423,7 +1427,37 @@ class Reconfigurator:
             return
         if int(body.get("epoch", -1)) != rec.epoch:
             return  # stale suggestion from a lagging active
+        # every active of the name suggests it within seconds of the
+        # others: one intent a name, not three (the record stays READY
+        # until the first is applied, which takes it off this table; the
+        # age guards an intent that was lost)
+        now = self.tasks.clock()
+        if now - self._pause_suggested.get(name, -1e9) < self.SUGGEST_ONCE_S:
+            return
+        self._pause_suggested[name] = now
         self.propose_op({"op": PAUSE_INTENT, "name": name})
+
+    def _handle_reactivate_service(self, body: Dict) -> None:
+        """An active holds a write for a name that sleeps there (wake on
+        write): drive the resume round.  Where the record is NOT paused
+        — the round that woke the others has passed this member by, or
+        a pause round that was called off froze it alone — the member's
+        pause record is the stranded form :meth:`_handle_epoch_probe`
+        heals, now and not at the member's next sweep."""
+        name = body["name"]
+        if not self.is_primary(name):
+            self.send(("RC", self.primary_of(name)),
+                      "reactivate_service", body)
+            return
+        rec = self.rc_app.get_record(name)
+        if rec is not None and not rec.deleted and rec.state in (
+                RCState.PAUSED, RCState.WAIT_PAUSE):
+            self.kick_reactivate(name)
+        elif body.get("from") is not None and body.get("epoch") is not None:
+            self._handle_epoch_probe({
+                "name": name, "epoch": int(body["epoch"]),
+                "from": int(body["from"]),
+            })
 
     def kick_reactivate(self, name: str) -> None:
         """Touch of a paused name: drive PAUSED/WAIT_PAUSE -> resume round
@@ -1652,6 +1686,8 @@ class Reconfigurator:
                 self._epoch_change_t0.pop(name, None)
             elif kind == CREATE_INTENT:
                 self._create_t0.pop(name, None)
+            elif kind == PAUSE_INTENT:
+                self._pause_suggested.pop(name, None)
             return
         if not self.is_primary(name):
             return
@@ -1770,6 +1806,7 @@ class Reconfigurator:
                 spawn_prev_drop()
         elif kind == PAUSE_INTENT:
             assert rec is not None
+            self._pause_suggested.pop(name, None)
             live = [a for a in rec.actives if a in self.ar_ids]
             if live:
                 self.tasks.spawn_if_not_running(
@@ -1780,6 +1817,10 @@ class Reconfigurator:
                 )
         elif kind == REACTIVATE:
             assert rec is not None
+            # the pause round this calls off must send no pause_epoch
+            # behind the resume: a member that froze again then would
+            # wait for its next sweep's probe
+            self.tasks.cancel(f"pause:{name}")
             skey = f"start:{name}:{rec.epoch}"
             self.tasks.spawn_if_not_running(
                 skey,

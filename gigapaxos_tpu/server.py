@@ -661,11 +661,13 @@ class PaxosServer:
             request_id=request_id, trace_ctx=tc,
         )
         if vid is None and request_id not in self.manager.response_cache \
-                and self.manager.names.get(name) is None:
+                and self.manager.names.get(name) is None \
+                and not self.manager.sleeps_here(name):
             # None + uncached + hosted here means the original proposal
-            # is still in flight (callback re-registered) — only an
-            # UNHOSTED name is a real error; erroring the inflight case
-            # double-answers the client (batch-path parity)
+            # is still in flight (callback re-registered), and a name
+            # that SLEEPS here has the write held until its row is back
+            # — only an UNHOSTED name is a real error; erroring the
+            # inflight case double-answers the client (batch-path parity)
             self._buffer_response(reply, {
                 "request_id": request_id, "response": None,
                 "name": name, "error": "unknown_name",

@@ -44,6 +44,43 @@ def test_engine_parity_phase_cpu_vs_cpu(smoke):
     assert line["compile"]["retraces"] == 0
 
 
+def test_engine_parity_trace_pauses_rows_and_resumes_them_in_one_batch(smoke):
+    """The parity trace holds a pause and a batched resume (PR 31): the
+    rows are dead between the two and live, from their records, after."""
+    from gigapaxos_tpu.ops.engine import EngineConfig
+    from gigapaxos_tpu.parallel.spmd import make_step
+
+    cpu = jax.devices("cpu")
+    line = smoke.phase_engine_parity(
+        256, 16, 8, 3, n_steps=24, seed=11,
+        device=cpu[0], reference_device=cpu[1],
+    )
+    res = line["residency"]
+    assert res == {"rows": 8, "paused_at_step": 8, "resumed_at_step": 16,
+                   "executed_while_awake": res["executed_while_awake"]}
+    assert res["executed_while_awake"] > 0 and line["bit_exact"]
+    # and the arm's own view: freed rows admit nothing, restored rows
+    # carry on from their frontier
+    cfg = EngineConfig(256, 16, 8, 3)
+    arm = smoke._ReplicaArm(cfg, cpu[0], make_step(
+        cfg, None, 1, donate=False, io="packed_host"))
+    rows = np.array([5, 42], np.int32)
+    trace = list(smoke.make_trace(cfg, 12, 3))
+    for req, want, heard in trace[:6]:
+        arm.step(req, want, heard)
+    before = np.asarray(arm.states[0].exec_slot)[rows].copy()
+    assert before.min() > 0
+    arm.pause(rows)
+    for req, want, heard in trace[6:9]:
+        arm.step(req, want, heard)
+    assert (np.asarray(arm.states[0].member_mask)[rows] == 0).all()
+    arm.resume(rows)
+    assert (np.asarray(arm.states[0].exec_slot)[rows] == before).all()
+    for req, want, heard in trace[9:]:
+        arm.step(req, want, heard)
+    assert (np.asarray(arm.states[0].exec_slot)[rows] > before).all()
+
+
 def test_parity_comparison_names_the_first_differing_word(smoke):
     a = np.arange(12, dtype=np.int32).reshape(3, 4)
     smoke._assert_same("leaf", a, a.copy())

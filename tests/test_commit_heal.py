@@ -84,6 +84,8 @@ def test_resume_heal_shapes():
             "initial_state": initial, "prev_actives": [], "prev_epoch": -1,
             "resume": True, "committed": True, "rc": ["RC", 0],
         })
+        ar.drain_residency()  # since PR 31 a resume waits for the next
+        #   message or the layer's tick (a burst restores in one batch)
 
     # losing pending row -> re-homed to the winning row, unpended, queue kept
     mgr.create_paxos_instance("x", [0, 1, 2], row=1, pending=True)

@@ -190,3 +190,61 @@ def test_http_front_ends(cluster):
 
     code, body = post(rc_url, {"type": "DELETE", "name": "httpsvc"})
     assert code == 200 and body["ok"], body
+
+
+def test_a_write_straight_to_an_active_wakes_the_name_it_paused(cluster):
+    """Wake on write over sockets (PR 31): the name is paused on all
+    three actives; a write sent straight to one of them, as a load
+    generator sends (``send_prepared``: no resolution, no retry), is
+    acknowledged like any other; a name nobody created is refused."""
+    import threading
+
+    nodes, client = cluster
+    ack = client.create_name("nap", actives=[0, 1, 2], timeout=30)
+    assert ack and ack.get("ok"), ack
+    for i in range(3):
+        assert client.send_request_sync("nap", f"n{i}", timeout=20) is not None
+    ars = [ar_server(nodes, i) for i in range(3)]
+
+    def wait(cond, seconds, what):
+        deadline = time.time() + seconds
+        while time.time() < deadline:
+            if cond():
+                return
+            time.sleep(0.1)
+        raise AssertionError(what)
+
+    wait(lambda: len({s.manager.app.n_executed.get("nap") for s in ars}) == 1
+         and ars[0].manager.app.n_executed.get("nap") == 3, 20, "3 executed")
+    with ars[0]._layer_lock:
+        ars[0].active_replica.send(("RC", 0), "suggest_pause", {
+            "name": "nap", "epoch": 0, "from": 0})
+    wait(lambda: all(s.manager.sleeps_here("nap") for s in ars), 30,
+         "the pause round")
+    # the stats admin op through the client library: who sleeps, and
+    # where the sweep stands
+    for i in range(3):
+        stats = client.admin_sync(i, {"op": "stats"}, timeout=10)
+        assert stats["residency"]["paused_names"] >= 1, stats["residency"]
+        assert stats["layer"]["sweep"]["period_s"] > 0
+        assert stats["layer"]["sweep"]["pause_option"] is True
+    assert client.admin_sync(0, {"op": "nonesuch"})["error"] == "unknown_op"
+
+    answers, done = [], threading.Event()
+
+    def cb(rid, response, error):
+        answers.append((response, error))
+        done.set()
+
+    client.send_prepared(tuple(client.actives[1]), "nap", "after-the-nap", cb)
+    assert done.wait(30), "the first write to a sleeping name went unanswered"
+    assert answers[0][1] is None and answers[0][0] is not None, answers
+    wait(lambda: all(s.manager.app.n_executed.get("nap") == 4 for s in ars),
+         20, "executed on all three after the wake")
+    assert len({s.manager.app.state["nap"] for s in ars}) == 1
+    assert ars[1].manager.metrics.get("writes_held_for_wake") == 1
+    assert ars[1].manager.metrics.get("wake_requests_sent") >= 1
+
+    done.clear()
+    client.send_prepared(tuple(client.actives[1]), "nobody", "x", cb)
+    assert done.wait(10) and answers[-1] == (None, "unknown_name")
