@@ -140,11 +140,12 @@ class _ReplicaArm:
         self.stacks = [jax.tree.map(self.put, init_stack(cfg))
                        for _ in range(R)]
         self.held = [None] * R  # the vectors every stack holds
-        self.news = GatherNews(cfg)
+        self.heard_news = GatherNews(cfg)
         self.no_rows = empty_update_vec(cfg)
         self.scattered = self.whole = 0
         self.heat = [self.put(jnp.zeros((G,), jnp.int32)) for _ in range(R)]
         self.digests = [None] * R  # the last step's, per replica
+        self.news = [None] * R    # and its blob's news
 
     def step(self, req, want, heard):
         import jax.numpy as jnp
@@ -157,10 +158,10 @@ class _ReplicaArm:
         # may use): one update serves all R stacks, its row for a
         # replica's own id overwritten by the step from that state
         self.held = [
-            self.news.hear(j, np.asarray(blob), self.held[j])
+            self.heard_news.hear(j, np.asarray(blob), self.held[j])
             for j, blob in enumerate(self.blobs)
         ]
-        update = self.news.drain(dict(enumerate(self.held)))
+        update = self.heard_news.drain(dict(enumerate(self.held)))
         self.whole += len(update.whole)
         self.scattered += update.n_scattered
         upd = put(self.no_rows if update.rows is None else update.rows)
@@ -170,16 +171,47 @@ class _ReplicaArm:
             for peer, vec in update.whole:
                 self.stacks[r] = set_peer_rows(
                     self.stacks[r], put(vec), put(np.int32(peer)), cfg=cfg)
+            # the blob of the last step goes in as the published vector
+            # (donated on the arm that donates) and the fresh one comes
+            # back with its news: the rows that differ, lifecycle
+            # operations since the last step included
             (self.states[r], self.stacks[r], out, blob, self.heat[r],
-             digest) = self.step_fn(
+             digest, news) = self.step_fn(
                 self.states[r], self.stacks[r], upd, put(heard[r]), ring,
                 put(want[r]), put(np.int32(r)), self.heat[r],
+                self.blobs[r],
             )
             outs.append(out)
             blobs.append(blob)
             self.digests[r] = digest
+            self.news[r] = news
         self.blobs = blobs
         return outs
+
+    def check_news(self, t: int) -> int:
+        """Each replica's news of step ``t`` against the host's compare
+        of the fresh vector with the one held of it before (``held`` is
+        what the last step published): the same rows, the same words.
+        -> the rows the step named."""
+        from gigapaxos_tpu.net.codec import changed_rows, rows_of
+        from gigapaxos_tpu.net.mirror import news_blocks
+        from gigapaxos_tpu.ops.engine import split_news_vec, update_rows
+
+        cfg, named = self.cfg, 0
+        for r, (news, blob) in enumerate(zip(self.news, self.blobs)):
+            n, rows, body = split_news_vec(np.asarray(news), cfg)
+            fresh = np.asarray(blob)
+            want = changed_rows(fresh, self.held[r], cfg)
+            if n != want.size:
+                raise AssertionError(
+                    f"step {t} r{r}: news names {n} rows, {want.size} differ")
+            if n <= update_rows(cfg):
+                _assert_same(f"step {t} r{r}.news.rows", rows, want)
+                for got, exp in zip(news_blocks(body, n, cfg),
+                                    rows_of(fresh, want, cfg)):
+                    _assert_same(f"step {t} r{r}.news.words", got, exp)
+            named += n
+        return named
 
     def pause(self, rows):
         """What ``manager.pause_group`` does to the device, on every
@@ -236,6 +268,7 @@ class _ReplicaArm:
                 yield f"r{r}.stack.{name}", leaf
             yield f"r{r}.heat", heat
             yield f"r{r}.digest", self.digests[r]
+            yield f"r{r}.news", self.news[r]
 
 
 def _assert_same(name: str, got, want) -> None:
@@ -268,7 +301,7 @@ def phase_engine_parity(n_groups: int, window: int, req_lanes: int,
     ref_fn = make_step(cfg, None, 1, donate=False, io="packed_host")
     arm = _ReplicaArm(cfg, device, step_fn)
     ref = _ReplicaArm(cfg, reference_device, ref_fn)
-    decided = admitted = 0
+    decided = admitted = news_rows = 0
     first_call_s = steady_s = 0.0
     # residency in the trace: eight rows are paused a third of the way
     # in (freed on every replica, their records kept) and come back two
@@ -291,6 +324,9 @@ def phase_engine_parity(n_groups: int, window: int, req_lanes: int,
         for r, (got, exp) in enumerate(zip(outs_np, ref.step(req, want,
                                                              heard))):
             _assert_same(f"step {t} r{r}.out", got, exp)
+        for r, (got, exp) in enumerate(zip(arm.news, ref.news)):
+            _assert_same(f"step {t} r{r}.news", got, exp)
+        news_rows += arm.check_news(t)
         out0 = split_out_vec(outs_np[0][0], cfg)
         decided += int(out0.n_committed.sum())
         admitted += sum(
@@ -314,6 +350,7 @@ def phase_engine_parity(n_groups: int, window: int, req_lanes: int,
         "decided": decided,
         "admitted": admitted,
         "gather_updates": {"scattered": arm.scattered, "whole": arm.whole},
+        "blob_news_rows": news_rows,
         "residency": {"rows": int(slept.size), "paused_at_step": pause_at,
                       "resumed_at_step": resume_at,
                       "executed_while_awake": int(

@@ -68,9 +68,12 @@ from .ops.engine import (
     set_peer_rows,
     split_blob_vec,
     split_digest_vec,
+    split_news_vec,
     split_out_vec,
+    update_rows,
 )
 from .net.gather import NO_NEWS, GatherUpdate, empty_update_vec
+from .net.mirror import PublishMirror, news_blocks
 from .parallel.spmd import make_step
 from .obs import gplog
 from .obs.flight import FlightRecorder
@@ -87,7 +90,11 @@ from .storage.logger import PaxosLogger
 # reused in place by the new state — on-device this halves state HBM;
 # backends without donation support ignore it.  The gathered stack (the
 # peers' blobs, ops/engine.py:init_stack) is donated with it and never
-# leaves the device: a dispatch sends up the rows its frames named.
+# leaves the device: a dispatch sends up the rows its frames named.  So
+# is the published vector (the blob the host last received from this
+# node's step): the step hands back the fresh one in its place and names
+# the rows that differ (ops/engine.py:make_news), which is all of it
+# that comes down on a tick whose news fits.
 _publish_vec_jit = jax.jit(lambda state: pack_blob(make_blob(state)))
 
 
@@ -414,6 +421,18 @@ class PaxosManager:
         # tick)
         self._stack = init_stack(cfg)
         self._no_rows = jnp.asarray(empty_update_vec(cfg))
+        # my own publish vector, twice: on the device the one the last
+        # step made (the next step's base for its news), on the host the
+        # mirror the transport cuts its frames from (net/mirror.py),
+        # patched with each step's news AFTER the post-step has journaled
+        # what the rows show.  The two are equal between steps; a
+        # completion that ended before its patch leaves the mirror
+        # behind, and the next pulls the whole vector (as the first does)
+        self._published = jnp.zeros((blob_vec_len(cfg),), jnp.int32)
+        self.mirror = PublishMirror(cfg, my_id)
+        self._mirror_behind = True
+        for key in ("blob_news_dispatches", "blob_news_overflows"):
+            self.metrics.count(key, 0)  # present from the start
         # the dispatch's other inputs, kept on the device while the host
         # value stands: my id; a ring with no request in it; who is heard
         # (by its bytes); the election mask (by identity: the failure
@@ -1076,6 +1095,7 @@ class PaxosManager:
             jnp.asarray(np.zeros(R, bool)), jnp.asarray(req),
             jnp.asarray(np.zeros((G,), bool)), jnp.int32(self.my_id),
             jnp.zeros((G,), jnp.int32),
+            jnp.zeros((blob_vec_len(cfg),), jnp.int32),
         )
         jax.block_until_ready(out)
         return time.monotonic() - t0
@@ -3392,24 +3412,25 @@ class PaxosManager:
         update: Optional[GatherUpdate],
         heard: np.ndarray,
         want_coord: Optional[np.ndarray] = None,
-    ) -> Tuple[np.ndarray, "EngineState", Dict]:
+    ) -> Tuple[int, "EngineState", Dict]:
         """One full cycle, serially: :meth:`step_dispatch` and
         :meth:`step_complete` back to back under ONE hold of the lock —
         the reference the pipelined pair is held to
         (tests/test_pipeline.py), and what the stepped harnesses call.
         `update` is what came of the peers since the last dispatch
         (net/gather.py: rows for the device's stack, whole vectors; None
-        where there are no peers or no news); returns (my fresh packed
-        blob vector, the state it reflects, the host delta).  User callbacks
+        where there are no peers or no news); returns (the tick of
+        ``self.mirror``, which now holds my fresh publish vector, the
+        state it reflects, the host delta).  User callbacks
         collected during execution fire AFTER the lock is released (a
         blocking callback must not wedge transport threads)."""
         with self._step_locked():
             pend = self._dispatch_locked(update, heard, want_coord)
-            digest_np, blob_np = self._device_wait(pend)
-            host_delta = self._complete_locked(pend, digest_np, blob_np)
+            host_delta = self._complete_locked(
+                pend, *self._device_wait(pend))
             fired, self._fired_callbacks = self._fired_callbacks, []
         self._fire(fired)
-        return blob_np, pend["state"], host_delta
+        return self.mirror.tick, pend["state"], host_delta
 
     # ------------------------------------------------------------------
     # the tick's spans (obs/spans.py): both ways to run a tick —
@@ -3449,8 +3470,9 @@ class PaxosManager:
         news (whole vectors through the whole-row program, rows with the
         step) and fire the step without waiting for the device.
         Returns the pending handle of device values (``out_vec`` stays
-        on the device unless a substep's digest overflows) with
-        ``self.state`` already the in-flight result."""
+        on the device unless a substep's digest overflows, ``blob_vec``
+        unless its news does) with ``self.state`` already the in-flight
+        result."""
         with self._span("step.ring_build", cpu=False):
             req = self.build_request_ring(self.steps_per_dispatch)
             old_state = self.state
@@ -3460,14 +3482,14 @@ class PaxosManager:
             # every upload is a temporary of this call: a device value
             # made of host memory may hold its last reference until the
             # step has read it, and that wait belongs to this span
-            new_state, self._stack, out_vec, blob_vec, new_heat, \
-                digest_vec = self._dispatch_step(
+            new_state, self._stack, out_vec, self._published, new_heat, \
+                digest_vec, news_vec = self._dispatch_step(
                     old_state, *self._send_up_locked(update or NO_NEWS),
                     self._heard_locked(heard),
                     jnp.asarray(req) if self._last_ring_depth
                     else self._null_ring,
                     self._want_locked(want_coord),
-                    self._my_id_dev, self._heat_dev,
+                    self._my_id_dev, self._heat_dev, self._published,
                 )
             # the donated buffers' last references go inside the span:
             # where letting go of one waits for the step (the CPU
@@ -3478,9 +3500,13 @@ class PaxosManager:
                 self._np_cache = carried
                 self._np_cache_state = new_state
             del old_state
+        # the mirror is behind the device's published vector from here
+        # until this dispatch's completion has patched it
+        behind, self._mirror_behind = self._mirror_behind, True
         return {
-            "out_vec": out_vec, "blob_vec": blob_vec,
-            "digest_vec": digest_vec, "state": new_state, "t0": t0,
+            "out_vec": out_vec, "blob_vec": self._published,
+            "digest_vec": digest_vec, "news_vec": news_vec,
+            "mirror_behind": behind, "state": new_state, "t0": t0,
         }
 
     def _heard_locked(self, heard):
@@ -3560,26 +3586,47 @@ class PaxosManager:
         return carry
 
     def _device_wait(self, pend: Dict):
-        """The step's digests and blob on the host: two transfers, the
-        first forces the sync.  Its annotation wraps JAX's own host
-        events, so an idle gap of the device under it keeps their
-        names."""
+        """The step's digests and the news of its blob on the host: two
+        transfers asked for together, the first forces the sync; the
+        whole blob only where its news does not fit (or the mirror has
+        none to patch).  Its annotation wraps JAX's own host events, so
+        an idle gap of the device under it keeps their names."""
         with self._span("step.device_wait"):
-            return np.asarray(pend["digest_vec"]), np.asarray(pend["blob_vec"])
+            digest_np, news_np = jax.device_get(
+                (pend["digest_vec"], pend["news_vec"]))
+            whole = None
+            if pend["mirror_behind"] \
+                    or int(news_np[0]) > update_rows(self.cfg):
+                whole = self._pull_blob_vec(pend)
+            return digest_np, news_np, whole
 
-    def _complete_locked(self, pend: Dict, digest_np, blob_np) -> Dict:
-        """Lock held, digests and blob on the host: close the
-        ``engine_step_s`` envelope and run the post-step host cycle."""
+    def _pull_blob_vec(self, pend: Dict) -> np.ndarray:
+        """The whole publish vector of a dispatch, as the mirror's own
+        (writable; never a view of the buffer the next dispatch
+        donates)."""
+        return np.array(pend["blob_vec"])
+
+    def _complete_locked(self, pend: Dict, digest_np, news_np,
+                         whole) -> Dict:
+        """Lock held, digests and the blob's news on the host: close the
+        ``engine_step_s`` envelope, run the post-step host cycle, and
+        only then let the mirror show what the step made (the journal is
+        written before a peer can be sent the rows it covers)."""
         self.last_engine_step_s = time.monotonic() - pend["t0"]
         with self._span("post_step"):
+            mx = self.metrics
+            n_news, rows, body = split_news_vec(news_np, self.cfg)
+            mx.count("blob_news_dispatches")
+            mx.observe("blob_news_rows", n_news, bounds=ROW_BOUNDS)
+            if whole is not None:
+                mx.count("blob_news_overflows")
             if pend["state"] is self.state:
                 # the new state's ballots and frontiers are in the blob,
                 # unmasked (ops/engine.py:make_blob): the tick path reads
-                # them from here and not from the device
-                blob = split_blob_vec(blob_np, self.cfg)
+                # them from here and not from the device — the mirror's
+                # as the last step left them, with this step's rows
                 self._np_cache_locked().update(
-                    bal=blob.bal, exec_slot=blob.exec_slot)
-            mx = self.metrics
+                    self._fresh_bal_exec(rows, body, whole))
             digests = []
             for i, row in enumerate(digest_np):
                 digest, n_busy = split_digest_vec(row, self.cfg)
@@ -3591,7 +3638,31 @@ class PaxosManager:
                         pend["out_vec"][i], digest.live)
                 digests.append(digest)
             self._work_in_flight = digests[-1].live
-            return self._post_step_locked(digests)
+            host_delta = self._post_step_locked(digests)
+            if whole is not None:
+                self.mirror.replace(whole)
+            else:
+                self.mirror.patch(
+                    rows, news_blocks(body, rows.size, self.cfg))
+            self._mirror_behind = False
+            return host_delta
+
+    def _fresh_bal_exec(self, rows, body, whole) -> Dict[str, np.ndarray]:
+        """``bal`` and ``exec_slot`` of the blob a step just made, before
+        the mirror shows it: private copies (the mirror is patched in
+        place)."""
+        cfg, names = self.cfg, ("bal", "exec_slot")
+        if whole is not None:
+            blob = split_blob_vec(whole, cfg)
+            return {name: getattr(blob, name).copy() for name in names}
+        held = split_blob_vec(self.mirror.vec, cfg)
+        news = split_blob_vec(body, cfg._replace(n_groups=update_rows(cfg)))
+        out = {}
+        for name in names:
+            leaf = getattr(held, name).copy()
+            leaf[rows] = getattr(news, name)[:rows.size]
+            out[name] = leaf
+        return out
 
     def _whole_planes_locked(self, out_vec_row, live: bool) -> StepDigest:
         """A substep whose busy rows overflowed the device's digest: its
@@ -3665,19 +3736,19 @@ class PaxosManager:
 
     def step_complete(
         self, pend: Dict
-    ) -> Tuple[np.ndarray, "EngineState", Dict]:
+    ) -> Tuple[int, "EngineState", Dict]:
         """Sync the in-flight step and run the post-step host cycle;
-        returns (packed publish vector, the state it reflects, host
-        delta) — the same triple as :meth:`tick_host`."""
-        # device sync OUTSIDE the lock: np.asarray blocks with the GIL
+        returns (the mirror's tick, the state it reflects, host delta)
+        — the same triple as :meth:`tick_host`."""
+        # device sync OUTSIDE the lock: the transfer blocks with the GIL
         # released, so transport threads run the ingress/codec path
         # against the still-valid carried caches while the device works
-        digest_np, blob_vec = self._device_wait(pend)
+        waited = self._device_wait(pend)
         with self._span("post_step.lock_wait", cpu=False):
             self._state_lock.acquire()
         try:
             try:
-                host_delta = self._complete_locked(pend, digest_np, blob_vec)
+                host_delta = self._complete_locked(pend, *waited)
             finally:
                 self._step_inflight = False
                 self._step_thread = None
@@ -3686,7 +3757,7 @@ class PaxosManager:
         finally:
             self._state_lock.release()
         self._fire(fired)
-        return blob_vec, pend["state"], host_delta
+        return self.mirror.tick, pend["state"], host_delta
 
     def _post_step_locked(self, outs) -> Dict:
         """Shared post-engine host work (requeue, watermarks, journaling,

@@ -17,7 +17,8 @@ and hand-rolled byte layouts on one NIO channel,
   never parsed misaligned — a mixed-version node fails loudly instead
   of feeding misparsed ballots into consensus.
 * ``d`` frames — row deltas of a ``D`` blob against the vector the
-  sender last wrote to THIS connection (the base, named by its tick):
+  sender had published at the tick it last wrote to THIS connection (the
+  base, named by that tick):
   sender + tick + base tick + row count, the int32 row indices, then each
   leaf's changed rows in ``Blob._fields`` order.  The receiver patches
   them into the vector it holds of that sender, which then equals the
@@ -165,27 +166,44 @@ def rows_of(vec: np.ndarray, rows: np.ndarray,
     return [block[:, rows] for block in _row_blocks(vec, cfg)]
 
 
+def delta_is_smaller(n_rows: int, cfg: EngineConfig) -> bool:
+    """The size rule: a ``d`` frame of ``n_rows`` rows wherever it is
+    smaller than the whole vector's ``D`` frame."""
+    words = blob_vec_len(cfg)
+    return _DHDR.size + 4 * n_rows * (1 + words // cfg.n_groups) \
+        < _BHDR.size + 4 * words
+
+
+def encode_blob_delta(sender: int, tick: int, base_tick: int,
+                      rows: np.ndarray, blocks: List[np.ndarray]) -> bytes:
+    """The ``d`` frame of the int32 ``rows`` with their words
+    (:func:`rows_of`) against the vector published at ``base_tick``."""
+    parts = [_DHDR.pack(b"d", sender, tick, base_tick, int(rows.size)),
+             rows.tobytes()]
+    parts += [block.tobytes() for block in blocks]
+    return b"".join(parts)
+
+
 def encode_blob_frame(
     sender: int, cfg: EngineConfig,
     item: Tuple[int, np.ndarray],
     base: Optional[Tuple[int, np.ndarray]],
 ) -> Tuple[bytes, Optional[int]]:
-    """The blob frame for one peer connection, encoded when its turn to
-    be written comes: ``item`` is the newest (tick, publish vector),
-    ``base`` the pair last written and drained on that connection (None
-    on a new one).  -> (frame, rows in the delta; None for a full ``D``
-    frame).  The rule is what the two vectors show, nothing else: a
-    delta wherever it is smaller than the whole vector."""
+    """The blob frame for one peer connection found by COMPARING two
+    vectors: ``item`` is the newest (tick, publish vector), ``base`` the
+    pair that connection last carried (None on a new one).  -> (frame,
+    rows in the delta; None for a full ``D`` frame).  The rule is what
+    the two vectors show, nothing else: a delta wherever it is smaller
+    than the whole vector.  A node's sender is told its rows by the step
+    and compares nothing (``net/mirror.py``); this is what the tests
+    hold its frames against, byte for byte."""
     tick, vec = item
     if base is not None:
         rows = changed_rows(vec, base[1], cfg)
-        n = int(rows.size)
-        if _DHDR.size + 4 * n * (1 + vec.size // cfg.n_groups) \
-                < _BHDR.size + 4 * vec.size:
-            parts = [_DHDR.pack(b"d", sender, tick, base[0], n),
-                     rows.tobytes()]
-            parts += [block.tobytes() for block in rows_of(vec, rows, cfg)]
-            return b"".join(parts), n
+        if delta_is_smaller(rows.size, cfg):
+            return encode_blob_delta(
+                sender, tick, base[0], rows, rows_of(vec, rows, cfg),
+            ), int(rows.size)
     return encode_blob_vec(sender, tick, vec), None
 
 

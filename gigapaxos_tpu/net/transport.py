@@ -41,13 +41,16 @@ class _Latest:
         self.slot = slot
 
 
-# latest_encoder(item, base) -> (frame, rows): called in ``_sender`` when a
-# latest-wins slot's turn comes.  ``item`` is whatever
-# ``send_latest_to_id`` was last given for that peer; ``base`` is the item
-# last written AND drained on the connection that is open now, None on a
-# new connection or after ``forget_latest_base``.  ``rows`` is None for a
-# frame that stands alone, else the rows of a delta against ``base``.
-LatestEncoder = Callable[[Any, Any], Tuple[bytes, Optional[int]]]
+# latest_encoder(item, base) -> (frame, rows, next base): called in
+# ``_sender`` when a latest-wins slot's turn comes.  ``item`` is whatever
+# ``send_latest_to_id`` was last given for that peer (for the blob a
+# marker: the frame is cut from the sender's mirror as it stands then);
+# ``base`` is what the encoder named as the next base for the frame last
+# written AND drained on the connection that is open now (for the blob
+# its tick), None on a new connection or after ``forget_latest_base``.
+# ``rows`` is None for a frame that stands alone, else the rows of a
+# delta against ``base``.
+LatestEncoder = Callable[[Any, Any], Tuple[bytes, Optional[int], Any]]
 
 # handler(payload: bytes, sender: (host, port), reply) -> None
 # ``reply(bytes)`` queues a frame back on the SAME connection (needed for
@@ -82,7 +85,7 @@ class MessageTransport:
                         "blob_frames_delta", "blob_frames_full"):
                 metrics.count(key, 0)
         # frames of a latest-wins slot are encoded at the sender's turn,
-        # against what THIS connection last carried (see LatestEncoder)
+        # against the base THIS connection last carried (see LatestEncoder)
         self._latest_encoder = latest_encoder
         self.node_config = node_config
         self.handler = handler
@@ -220,14 +223,14 @@ class MessageTransport:
         """Queue an item that SUPERSEDES a still-unsent item of the same
         ``slot`` to that node — for frames that carry a whole state, where
         only the newest matters (the consensus blob: the engine is built
-        for dropped and stale deliveries).  The item is turned into bytes
-        by ``latest_encoder`` when its turn to be written comes, against
-        what that connection last carried, so a peer that keeps up gets
-        what changed and a new connection gets the whole; the caller must
-        not write to the item afterwards.  CONGESTION_LIMIT counts
-        frames, and a whole blob is 17.8 MB at the deployed 65,536 rows:
-        at most one item per (peer, slot) waits, whatever the peer's
-        pace."""
+        for dropped and stale deliveries).  ``latest_encoder`` makes the
+        frame when its turn to be written comes, against the base that
+        connection last carried, so a peer that keeps up gets what
+        changed, one that fell behind the union of what it missed, and a
+        new connection the whole; a superseded item costs nothing.
+        CONGESTION_LIMIT counts frames, and a whole blob is 17.8 MB at
+        the deployed 65,536 rows: at most one item per (peer, slot)
+        waits, whatever the peer's pace."""
         if self._latest_encoder is None:
             raise ValueError("this transport was given no latest_encoder")
         if node_id not in self.node_config:
@@ -294,8 +297,9 @@ class MessageTransport:
     async def _sender(self, addr: Tuple[str, int], q: asyncio.Queue) -> None:
         """Per-peer writer with auto-reconnect (pending-writes analog)."""
         writer: Optional[asyncio.StreamWriter] = None
-        # slot -> the item last written and drained on `writer`: what the
-        # peer's reader holds once it has read this connection that far
+        # slot -> the base of the frame last written and drained on
+        # `writer`: what the peer's reader holds once it has read this
+        # connection that far
         bases: Dict[str, Any] = {}
         while not self._stopped:
             payload = await q.get()
@@ -341,12 +345,12 @@ class MessageTransport:
             self._base_forgotten.discard(addr)
             base = None
         with span(self.metrics, "blob.encode", node=self.my_id):
-            frame, rows = self._latest_encoder(item, base)
+            frame, rows, next_base = self._latest_encoder(item, base)
         # the span crosses awaits, so it carries no CPU time: other tasks
         # of this loop run meanwhile
         with span(self.metrics, "blob.send", node=self.my_id):
             await self._write(writer, frame)
-        bases[slot] = item
+        bases[slot] = next_base
         mx = self.metrics
         if mx is None:
             return

@@ -1151,3 +1151,62 @@ def digest_from_planes(out: StepOutputs, acc_slot: np.ndarray,
         *[getattr(out, f)[rows] for f in _DIGEST_OUT_PLANES],
         acc_slot[rows], acc_bal[rows], acc_vid[rows],
     )
+
+
+# ---------------------------------------------------------------------------
+# The blob news: the rows in which the fresh publish vector differs from
+# the one the host last received from this node's step — what the sender
+# used to find by comparing two 17.8 MB vectors for every peer, every
+# tick.  The ``published`` vector lives on the device beside the stack
+# (donated to the step, which hands the fresh one back in its place), so
+# it equals the host's mirror by construction, whatever rewrote a row
+# between two steps (a create, a kill, a restore, a state replaced).
+#
+# Vector layout (int32): n_changed, then an update's (``update_vec_len``):
+# C row indices ascending, G past the last, then those rows' words as a
+# packed vector of C rows in the wire layout.  More than C changed rows:
+# the count says so, and the host pulls the whole vector for that one.
+# ---------------------------------------------------------------------------
+
+def make_news(blob: Blob, published: jnp.ndarray,
+              cfg: EngineConfig) -> jnp.ndarray:
+    """Inside jit: the news vector of the fresh ``blob`` against the
+    packed ``published`` vector.  As :func:`make_digest`: one sort of [G]
+    keys names the rows, and their words are gathered a chunk of rows at
+    a time, as many chunks as hold them."""
+    G, C = cfg.n_groups, update_rows(cfg)
+    S = min(_DIGEST_CHUNK, C)
+    old = _unpack(published, Blob._fields, cfg, Blob, batched=False)
+    changed = jnp.zeros((G,), bool)
+    for new, was in zip(blob, old):
+        diff = new != was
+        changed |= diff if diff.ndim == 1 else diff.any(-1)
+    n = changed.sum(dtype=jnp.int32)
+    rows = jnp.sort(jnp.where(changed, jnp.arange(G, dtype=jnp.int32), G))[:C]
+    at = jnp.minimum(rows, G - 1)
+
+    def gather_chunk(i, leaves):
+        chunk = lax.dynamic_slice(at, (i * S,), (S,))
+        return tuple(
+            lax.dynamic_update_slice_in_dim(leaf, src[chunk], i * S, 0)
+            for leaf, src in zip(leaves, blob)
+        )
+
+    leaves = lax.fori_loop(
+        0, (jnp.minimum(n, C) + S - 1) // S, gather_chunk,
+        tuple(jnp.zeros((C,) + src.shape[1:], jnp.int32) for src in blob),
+    )
+    return jnp.concatenate(
+        [n[None], rows] + [jnp.ravel(leaf) for leaf in leaves])
+
+
+def split_news_vec(vec: np.ndarray, cfg: EngineConfig):
+    """Host-side: one transferred news vector -> (n_changed, the changed
+    rows ascending, the packed vector of ``update_rows`` rows whose first
+    ``len(rows)`` hold their words).  Where ``n_changed`` exceeds what
+    the vector holds, the rows are only the first of them: the caller
+    pulls the whole publish vector instead."""
+    C = update_rows(cfg)
+    vec = np.asarray(vec)
+    n = int(vec[0])
+    return n, vec[1:1 + min(n, C)], vec[1 + C:]

@@ -37,15 +37,21 @@ Two I/O flavors:
   donated and handed back), the tick's news as one
   fixed-shape update (``ops/engine.py:scatter_update``: the rows the
   peers' ``d`` frames named, scattered into the stack before it is
-  read), and a donated ``[G]`` int32 activity accumulator as the
-  trailing argument.  Returns ``(state', stack', out_rings [N, M],
-  blob_vec, heat', digests [N, L])`` — ``heat'`` is the accumulator plus
+  read), a donated ``[G]`` int32 activity accumulator, and as the
+  trailing argument the PUBLISHED vector: the packed blob the host last
+  received from this node's step, donated too.  Returns ``(state',
+  stack', out_rings [N, M], blob_vec, heat', digests [N, L], news)`` —
+  ``heat'`` is the accumulator plus
   ``n_committed + n_admitted`` of every substep (the host pulls it at
   the stats cadence, never per tick), ``digests`` per substep what the
   host's post-step reads (``ops/engine.py:make_digest``: the [G] output
   leaves, the busy rows of the [G, W] planes with their accept lanes of
   the new state, a work-in-flight flag), so that ``out_rings`` can stay
-  on the device.
+  on the device; ``news`` the rows in which ``blob_vec`` differs from
+  the published vector (``ops/engine.py:make_news``), so that
+  ``blob_vec`` stays there too: it is the next dispatch's published
+  vector, and the host pulls it only when more rows changed than the
+  news holds.
   Row ``my_id`` of the stack is never sent up: every substep takes it
   from the state it steps (at entry that is the row a host would have
   gathered, after a lifecycle operation included); peers' rows stay
@@ -75,6 +81,7 @@ from ..ops.engine import (
     StepOutputs,
     make_blob,
     make_digest,
+    make_news,
     out_vec_len,
     pack_blob,
     scatter_update,
@@ -243,7 +250,7 @@ def _build_packed(cfg: EngineConfig, mesh: Optional[Mesh], n_steps: int,
     if n_steps == 1:
         # one scatter of the tick's news, one step, three downloads
         def run_heat(state, stack, upd, heard, req_ring, want_coord, my_id,
-                     heat_acc):
+                     heat_acc, published):
             state = _constrain(mesh, state, GROUP_AXIS)
             stack = with_my_row(
                 scatter_update(stack, upd, cfg), state, my_id)
@@ -256,15 +263,16 @@ def _build_packed(cfg: EngineConfig, mesh: Optional[Mesh], n_steps: int,
                 GROUP_AXIS,
             )
             out_rings = _pack_out(out)[None]
-            blob_vec = pack_blob(make_blob(new_state))
+            blob = make_blob(new_state)
             return (
                 _constrain(mesh, new_state, GROUP_AXIS), stack,
-                out_rings, blob_vec, heat_acc,
+                out_rings, pack_blob(blob), heat_acc,
                 make_digest(out, new_state, cfg)[None],
+                make_news(blob, published, cfg),
             )
     else:
         def run_heat(state, stack, upd, heard, req_ring, want_coord, my_id,
-                     heat_acc):
+                     heat_acc, published):
             state = _constrain(mesh, state, GROUP_AXIS)
             heat_acc = _constrain(mesh, heat_acc, GROUP_AXIS)
             stack = scatter_update(stack, upd, cfg)
@@ -291,7 +299,7 @@ def _build_packed(cfg: EngineConfig, mesh: Optional[Mesh], n_steps: int,
             new_state, stack, out_rings, heat_acc = lax.fori_loop(
                 0, n_steps, body, (state, stack, out0, heat_acc)
             )
-            blob_vec = pack_blob(make_blob(new_state))
+            blob = make_blob(new_state)
             # one digest row per substep, each against the dispatch's
             # final state: the journal values an accepted lane from it
             digests = jax.vmap(
@@ -299,13 +307,17 @@ def _build_packed(cfg: EngineConfig, mesh: Optional[Mesh], n_steps: int,
             )(out_rings)
             return (
                 _constrain(mesh, new_state, GROUP_AXIS), stack, out_rings,
-                blob_vec, _constrain(mesh, heat_acc, GROUP_AXIS), digests,
+                pack_blob(blob), _constrain(mesh, heat_acc, GROUP_AXIS),
+                digests,
+                # the dispatch's news, once: the final state's blob
+                make_news(blob, published, cfg),
             )
 
-    # the gathered stack and the accumulator ride the dispatch like
-    # state leaves (donated alongside them); the accumulator is pulled
-    # host-side only at the stats cadence, the stack never
-    return jax.jit(run_heat, donate_argnums=(0, 1, 7) if donate else ())
+    # the gathered stack, the accumulator and the published vector ride
+    # the dispatch like state leaves (donated alongside them); the
+    # accumulator is pulled host-side only at the stats cadence, the
+    # stack never, the fresh vector only when its news does not fit
+    return jax.jit(run_heat, donate_argnums=(0, 1, 7, 8) if donate else ())
 
 
 @functools.lru_cache(maxsize=None)

@@ -17,7 +17,6 @@ transport).  Each server runs:
 
 from __future__ import annotations
 
-import functools
 import logging
 import threading
 import time
@@ -34,7 +33,6 @@ from .net.codec import (
     decode_blob_vec,
     decode_json,
     decode_kind,
-    encode_blob_frame,
     encode_json,
     extract_trace,
     patch_blob_vec,
@@ -83,9 +81,9 @@ class PaxosServer:
             ssl_server_context=ssl_server, ssl_client_context=ssl_client,
             metrics=self.manager.metrics,
             # a blob is encoded when its turn to be written comes, as the
-            # rows that differ from what that connection last carried
-            latest_encoder=functools.partial(
-                encode_blob_frame, self.my_id, cfg),
+            # rows the manager's mirror of my publish vector has changed
+            # since the tick that connection last carried
+            latest_encoder=self.manager.mirror.encode,
         )
         # per-plane port split (PaxosConfig.java:219-224): when
         # CLIENT_SSL_MODE is set, clients speak to a SEPARATE listener at
@@ -988,10 +986,10 @@ class PaxosServer:
         self._publish_pending()
         self._flush_responses()
         overlap_s = time.perf_counter() - t_overlap
-        blob_vec, _state, delta = m.step_complete(pend)
+        _tick, _state, delta = m.step_complete(pend)
         m.metrics.observe("pipeline_overlap_s", overlap_s)
         with m._span("tick.finish"):
-            self._finish_tick(blob_vec, delta)
+            self._finish_tick(delta)
             self._drain_self_msgs()
         if not m.has_backlog():
             # the loop is about to go idle: publish this tick now —
@@ -1003,7 +1001,7 @@ class PaxosServer:
             self._layer_tick()
         self._flush_responses()  # callbacks fired by this tick's execution
 
-    def _finish_tick(self, blob_vec, delta) -> None:
+    def _finish_tick(self, delta) -> None:
         """Post-step bookkeeping: stage this tick's
         outbound frames (blob / payload delta / forwards) for
         :meth:`_publish_pending`."""
@@ -1033,8 +1031,9 @@ class PaxosServer:
             time.monotonic() - self._last_publish > self.IDLE_REPUBLISH_S
         )
         self._pub = {
-            "blob_vec": blob_vec if publish_blob else None,
-            "tick": self._tick,
+            # a marker: the frame is cut from the manager's mirror, which
+            # holds this tick's vector already, when its turn comes
+            "blob": publish_blob,
             "delta": delta if (
                 delta["arena"] or delta.get("app_exec")
             ) else None,
@@ -1064,15 +1063,14 @@ class PaxosServer:
         peers = [r for r in self.node_config.get_node_ids()
                  if r != self.my_id]
         with self.manager._span("publish"):
-            if pub["blob_vec"] is not None:
+            if pub["blob"]:
                 self._last_publish = time.monotonic()
-                # counted as QUEUED, one per peer: a vector superseded
+                # counted as QUEUED, one per peer: a marker superseded
                 # before its turn is in here (its bytes exist only once a
                 # frame is encoded, and are counted there: net/transport.py)
                 self.manager.metrics.count("blob_frames_sent", len(peers))
-                item = (pub["tick"], pub["blob_vec"])
                 for r in peers:
-                    self.transport.send_latest_to_id(r, "blob", item)
+                    self.transport.send_latest_to_id(r, "blob", self._tick)
             if pub["delta"] is not None:
                 frame = encode_json("payloads", self.my_id, pub["delta"])
                 for r in peers:

@@ -174,10 +174,13 @@ class ManagerCluster:
                 )
             if self.pipelined:
                 pend = m.step_dispatch(update, heard, want)
-                new_vecs[i], _state, delta = m.step_complete(pend)
+                _tick, _state, delta = m.step_complete(pend)
             else:
-                new_vecs[i], _state, delta = m.tick_host(
-                    update, heard, want)
+                _tick, _state, delta = m.tick_host(update, heard, want)
+            # what a node's peers are sent is cut from its mirror, which
+            # the step's news has just patched; this round's stays as it
+            # is while the later replicas of the round step
+            new_vecs[i] = m.mirror.vec.copy()
             deltas.append(delta)
         self.vecs = new_vecs
 

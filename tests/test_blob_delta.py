@@ -1,8 +1,13 @@
 """The blob exchange sends what changed: ``d`` frames carry the rows that
-differ from the vector the sender last wrote to THAT connection, the
+changed since the tick the sender last wrote to THAT connection, the
 receiver patches them into the vector it holds, and after every frame it
 accepts its copy equals the sender's publish vector bit for bit.  Full
-``D`` frames open every connection and are chosen by what the code sees."""
+``D`` frames open every connection and are chosen by what the code sees.
+
+The sender finds the rows in its mirror's ``[G]`` row ticks
+(``net/mirror.py``: the step named them); ``codec.encode_blob_frame``,
+which finds them by comparing two whole vectors, is the reference its
+frames are held against byte for byte."""
 
 import functools
 import threading
@@ -13,6 +18,7 @@ import pytest
 
 from gigapaxos_tpu.models import StatefulAdderApp
 from gigapaxos_tpu.net.codec import (
+    changed_rows,
     decode_blob_delta,
     decode_blob_vec,
     decode_json,
@@ -20,7 +26,9 @@ from gigapaxos_tpu.net.codec import (
     encode_blob_frame,
     encode_blob_vec,
     patch_blob_vec,
+    rows_of,
 )
+from gigapaxos_tpu.net.mirror import PublishMirror
 from gigapaxos_tpu.net.node_config import NodeConfig
 from gigapaxos_tpu.net.transport import MessageTransport
 from gigapaxos_tpu.obs.metrics import MetricsRegistry
@@ -57,6 +65,17 @@ def touch_rows(rng, vec, rows):
     return out
 
 
+def publish(mirror, new):
+    """What a completion does with a step's news — the rows found here by
+    the compare the step spares the host.  -> the mirror's tick."""
+    if mirror.vec is None:
+        mirror.replace(new.copy())
+    else:
+        rows = changed_rows(new, mirror.vec, CFG)
+        mirror.patch(rows, rows_of(new, rows, CFG))
+    return mirror.tick
+
+
 def receiver(cfg=CFG):
     """Node 1 of three as a server that is never started: its ingress and
     its gather are driven by hand (no tick thread, no listener)."""
@@ -86,10 +105,10 @@ def test_receiver_equals_sender_after_every_applied_frame(seed):
     nc = NodeConfig({0: ("127.0.0.1", 0),
                      1: ("127.0.0.1", srv.transport.listen_port)})
     reg = MetricsRegistry(node=0)
+    mirror = PublishMirror(CFG, 0)
     sender = MessageTransport(
         0, nc, lambda *a: None, listen_host="127.0.0.1", listen_port=0,
-        metrics=reg,
-        latest_encoder=functools.partial(encode_blob_frame, 0, CFG))
+        metrics=reg, latest_encoder=mirror.encode)
     published = {}  # tick -> the vector published at it
     wrong = []
     inner = srv._on_message
@@ -103,16 +122,18 @@ def test_receiver_equals_sender_after_every_applied_frame(seed):
             wrong.append((decode_kind(payload), tick))
 
     srv.transport.handler = checked
-    tick, vec = 0, random_vec(rng)
+    vec = random_vec(rng)
 
-    def publish(new):
-        nonlocal tick, vec
-        tick, vec = tick + 1, new
-        published[tick] = new
-        assert sender.send_latest_to_id(1, "blob", (tick, new))
+    def send(new):
+        nonlocal vec
+        vec = new
+        # known before an encoder can cut a frame of that tick: a marker
+        # still waiting for its turn is served from the mirror as it is
+        published[mirror.tick + 1] = new
+        assert sender.send_latest_to_id(1, "blob", publish(mirror, new))
 
     def settled():
-        return srv._peer_blob_tick.get(0) == tick
+        return srv._peer_blob_tick.get(0) == mirror.tick
 
     try:
         sender.start()
@@ -120,14 +141,14 @@ def test_receiver_equals_sender_after_every_applied_frame(seed):
             op = rng.integers(10)
             if op < 5:  # a tick's worth: a few rows, sometimes none
                 k = int(rng.integers(0, 6))
-                publish(touch_rows(
+                send(touch_rows(
                     rng, vec, rng.choice(CFG.n_groups, k, replace=False)))
             elif op == 5:  # a burst: the slot is replaced before its turn
                 for _ in range(4):
-                    publish(touch_rows(rng, vec, rng.choice(
+                    send(touch_rows(rng, vec, rng.choice(
                         CFG.n_groups, 3, replace=False)))
             elif op == 6:  # state replaced outside the tick: every row
-                publish(random_vec(rng))
+                send(random_vec(rng))
             elif op == 7:  # the connection goes
                 for w in list(sender._writers.values()):
                     sender._loop.call_soon_threadsafe(w.close)
@@ -137,15 +158,17 @@ def test_receiver_equals_sender_after_every_applied_frame(seed):
                 assert wait_until(settled), "the newest vector never came"
             if rng.integers(3) == 0:
                 time.sleep(0.002)
-        publish(touch_rows(rng, vec, [0]))
+        send(touch_rows(rng, vec, [0]))
         if not wait_until(settled, timeout=2):
             # a frame written into a connection cut under it is gone, as
             # on any network; the next one opens a new connection
-            publish(touch_rows(rng, vec, [1]))
+            send(touch_rows(rng, vec, [1]))
             assert wait_until(settled), "the newest vector never came"
         assert not wrong, wrong
-        s2, t2, full = decode_blob_vec(encode_blob_vec(0, tick, vec), CFG)
+        s2, t2, full = decode_blob_vec(
+            encode_blob_vec(0, mirror.tick, vec), CFG)
         np.testing.assert_array_equal(srv._peer_blobs[0], full)
+        np.testing.assert_array_equal(mirror.vec, full)
         c = reg.snapshot()["counters"]
         assert c["blob_frames_delta"] > 0 and c["blob_frames_full"] > 0
         assert c["blob_frames_delta"] + c["blob_frames_full"] \
@@ -255,23 +278,36 @@ def test_reader_racing_the_patch_never_sees_a_row_of_two_ticks():
 
 # ---- (4) the size rule, observed and never switched --------------------
 def test_size_rule_all_rows_full_none_header_only():
+    """The rule's three cases, from the mirror's encoder and from the
+    reference that compares two vectors: the same bytes."""
     rng = np.random.default_rng(7)
-    enc = functools.partial(encode_blob_frame, 0, CFG)
     v1 = random_vec(rng)
+
+    def enc(new):
+        """The frame for a connection at tick 1 = v1, once ``new`` is
+        published at tick 2."""
+        mirror = PublishMirror(CFG, 0)
+        assert (publish(mirror, v1), publish(mirror, new)) == (1, 2)
+        frame, rows, tick = mirror.encode(None, 1)
+        assert tick == 2
+        assert (frame, rows) == encode_blob_frame(0, CFG, (2, new), (1, v1))
+        return frame, rows
+
     # every row changed: the delta would be larger, so a D frame
-    frame, rows = enc((2, v1 + 1), (1, v1))
+    frame, rows = enc(v1 + 1)
     assert decode_kind(frame) == "D" and rows is None
     assert len(frame) == FULL_BYTES
     # the largest delta that is still smaller, and the first that is not
     most = (FULL_BYTES - 25 - 1) // (4 * (1 + ROW_WORDS))
-    frame, rows = enc((2, touch_rows(rng, v1, range(most))), (1, v1))
+    frame, rows = enc(touch_rows(rng, v1, range(most)))
     assert decode_kind(frame) == "d" and rows == most
     assert len(frame) < FULL_BYTES
-    frame, rows = enc((2, touch_rows(rng, v1, range(most + 1))), (1, v1))
+    frame, rows = enc(touch_rows(rng, v1, range(most + 1)))
     assert decode_kind(frame) == "D" and rows is None
     # nothing changed: a header, and still a blob to the receiver
-    frame, rows = enc((2, v1.copy()), (1, v1))
+    frame, rows = enc(v1.copy())
     assert decode_kind(frame) == "d" and rows == 0 and len(frame) == 25
+    enc = functools.partial(encode_blob_frame, 0, CFG)
     sender, tick, base_tick, idx, blocks = decode_blob_delta(frame, CFG)
     assert (sender, tick, base_tick, len(idx)) == (0, 2, 1, 0)
     srv, _ = receiver()
@@ -317,7 +353,165 @@ def test_patch_equals_the_full_frame_leaf_by_leaf():
         decode_blob_delta(bytes(bad), CFG)
 
 
-# ---- (5) three nodes on loopback ---------------------------------------
+# ---- (5) the sender's base is a tick ------------------------------------
+@pytest.mark.parametrize("k", [1, 2, 5])
+def test_a_connection_superseded_for_k_ticks_gets_the_union_of_their_rows(k):
+    """A peer that missed k ticks is sent every row any of them changed,
+    at its newest value, in ONE ``d`` frame — the bytes the reference
+    cuts from the two vectors the connection's ticks stand for."""
+    rng = np.random.default_rng(100 + k)
+    mirror = PublishMirror(CFG, 2)
+    vec = random_vec(rng)
+    base = publish(mirror, vec)
+    base_vec = vec.copy()
+    touched = set()
+    for _ in range(k):
+        rows = rng.choice(CFG.n_groups, 4, replace=False)
+        touched |= set(int(g) for g in rows)
+        vec = touch_rows(rng, vec, rows)
+        publish(mirror, vec)
+    frame, n, tick = mirror.encode("marker", base)
+    assert (n, tick) == (len(touched), base + k)
+    assert (frame, n) == encode_blob_frame(
+        2, CFG, (tick, vec), (base, base_vec))
+    sender, t, base_tick, idx, blocks = decode_blob_delta(frame, CFG)
+    assert (sender, t, base_tick) == (2, base + k, base)
+    assert idx.tolist() == sorted(touched)
+    patch_blob_vec(base_vec, idx, blocks, CFG)
+    np.testing.assert_array_equal(base_vec, vec)
+    # a connection that kept up is sent the last tick's rows alone, and
+    # one that holds this very tick a header
+    assert mirror.encode("marker", tick - 1)[1] == (4 if k > 1 else n)
+    frame, n, t2 = mirror.encode("marker", tick)
+    assert (len(frame), n, t2) == (25, 0, tick)
+
+
+def test_new_connection_and_forgotten_base_get_the_whole_vector():
+    """Through a real transport: the first frame on a connection is a
+    ``D`` frame, the next a ``d`` frame against its tick; after
+    ``forget_latest_base`` (the peer's ``blob_resync``) and after a
+    reconnect the vector goes whole again.  A marker superseded before
+    its turn costs no frame."""
+    rng = np.random.default_rng(77)
+    got = []
+    rx = MessageTransport(
+        1, NodeConfig({1: ("127.0.0.1", 0)}),
+        lambda payload, peer, reply: got.append(payload),
+        listen_host="127.0.0.1", listen_port=0)
+    rx.start()
+    nc = NodeConfig({0: ("127.0.0.1", 0), 1: ("127.0.0.1", rx.listen_port)})
+    reg = MetricsRegistry(node=0)
+    mirror = PublishMirror(CFG, 0)
+    tx = MessageTransport(0, nc, lambda *a: None, listen_host="127.0.0.1",
+                          listen_port=0, metrics=reg,
+                          latest_encoder=mirror.encode)
+    vec = random_vec(rng)
+
+    def send(rows):
+        nonlocal vec
+        vec = touch_rows(rng, vec, rows)
+        n = len(got)
+        assert tx.send_latest_to_id(1, "blob", publish(mirror, vec))
+        assert wait_until(lambda: len(got) > n)
+        return got[-1]
+
+    try:
+        tx.start()
+        first = send([1])
+        assert first == encode_blob_vec(0, 1, vec)
+        delta = send([2, 3])
+        assert decode_kind(delta) == "d"
+        assert decode_blob_delta(delta, CFG)[1:3] == (2, 1)
+        tx.forget_latest_base(1)
+        assert send([4]) == encode_blob_vec(0, 3, vec)
+        assert decode_blob_delta(send([5]), CFG)[1:3] == (4, 3)
+        for w in list(tx._writers.values()):
+            tx._loop.call_soon_threadsafe(w.close)
+        time.sleep(0.05)
+        # written into the cut connection or onto a new one: either way
+        # the next frame the peer reads that names tick 6 or later came
+        # over a new connection and stands alone
+        n = len(got)
+        send([6])
+        if decode_kind(got[-1]) != "D":
+            send([7])
+        assert any(decode_kind(f) == "D" for f in got[n:])
+        c = reg.snapshot()["counters"]
+        assert c["blob_frames_full"] >= 3 and c["blob_frames_delta"] >= 2
+    finally:
+        tx.stop()
+        rx.stop()
+
+
+# ---- (6) an encoder racing the patch ------------------------------------
+def test_encoder_racing_the_patch_never_frames_a_row_of_two_ticks():
+    """The sender-side twin of (3): every word of a row carries the tick
+    that wrote it, one thread patches the mirror as completions do, and
+    the frames cut meanwhile — deltas against bases that fall behind,
+    whole vectors — must show every row uniform, no row newer than the
+    frame's tick, and every delta row newer than its base."""
+    mirror = PublishMirror(CFG, 0)
+    mirror.replace(np.ones(N, np.int32))  # tick 1, every word 1
+    stop = threading.Event()
+
+    def writer():
+        rng = np.random.default_rng(11)
+        while not stop.is_set():
+            tick = mirror.tick + 1
+            rows = np.sort(rng.choice(CFG.n_groups, 8, replace=False)
+                           ).astype(np.int32)
+            words = [np.full((4, 8, w), tick, np.int32)
+                     for w in (1, CFG.window)]
+            mirror.patch(rows, words)  # a row is written whole
+
+    def by_row(vec):
+        return np.concatenate([leaf.reshape(CFG.n_groups, -1)
+                               for leaf in split_blob_vec(vec, CFG)], axis=1)
+
+    t = threading.Thread(target=writer, daemon=True)
+    held, base = None, None
+    seen, kinds = set(), {"d": 0, "D": 0}
+    try:
+        t.start()
+        assert wait_until(lambda: mirror.tick > 3)
+        for i in range(400):
+            if i % 50 == 49:
+                base = None  # a new connection now and then
+            frame, n, tick = mirror.encode(None, base)
+            kind = decode_kind(frame)
+            kinds[kind] += 1
+            if kind == "D":
+                _s, t_frame, vec = decode_blob_vec(frame, CFG)
+                held = vec.copy()
+            else:
+                _s, t_frame, base_tick, rows, blocks = decode_blob_delta(
+                    frame, CFG)
+                assert base_tick == base and n == rows.size
+                if n:
+                    words = np.concatenate(
+                        [b.transpose(1, 0, 2).reshape(n, -1)
+                         for b in blocks], axis=1)
+                    assert words.shape == (n, ROW_WORDS)
+                    assert (words.min(axis=1) == words.max(axis=1)).all(), \
+                        "a row mixed from two ticks"
+                    assert base < words.min() and words.max() <= tick
+                patch_blob_vec(held, rows, blocks, CFG)
+            assert t_frame == tick
+            rows_now = by_row(held)
+            assert (rows_now.min(axis=1) == rows_now.max(axis=1)).all()
+            # the newest row IS the frame's tick: nothing was left out
+            assert rows_now.max() == tick
+            seen.add(tick)
+            base = tick
+            if i % 7 == 0:
+                time.sleep(0.001)  # fall a few ticks behind
+        assert len(seen) > 20 and kinds["d"] > 300 and kinds["D"] >= 8
+    finally:
+        stop.set()
+        t.join(10)
+
+
+# ---- (7) three nodes on loopback ---------------------------------------
 @pytest.mark.timeout(180)
 def test_three_nodes_commit_over_delta_frames():
     from gigapaxos_tpu.clients import PaxosClientAsync
@@ -328,7 +522,50 @@ def test_three_nodes_commit_over_delta_frames():
     nc = NodeConfig({i: ("127.0.0.1", p) for i, p in enumerate(ports)})
     servers = [PaxosServer(i, nc, StatefulAdderApp(), cfg,
                            tick_interval=0.01) for i in range(3)]
-    for s in servers:
+    # the property of (1) on the served path: whatever a node accepts of
+    # a peer's frame leaves it with the vector that peer's mirror held at
+    # the frame's tick (kept here as the mirror moves on)
+    history = [{} for _ in servers]
+    wrong, checked = [], [0]
+
+    def remember(i, mirror):
+        # known BEFORE the mirror shows it: a frame of that tick can be
+        # cut, sent and checked before the patching thread runs again
+        patch, replace = mirror.patch, mirror.replace
+
+        def kept_patch(rows, blocks):
+            vec = mirror.vec.copy()
+            patch_blob_vec(vec, rows, blocks, cfg)
+            history[i][mirror.tick + 1] = vec
+            patch(rows, blocks)
+
+        def kept_replace(vec):
+            history[i][mirror.tick + 1] = vec.copy()
+            replace(vec)
+
+        mirror.patch, mirror.replace = kept_patch, kept_replace
+
+    def check(srv):
+        inner = srv._on_blob
+
+        def on_blob(kind, payload):
+            inner(kind, payload)
+            sender = (decode_blob_vec if kind == "D" else decode_blob_delta
+                      )(payload, cfg)[0]
+            with srv._blob_lock:
+                tick = srv._peer_blob_tick.get(sender)
+                held = srv._peer_blobs[sender].copy() \
+                    if tick is not None else None
+            if tick is not None:
+                checked[0] += 1
+                if not np.array_equal(held, history[sender].get(tick)):
+                    wrong.append((srv.my_id, sender, kind, tick))
+
+        srv._on_blob = on_blob
+
+    for i, s in enumerate(servers):
+        remember(i, s.manager.mirror)
+        check(s)
         s.start()
     client = PaxosClientAsync([("127.0.0.1", p) for p in ports])
     try:
@@ -338,6 +575,7 @@ def test_three_nodes_commit_over_delta_frames():
                 "dl0", "1", timeout=30, server=k % 3) == str(k + 1)
         assert wait_until(lambda: all(
             s.manager.app.totals.get("dl0") == 20 for s in servers))
+        assert not wrong and checked[0] > 100, (wrong[:5], checked)
         for s in servers:
             snap = s.manager.metrics.snapshot()  # one: the nodes run on
             c, h = snap["counters"], snap["hists"]
@@ -352,8 +590,10 @@ def test_three_nodes_commit_over_delta_frames():
             per_frame = c["blob_bytes_written"] / c["blob_frames_written"]
             assert per_frame < full / 8, (per_frame, full)
             assert h["blob_delta_rows"]["max"] < 16
-            assert h["phase_blob_encode_s"]["count"] \
-                == c["blob_frames_written"]
+            # every frame written was encoded at its turn (the nodes run
+            # on: one frame a peer may be between its encode and its drain)
+            assert 0 <= h["phase_blob_encode_s"]["count"] \
+                - c["blob_frames_written"] <= 2
     finally:
         client.close()
         for s in servers:

@@ -98,16 +98,20 @@ def test_packed_host_step_compiles_and_fits_one_chip(one_chip, G, W, K):
         sds((update_vec_len(cfg),), jnp.int32), sds((R,), jnp.bool_),
         sds((1, G, K), jnp.int32), sds((G,), jnp.bool_),
         sds((), jnp.int32), sds((G,), jnp.int32),
+        sds((blob_vec_len(cfg),), jnp.int32),
     )
     compiled = step.lower(state, stack, *args).compile()
     need = _dispatch_bytes(compiled)
     assert 0 < need < HBM_BYTES, need
-    # state, stack and heat are donated: their buffers are aliased into
-    # the results — the stack's share is its whole size
+    # state, stack, heat and the published vector are donated: their
+    # buffers are aliased into the results — the stack's share is its
+    # whole size, and the fresh blob comes back where the published
+    # vector went in
     aliased = compiled.memory_analysis().alias_size_in_bytes
     state_bytes = sum(
         4 * int(np.prod(x.shape)) for x in jax.tree.leaves(state))
-    assert aliased >= state_bytes + stack_bytes, (aliased, stack_bytes)
+    assert aliased >= state_bytes + stack_bytes + 4 * blob_vec_len(cfg), (
+        aliased, stack_bytes)
 
 
 @pytest.mark.parametrize("G,W,K", [(65_536, 16, 8), (1_048_576, 32, 16)])

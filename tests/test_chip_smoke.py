@@ -38,8 +38,12 @@ def test_engine_parity_phase_cpu_vs_cpu(smoke):
     )
     json.dumps(line)  # every phase prints its line as JSON
     assert line["bit_exact"] and line["decided"] > 0
-    # state, blob, heat, digest, and the eight leaves of the stack
-    assert line["leaves_compared"] == 3 * (19 + 3 + 8)
+    # state, blob, heat, digest, the blob's news, and the eight leaves
+    # of the stack
+    assert line["leaves_compared"] == 3 * (19 + 4 + 8)
+    # every step's news was held against the host's compare of the two
+    # vectors, the rows paused and restored between steps among them
+    assert line["blob_news_rows"] > 16
     assert line["gather_updates"]["whole"] >= 3
     assert line["compile"]["retraces"] == 0
 

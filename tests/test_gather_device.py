@@ -296,10 +296,10 @@ SMALL = EngineConfig(n_groups=16, window=8, req_lanes=4, n_replicas=3)
 def _watch(m, seen):
     orig = m._complete_locked
 
-    def wrapped(pend, digest_np, blob_np):
+    def wrapped(pend, digest_np, news_np, whole):
         seen.append((np.asarray(pend["out_vec"]).copy(), digest_np.copy(),
-                     blob_np.copy()))
-        return orig(pend, digest_np, blob_np)
+                     np.array(pend["blob_vec"]), news_np.copy()))
+        return orig(pend, digest_np, news_np, whole)
 
     m._complete_locked = wrapped
 
@@ -361,10 +361,10 @@ def test_garbage_in_an_unheard_peers_row_changes_no_leaf_and_no_output(
                     ), (step_no, a.my_id, leaf)
         for sa, sb in zip(seen[id(clean)], seen[id(dirty)]):
             assert len(sa) == len(sb) == 36
-            for (oa, da, ba), (ob, db, bb) in zip(sa, sb):
-                assert np.array_equal(oa, ob)
-                assert np.array_equal(da, db)
-                assert np.array_equal(ba, bb)
+            for a, b in zip(sa, sb):  # out, digest, blob, its news
+                assert len(a) == len(b) == 4
+                for va, vb in zip(a, b):
+                    assert np.array_equal(va, vb)
         assert sorted(resp[id(clean)], key=str) \
             == sorted(resp[id(dirty)], key=str)
         assert len(resp[id(clean)]) >= 5  # the schedule decided things

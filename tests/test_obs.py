@@ -678,7 +678,7 @@ def test_superseded_blob_is_counted_and_never_as_written():
 
     sender = MessageTransport(0, nc, lambda *a: None, metrics=reg,
                               listen_host="127.0.0.1", listen_port=0,
-                              latest_encoder=lambda item, base: (item, None))
+                              latest_encoder=lambda item, base: (item, None, item))
     reader = MessageTransport(1, nc, slow_reader,
                               listen_host="127.0.0.1", listen_port=0)
     try:

@@ -37,9 +37,9 @@ class Twin:
         for m in self.c.managers:
             whole = m._complete_locked
 
-            def spy(pend, digest_np, blob_np, _whole=whole):
+            def spy(pend, digest_np, *news, _whole=whole):
                 self.digests.append(np.array(digest_np))
-                return _whole(pend, digest_np, blob_np)
+                return _whole(pend, digest_np, *news)
 
             m._complete_locked = spy
 

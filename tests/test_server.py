@@ -241,7 +241,7 @@ def test_latest_wins_send_keeps_one_frame_waiting_per_peer():
     # the slot holds items; this encoder's frames all stand alone
     sender = MessageTransport(0, nc, lambda *a: None,
                               listen_host="127.0.0.1", listen_port=0,
-                              latest_encoder=lambda item, base: (item, None))
+                              latest_encoder=lambda item, base: (item, None, item))
     reader = MessageTransport(1, nc, slow_reader,
                               listen_host="127.0.0.1", listen_port=0)
     try:

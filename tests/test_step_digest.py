@@ -50,7 +50,7 @@ def _watch(m, seen):
     dispatch, at the moment the post-step is handed it."""
     orig = m._complete_locked
 
-    def wrapped(pend, digest_np, blob_np):
+    def wrapped(pend, digest_np, *news):
         out_rows = np.asarray(pend["out_vec"])
         planes = _state_np(m, "acc_slot", "acc_bal", "acc_vid")
         for i, row in enumerate(digest_np):
@@ -70,7 +70,7 @@ def _watch(m, seen):
             seen["busy"] += n_busy
             seen["multi_slot"] += int((out.n_committed > 1).sum())
             seen["live"].add(got.live)
-        result = orig(pend, digest_np, blob_np)
+        result = orig(pend, digest_np, *news)
         assert m.engine_work_in_flight() == _old_work_in_flight(m)
         return result
 

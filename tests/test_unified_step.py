@@ -205,10 +205,11 @@ def test_packed_flavor_frozen_peer_parity():
     # (held rows minor, as ops/engine.py:init_stack lays it out)
     stack = stack_blob(jax.tree.map(lambda leaf: leaf.at[my_id].set(12345), g0))
     fn = make_step(cfg, None, N, donate=False, io="packed_host")
-    st_u, stack_u, out_rings, blob_vec, _heat, _digests = fn(
+    published = pack_blob(make_blob(per[my_id]))
+    st_u, stack_u, out_rings, blob_vec, _heat, _digests, news = fn(
         per[my_id], stack, jnp.asarray(empty_update_vec(cfg)), heard,
         jnp.asarray(np.stack(reqs)), want,
-        jnp.int32(my_id), jnp.zeros((8,), jnp.int32),
+        jnp.int32(my_id), jnp.zeros((8,), jnp.int32), published,
     )
     _assert_trees_equal(st, st_u, "state")
     _assert_trees_equal(g, stack_blob(stack_u), "stack")  # the last substep's
@@ -218,6 +219,19 @@ def test_packed_flavor_frozen_peer_parity():
         u_out = split_out_vec(rows[i], cfg)
         _assert_trees_equal(g_out, u_out, f"out_ring[{i}]")
     np.testing.assert_array_equal(golden_blob, np.asarray(blob_vec))
+    # the dispatch's news, once, against the final state: the rows in
+    # which the fresh vector differs from the published one
+    from gigapaxos_tpu.net.codec import changed_rows, rows_of
+    from gigapaxos_tpu.net.mirror import news_blocks
+    from gigapaxos_tpu.ops.engine import split_news_vec
+
+    n_news, rows, body = split_news_vec(np.asarray(news), cfg)
+    want_rows = changed_rows(golden_blob, np.asarray(published), cfg)
+    assert n_news == want_rows.size > 0
+    np.testing.assert_array_equal(rows, want_rows)
+    for got, exp in zip(news_blocks(body, n_news, cfg),
+                        rows_of(golden_blob, want_rows, cfg)):
+        np.testing.assert_array_equal(got, exp)
     # peers are frozen for the whole dispatch, so commits need a later
     # exchange — ADMISSION is the local progress that proves the ring
     # slabs actually fed the substeps
