@@ -14,6 +14,11 @@ process:
   engine at the deployed defaults; names created and written through
   ``ReconfigurableAppClient`` over the binary client frames; every
   acknowledged write read back from the app of each of the three actives.
+  After the first round of writes active 1 goes dark for ten seconds (the
+  ``crash`` admin op, upstream's emulated crash) and the second round is
+  written into its absence: detection, election, the forwards it took
+  with it, its return, resync and catch-up run on the chip, the read-back
+  is from the returned node too, and nothing may compile after boot.
 
 ``python chip_smoke.py --chips 4`` needs four chips and runs only the
 ``('g',)``-sharded step against the unsharded step on one of the chips.
@@ -451,13 +456,26 @@ def _write_round(client, targets, items, timeout_s: float):
     return resp, resent
 
 
+def _compiles(ars) -> list:
+    """Per active: every compile its manager's programs have made (the
+    step, the lifecycle scatters, the whole-row program)."""
+    return [sum(c["compiles"] + c["retraces"]
+                for c in s.manager.engine_compile_stats().values())
+            for s in ars]
+
+
 def phase_served(n_names: int, writes_per_name: int, engine_rows: int,
                  window: int, seed: int, expect_platform: str,
-                 timeout_s: float = 600.0) -> dict:
+                 timeout_s: float = 600.0, crash_s: float = 10.0,
+                 fd_timeout_s: float = 6.0) -> dict:
     """Boot the scenario's six names in this process, create ``n_names``
     names on all three actives, write ``writes_per_name`` deltas to each,
     and read the per-name sum of acknowledged deltas back from the app
-    of every active."""
+    of every active.  After the first round active 1 goes dark for
+    ``crash_s`` seconds (the ``crash`` admin op): the second round is
+    written into its absence — the writes it led wait for the election,
+    those that enter at it for its return — and the read-back at the end
+    is from the returned node too, with no program compiled since boot."""
     import jax
 
     from gigapaxos_tpu.clients.reconfigurable_client import (
@@ -483,6 +501,8 @@ def phase_served(n_names: int, writes_per_name: int, engine_rows: int,
     # idle for DEACTIVATION_PERIOD_S (60 s by default), which checkpoints
     # its state out of the app — and the read-back below is from the app
     Config.set("DEACTIVATION_PERIOD_S", str(2 * timeout_s))
+    Config.set("ALLOW_CRASH_EMULATION", "true")
+    Config.set("FAILURE_DETECTION_TIMEOUT_S", str(fd_timeout_s))
 
     rng = np.random.default_rng(seed)
     names = [f"smoke{i:05d}" for i in range(n_names)]
@@ -497,6 +517,7 @@ def phase_served(n_names: int, writes_per_name: int, engine_rows: int,
         compiles_at_boot = [
             s.manager._dispatch_step.n_compiles for s in ars
         ]
+        programs_at_boot = _compiles(ars)
         client = ReconfigurableAppClient.from_properties()
 
         t0 = time.perf_counter()
@@ -525,8 +546,17 @@ def phase_served(n_names: int, writes_per_name: int, engine_rows: int,
             for i, n in enumerate(names)
         }
         resent = 0
+        crash = None
         t0 = time.perf_counter()
-        for _ in range(writes_per_name):
+        for round_no in range(writes_per_name):
+            if round_no == 1 and crash_s > 0:
+                answer = client.admin_sync(
+                    1, {"op": "crash", "for_s": crash_s}, timeout=30)
+                if not (answer or {}).get("ok"):
+                    raise AssertionError(f"crash op refused: {answer}")
+                crash = {"active": 1, "for_s": crash_s,
+                         "at_s": time.perf_counter() - t0}
+                log(f"served: active 1 dark for {crash_s:.0f}s")
             deltas = rng.integers(1, 1000, size=n_names)
             try:
                 resp, again = _write_round(
@@ -569,6 +599,26 @@ def phase_served(n_names: int, writes_per_name: int, engine_rows: int,
                 f"acknowledged writes: {wrong[:5]}"
             )
 
+        if crash is not None:
+            snaps = [s.manager.metrics.snapshot() for s in ars]
+            back = snaps[1]["counters"]
+            crash.update({
+                "frames_dropped": back.get("frames_dropped_while_crashed", 0),
+                "rows_caught_up": back.get("rows_caught_up", 0),
+                "catchup_s": snaps[1]["hists"].get(
+                    "phase_catchup_s", {}).get("sum"),
+                **{key: [snap["counters"].get(key, 0) for snap in snaps]
+                   for key in ("coordinator_flips",
+                               "executions_skipped_duplicate",
+                               "requests_reforwarded")},
+            })
+            if not crash["frames_dropped"]:
+                raise AssertionError(f"active 1 was never dark: {crash}")
+        if _compiles(ars) != programs_at_boot:
+            raise AssertionError(
+                f"a program compiled after boot: {programs_at_boot} -> "
+                f"{_compiles(ars)}: "
+                f"{[s.manager.engine_compile_stats() for s in ars]}")
         codec = None
         meshes, tick_means = [], []
         for i, (s, st) in enumerate(zip(ars, _stats(ports[:len(ars)]))):
@@ -599,6 +649,7 @@ def phase_served(n_names: int, writes_per_name: int, engine_rows: int,
             "writes_per_name": writes_per_name,
             "writes_acknowledged": n_names * writes_per_name,
             "retransmissions": resent,
+            "crash": crash,
             "read_back_from_actives": len(ars),
             "engine": {"rows": engine_rows, "W": window,
                        "K": ars[0].cfg.req_lanes, "R": len(ars)},

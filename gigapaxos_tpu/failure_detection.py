@@ -57,6 +57,14 @@ class FailureDetector:
     def heard_from(self, node_id: int) -> None:
         self.last_heard[int(node_id)] = time.time()
 
+    def heard_from_all(self) -> None:
+        """A node that was itself away (frozen, partitioned, an emulated
+        crash) knows nothing of who is alive: everyone has one timeout
+        from now to be heard again."""
+        now = time.time()
+        for n in self.last_heard:
+            self.last_heard[n] = now
+
     def is_node_up(self, node_id: int) -> bool:
         if node_id == self.my_id:
             return True

@@ -79,8 +79,14 @@ from .obs import gplog
 from .obs.flight import FlightRecorder
 from .obs.metrics import ROW_BOUNDS, TICK_BOUNDS, MetricsRegistry
 from .obs.reqtrace import RequestTracer
+from .dedup import ExecutedIds
 from .obs.spans import observe_interval, span
-from .ops.lifecycle import create_groups, kill_groups, restore_paused_rows
+from .ops.lifecycle import (
+    create_groups,
+    jump_rows,
+    kill_groups,
+    restore_paused_rows,
+)
 from .storage.logger import PaxosLogger
 
 # Both ways to run a tick step through the ONE unified factory
@@ -367,12 +373,6 @@ class PaxosManager:
         # group-size ceiling (MAX_GROUP_SIZE, PaxosConfig.java:532); the
         # engine's member bitmask caps at 32 regardless
         self.max_group_size = min(32, Config.get_int(PC.MAX_GROUP_SIZE))
-        # exactly-once dedup window: like the reference's TTL'd
-        # GCConcurrentHashMap (PaxosManager.java:318-346), dedup is
-        # guaranteed only within the cache's TTL+size window — a duplicate
-        # re-proposal arriving after eviction can re-execute
-        self.response_cache_ttl = Config.get_float(PC.RESPONSE_CACHE_TTL_S)
-        self.response_cache_cap = Config.get_int(PC.RESPONSE_CACHE_SIZE)
         # admission back-pressure (MAX_OUTSTANDING_REQUESTS 8000 analog,
         # PaxosConfig.java:537): past this many in-flight requests the
         # entry path refuses with "overload" and clients back off
@@ -560,7 +560,11 @@ class PaxosManager:
         # same decided sequence, so skipping re-execution of a seen id is
         # deterministic across the group — at-least-once commit,
         # exactly-once execution; ref: PaxosManager.java:318-346).
-        # request_id -> (time, response, name-of-execution).  The name
+        # request_id -> (time, response, name-of-execution, seq).  What
+        # is remembered, and for how long, is a function of the name's
+        # own decided sequence (dedup.py: the last DEDUP_SLOTS executed
+        # slots of the name), never of a clock or of what other names
+        # did: three replicas skip or execute a duplicate ALIKE.  The name
         # tag makes state-transfer dedup SOUND: a donor ships only
         # entries executed in the groups whose app state it serves — an
         # entry for any other group would suppress an execution the
@@ -569,7 +573,8 @@ class PaxosManager:
         # re-execute; both directions diverge the RSM (each was caught
         # by the chaos soak).  Names, not rows: the tag must survive
         # migrations that re-home a name to a new row.
-        self.response_cache: Dict[int, Tuple[float, Optional[str], str]] = {}
+        self._executed = ExecutedIds()
+        self.response_cache = self._executed.entries
         # in-flight dedup (the reference's outstanding-table propose dedup,
         # PaxosManager.java:1209): a retransmitted request id whose original
         # proposal is still queued locally must not mint a second vid —
@@ -592,6 +597,35 @@ class PaxosManager:
         self.repropose_after_s = Config.get_float(
             PC.FAILURE_DETECTION_TIMEOUT_S
         )
+        # writes this node forwarded as their ENTRY replica and has not
+        # executed yet: request id -> (name, the coordinator they went
+        # to, value).  A coordinator that goes away takes its forwards
+        # with it; when the row's ballot names another, they are proposed
+        # again from here (and forwarded there, or admitted here) — the
+        # client's own resend is a whole timeout away.  A copy that
+        # decides twice executes once (dedup.py)
+        self._forwarded: Dict[int, Tuple[str, int, str]] = {}
+        # the failover's account (METRICS.md, "Failover").  An election
+        # WAVE: the rows the failure detector named, from the tick it
+        # first named any until each is led by this node (majority of
+        # promises in) or by another's higher ballot.  The coordinator
+        # gap: per row whose coordinator changed, when it last executed
+        # under the old one.  The catch-up: on a node back from a crash,
+        # from the first frame taken in until every member row's app
+        # cursor has reached the frontier the first peer heard again had
+        # executed
+        self._wave_t0: Optional[float] = None
+        self._wave_rows = np.zeros(G, bool)
+        self._last_exec_t = np.zeros(G, np.float64)  # wall time
+        self._coord_gap_from: Dict[int, float] = {}
+        self._catchup_t0: Optional[float] = None
+        self._catchup_target = np.zeros(G, np.int64)
+        self._catchup_behind = np.zeros(G, bool)
+        for key in ("executions_skipped_duplicate", "requests_reforwarded",
+                    "requests_reproposed", "election_waves",
+                    "pvalues_carried_over", "rows_caught_up",
+                    "rows_caught_up_by_state_pull", "coordinator_flips"):
+            self.metrics.count(key, 0)  # present from the start
         self._next_counter = 1
         # node-minted request-id namespace: (boot nonce << 24) | counter,
         # < 2^61 (disjoint from reserved-bit-62 stop ids; client ids are
@@ -741,12 +775,9 @@ class PaxosManager:
         self.arena.update(rec.payloads)  # journal blocks are newer
         for k, v in rec.payload_meta.items():
             self.vid_meta.setdefault(int(k), (int(v[0]), int(v[1])))
-        for rid_s, ent in (meta.get("response_cache") or {}).items():
-            # exactly-once dedup survives restarts (the restored app
-            # state's history includes these executions)
-            self.response_cache.setdefault(
-                int(rid_s), (float(ent[0]), ent[1], str(ent[2]))
-            )
+        # exactly-once dedup survives restarts (the restored app
+        # state's history includes these executions)
+        self._executed.install(meta.get("response_cache"))
         self.names = {str(k): int(v) for k, v in meta.get("names", {}).items()}
         self.old_epochs = {
             (str(n), int(e)): int(r)
@@ -886,10 +917,7 @@ class PaxosManager:
                     if int(self.app_exec_slot[r]) < int(prec["exec"]):
                         self._needs_state.add(r)
                     self.pending_exec.pop(r, None)
-                    for rid_s, ent in (prec.get("dedup") or {}).items():
-                        self.response_cache.setdefault(
-                            int(rid_s), (float(ent[0]), ent[1], str(ent[2]))
-                        )
+                    self._executed.install(prec.get("dedup"))
             elif nm not in self.names:
                 self._paused_put((nm, e), prec)
         # Roll the execute frontier forward through EVERY journaled
@@ -1081,6 +1109,10 @@ class PaxosManager:
                 nullw, nullw, nullw, nullw, nullw)
         # a sweep's burst of pauses frees its rows PAUSE_CHUNK at a time
         kill_groups(scratch, np.zeros(self.PAUSE_CHUNK, np.int32))
+        # a straggler's state pull (a node back after a while) jumps its
+        # rows JUMP_CHUNK at a time
+        zj = np.zeros(self.JUMP_CHUNK, np.int32)
+        jump_rows(scratch, zj, zj, zj, zj, zj, zj)
         _publish_vec_jit(scratch)
         req = np.full(
             (self.steps_per_dispatch, G, cfg.req_lanes), NULL, np.int32
@@ -1098,6 +1130,9 @@ class PaxosManager:
             jnp.zeros((blob_vec_len(cfg),), jnp.int32),
         )
         jax.block_until_ready(out)
+        # a substep whose busy rows overflow the digest has its whole
+        # planes pulled through this slice: its program too
+        np.asarray(out[2][0])
         return time.monotonic() - t0
 
     def mesh_info(self) -> Dict[str, Any]:
@@ -1174,10 +1209,12 @@ class PaxosManager:
             # shapes an epoch change uses, so growth of these caches
             # after boot is a compile under the state lock, in traffic
             "lifecycle": {
-                "label": "create_groups+kill_groups+restore_paused_rows",
+                "label": "create_groups+kill_groups+restore_paused_rows"
+                         "+jump_rows",
                 "compiles": create_groups._cache_size()
                 + kill_groups._cache_size()
-                + restore_paused_rows._cache_size(),
+                + restore_paused_rows._cache_size()
+                + jump_rows._cache_size(),
                 "retraces": 0,
             },
             # the whole-row program of the gathered stack: one compile a
@@ -1417,10 +1454,11 @@ class PaxosManager:
         return True
 
     def _repropose_locked(self, name: str, items) -> None:
-        """Writes decided behind the previous epoch's stop, as (request
-        id, entry replica, value): proposed again into ``name``'s current
-        row under their ids (their callbacks wait at their entry
-        replicas, whichever those are)."""
+        """Writes as (request id, entry replica, value) — decided behind
+        the previous epoch's stop, or forwarded to a coordinator that no
+        longer leads: proposed again into ``name``'s current row under
+        their ids (their callbacks wait at their entry replicas,
+        whichever those are)."""
         if items:
             results = self.propose_batch([
                 (name, value, rid, None, entry)
@@ -1835,11 +1873,8 @@ class PaxosManager:
                     jobs.append((name, epoch, row))
             if not jobs:
                 return out
-            dedup_by_name = self._dedup_by_name_locked(
-                {name for name, _e, _r in jobs})
             for name, epoch, row in jobs:
-                rec = self._extract_record(
-                    name, epoch, row, dedup=dedup_by_name.get(name, {}))
+                rec = self._extract_record(name, epoch, row)
                 if self.logger:
                     self.logger.log_pause(rec)
                 self._paused_put((name, epoch), rec)
@@ -1851,16 +1886,6 @@ class PaxosManager:
             for name, _epoch, row in jobs:
                 self._forget_row_locked(name, row)
             self.metrics.count("pause_evictions", len(jobs))
-        return out
-
-    def _dedup_by_name_locked(self, wanted: set) -> Dict[str, Dict]:
-        """The exactly-once entries of every name in ``wanted``, by name,
-        from ONE pass over the response cache (:meth:`dedup_for_name`
-        scans it whole for each name)."""
-        out: Dict[str, Dict] = {}
-        for rid, (t, resp, nm) in self.response_cache.items():
-            if nm in wanted:
-                out.setdefault(nm, {})[str(rid)] = [t, resp, nm]
         return out
 
     def _forget_row_locked(self, name: str, row: int) -> None:
@@ -1879,18 +1904,12 @@ class PaxosManager:
         self.queues.pop(row, None)
         self.pending_exec.pop(row, None)
 
-    def _extract_record(
-        self, name: str, epoch: int, row: int,
-        dedup: Optional[Dict] = None,
-    ) -> Dict:
+    def _extract_record(self, name: str, epoch: int, row: int) -> Dict:
         """Snapshot one row for pause/re-home (HotRestoreInfo analog).
         Reads go through the ``_np`` leaf cache — one host transfer per
         leaf per state version, not per paused name (the old per-call
         ``np.asarray(leaf)`` copied whole [G, W] planes per pause; a
-        density sweep pays extraction thousands of times per state).
-        ``dedup`` lets a batch caller supply this name's exactly-once
-        entries from ONE grouped response-cache pass instead of the
-        per-name O(cache) scan of :meth:`dedup_for_name`."""
+        density sweep pays extraction thousands of times per state)."""
         exec_now = int(self._np("exec_slot")[row])
         acc = []
         dec = []
@@ -1914,7 +1933,7 @@ class PaxosManager:
             "app_state": self.app.checkpoint(name),
             "app_exec": int(self.app_exec_slot[row]),
             "acc": acc, "dec": dec,
-            "dedup": self.dedup_for_name(name) if dedup is None else dedup,
+            "dedup": self._executed.of_name(name),
             # member set rides along so a LOCAL restore (hibernate wake-up)
             # needs no reconfigurator round to learn the group
             "members": self.get_replica_group(name),
@@ -1973,11 +1992,7 @@ class PaxosManager:
                 # with an empty app state, forever).  Epoch>0 joins
                 # adopt a donor's state+dedup wholesale via _needs_state;
                 # epoch-0 rejoins rebuild by re-executing history.
-                for rid in [
-                    r for r, (_t, _resp, nm) in self.response_cache.items()
-                    if nm == name
-                ]:
-                    del self.response_cache[rid]
+                self._executed.forget(name)
                 ok = self._create_locked(
                     name, members, initial_state, epoch, int(row), pending
                 )
@@ -2089,11 +2104,7 @@ class PaxosManager:
         # one member, woken as a straggler, came back short one
         # committed transfer).  The snapshot's own paired dedup
         # reinstalls right below.
-        for rid in [
-            r2 for r2, (_t, _resp, nm) in self.response_cache.items()
-            if nm == name
-        ]:
-            del self.response_cache[rid]
+        self._executed.forget(name)
         self.install_dedup(rec.get("dedup"))
         # the _create_locked journal entry has the app state as init;
         # the consensus remnants need the pause record on replay too
@@ -2325,14 +2336,10 @@ class PaxosManager:
                 jobs.append((name, int(versions[row]), row))
             if not jobs:
                 return 0
-            dedup_by_name = self._dedup_by_name_locked(
-                {name for name, _e, _r in jobs})
             rows_l: List[int] = []
             keys: List[Tuple[str, int]] = []
             for name, epoch, row in jobs:
-                rec = self._extract_record(
-                    name, epoch, row, dedup=dedup_by_name.get(name, {})
-                )
+                rec = self._extract_record(name, epoch, row)
                 held = list(self.queues.get(row, []))
                 if held:
                     rec["held_vids"] = held
@@ -2507,20 +2514,11 @@ class PaxosManager:
         duplicates; entries for other names suppress executions the
         adopted state lacks — both diverge the RSM."""
         with self._state_lock:
-            return {
-                str(rid): [t, resp, nm]
-                for rid, (t, resp, nm) in self.response_cache.items()
-                if nm == name
-            }
+            return self._executed.of_name(name)
 
     def install_dedup(self, entries: Optional[Dict]) -> None:
-        now = time.time()
         with self._state_lock:
-            for rid_s, ent in (entries or {}).items():
-                self.response_cache.setdefault(
-                    int(rid_s),
-                    (min(float(ent[0]), now), ent[1], str(ent[2])),
-                )
+            self._executed.install(entries)
 
     def drain_demand(self) -> Dict[str, Tuple[int, int]]:
         """Take the per-name request counts since the last drain; returns
@@ -3353,12 +3351,17 @@ class PaxosManager:
                         # under its own vid space
                         for rid, entry, value in decode_batch(self.arena[vid]):
                             reqs.append([rid, entry, value, False])
+                            if entry == self.my_id:
+                                self._forwarded[rid] = (name, coord, value)
                     else:
                         entry, rid = self.vid_meta.get(vid, (self.my_id, vid))
                         reqs.append(
                             [rid, entry, self.arena[vid],
                              bool(vid & STOP_BIT)]
                         )
+                        if entry == self.my_id and not vid & STOP_BIT:
+                            self._forwarded[rid] = (
+                                name, coord, self.arena[vid])
                     # the coordinator re-mints its own vid; our local copy
                     # would only go stale (the callback stays in
                     # self.outstanding keyed by request_id)
@@ -3645,7 +3648,96 @@ class PaxosManager:
                 self.mirror.patch(
                     rows, news_blocks(body, rows.size, self.cfg))
             self._mirror_behind = False
+            if self._wave_t0 is not None:
+                self._election_progress_locked()
+            if self._catchup_t0 is not None:
+                self._catchup_progress_locked()
             return host_delta
+
+    # ---- the failover's account -----------------------------------------
+    ELECTION_WAVE_MAX_S = 30.0  # a wave still open then is given up
+
+    def note_election(self, want: np.ndarray) -> None:
+        """The failure detector names rows (``want``, [G] bool) for the
+        dispatch about to go: they join the open election wave, or open
+        one."""
+        with self._state_lock:
+            if self._wave_t0 is None:
+                self._wave_t0 = time.monotonic()
+                self._wave_rows[:] = False
+                self.metrics.count("election_waves")
+            self._wave_rows |= want
+
+    def _election_progress_locked(self) -> None:
+        """A step is done and the mirror shows its blob: wave rows this
+        node now leads (``coord`` ACTIVE: a majority promised, and what
+        they had accepted and not decided is in its proposal ring) or
+        another's higher ballot took are through; the wave ends with
+        the last of them."""
+        rows = np.flatnonzero(self._wave_rows)
+        blob = split_blob_vec(self.mirror.vec, self.cfg)
+        coord = blob.coord[rows]
+        won = (coord < 0) & (coord != NULL)
+        lost = ballot_coord(blob.bal[rows]) != self.my_id
+        if won.any():
+            # what another node minted can be in MY proposal ring only as
+            # a carried-over value (a forward is minted anew here)
+            pv = blob.prop_vid[rows[won]]
+            self.metrics.count("pvalues_carried_over", int((
+                (pv > 0) & (((pv >> VID_NODE_SHIFT) & 31) != self.my_id)
+            ).sum()))
+        self._wave_rows[rows[won | lost]] = False
+        now = time.monotonic()
+        if not self._wave_rows.any():
+            observe_interval(self.metrics, "election", now - self._wave_t0)
+            self._wave_t0 = None
+        elif now - self._wave_t0 > self.ELECTION_WAVE_MAX_S:
+            self._wave_t0 = None  # a row that cannot run (stopped, gone)
+
+    def _reforward_locked(self) -> None:
+        """Ballots moved: what this node forwarded, as its entry replica,
+        to a coordinator that no longer leads the name is proposed again
+        here, under its request id — the next ring build admits it or
+        forwards it to the coordinator that is."""
+        again: Dict[str, List[Tuple[int, int, str]]] = {}
+        for rid, (name, coord, value) in list(self._forwarded.items()):
+            row = self.names.get(name)
+            if row is not None and int(ballot_coord(
+                    int(self._bal_host[row]))) != coord:
+                del self._forwarded[rid]
+                again.setdefault(name, []).append((rid, self.my_id, value))
+        for name, items in again.items():
+            self._repropose_locked(name, items)
+            self.metrics.count("requests_reforwarded", len(items))
+
+    def begin_catchup(self, t0: Optional[float],
+                      frontier: np.ndarray) -> None:
+        """This node is back from a crash and the dispatch about to go
+        holds a peer's news since: the catch-up's account runs from
+        ``t0`` (the first frame taken in, monotonic).  ``frontier``
+        ([G], that peer's executed slots as its frame gave them) is the
+        target: what the others executed while this node was away.  A
+        row is caught up when its app cursor has reached it (the lag of
+        a tick that every replica has under load is not the crash's),
+        the node when every row is."""
+        with self._state_lock:
+            self._catchup_t0 = time.monotonic() if t0 is None else t0
+            self._catchup_target = np.where(
+                self._np("member_mask") != 0, frontier, 0)
+            self._catchup_behind = \
+                self._catchup_target > self.app_exec_slot
+
+    def _catchup_progress_locked(self) -> None:
+        behind = self._catchup_behind \
+            & (self._catchup_target > self.app_exec_slot)
+        n_up = int((self._catchup_behind & ~behind).sum())
+        if n_up:
+            self.metrics.count("rows_caught_up", n_up)
+        self._catchup_behind = behind
+        if not behind.any():
+            observe_interval(self.metrics, "catchup",
+                             time.monotonic() - self._catchup_t0)
+            self._catchup_t0 = None
 
     def _fresh_bal_exec(self, rows, body, whole) -> Dict[str, np.ndarray]:
         """``bal`` and ``exec_slot`` of the blob a step just made, before
@@ -3816,10 +3908,18 @@ class PaxosManager:
             bal_host = self._np("bal")
             self._bal_host = bal_host.copy()
             new_coord = ballot_coord(bal_host[pg_m]).astype(np.int32)
-            flips = int((new_coord != self._coord_cache[pg_m]).sum())
+            moved = new_coord != self._coord_cache[pg_m]
+            flips = int(moved.sum())
             if flips:
                 mx.count("coordinator_flips", flips)
+                for g in pg_m[moved & (self._last_exec_t[pg_m] > 0)]:
+                    # the name is without service from its last
+                    # execution under the old coordinator on
+                    self._coord_gap_from.setdefault(
+                        int(g), float(self._last_exec_t[g]))
             self._coord_cache[pg_m] = new_coord
+            if flips and self._forwarded:
+                self._reforward_locked()
             rises = len(pg_m)
             mx.count("ballot_rises", rises)
         mx.gauge("frontier_stall_groups", len(self._payload_blocked))
@@ -3953,6 +4053,10 @@ class PaxosManager:
                 r: t for r, t in self._inflight_since.items()
                 if r in self.inflight
             }
+            if self._forwarded:  # nobody waits for it here any more
+                waiting = self.outstanding._map
+                self._forwarded = {r: v for r, v in self._forwarded.items()
+                                   if r in waiting}
         self._maybe_checkpoint(last)
 
         # periodic full-baseline refresh: a dropped gossip frame must not
@@ -4001,7 +4105,15 @@ class PaxosManager:
     def _execute(self, out_np: StepDigest) -> None:
         committed = _committed_rows(out_np)
         if committed:
-            self.row_activity[[g for _k, g, _n in committed]] = time.time()
+            rows = [g for _k, g, _n in committed]
+            now = time.time()
+            self.row_activity[rows] = now
+            self._last_exec_t[rows] = now
+            if self._coord_gap_from:
+                for g in rows:
+                    t_old = self._coord_gap_from.pop(g, None)
+                    if t_old is not None:
+                        self.metrics.observe("coord_gap_s", now - t_old)
         tr = self.tracer
         tcm = self.trace_ctx
         # ballot attribution for decide events + the flight recorder's
@@ -4081,9 +4193,18 @@ class PaxosManager:
             while cursor in pend:
                 vid = pend[cursor]
                 if not self._execute_one(name, g, cursor, vid):
-                    missing.append(vid)
+                    # payload not here yet: pull it, and with it every
+                    # later decided slot's that is missing too — a node
+                    # that was away for a while is several slots behind
+                    # on every row, and one payload a round trip kept it
+                    # behind for as many round trips (13-15 s on the
+                    # chip, PR 35) — and retry next tick
+                    arena = self.arena
+                    missing.extend(
+                        v for _s, v in sorted(pend.items())
+                        if v and v not in arena)
                     blocked = True
-                    break  # payload not here yet; pull + retry next tick
+                    break
                 del pend[cursor]
                 cursor += 1
             if cursor != int(self.app_exec_slot[g]):
@@ -4132,9 +4253,7 @@ class PaxosManager:
 
     def _cache_response(self, request_id: int, response: Optional[str],
                         name: str) -> None:
-        self.response_cache[request_id] = (time.time(), response, name)
-        if len(self.response_cache) > self.response_cache_cap:
-            self._evict_response_cache()
+        self._executed.add(name, ((request_id, response),))
 
     @staticmethod
     def _cacheable(req) -> bool:
@@ -4147,29 +4266,12 @@ class PaxosManager:
         skips the cache for the same decided entry."""
         return not getattr(req, "txn_retry", False)
 
-    def _evict_response_cache(self) -> None:
-        """Size bound (RESPONSE_CACHE_SIZE analog): evict the oldest
-        tenth so the cache (and its state-transfer ride-along) stays
-        bounded under sustained load between checkpoint GCs.  Eviction
-        is per-node (like the reference's time+size-GC'd
-        GCConcurrentHashMap): exactly-once is guaranteed within the
-        TTL/size window, not beyond it.
-
-        Evicts the INSERTION-ORDER head: entries land with a fresh
-        timestamp, so dict order ≈ age order (a restored/installed
-        older entry can be slightly mis-ranked — the window is a
-        heuristic either way).  The previous full timestamp sort was
-        O(cap·log cap) per eviction — sampling-profiled at ~25% of a
-        loaded core at 20k req/s across three replicas."""
-        n = max(1, len(self.response_cache) // 10)
-        for rid in list(itertools.islice(self.response_cache, n)):
-            del self.response_cache[rid]
-
     def _answer(self, request_id: int, response: Optional[str]) -> None:
         """Entry replica, lock held: queue the waiting client callback
         (fired after the lock) and record how long the commit took on
         this node's own clocks — manager ticks and seconds from the
         request's first put to now."""
+        self._forwarded.pop(request_id, None)
         ent = self.outstanding.pop(request_id)
         if ent is None:
             return
@@ -4205,15 +4307,17 @@ class PaxosManager:
             # cache size-bound check amortize once per BATCH (at 2000
             # sub-requests/slot the per-request constants here are the
             # replica's whole execution budget).
-            now = time.time()
             rc = self.response_cache
             nm = name or ""
             my = self.my_id
             tr_on = self.tracer.enabled
+            done: Dict[int, Optional[str]] = {}  # this slot's executions
+            skipped = 0
             for request_id, entry, value in decode_batch(payload):
-                if request_id in rc:
-                    if entry == my:
-                        self._answer(request_id, rc[request_id][1])
+                if request_id in rc or request_id in done:
+                    skipped += 1
+                    self._answer(request_id, rc[request_id][1]
+                                 if request_id in rc else done[request_id])
                     continue
                 req = SlimRequest(nm, request_id, value)
                 self._app_execute_retrying(req, do_not_reply=(entry != my))
@@ -4229,23 +4333,24 @@ class PaxosManager:
                 self.inflight.pop(request_id, None)
                 response = req.response_value
                 if self._cacheable(req):
-                    rc[request_id] = (now, response, nm)
-                if entry == my:
-                    self._answer(request_id, response)
-            if len(rc) > self.response_cache_cap:
-                self._evict_response_cache()
+                    done[request_id] = response
+                self._answer(request_id, response)
+            self._executed.add(nm, done.items())
+            if skipped:
+                self.metrics.count("executions_skipped_duplicate", skipped)
             self._slots_since_ckpt += 1
             self.retained[vid] = (g, slot)
             return True
         entry, request_id = self.vid_meta.get(vid, (-1, vid))
         if request_id in self.response_cache:
-            # duplicate of an already-executed request (client retransmit
-            # through a different entry replica): skip re-execution on
-            # EVERY replica — deterministic, since all see the same
-            # decided sequence and the same earlier execution.
-            if entry == self.my_id:
-                self._answer(request_id,
-                             self.response_cache[request_id][1])
+            # duplicate of an already-executed request (proposed again
+            # at its entry replica, or through a second one because its
+            # client moved): skipped on EVERY replica — they all see the
+            # same decided sequence and remember the same ids of it
+            # (dedup.py) — and answered with the first execution's
+            # response wherever a client waits for it
+            self.metrics.count("executions_skipped_duplicate")
+            self._answer(request_id, self.response_cache[request_id][1])
             self.retained[vid] = (g, slot)
             return True
         req = SlimRequest(
@@ -4280,8 +4385,9 @@ class PaxosManager:
                 self.on_stop_executed(name, g, epoch)
             except Exception:
                 pass  # reconfiguration-layer hook must not wedge execution
-        if entry == self.my_id:
-            self._answer(request_id, response)
+        # whoever holds a client's callback for this id answers it: the
+        # entry replica, or another the client moved to meanwhile
+        self._answer(request_id, response)
         self.retained[vid] = (g, slot)  # keep for straggler pulls
         return True
 
@@ -4356,6 +4462,7 @@ class PaxosManager:
     # sibling for stranded EPOCH forms (pause records, pending rows) is
     # the reconfigurator's epoch_probe.
     # ------------------------------------------------------------------
+    JUMP_CHUNK = 8  # rows a jump_rows program (ops/lifecycle.py)
     STATE_REQ_INTERVAL = 16  # ticks between pulls for the same row
     PAYLOAD_BLOCKED_TICKS = 64  # parked-on-missing-payload pull trigger
     FRONTIER_STALLED_TICKS = 64  # behind-majority-without-progress trigger
@@ -4492,37 +4599,17 @@ class PaxosManager:
                 state=self.app.checkpoint(name),
             ).to_json())
         if states:
-            # The FULL (TTL+size-bounded) response cache rides along:
-            # without these entries the receiver cannot dedup a duplicate
-            # decision (same request id, different vid) landing after its
-            # jumped frontier — replicas that executed the first copy skip
-            # it, a jumped replica would execute it and DIVERGE the RSM.
-            # Filtering by the retained-payload index proved unsound: a
-            # re-proposed duplicate's first execution can predate payload
-            # GC, leaving the one dedup entry that matters out of the
-            # filter (caught by the chaos soak).
-            # entries for the SERVED names only, over their in-TTL
-            # history (no dependence on payload retention), BOUNDED: a
-            # hot name's cache can hold tens of thousands of entries and
-            # shipping all of them makes every straggler pull O(cache)
-            # (VERDICT r3 weak #5).  The newest `cap` entries per name
-            # ship; older ones fall outside the same probabilistic
-            # exactly-once window the per-node TTL+size eviction already
-            # defines (a duplicate older than the window can re-execute
-            # on ANY replica, transferred state or not).
-            served = {s_["paxos_id"] for s_ in states}
-            by_name: Dict[str, list] = {}
-            for rid, (t, resp, nm) in self.response_cache.items():
-                if nm in served:
-                    by_name.setdefault(nm, []).append((t, rid, resp))
-            cap = max(1024, self.response_cache_cap // 8)
-            cache = {}
-            for nm, ents in by_name.items():
-                if len(ents) > cap:
-                    ents.sort()  # oldest first; keep the newest cap
-                    ents = ents[-cap:]
-                for t, rid, resp in ents:
-                    cache[str(rid)] = [t, resp, nm]
+            # the served names' exactly-once entries ride along, whole
+            # (dedup.py bounds them by the names' own decided slots):
+            # without them the receiver cannot skip a duplicate decision
+            # (same request id, different vid) landing after its jumped
+            # frontier, which the replicas that executed the first copy
+            # skip — and with any other name's it would skip executions
+            # its state does not contain.
+            cache: Dict[str, list] = {}
+            for ents in self._executed.of_names(
+                    {s_["paxos_id"] for s_ in states}).values():
+                cache.update(ents)
             self.forward_out.append(
                 (body["from"], "state_reply",
                  {"states": states, "response_cache": cache})
@@ -4533,8 +4620,6 @@ class PaxosManager:
     ) -> None:
         """Adopt donor frontiers for rows still stranded (jumpSlot).
         Entries are StatePacket JSON (the CHECKPOINT_STATE wire schema)."""
-        from .ops.lifecycle import jump_rows
-
         # a state jump replaces engine rows: it must observe a COMPLETED
         # tick (an in-flight step's post-step would otherwise process
         # out_np against rows this jump just rewrote)
@@ -4591,15 +4676,11 @@ class PaxosManager:
         if not jumps and not app_only:
             return
         if jumps:
-            self.state = jump_rows(
-                self.state,
-                np.array([e["row"] for e in jumps]),
-                np.array([e["exec"] for e in jumps]),
-                np.array([e["bal"] for e in jumps]),
-                np.array([e["app_hash"] for e in jumps]),
-                np.array([e["n_execd"] for e in jumps]),
-                np.array([e["stopped"] for e in jumps]),
-            )
+            cols = [np.array([e[k] for e in jumps], np.int32)
+                    for k in ("row", "exec", "bal", "app_hash", "n_execd",
+                              "stopped")]
+            for pad in _padded_chunks(len(jumps), self.JUMP_CHUNK):
+                self.state = jump_rows(self.state, *[c[pad] for c in cols])
         # install the donor's dedup entries ONLY for names whose state
         # was actually ADOPTED here: an entry is sound exactly when it is
         # paired with a state that contains its execution.  Installing a
@@ -4609,6 +4690,12 @@ class PaxosManager:
         # (the chaos sweeps' remaining breach shape: identical dedup
         # sets, app_n_executed 5 vs 3 at equal frontiers).
         adopted = {e["name"] for e in jumps} | {e["name"] for e in app_only}
+        if self._catchup_t0 is not None:
+            pulled = [int(e["row"]) for e in jumps + app_only
+                      if self._catchup_behind[int(e["row"])]]
+            if pulled:
+                self.metrics.count("rows_caught_up_by_state_pull",
+                                   len(pulled))
         self.install_dedup({
             rid: ent for rid, ent in (response_cache or {}).items()
             if str(ent[2]) in adopted
@@ -4725,11 +4812,7 @@ class PaxosManager:
             "names": dict(self.names),
             "pending_rows": sorted(self.pending_rows),
             "needs_state": sorted(self._needs_state),
-            "response_cache": {
-                str(rid): [t, resp, nm]
-                for rid, (t, resp, nm) in self.response_cache.items()
-                if t >= time.time() - self.response_cache_ttl
-            },
+            "response_cache": self._executed.wire(),
             "paused": {
                 f"{n}@{e}": rec for (n, e), rec in (
                     self.paused.peek_items()
@@ -4748,11 +4831,6 @@ class PaxosManager:
             },
         })
         self._slots_since_ckpt = 0
-        # response-cache GC piggybacks on checkpoint cadence
-        cut = time.time() - self.response_cache_ttl
-        for key in [k for k in self.response_cache
-                    if self.response_cache[k][0] < cut]:
-            del self.response_cache[key]
 
     def drain_forward_out(self) -> List[Tuple[int, str, Dict]]:
         """Atomically take the pending outbound host-channel messages.

@@ -103,6 +103,8 @@ class MessageTransport:
             target=self._loop.run_forever, name=f"transport-{my_id}", daemon=True
         )
         self._writers: Dict[Tuple[str, int], asyncio.StreamWriter] = {}
+        # accepted connections' writers, while their read loops run
+        self._inbound: set = set()
         self._queues: Dict[Tuple[str, int], asyncio.Queue] = {}
         self._senders: Dict[Tuple[str, int], asyncio.Task] = {}
         # (addr, slot) -> newest unsent item of a latest-wins slot
@@ -175,6 +177,7 @@ class MessageTransport:
 
     async def _on_connection(self, reader: asyncio.StreamReader, writer):
         peer = writer.get_extra_info("peername") or ("?", 0)
+        self._inbound.add(writer)
 
         def reply(payload: bytes) -> None:
             def _w():
@@ -205,10 +208,32 @@ class MessageTransport:
         except asyncio.CancelledError:
             raise
         finally:
+            self._inbound.discard(writer)
             try:
                 writer.close()
             except Exception:
                 pass  # loop may already be closing (shutdown teardown)
+
+    def reset_connections(self) -> None:
+        """Close every established connection, accepted and dialled, and
+        forget what was queued for them — what the peers of a process
+        that died see.  The listener stays: whoever connects anew is
+        served by the handler as before.  A sender whose writer was
+        closed under it finds out at its next write and dials again, and
+        its latest-wins bases go with the connection."""
+        def _reset():
+            for w in list(self._inbound) + list(self._writers.values()):
+                try:
+                    w.close()
+                except Exception:
+                    pass  # already gone
+            for q in self._queues.values():
+                while not q.empty():
+                    q.get_nowait()
+            with self._latest_lock:
+                self._latest.clear()
+
+        self._loop.call_soon_threadsafe(_reset)
 
     # ---- send path -----------------------------------------------------
     def send_to_id(self, node_id: int, payload: bytes) -> bool:
@@ -307,7 +332,9 @@ class MessageTransport:
             if isinstance(payload, _Latest):
                 slot = payload.slot
                 with self._latest_lock:
-                    item = self._latest.pop((addr, slot))
+                    item = self._latest.pop((addr, slot), None)
+                if item is None:
+                    continue  # forgotten meanwhile (reset_connections)
             for _attempt in (0, 1):
                 if writer is None:
                     bases.clear()

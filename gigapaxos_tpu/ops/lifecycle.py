@@ -118,6 +118,7 @@ def kill_groups(state: EngineState, idx: jnp.ndarray) -> EngineState:
     )
 
 
+@jax.jit
 def jump_rows(
     state: EngineState,
     idx: jnp.ndarray,       # [N] rows to jump
@@ -137,7 +138,12 @@ def jump_rows(
     safe at ANY gap size, not only past the whole ring (the small-gap
     case matters: a member stranded one slot behind a majority that
     paused+resumed can ONLY heal by jumping — the decisions it needs
-    left every ring; chaos-soak find)."""
+    left every ring; chaos-soak find).  One program a row count
+    (jitted: as eager scatters each leaf compiled where a straggler's
+    first pull landed, in traffic); ``manager._apply_state_reply`` jumps
+    ``JUMP_CHUNK`` rows a call, a row repeated to fill (the same values
+    written twice change nothing), and ``warm_engine`` compiles that
+    shape at boot."""
     idx = jnp.asarray(idx, jnp.int32)
     n = idx.shape[0]
     W = state.acc_bal.shape[1]

@@ -75,6 +75,10 @@ class PC(FlagEnum):
     FAILURE_DETECTION_TIMEOUT_S = 6.0
     PING_PERIOD_S = 3.0                  # = timeout / 2
     COORDINATOR_LONG_DEAD_FACTOR = 3.0   # long-dead at 3x timeout
+    # the admin op {"op": "crash", "for_s": T} (upstream's emulated crash,
+    # TESTPaxosConfig.java:563-580): refused unless true — the admin
+    # plane is unauthenticated
+    ALLOW_CRASH_EMULATION = False
     SYNC_THRESHOLD = 32                  # missing decisions before sync kicks in
     MAX_SYNC_DECISIONS_GAP = 1 << 14
     # payload-retention/jump horizon in units of the slot window: a member
@@ -83,7 +87,6 @@ class PC(FlagEnum):
     # (MAX_SYNC_DECISIONS_GAP plays this role in the reference)
     JUMP_HORIZON_WINDOWS = 4
     TICK_INTERVAL_S = 0.01               # server drive-loop cadence
-    RESPONSE_CACHE_TTL_S = 60.0          # exactly-once retransmit cache TTL
 
     # ---- observability (obs/: gplog + reqtrace + metrics + flight) ----
     # cadence of the server's INFO stats line (the registry's
@@ -176,7 +179,6 @@ class PC(FlagEnum):
 
     # ---- request handling ---------------------------------------------
     REQUEST_TIMEOUT_S = 8.0              # client callback GC (ref: PaxosClientAsync 8s)
-    RESPONSE_CACHE_SIZE = 1 << 16        # exactly-once retransmit cache
 
     # ---- test / emulation modes (ref: PaxosConfig.java:435,453) -------
     EMULATE_UNREPLICATED = False

@@ -42,8 +42,8 @@ from .net.gather import GatherNews
 from .net.node_config import NodeConfig
 from .net.transport import MessageTransport
 from .obs import gplog
-from .obs.spans import span
-from .ops.engine import EngineConfig
+from .obs.spans import observe_interval, span
+from .ops.engine import EngineConfig, split_blob_vec
 from .paxos_config import PC
 from .utils.config import Config
 
@@ -133,10 +133,28 @@ class PaxosServer:
         self._peer_blob_folded: Dict[int, int] = {}
         for key in ("blob_frames_received", "blob_frames_replaced_unread",
                     "blob_base_mismatch", "ticks", "ticks_noprog",
-                    "ticks_inflight_noprog", "ticks_without_fresh_blob"):
+                    "ticks_inflight_noprog", "ticks_without_fresh_blob",
+                    "crash_emulations", "frames_dropped_while_crashed"):
             self.manager.metrics.count(key, 0)  # present from the start
+        # the emulated crash (upstream's TESTPaxosConfig.crash/isCrashed:
+        # a crashed node's traffic is dropped): until this moment on the
+        # monotonic clock the node takes nothing in, sends nothing out
+        # and does not tick; it keeps its memory and its journal.  Only
+        # where ALLOW_CRASH_EMULATION says so: the admin plane is
+        # unauthenticated
+        self._allow_crash = Config.get_bool(PC.ALLOW_CRASH_EMULATION)
+        self._dark_until = 0.0
+        # back from a crash: when the first frame was taken in, and the
+        # peers a blob frame of whose was accepted since (the catch-up's
+        # account starts with the first dispatch that holds one)
+        self._back_t0: Optional[float] = None
+        self._back_heard: Optional[set] = None
+        # peers whose silence has made want_coord name rows (fd.suspect
+        # is observed once a silence)
+        self._suspected: set = set()
         self._blob_lock = threading.Lock()
         self._tick = 0
+        self._want_seen = None  # the failure detector's last answer, seen
         self._last_ping = 0.0
         self._stop = threading.Event()
         # event-kicked cadence: a frame carrying NEW work (client request,
@@ -237,9 +255,31 @@ class PaxosServer:
         "admin", "fd_ping", "echo",
     ))
 
+    def _dark(self) -> bool:
+        """True while the emulated crash lasts: the frame in hand is
+        dropped unanswered, and counted."""
+        if self._dark_until and time.monotonic() < self._dark_until:
+            self.manager.metrics.count("frames_dropped_while_crashed")
+            return True
+        return False
+
+    # frame kinds that only a node of THIS mesh sends (beside its blobs):
+    # hearing one is hearing that node.  Every other kind carries the id
+    # of another id space — reconfigurator 1's epoch and echo frames name
+    # sender 1 as active 1's do, and kept a dead active 1 alive in its
+    # peers' failure detectors for as long as reconfigurator 1 talked to
+    # them (found by the first g1k-crash on the chip, PR 35: no election
+    # in 14 s of darkness)
+    MESH_KINDS = frozenset((
+        "payloads", "forward", "forward_batch", "need_payloads",
+        "state_request", "state_reply", "fd_ping", "blob_resync",
+    ))
+
     def _on_client_plane_message(
         self, payload: bytes, peer: Tuple[str, int], reply
     ) -> None:
+        if self._dark():
+            return
         kind = decode_kind(payload)
         if kind == "R":  # binary request batch (hot path)
             self._on_binary_requests(payload, reply)
@@ -276,6 +316,10 @@ class PaxosServer:
 
     # ---- message ingress (demultiplexer analog) ------------------------
     def _on_message(self, payload: bytes, peer: Tuple[str, int], reply) -> None:
+        if self._dark():
+            return
+        if self._back_heard is not None and self._back_t0 is None:
+            self._back_t0 = time.monotonic()  # the first frame since
         kind = decode_kind(payload)
         if kind == "R":  # binary client request batch (hot path)
             self._on_binary_requests(payload, reply)
@@ -300,7 +344,7 @@ class PaxosServer:
             self._on_blob(kind, payload)
             return
         k, sender, body = decode_json(payload)
-        if sender >= 0:
+        if sender >= 0 and k in self.MESH_KINDS:
             self.fd.heard_from(sender)
         self._on_json(k, sender, body, reply)
         if k != "fd_ping":
@@ -338,6 +382,8 @@ class PaxosServer:
                                        blocks, self.cfg)
                         self._peer_news.rows(sender, rows, blocks)
                 if accepted:
+                    if self._back_heard is not None:
+                        self._back_heard.add(sender)  # since the return
                     replaced = self._peer_blob_unread.get(sender, False)
                     self._peer_blob_tick[sender] = tick
                     self._peer_blob_unread[sender] = True
@@ -673,7 +719,19 @@ class PaxosServer:
 
     def _on_admin(self, body: Dict, reply) -> None:
         op = body.get("op")
-        if op == "rowfor":
+        if op == "crash":
+            # go dark for ``for_s`` seconds (0: a probe, nothing happens)
+            for_s = float(body.get("for_s", 0.0))
+            ok = self._allow_crash and for_s >= 0.0
+            reply(encode_json("admin_response", self.my_id, {
+                "op": op, "name": body.get("name"), "ok": ok,
+                **({} if ok else {"error": "crash_emulation_not_allowed"}),
+            }))
+            if ok and for_s > 0.0:
+                self.manager.metrics.count("crash_emulations")
+                self._dark_until = time.monotonic() + for_s
+                self._kick.set()  # the tick loop resets the connections
+        elif op == "rowfor":
             reply(encode_json("admin_response", self.my_id, {
                 "op": op, "name": body["name"],
                 "row": self.manager.default_row_for(body["name"]),
@@ -825,8 +883,33 @@ class PaxosServer:
             }))
 
     # ---- the tick loop -------------------------------------------------
+    def _stay_dark(self) -> None:
+        """The tick thread's part of the emulated crash: the established
+        connections are reset as a dead process's are (the peers' and
+        the clients' senders find out and connect anew, to a node that
+        reads and drops), nothing ticks until the time is up, and the
+        node that comes back knows nothing of who is alive — it was
+        itself away — so everyone has one timeout to be heard again."""
+        self.log.warning("emulated crash: dark for %.1fs",
+                         self._dark_until - time.monotonic())
+        for t in (self.transport, self.client_transport):
+            if t is not None:
+                t.reset_connections()
+        while not self._stop.is_set():
+            left = self._dark_until - time.monotonic()
+            if left <= 0:
+                break
+            self._stop.wait(min(left, 0.25))
+        self._dark_until = 0.0
+        self.fd.heard_from_all()
+        self._suspected.clear()
+        self._back_t0, self._back_heard = None, set()
+        self.log.warning("emulated crash: back")
+
     def _run(self) -> None:
         while not self._stop.is_set():
+            if self._dark_until:
+                self._stay_dark()
             t0 = time.perf_counter()
             try:
                 if self._should_tick():
@@ -949,6 +1032,15 @@ class PaxosServer:
             ]
             self._peer_blob_folded.update(self._peer_blob_tick)
             self._peer_blob_unread.clear()
+            # back from a crash, and this dispatch holds a peer's news
+            # since: the catch-up's account starts, measured against the
+            # frontier that peer (or the further of two) had executed
+            frontier = None
+            if self._back_heard:
+                frontier = np.maximum.reduce([
+                    split_blob_vec(self._peer_blobs[r], self.cfg).exec_slot
+                    for r in self._back_heard])
+                self._back_heard = None
         for age in ages:  # 1 = this node saw every tick of that peer
             mx.observe("blob_age_ticks", age, bounds=TICK_BOUNDS)
         if not ages:
@@ -961,7 +1053,30 @@ class PaxosServer:
             self.manager._np("member_mask"),
             R,
         )
+        if want is not self._want_seen:
+            self._want_seen = want
+            self._note_want(want)
+        if frontier is not None:
+            self.manager.begin_catchup(self._back_t0, frontier)
         return update, heard, want
+
+    def _note_want(self, want) -> None:
+        """A new answer of the failure detector's (it hands back the same
+        array while its inputs stand): a peer whose silence makes it name
+        rows for the first time is suspected now (``fd.suspect``: from the
+        last frame heard of it), and the manager opens or extends its
+        election wave with the rows named."""
+        mx = self.manager.metrics
+        down = {r for r in self.node_config.get_node_ids()
+                if r != self.my_id and not self.fd.is_node_up(r)}
+        self._suspected &= down  # heard again: the next silence is new
+        if want is None or not want.any():
+            return
+        with span(mx, "election", record=False, node=self.my_id):
+            for r in down - self._suspected:
+                observe_interval(mx, "fd.suspect", self.fd.dead_for(r))
+            self._suspected |= down
+            self.manager.note_election(want)
 
     def _tick_once_inner(self) -> None:
         m = self.manager

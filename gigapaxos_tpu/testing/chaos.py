@@ -433,12 +433,6 @@ def run_soak(
         # these process-wide mutations in the finally)
         for cls in task_classes:
             cls.restart_period_s = 0.05
-        # exactly-once is only guaranteed within the response-cache TTL; a
-        # loaded box can stretch one soak across minutes, and TTL-expired
-        # dedup entries re-executing re-proposed duplicates is a documented
-        # semantics boundary, not what this probes.  Pin the window wide.
-        Config.set("RESPONSE_CACHE_TTL_S", "3600")
-
         rng = random.Random(seed)
         ar_cfg = ar_cfg or EngineConfig(
             n_groups=24, window=8, req_lanes=4, n_replicas=4
@@ -597,7 +591,6 @@ def run_sharded_soak(
     try:
         for cls in task_classes:
             cls.restart_period_s = 0.05
-        Config.set("RESPONSE_CACHE_TTL_S", "3600")
         rng = random.Random(seed)
         ar_cfg = EngineConfig(n_groups=16, window=8, req_lanes=4,
                               n_replicas=3)
@@ -784,8 +777,6 @@ def run_txn_soak(
     c = None
     tmp = None
     try:
-        # exactly-once within the TTL only; pin it wide (soak convention)
-        Config.set("RESPONSE_CACHE_TTL_S", "3600")
         # the soak's concurrency never exceeds the deployed driver cap
         from ..paxos_config import PC
         max_inflight = min(max_inflight, Config.get_int(PC.TXN_MAX_INFLIGHT))

@@ -109,7 +109,6 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     t_boot = time.time()
-    Config.set("RESPONSE_CACHE_TTL_S", "3600")
     c, accounts = build_cluster(args.accounts, args.replicas)
     print(json.dumps({
         "event": "booted", "accounts": args.accounts,

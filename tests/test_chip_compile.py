@@ -27,7 +27,11 @@ from gigapaxos_tpu.ops.engine import (
     set_peer_rows,
     update_vec_len,
 )
-from gigapaxos_tpu.ops.lifecycle import create_groups, restore_paused_rows
+from gigapaxos_tpu.ops.lifecycle import (
+    create_groups,
+    jump_rows,
+    restore_paused_rows,
+)
 from gigapaxos_tpu.parallel.mesh import GROUP_AXIS
 from gigapaxos_tpu.parallel.spmd import make_step
 
@@ -150,6 +154,21 @@ def test_lifecycle_scatters_compile_at_deployed_rows(one_chip):
     compiled = jax.jit(create_then_restore, donate_argnums=(0,)).lower(
         state, *[sds((N,))] * 8, *[sds((N, W))] * 5
     ).compile()
+    assert "scatter" in compiled.as_text()
+    assert _dispatch_bytes(compiled) < HBM_BYTES
+
+
+def test_state_pull_jump_compiles_at_deployed_rows(one_chip):
+    """jump_rows (a straggler's state pull: a node back after a while)
+    over a 65,536-row state, ``PaxosManager.JUMP_CHUNK`` rows a call —
+    the shape ``warm_engine`` compiles at boot."""
+    from gigapaxos_tpu.manager import PaxosManager
+
+    cfg = EngineConfig(65_536, 16, 8, 3)
+    row = jax.ShapeDtypeStruct((PaxosManager.JUMP_CHUNK,), jnp.int32,
+                               sharding=one_chip)
+    compiled = jump_rows.lower(
+        _state_shapes(cfg, one_chip), *[row] * 6).compile()
     assert "scatter" in compiled.as_text()
     assert _dispatch_bytes(compiled) < HBM_BYTES
 

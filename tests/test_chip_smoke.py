@@ -114,10 +114,14 @@ def test_trace_is_seeded_and_exercises_faults(smoke):
 def test_served_phase_reads_every_write_back_from_three_actives(smoke):
     line = smoke.phase_served(
         n_names=8, writes_per_name=3, engine_rows=256, window=16, seed=7,
-        expect_platform="cpu", timeout_s=120,
+        expect_platform="cpu", timeout_s=120, crash_s=3.0, fd_timeout_s=1.0,
     )
     json.dumps(line)
     assert line["writes_acknowledged"] == 24
+    # active 1 went dark after the first round and the last two rounds
+    # were read back from it too, with nothing compiled since boot
+    assert line["crash"]["active"] == 1 and line["crash"]["frames_dropped"]
+    assert sum(line["crash"]["coordinator_flips"]) > 0
     assert line["read_back_from_actives"] == 3
     assert line["engine"] == {"rows": 256, "W": 16, "K": 8, "R": 3}
     assert [m["platform"] for m in line["mesh"]] == ["cpu"] * 3

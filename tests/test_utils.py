@@ -110,8 +110,8 @@ def test_no_flag_aliasing():
         assert Config.get_bool(PC.PAUSE_OPTION) is True
         assert Config.get_bool(PC.BATCHING_ENABLED) is False
         Config.set("ENGINE_ROWS", "128")
-        assert Config.get_int(PC.RESPONSE_CACHE_SIZE) == flag_default(
-            PC.RESPONSE_CACHE_SIZE
+        assert Config.get_int(PC.MAX_BATCH_SIZE) == flag_default(
+            PC.MAX_BATCH_SIZE
         )
     finally:
         Config.clear()
