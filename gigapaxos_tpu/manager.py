@@ -33,6 +33,7 @@ The tick cycle (one call to :meth:`tick`):
 from __future__ import annotations
 
 import contextlib
+import functools
 import itertools
 import json
 import threading
@@ -431,7 +432,8 @@ class PaxosManager:
         self._published = jnp.zeros((blob_vec_len(cfg),), jnp.int32)
         self.mirror = PublishMirror(cfg, my_id)
         self._mirror_behind = True
-        for key in ("blob_news_dispatches", "blob_news_overflows"):
+        for key in ("blob_news_dispatches", "blob_news_overflows",
+                    "post_step_rows_scanned", "post_step_rows_total"):
             self.metrics.count(key, 0)  # present from the start
         # the dispatch's other inputs, kept on the device while the host
         # value stands: my id; a ring with no request in it; who is heard
@@ -713,6 +715,20 @@ class PaxosManager:
         # per CALL — that is O(calls * G) traffic (VERDICT r2 weak #3)
         self._np_cache: Dict[str, np.ndarray] = {}
         self._np_cache_state: Optional[EngineState] = None
+        # ``bal`` / ``exec_slot`` of my publish vector as the last step
+        # made it, owned: each step's news is written into this ONE pair
+        # (whole copies only where the whole vector came down), which the
+        # leaf cache then shows for the new state
+        self._bal_exec: Optional[Dict[str, np.ndarray]] = None
+        # the rows that hold a name (``member_mask`` non-zero, current
+        # and old epochs alike), ascending, in blocks of ROWS_A_PASS,
+        # each with its members as [R, n] bits: what every per-tick pass
+        # of the post-step runs over.  Derived anew when the cached
+        # ``member_mask`` ARRAY is another one — the step carries the
+        # array across its swap (_carried_leaves), a lifecycle op
+        # replaces the state and with it the array — so one [G] pass a
+        # lifecycle operation
+        self._member_rows: Tuple[Optional[np.ndarray], List] = (None, [])
         self.state: EngineState = init_state(cfg)
         self._recover()
 
@@ -746,6 +762,37 @@ class PaxosManager:
             self._np_cache = {}
             self._np_cache_state = self.state
         return self._np_cache
+
+    # numpy keeps the interpreter lock through a loop of at most 500
+    # elements and gives it up, to queue for it again, around every
+    # longer one.  On the chip's host that hand-over is what a pass
+    # costs, not its bytes: ~65 passes a tick over 1,000 rows left the
+    # post-step at 17.9 ms where the same passes over one row leave it
+    # at 1.5 (PERF.md section 6, PR 36).  So the member rows are read a
+    # block at a time, and no pass over a block lets go of the lock
+    ROWS_A_PASS = 500
+
+    def _member_rows_locked(self) -> List[Tuple[np.ndarray, np.ndarray]]:
+        """Lock held: the rows that hold a name in the CURRENT state,
+        ascending, as blocks of (rows [n], their members [R, n] bool)
+        with n at most ``ROWS_A_PASS``."""
+        mask = self._np("member_mask")
+        if mask is not self._member_rows[0]:
+            self._member_rows = (mask, self._index_member_rows(mask))
+        return self._member_rows[1]
+
+    def _index_member_rows(
+        self, mask: np.ndarray
+    ) -> List[Tuple[np.ndarray, np.ndarray]]:
+        """The one pass over ``[G]`` the post-step's index costs."""
+        rows = np.flatnonzero(mask)
+        rids = np.arange(self.cfg.n_replicas)[:, None]
+        return [
+            (block, ((mask[block][None, :] >> rids) & 1) == 1)
+            for block in (
+                rows[i:i + self.ROWS_A_PASS]
+                for i in range(0, rows.size, self.ROWS_A_PASS))
+        ]
 
     # ------------------------------------------------------------------
     # recovery (initiateRecovery analog, PaxosManager.java:1832-2035)
@@ -3467,8 +3514,7 @@ class PaxosManager:
         finally:
             self._state_lock.release()
 
-    def _dispatch_locked(self, update, heard, want_coord,
-                         carry: bool = False):
+    def _dispatch_locked(self, update, heard, want_coord):
         """Lock held: admit into the request ring, send up the peers'
         news (whole vectors through the whole-row program, rows with the
         step) and fire the step without waiting for the device.
@@ -3479,7 +3525,7 @@ class PaxosManager:
         with self._span("step.ring_build", cpu=False):
             req = self.build_request_ring(self.steps_per_dispatch)
             old_state = self.state
-            carried = self._carried_leaves(old_state) if carry else None
+            carried = self._carried_leaves(old_state)
         with self._span("step.dispatch"):
             t0 = time.monotonic()
             # every upload is a temporary of this call: a device value
@@ -3499,9 +3545,8 @@ class PaxosManager:
             # backend), that wait is the dispatch's
             self.state = new_state
             self._heat_dev = new_heat
-            if carry:
-                self._np_cache = carried
-                self._np_cache_state = new_state
+            self._np_cache = carried
+            self._np_cache_state = new_state
             del old_state
         # the mirror is behind the device's published vector from here
         # until this dispatch's completion has patched it
@@ -3623,13 +3668,13 @@ class PaxosManager:
             mx.observe("blob_news_rows", n_news, bounds=ROW_BOUNDS)
             if whole is not None:
                 mx.count("blob_news_overflows")
+            # the new state's ballots and frontiers are in the blob,
+            # unmasked (ops/engine.py:make_blob): the tick path reads
+            # them from here and not from the device — the mirror's as
+            # the last step left them, with this step's rows
+            fresh = self._fresh_bal_exec(rows, body, whole)
             if pend["state"] is self.state:
-                # the new state's ballots and frontiers are in the blob,
-                # unmasked (ops/engine.py:make_blob): the tick path reads
-                # them from here and not from the device — the mirror's
-                # as the last step left them, with this step's rows
-                self._np_cache_locked().update(
-                    self._fresh_bal_exec(rows, body, whole))
+                self._np_cache_locked().update(fresh)
             digests = []
             for i, row in enumerate(digest_np):
                 digest, n_busy = split_digest_vec(row, self.cfg)
@@ -3741,20 +3786,19 @@ class PaxosManager:
 
     def _fresh_bal_exec(self, rows, body, whole) -> Dict[str, np.ndarray]:
         """``bal`` and ``exec_slot`` of the blob a step just made, before
-        the mirror shows it: private copies (the mirror is patched in
-        place)."""
+        the mirror shows it: the manager's own pair (the mirror is
+        patched in place, and later), the news' rows written into it."""
         cfg, names = self.cfg, ("bal", "exec_slot")
         if whole is not None:
             blob = split_blob_vec(whole, cfg)
-            return {name: getattr(blob, name).copy() for name in names}
-        held = split_blob_vec(self.mirror.vec, cfg)
-        news = split_blob_vec(body, cfg._replace(n_groups=update_rows(cfg)))
-        out = {}
-        for name in names:
-            leaf = getattr(held, name).copy()
-            leaf[rows] = getattr(news, name)[:rows.size]
-            out[name] = leaf
-        return out
+            self._bal_exec = {
+                name: getattr(blob, name).copy() for name in names}
+        else:
+            news = split_blob_vec(
+                body, cfg._replace(n_groups=update_rows(cfg)))
+            for name in names:
+                self._bal_exec[name][rows] = getattr(news, name)[:rows.size]
+        return self._bal_exec
 
     def _whole_planes_locked(self, out_vec_row, live: bool) -> StepDigest:
         """A substep whose busy rows overflowed the device's digest: its
@@ -3819,9 +3863,7 @@ class PaxosManager:
         done, which is correct but serializing; the hot propose path
         avoids that via the carried lifecycle-leaf cache below)."""
         with self._step_locked():  # single-depth pipeline
-            pend = self._dispatch_locked(
-                update, heard, want_coord, carry=True,
-            )
+            pend = self._dispatch_locked(update, heard, want_coord)
             self._step_inflight = True
             self._step_thread = threading.get_ident()
             return pend
@@ -3866,11 +3908,27 @@ class PaxosManager:
         last = outs[-1]
         n_sub = len(outs)
         self._tick_no += 1
-        if any(
-            o.n_admitted.any() or o.n_committed.any()
-            or o.acc_new.any() or o.bal_new.any()
-            for o in outs
-        ):
+        # every pass below runs over the rows that hold a name, a block
+        # at a time, not over [G]: a row that admits, commits or raises
+        # a ballot has this node among its members (the step reports
+        # nothing of the rest)
+        blocks = self._member_rows_locked()
+        mx = self.metrics
+        mx.count("post_step_rows_scanned",
+                 sum(rows.size for rows, _members in blocks))
+        mx.count("post_step_rows_total", self.cfg.n_groups)
+        n_admit = n_dec = 0
+        risen = [np.zeros(0, np.int64)]
+        for rows, _members in blocks:
+            n_admit += sum(int(o.n_admitted[rows].sum()) for o in outs)
+            n_dec += sum(int(o.n_committed[rows].sum()) for o in outs)
+            risen.append(rows[np.flatnonzero(functools.reduce(
+                np.bitwise_or, [o.bal_new[rows] for o in outs]))])
+        # the rows whose promised ballot some substep raised: the flips
+        # below, and the journal's promises further down
+        pg_m = np.concatenate(risen)
+        if n_admit or n_dec or len(pg_m) \
+                or any(o.acc_new.any() for o in outs):
             self.last_progress_tick = self._tick_no
         # re-propose preempted requests at a fresh slot (PREEMPTED
         # analog), in substep order; appended AFTER the ring requeue
@@ -3884,27 +3942,20 @@ class PaxosManager:
                 if vid in self.arena and vid not in self.retained:
                     preempt_requeue.append((int(o.rows[k_]), vid))
         # per-step engine metrics: aggregate counters reduced from the
-        # vectorized step outputs — a few O(G) numpy sums per DISPATCH
-        # (the engine step itself is ~1ms), never per-request host work
-        mx = self.metrics
-        n_dec = int(sum(int(o.n_committed.sum()) for o in outs))
+        # vectorized step outputs — a few numpy sums over the member
+        # rows per DISPATCH, never per-request host work
         if n_dec:
             mx.count("decisions_executed", n_dec)
-        n_admit = int(sum(int(o.n_admitted.sum()) for o in outs))
         if n_admit:
             mx.count("requests_admitted", n_admit)
         if preempt_requeue:
             mx.count("preempts", len(preempt_requeue))
-        bal_rose = outs[0].bal_new
-        for o in outs[1:]:
-            bal_rose = bal_rose | o.bal_new
         flips = rises = 0
-        if bal_rose.any():
+        if len(pg_m):
             # coordinator flips: only on the rare dispatches where a
             # promised ballot rose (elections), and only the risen rows
             # are compared against the cached view; `bal` is the
             # dispatch-final state's, seeded from the blob
-            pg_m = np.nonzero(bal_rose)[0]
             bal_host = self._np("bal")
             self._bal_host = bal_host.copy()
             new_coord = ballot_coord(bal_host[pg_m]).astype(np.int32)
@@ -3966,26 +4017,28 @@ class PaxosManager:
         # Peer cursors arrive by host-channel gossip; unheard-from peers
         # hold the watermark down until they gossip (a long-dead member
         # is eventually bypassed via checkpoint transfer, not GC).
-        mask = self._np("member_mask")
-        R = self.cfg.n_replicas
-        rids = np.arange(R)
-        in_group = ((mask[None, :] >> rids[:, None]) & 1) == 1
-        cursors = np.stack([
-            self.peer_app_exec.get(r, self._zero_cursors)
-            if r != self.my_id else self.app_exec_slot
-            for r in range(R)
-        ])
         # A member more than JUMP_HORIZON behind the majority frontier no
         # longer holds the payload-retention watermark down: it can never
         # catch up through the rings and will recover via checkpoint
         # transfer instead (state_request/state_reply below) — without
         # this, one dead member pins every payload forever.
-        horizon = last.maj_exec.astype(np.int64) - self.jump_horizon
-        eligible = in_group & (cursors >= horizon[None, :])
-        cur_masked = np.where(eligible, cursors, np.iinfo(np.int64).max)
-        self._min_exec = np.where(
-            eligible.any(axis=0), cur_masked.min(axis=0), self._min_exec
-        )
+        cursors = [
+            self.app_exec_slot if r == self.my_id
+            else self.peer_app_exec.get(r, self._zero_cursors)
+            for r in range(self.cfg.n_replicas)
+        ]
+        for rows, members in blocks:
+            horizon = last.maj_exec[rows].astype(np.int64) \
+                - self.jump_horizon
+            lowest = np.full(rows.size, np.iinfo(np.int64).max)
+            ok = np.zeros(rows.size, bool)
+            for r, cursor in enumerate(cursors):
+                cursor = cursor[rows]
+                eligible = members[r] & (cursor >= horizon)
+                lowest = np.where(
+                    eligible, np.minimum(lowest, cursor), lowest)
+                ok |= eligible
+            self._min_exec[rows[ok]] = lowest[ok]
         # requeue what wasn't admitted: the ring staged queue slab i into
         # substep i's lanes, and the engine admits a contiguous prefix
         # per slab — admitted = union of slab prefixes, leftovers keep
@@ -4022,10 +4075,10 @@ class PaxosManager:
         # for the published blob, and the `journal` span holds the write.
         if self.logger is not None:
             with self._span("journal", cpu=False), self.logger.batch():
-                pg = np.nonzero(bal_rose)[0]
-                if len(pg):
+                if len(pg_m):
                     bal_np = self._np("bal")
-                    self.logger.log_promises(pg.astype(np.int32), bal_np[pg])
+                    self.logger.log_promises(
+                        pg_m.astype(np.int32), bal_np[pg_m])
                 # accept lanes changed by ANY substep, valued from the
                 # dispatch-final state: a lane overwritten by a LATER
                 # substep's accept implies its earlier slot was decided
@@ -4482,63 +4535,31 @@ class PaxosManager:
         the needed decisions can leave every peer's window entirely (a
         majority that paused+resumed keeps only >= frontier remnants),
         and a row in this state must heal by a (small-gap) jump."""
-        W = self.cfg.window
-        # post-step frontier derived from the step outputs (exec_base +
-        # newly executed) — the profiler caught the per-tick
-        # _np("exec_slot") device pull at ~4% of a loaded core, paid on
-        # EVERY tick for a detector that almost never fires
-        exec_np = (
-            out_np.exec_base.astype(np.int64)
-            + out_np.n_committed.astype(np.int64)
+        # over the rows that hold a name (taken anew: an execution above
+        # may have run a lifecycle op): a row without a member has no
+        # frontier, no majority and no peer, so it is never behind, and
+        # every path that frees or reuses a row disarms its stall timer
+        need: set = set()
+        for rows, members in self._member_rows_locked():
+            need.update(self._rows_behind(out_np, rows, members).tolist())
+        # (c) parked on a missing payload for too long, at any gap
+        need.update(
+            g for g, (t0, _slot) in self._payload_blocked.items()
+            if self._tick_no - t0 > self.PAYLOAD_BLOCKED_TICKS
         )
-        behind_dev = (out_np.maj_exec - exec_np) > W
-        behind_app = (exec_np - self.app_exec_slot) > self.jump_horizon
-        need = behind_dev | behind_app
-        for g, (t0, _slot) in self._payload_blocked.items():
-            if self._tick_no - t0 > self.PAYLOAD_BLOCKED_TICKS:
-                need[g] = True
-        # (d) frontier-stalled tracking, vectorized: (re)arm whenever the
-        # stalled SLOT changes; rows making progress or caught up disarm.
-        # Behind is measured against the MAX known frontier (own device
-        # frontier vs every peer's gossiped app cursor), not the majority
-        # frontier: the chaos soak found the inverted shape too — a
-        # MAJORITY stranded behind one resumed member, where maj_exec
-        # equals the stragglers' own frontier and a majority-based
-        # detector never fires (yet only that one member can donate the
-        # decisions, which left every window).
-        mask_np = self._np("member_mask")
-        peak = np.maximum(
-            exec_np.astype(np.int64), out_np.maj_exec.astype(np.int64)
-        )
-        for r, arr in self.peer_app_exec.items():
-            in_grp = ((mask_np >> r) & 1) == 1
-            peak = np.maximum(peak, np.where(in_grp, arr, 0))
-        behind = peak > exec_np
-        rearm = behind & (self._stall_slot != exec_np)
-        self._stall_since = np.where(
-            rearm, self._tick_no, np.where(behind, self._stall_since, -1)
-        )
-        self._stall_slot = np.where(behind, exec_np, -1)
-        need |= (
-            behind & (self._stall_since >= 0)
-            & (self._tick_no - self._stall_since > self.FRONTIER_STALLED_TICKS)
-        )
-        for g in self._needs_state:
-            need[g] = True
-        if self.hydrating_rows:
-            # un-hydrated rows LOOK app-lagged (cursor parked at the
-            # checkpoint frontier by design) but need hydration, not a
-            # donor pull — pulling would adopt peer state that the
-            # hydrator later overwrites with the stale checkpoint copy.
-            # Rows still behind after hydration pull on the next tick
-            need[np.fromiter(self.hydrating_rows, np.int64)] = False
-        if not need.any():
+        need |= self._needs_state
+        # un-hydrated rows LOOK app-lagged (cursor parked at the
+        # checkpoint frontier by design) but need hydration, not a
+        # donor pull — pulling would adopt peer state that the
+        # hydrator later overwrites with the stale checkpoint copy.
+        # Rows still behind after hydration pull on the next tick
+        need -= self.hydrating_rows
+        if not need:
             return
         versions = self._np("version")
         masks = self._np("member_mask")
         by_dst: Dict[int, List[Dict]] = {}
-        for g in np.nonzero(need)[0]:
-            g = int(g)
+        for g in sorted(int(g) for g in need):
             name = self.row_name.get(g)
             if name is None or self.names.get(name) != g:
                 continue  # only current-epoch mappings pull state
@@ -4561,6 +4582,50 @@ class PaxosManager:
             self.forward_out.append(
                 (dst, "state_request", {"rows": rows, "from": self.my_id})
             )
+
+    def _rows_behind(self, out_np, rows: np.ndarray,
+                     members: np.ndarray) -> np.ndarray:
+        """Detectors (a), (b) and (d) of :meth:`_maybe_request_state`
+        over one block of member rows (``members`` their [R, n] bits):
+        the stall timers of the block brought up to this tick, and the
+        rows of it that need a state pull."""
+        # post-step frontier derived from the step outputs (exec_base +
+        # newly executed) — the profiler caught the per-tick
+        # _np("exec_slot") device pull at ~4% of a loaded core, paid on
+        # EVERY tick for a detector that almost never fires
+        exec_np = (
+            out_np.exec_base[rows].astype(np.int64)
+            + out_np.n_committed[rows].astype(np.int64)
+        )
+        maj_exec = out_np.maj_exec[rows].astype(np.int64)
+        behind_dev = (maj_exec - exec_np) > self.cfg.window
+        behind_app = (exec_np - self.app_exec_slot[rows]) > self.jump_horizon
+        # (d) frontier-stalled tracking, vectorized: (re)arm whenever the
+        # stalled SLOT changes; rows making progress or caught up disarm.
+        # Behind is measured against the MAX known frontier (own device
+        # frontier vs every peer's gossiped app cursor), not the majority
+        # frontier: the chaos soak found the inverted shape too — a
+        # MAJORITY stranded behind one resumed member, where maj_exec
+        # equals the stragglers' own frontier and a majority-based
+        # detector never fires (yet only that one member can donate the
+        # decisions, which left every window).
+        peak = np.maximum(exec_np, maj_exec)
+        for r, arr in self.peer_app_exec.items():
+            peak = np.maximum(peak, np.where(members[r], arr[rows], 0))
+        behind = peak > exec_np
+        rearm = behind & (self._stall_slot[rows] != exec_np)
+        since = np.where(
+            rearm, self._tick_no,
+            np.where(behind, self._stall_since[rows], -1),
+        )
+        self._stall_since[rows] = since
+        self._stall_slot[rows] = np.where(behind, exec_np, -1)
+        return rows[
+            behind_dev | behind_app | (
+                behind & (since >= 0)
+                & (self._tick_no - since > self.FRONTIER_STALLED_TICKS)
+            )
+        ]
 
     def _serve_state_request(self, body: Dict) -> None:
         """Serve a consistent (device frontier == app cursor) snapshot of
