@@ -16,9 +16,9 @@ one ``all_gather`` of a packed int32 state blob over a 'replica' mesh axis
 (ICI), not per-group point-to-point messages.
 
 Layout (mirrors SURVEY.md §7):
-  utils/       config flags, delay profiler                   (ref: utils/)
+  utils/       config flags, disk-backed maps                 (ref: utils/)
   obs/         structured logging, per-request tracing,
-               engine metrics registry                        (ref: j.u.logging + RequestInstrumenter + DelayProfiler)
+               engine metrics registry                        (ref: j.u.logging + RequestInstrumenter)
   interfaces/  Replicable app SPI, Request types              (ref: gigapaxos/interfaces/)
   packets/     wire packets + tensor packing                  (ref: paxospackets/)
   ops/         the batched consensus kernels                  (ref: PaxosAcceptor/Coordinator)

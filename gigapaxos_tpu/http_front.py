@@ -16,11 +16,11 @@ Endpoints (reconfigurator):
   POST / {"type": "CREATE",  "name": N, "initialState": S}
   POST / {"type": "DELETE",  "name": N}
   POST / {"type": "RECONFIGURE", "name": N, "actives": [..]}
-  GET  /stats                   -> DelayProfiler + placement snapshot
+  GET  /stats                   -> placement snapshot
   GET  /metrics                 -> RC engine registry (placement gauges)
 Endpoints (active replica):
   POST / {"name": N, "request": value}   -> execute through consensus
-  GET  /stats                            -> DelayProfiler snapshot
+  GET  /stats                            -> engine registry (JSON)
   GET  /metrics                          -> engine registry (Prometheus)
 """
 
@@ -31,8 +31,6 @@ import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Callable, Dict, Optional, Tuple
 from urllib.parse import parse_qs, urlparse
-
-from .utils.profiler import DelayProfiler
 
 # HTTP op type -> (rc_client kind, ack kind) — HttpRequestType analog
 _RC_OPS = {
@@ -88,10 +86,8 @@ def _send_bytes(handler, code: int, data: bytes, ctype: str) -> None:
 
 
 def _metrics_body(metrics: Optional[Callable[[], str]]) -> str:
-    """The /metrics exposition: the node's registry render with the
-    DelayProfiler line riding along so one scrape sees both planes."""
-    body = metrics() if metrics is not None else ""
-    return body + "# delayprofiler " + DelayProfiler.get_stats() + "\n"
+    """The /metrics exposition: the node's registry render."""
+    return metrics() if metrics is not None else ""
 
 
 def _http_server(host: str, port: int, handler_cls) -> ThreadingHTTPServer:
@@ -152,10 +148,8 @@ def start_rc_http(
         def do_GET(self):
             path = urlparse(self.path).path
             if path == "/stats":
-                body = {"stats": DelayProfiler.get_stats()}
-                if stats is not None:
-                    body.update(stats() or {})
-                _send_json(self, 200, body)
+                _send_json(self, 200,
+                           (stats() or {}) if stats is not None else {})
                 return
             if path == "/metrics":
                 _send_text(self, 200, _metrics_body(metrics))
@@ -184,13 +178,16 @@ def start_ar_http(
     timeout_s: float = 20.0,
     overloaded: Optional[Callable[[], bool]] = None,
     metrics: Optional[Callable[[], str]] = None,
+    stats: Optional[Callable[[], Dict]] = None,
 ) -> ThreadingHTTPServer:
     """Mount the active-replica app-request API (HttpActiveReplica analog).
     ``propose(name, value, callback)`` is the manager's propose;
     ``overloaded()`` gates admission (503) so the MAX_OUTSTANDING back
     -pressure covers every entry path, not just the binary protocol;
     ``metrics()`` renders the node's engine-metrics registry as text
-    (``GET /metrics``, Prometheus-style — the obs-plane dump endpoint)."""
+    (``GET /metrics``, Prometheus-style — the obs-plane dump endpoint)
+    and ``stats()`` returns the same registry as a dict (``GET /stats``:
+    what the ``stats`` admin op's ``metrics`` field carries)."""
 
     class Handler(BaseHTTPRequestHandler):
         def log_message(self, *a):
@@ -199,7 +196,8 @@ def start_ar_http(
         def do_GET(self):
             path = urlparse(self.path).path
             if path == "/stats":
-                _send_json(self, 200, {"stats": DelayProfiler.get_stats()})
+                _send_json(self, 200,
+                           (stats() or {}) if stats is not None else {})
             elif path == "/metrics":
                 _send_text(self, 200, _metrics_body(metrics))
             else:

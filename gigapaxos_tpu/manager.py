@@ -240,36 +240,58 @@ def execute_uncoordinated(app, names, name: str, value: str, request_id,
     return True
 
 
+# the legs of a commit, each a histogram ``commit_leg_<leg>_ticks`` on
+# the tick counter of the node that observes it (METRICS.md).  At a
+# request's entry replica queue + away + gate tile its commit_ticks:
+# first put -> its vid first staged or forwarded -> its slot seen
+# decided here -> answered.  At its coordinator: forward taken in -> its
+# vid first staged (coord_queue) -> that vid seen decided (consensus).
+COMMIT_LEGS = ("queue", "away", "gate", "consensus", "coord_queue")
+
+
 class Outstanding:
     """Entry-replica callback table with TTL GC (GCConcurrentHashMap analog,
     ``PaxosManager.java:192-207``)."""
+
+    # the slots of an entry: time of the newest put, callback, time and
+    # manager tick of the FIRST put (a retransmission refreshes the
+    # callback and the TTL, not when the request entered), the manager
+    # tick in which it first LEFT the queue — staged into the ring here
+    # or forwarded; None until then — and whether that was a forward
+    T_NEWEST, CALLBACK, T_PUT, TICK_PUT, TICK_LEFT, FORWARDED = range(6)
 
     def __init__(self, timeout_s: Optional[float] = None):
         if timeout_s is None:
             timeout_s = Config.get_float(PC.REQUEST_TIMEOUT_S)
         self.timeout_s = timeout_s
-        # request id -> (time of the newest put, callback, time and
-        # manager tick of the FIRST put: a retransmission refreshes the
-        # callback and the TTL, not when the request entered)
-        self._map: Dict[int, Tuple[float, Callable, float, int]] = {}
+        self._map: Dict[int, list] = {}  # request id -> entry (above)
 
     def put(self, request_id: int, cb: Callable, tick: int,
             t: Optional[float] = None) -> None:
         """``t``: a caller-shared timestamp (batched ingress)."""
         t = time.time() if t is None else t
         old = self._map.get(request_id)
-        self._map[request_id] = (
-            (t, cb, t, tick) if old is None else (t, cb, old[2], old[3])
-        )
+        if old is None:
+            self._map[request_id] = [t, cb, t, tick, None, False]
+        else:
+            old[self.T_NEWEST], old[self.CALLBACK] = t, cb
 
-    def pop(self, request_id: int) -> Optional[Tuple[Callable, float, int]]:
-        """(callback, time and tick of the first put), or None."""
-        ent = self._map.pop(request_id, None)
-        return ent[1:] if ent else None
+    def leave(self, request_id: int, tick: int, forwarded: bool) -> None:
+        """The request's vid is staged into the ring or forwarded; only
+        the FIRST time counts (a vid preempted, forwarded again or
+        carried into the next epoch leaves once)."""
+        ent = self._map.get(request_id)
+        if ent is not None and ent[self.TICK_LEFT] is None:
+            ent[self.TICK_LEFT], ent[self.FORWARDED] = tick, forwarded
+
+    def pop(self, request_id: int) -> Optional[list]:
+        """The request's entry (its slots: see the class), or None."""
+        return self._map.pop(request_id, None)
 
     def gc(self) -> int:
         cut = time.time() - self.timeout_s
-        dead = [k for k, ent in self._map.items() if ent[0] < cut]
+        dead = [k for k, ent in self._map.items()
+                if ent[self.T_NEWEST] < cut]
         for k in dead:
             del self._map[k]
         return len(dead)
@@ -304,8 +326,17 @@ class PaxosManager:
         # fired apart from a program that has no such counter
         for key in ("requests_carried_over", "step_digest_dispatches",
                     "step_digest_overflows", "gather_updates_scattered",
-                    "gather_updates_whole", "gather_upload_bytes"):
+                    "gather_updates_whole", "gather_upload_bytes",
+                    "commit_requests_answered", "commit_requests_forwarded",
+                    "commit_legs_untiled"):
             self.metrics.count(key, 0)
+        # ... and so are the legs of a commit: a leg that never ran in a
+        # run (nothing forwarded) reads as a share of 0, not as nothing
+        self.metrics.register_hist("commit_ticks", TICK_BOUNDS)
+        self.metrics.register_hist("commit_entry_s")
+        for leg in COMMIT_LEGS:
+            self.metrics.register_hist("commit_leg_" + leg + "_ticks",
+                                       TICK_BOUNDS)
         # black-box flight recorder (obs/flight.py): always-on bounded
         # rings of per-step engine summaries + last-K decided
         # (group, slot, ballot, vid), dumped on divergence/exception/
@@ -638,6 +669,27 @@ class PaxosManager:
         self.queues: Dict[int, List[int]] = {}  # group row -> pending vids
         # vid -> (name, epoch) it was proposed under (admission guard)
         self.vid_scope: Dict[int, Tuple[str, int]] = {}
+        # vid -> (the tick in which it was first STAGED into the ring
+        # here, or None; before that: the tick in which each of its
+        # requests was taken in from a forward; its requests' ids).
+        # The coordinator's stamp for the commit legs: written where a
+        # foreign entry's vid is minted, a batch coalesced and a vid
+        # first staged, popped where the vid is seen decided — and with
+        # vid_meta wherever a vid dies undecided (forwarded on, coalesced,
+        # released with its row)
+        self.vid_stamp: Dict[int, Tuple] = {}
+        # what the requests answered since the last tick's end took, for
+        # the registry's one look a tick (_observe_legs_locked): ticks
+        # and seconds from the first put of each; (queue, away, gate)
+        # ticks of each that has every mark, and how many of those were
+        # forwarded; per decided vid staged here (ticks, requests); per
+        # forwarded-in request staged, its ticks in my queue
+        self._ans_ticks: List[int] = []
+        self._ans_entry_s: List[float] = []
+        self._leg_rows: List[Tuple[int, int, int]] = []
+        self._leg_forwarded = 0
+        self._leg_consensus: List[Tuple[int, int]] = []
+        self._leg_coord_queue: List[int] = []
         self.forward_out: List[Tuple[int, str, Dict]] = []  # (dst, kind, body)
         self._fired_callbacks: List[Tuple[Callable, int, Optional[str]]] = []
         self.app_exec_slot = np.zeros(G, np.int64)  # host app cursor per group
@@ -645,7 +697,9 @@ class PaxosManager:
         # delta ships SPARSE (a full [G] list per tick is O(G) host work
         # and wire bytes for idle groups)
         self._app_exec_dirty: set = set()
-        self.pending_exec: Dict[int, Dict[int, int]] = {}  # g -> slot -> vid
+        # g -> slot -> (vid, the tick in which this node saw it decided:
+        # None for a slot that came from the journal or a checkpoint)
+        self.pending_exec: Dict[int, Dict[int, Tuple]] = {}
         # executed payloads retained for straggler pulls until every live
         # member's frontier passes the slot (sync/catch-up analog; a peer
         # down past a checkpoint catches up via checkpoint transfer instead)
@@ -889,7 +943,7 @@ class PaxosManager:
             )
         for g_str, pend in (meta.get("pending_exec") or {}).items():
             self.pending_exec[int(g_str)] = {
-                int(s_): int(v) for s_, v in pend.items()
+                int(s_): (int(v), None) for s_, v in pend.items()
             }
         # stopped prior epochs never execute further on the host: the new
         # epoch's restore subsumed their trailing slots, and re-executing
@@ -1004,7 +1058,7 @@ class PaxosManager:
                 cursor = int(self.app_exec_slot[g])
                 for slot, vid in decs.items():
                     if slot >= cursor:
-                        pend.setdefault(slot, vid)
+                        pend.setdefault(slot, (vid, None))
                 if not pend:
                     del self.pending_exec[g]
         if arrays is not None:
@@ -1421,7 +1475,7 @@ class PaxosManager:
                 # was decided BEHIND it and is in no final state: carried.
                 dropped = self.pending_exec.pop(cur_row, None) or {}
                 if cur_row in self._stop_executed_rows:
-                    for slot_, vid_ in sorted(dropped.items()):
+                    for slot_, (vid_, _seen) in sorted(dropped.items()):
                         if vid_:
                             self._carry_behind_stop(
                                 name, cur_row, slot_, vid_)
@@ -1642,6 +1696,7 @@ class PaxosManager:
             except (ValueError, TypeError):
                 pass  # undecodable batch: the %64 inflight sweep heals
         self.vid_scope.pop(vid, None)
+        self.vid_stamp.pop(vid, None)
         _entry, rid = self.vid_meta.pop(vid, (None, None))
         if rid is not None and self.inflight.get(rid) == vid:
             del self.inflight[rid]
@@ -2860,13 +2915,18 @@ class PaxosManager:
                 self.vid_scope[vid] = (
                     name, int(self._np("version")[row])
                 )
+                now = time.time()
                 self.inflight[request_id] = vid
-                self._inflight_since[request_id] = time.time()
+                self._inflight_since[request_id] = now
                 if callback is not None:
                     self.outstanding.put(request_id, callback,
-                                         self._tick_no)
+                                         self._tick_no, now)
+                if entry != self.my_id:
+                    # taken in from its entry replica's forward
+                    self.vid_stamp[vid] = (
+                        None, (self._tick_no,), (request_id,))
                 self.queues.setdefault(row, []).append(vid)
-                self.row_activity[row] = time.time()
+                self.row_activity[row] = now
                 self.demand_counts[name] = self.demand_counts.get(name, 0) + 1
                 self.demand_backlog += 1
                 self._install_trace_locked(request_id, trace_ctx)
@@ -2909,6 +2969,7 @@ class PaxosManager:
                                  force=trace_ctx is not None,
                                  **self._tc_detail(trace_ctx))
             if callback:
+                self._count_cached_answers(1)
                 callback(request_id, cached_response)
             return None
         return vid
@@ -2964,6 +3025,7 @@ class PaxosManager:
         default_entry = self.my_id if entry_replica is None else entry_replica
         tr_on = self.tracer.enabled
         with self._state_lock:
+            taken_in = (self._tick_no,)  # the frame's forwarded-in requests
             versions = self._np("version")
             names, cache = self.names, self.response_cache
             inflight, meta = self.inflight, self.vid_meta
@@ -3017,6 +3079,8 @@ class PaxosManager:
                 self._inflight_since[rid] = now
                 if cb is not None:
                     self.outstanding.put(rid, cb, self._tick_no, now)
+                if entry != self.my_id:
+                    self.vid_stamp[vid] = (None, taken_in, (rid,))
                 self.queues.setdefault(row, []).append(vid)
                 self.row_activity[row] = now
                 self.demand_counts[name] = self.demand_counts.get(name, 0) + 1
@@ -3030,9 +3094,17 @@ class PaxosManager:
                         tick=self._tick_no,
                         force=tc is not None, **self._tc_detail(tc),
                     )
+        if fired:
+            self._count_cached_answers(len(fired))
         for cb, rid, resp in fired:
             cb(rid, resp)
         return results
+
+    def _count_cached_answers(self, n: int) -> None:
+        """``n`` waiting callbacks are answered from the response cache
+        where they were proposed: answered, with no leg to show."""
+        self.metrics.count("commit_requests_answered", n)
+        self.metrics.count("commit_legs_untiled", n)
 
     def _awaits_decision_locked(self, request_id: int, row: int,
                                 now: float) -> bool:
@@ -3320,12 +3392,22 @@ class PaxosManager:
                 # every id namespace, so nothing ever dedups against it
                 self.vid_meta[bvid] = (self.my_id, -1)
                 self.vid_scope[bvid] = (name, epoch)
+                taken_in: List[int] = []
                 for v in chunk:
                     self.arena.pop(v, None)
                     _e, rid = self.vid_meta.pop(v, (None, None))
                     self.vid_scope.pop(v, None)
+                    st = self.vid_stamp.pop(v, None)
+                    if st is not None and st[0] is None:
+                        taken_in.extend(st[1])  # not staged alone before
                     if rid is not None and self.inflight.get(rid) == v:
                         self.inflight[rid] = bvid
+                # the batch is staged as one vid: its requests leave the
+                # queue, and its forwarded-in ones the coordinator's, when
+                # IT first is (a member staged alone before keeps the
+                # marks it left then)
+                self.vid_stamp[bvid] = (
+                    None, tuple(taken_in), tuple(sub[0] for sub in subs))
                 out.append(bvid)
             chunk.clear()
 
@@ -3355,6 +3437,8 @@ class PaxosManager:
         req = np.full((n_steps, G, K), NULL, np.int32)
         staged = 0
         bal = self._np("bal")
+        tick = self._tick_no  # in which whatever leaves the queue does
+        stamps, leave = self.vid_stamp, self.outstanding.leave
         for row, vids in list(self.queues.items()):
             if not vids:
                 continue
@@ -3415,19 +3499,22 @@ class PaxosManager:
                     self.arena.pop(vid, None)
                     self.vid_meta.pop(vid, None)
                     self.vid_scope.pop(vid, None)
+                    stamps.pop(vid, None)
                 if reqs:
                     # traced requests carry their context to the
                     # coordinator, hop-incremented (one process boundary)
                     fwd_tc = {}
                     tcm = self.trace_ctx
-                    for rid, _e, _v, _s in reqs:
+                    for rid, entry, _v, _s in reqs:
+                        if entry == self.my_id:
+                            leave(rid, tick, True)
                         tc = tcm.get(rid) if tcm else None
                         if tc is not None:
                             fwd_tc[str(rid)] = [tc[0], tc[1], tc[2] + 1]
                         if self.tracer.enabled or tc is not None:
                             self.tracer.note(
                                 rid, "forward-out", name=name,
-                                node=self.my_id, to=coord,
+                                node=self.my_id, to=coord, tick=tick,
                                 force=tc is not None,
                                 **self._tc_detail(tc),
                             )
@@ -3454,8 +3541,38 @@ class PaxosManager:
                 slab = take[off:off + K]
                 req[off // K, row, : len(slab)] = slab
             staged += len(take)
+            for vid in take:
+                st = stamps.get(vid)
+                if st is None or st[0] is None:
+                    self._first_staged_locked(row, vid, st, tick)
         self._last_ring_depth = staged
         return req
+
+    def _first_staged_locked(self, row: int, vid: int,
+                             st: Optional[Tuple], tick: int) -> None:
+        """``vid`` goes into the ring for the first time (a vid staged
+        and not admitted, or preempted, is staged again and comes here no
+        more): its requests that wait here have left the queue, its
+        requests taken in from a forward have left the coordinator's,
+        and the vid's own stamp starts its consensus leg.  ``st`` None:
+        a request of this node's own, alone."""
+        if st is None:
+            rids = (self.vid_meta.get(vid, (None, vid))[1],)
+        else:
+            rids = st[2]
+            self._leg_coord_queue.extend(tick - taken for taken in st[1])
+        self.vid_stamp[vid] = (tick, (), rids)
+        leave = self.outstanding.leave
+        tr_on, tcm = self.tracer.enabled, self.trace_ctx
+        for rid in rids:
+            leave(rid, tick, False)
+            tc = tcm.get(rid) if tcm else None
+            if tr_on or tc is not None:
+                self.tracer.note(
+                    rid, "admit", name=self.row_name.get(row),
+                    node=self.my_id, vid=vid, row=row, tick=tick,
+                    force=tc is not None, **self._tc_detail(tc),
+                )
 
     def tick_host(
         self,
@@ -4111,6 +4228,7 @@ class PaxosManager:
                 self._forwarded = {r: v for r, v in self._forwarded.items()
                                    if r in waiting}
         self._maybe_checkpoint(last)
+        self._observe_legs_locked()
 
         # periodic full-baseline refresh: a dropped gossip frame must not
         # strand peers' cursor views forever (the sparse delta has no
@@ -4157,6 +4275,7 @@ class PaxosManager:
 
     def _execute(self, out_np: StepDigest) -> None:
         committed = _committed_rows(out_np)
+        seen = self._tick_no  # in which this substep's slots show decided
         if committed:
             rows = [g for _k, g, _n in committed]
             now = time.time()
@@ -4180,10 +4299,15 @@ class PaxosManager:
             pend = self.pending_exec.setdefault(g, {})
             for o in range(n):
                 vid = int(out_np.exec_vid[k, o])
-                pend[base + o] = vid
+                pend[base + o] = (vid, seen)
                 self.flight.record_decided(g, base + o, bal_g, vid)
                 if vid == 0:
                     continue
+                st = self.vid_stamp.pop(vid, None)
+                if st is not None and st[0] is not None:
+                    # staged here: the coordinator's consensus leg, once
+                    # a request of the vid
+                    self._leg_consensus.append((seen - st[0], len(st[2])))
                 meta = self.vid_meta.get(vid)
                 key = vid if meta is None or meta[1] == -1 else meta[1]
                 tc = tcm.get(key) if tcm else None
@@ -4214,6 +4338,7 @@ class PaxosManager:
                     self.arena.pop(vid, None)
                     self.vid_meta.pop(vid, None)
                     self.vid_scope.pop(vid, None)
+                    self.vid_stamp.pop(vid, None)
 
     def _drain_pending_exec(self) -> List[int]:
         """Execute decided slots in order through the app, payload-gated;
@@ -4244,8 +4369,8 @@ class PaxosManager:
             cursor = int(self.app_exec_slot[g])
             blocked = False
             while cursor in pend:
-                vid = pend[cursor]
-                if not self._execute_one(name, g, cursor, vid):
+                vid, seen = pend[cursor]
+                if not self._execute_one(name, g, cursor, vid, seen):
                     # payload not here yet: pull it, and with it every
                     # later decided slot's that is missing too — a node
                     # that was away for a while is several slots behind
@@ -4254,7 +4379,7 @@ class PaxosManager:
                     # chip, PR 35) — and retry next tick
                     arena = self.arena
                     missing.extend(
-                        v for _s, v in sorted(pend.items())
+                        v for _s, (v, _seen) in sorted(pend.items())
                         if v and v not in arena)
                     blocked = True
                     break
@@ -4319,23 +4444,70 @@ class PaxosManager:
         skips the cache for the same decided entry."""
         return not getattr(req, "txn_retry", False)
 
-    def _answer(self, request_id: int, response: Optional[str]) -> None:
+    def _answer(self, request_id: int, response: Optional[str],
+                seen: Optional[int] = None) -> None:
         """Entry replica, lock held: queue the waiting client callback
-        (fired after the lock) and record how long the commit took on
-        this node's own clocks — manager ticks and seconds from the
-        request's first put to now."""
+        (fired after the lock) and note how long the commit took on this
+        node's own clocks — manager ticks and seconds from the request's
+        first put to now (``commit_ticks``, ``commit_entry_s``) — and the
+        ticks leg by leg: from the first put, to its vid first leaving
+        the queue, to ``seen`` (the tick in which this node saw its slot
+        decided), to now.  The three add up to its ``commit_ticks``; with
+        a mark missing or out of order the request has no legs and counts
+        as untiled.  The tick's end hands all of it to the registry
+        (:meth:`_observe_legs_locked`)."""
         self._forwarded.pop(request_id, None)
         ent = self.outstanding.pop(request_id)
         if ent is None:
             return
-        cb, t_put, tick_put = ent
+        _t, cb, t_put, tick_put, tick_left, forwarded = ent
         self._fired_callbacks.append((cb, request_id, response))
-        mx = self.metrics
-        mx.observe("commit_ticks", self._tick_no - tick_put,
-                   bounds=TICK_BOUNDS)
-        mx.observe("commit_entry_s", time.time() - t_put)
+        tick = self._tick_no
+        self._ans_ticks.append(tick - tick_put)
+        self._ans_entry_s.append(time.time() - t_put)
+        if tick_left is None or seen is None or seen < tick_left:
+            return
+        self._leg_rows.append(
+            (tick_left - tick_put, seen - tick_left, tick - seen))
+        if forwarded:
+            self._leg_forwarded += 1
 
-    def _execute_one(self, name: Optional[str], g: int, slot: int, vid: int) -> bool:
+    def _observe_legs_locked(self) -> None:
+        """Hand what the requests answered and the vids decided since
+        the last tick's end took to the registry: one ``observe_bulk`` a
+        histogram, whatever their number."""
+        mx, tb = self.metrics, TICK_BOUNDS
+        ticks = self._ans_ticks
+        if ticks:
+            rows = self._leg_rows
+            mx.observe_bulk("commit_ticks", ticks, bounds=tb)
+            mx.observe_bulk("commit_entry_s", self._ans_entry_s)
+            mx.count("commit_requests_answered", len(ticks))
+            if rows:
+                queue, away, gate = zip(*rows)
+                mx.observe_bulk("commit_leg_queue_ticks", queue, bounds=tb)
+                mx.observe_bulk("commit_leg_away_ticks", away, bounds=tb)
+                mx.observe_bulk("commit_leg_gate_ticks", gate, bounds=tb)
+            if len(rows) < len(ticks):
+                mx.count("commit_legs_untiled", len(ticks) - len(rows))
+            if self._leg_forwarded:
+                mx.count("commit_requests_forwarded", self._leg_forwarded)
+            self._ans_ticks, self._ans_entry_s, self._leg_rows = [], [], []
+            self._leg_forwarded = 0
+        if self._leg_consensus:
+            # once a request: a coalesced batch of n counts n times
+            mx.observe_bulk(
+                "commit_leg_consensus_ticks",
+                [t for t, n in self._leg_consensus for _ in range(n)],
+                bounds=tb)
+            self._leg_consensus.clear()
+        if self._leg_coord_queue:
+            mx.observe_bulk("commit_leg_coord_queue_ticks",
+                            self._leg_coord_queue, bounds=tb)
+            self._leg_coord_queue.clear()
+
+    def _execute_one(self, name: Optional[str], g: int, slot: int, vid: int,
+                     seen: Optional[int] = None) -> bool:
         if vid == 0:  # NOOP hole-filler: nothing to execute
             return True
         if g in self._stop_executed_rows:
@@ -4370,7 +4542,8 @@ class PaxosManager:
                 if request_id in rc or request_id in done:
                     skipped += 1
                     self._answer(request_id, rc[request_id][1]
-                                 if request_id in rc else done[request_id])
+                                 if request_id in rc else done[request_id],
+                                 seen)
                     continue
                 req = SlimRequest(nm, request_id, value)
                 self._app_execute_retrying(req, do_not_reply=(entry != my))
@@ -4387,7 +4560,7 @@ class PaxosManager:
                 response = req.response_value
                 if self._cacheable(req):
                     done[request_id] = response
-                self._answer(request_id, response)
+                self._answer(request_id, response, seen)
             self._executed.add(nm, done.items())
             if skipped:
                 self.metrics.count("executions_skipped_duplicate", skipped)
@@ -4403,7 +4576,8 @@ class PaxosManager:
             # (dedup.py) — and answered with the first execution's
             # response wherever a client waits for it
             self.metrics.count("executions_skipped_duplicate")
-            self._answer(request_id, self.response_cache[request_id][1])
+            self._answer(request_id, self.response_cache[request_id][1],
+                         seen)
             self.retained[vid] = (g, slot)
             return True
         req = SlimRequest(
@@ -4440,7 +4614,7 @@ class PaxosManager:
                 pass  # reconfiguration-layer hook must not wedge execution
         # whoever holds a client's callback for this id answers it: the
         # entry replica, or another the client moved to meanwhile
-        self._answer(request_id, response)
+        self._answer(request_id, response, seen)
         self.retained[vid] = (g, slot)  # keep for straggler pulls
         return True
 
@@ -4891,7 +5065,7 @@ class PaxosManager:
             "vid_meta": {k: list(v) for k, v in self.vid_meta.items()},
             "app_exec_slot": self.app_exec_slot.tolist(),
             "pending_exec": {
-                str(g): {str(s_): v for s_, v in pend.items()}
+                str(g): {str(s_): v for s_, (v, _seen) in pend.items()}
                 for g, pend in self.pending_exec.items()
             },
         })

@@ -19,11 +19,14 @@ from __future__ import annotations
 import asyncio
 import struct
 import threading
+import time
 from typing import Any, Callable, Dict, Optional, Tuple
 
+from ..obs import gplog
 from ..obs.metrics import ROW_BOUNDS
 from ..obs.spans import span
 
+_LOG = gplog.get_logger("transport")
 MAGIC = 0x47503270  # "GP2p"
 _HDR = struct.Struct(">II")  # magic, payload length
 MAX_PAYLOAD = 256 * 1024 * 1024
@@ -84,6 +87,11 @@ class MessageTransport:
                         "blob_bytes_written", "blob_bytes_sent",
                         "blob_frames_delta", "blob_frames_full"):
                 metrics.count(key, 0)
+            # what a frame waits for this loop's turn, out and in: a
+            # reply from reply() to its write on the loop thread, and the
+            # loop's own lateness (_probe_lag)
+            metrics.register_hist("transport_reply_lag_s")
+            metrics.register_hist("transport_loop_lag_s")
         # frames of a latest-wins slot are encoded at the sender's turn,
         # against the base THIS connection last carried (see LatestEncoder)
         self._latest_encoder = latest_encoder
@@ -114,6 +122,8 @@ class MessageTransport:
         # sent: their next latest-wins frame stands alone
         self._base_forgotten: set = set()
         self._server: Optional[asyncio.AbstractServer] = None
+        self._lag_probe: Optional[asyncio.TimerHandle] = None
+        self._reply_lags: list = []  # this turn's, loop thread only
         self._started = threading.Event()
         self._stopped = False
         self.n_sent = 0
@@ -140,6 +150,21 @@ class MessageTransport:
             # ephemeral bind: report the kernel-chosen port (race-free
             # alternative to probe-and-rebind in tests/tools)
             self.listen_port = self._server.sockets[0].getsockname()[1]
+        if self.metrics is not None:
+            self._probe_lag(None)
+
+    LAG_PROBE_S = 0.1
+
+    def _probe_lag(self, due: Optional[float]) -> None:
+        """The event loop's health probe: a timer every LAG_PROBE_S that
+        observes how late the loop ran it (``transport_loop_lag_s``) —
+        what a frame that arrives, or a marker that is queued, waits for
+        this loop's turn.  Runs on the loop; ``stop`` cancels it."""
+        now = self._loop.time()
+        if due is not None:
+            self.metrics.observe("transport_loop_lag_s", max(0.0, now - due))
+        due = now + self.LAG_PROBE_S
+        self._lag_probe = self._loop.call_at(due, self._probe_lag, due)
 
     def stop(self) -> None:
         if self._stopped:
@@ -147,6 +172,8 @@ class MessageTransport:
         self._stopped = True
 
         async def _shutdown():
+            if self._lag_probe is not None:
+                self._lag_probe.cancel()
             if self._server is not None:
                 self._server.close()
             # cancel every task on this loop (senders AND the per-connection
@@ -175,12 +202,29 @@ class MessageTransport:
     # forward path; clients retransmit)
     REPLY_BUFFER_LIMIT = 8 * 1024 * 1024
 
+    def _note_reply_lag(self, lag_s: float) -> None:
+        """On the loop: a reply's wait for this loop's turn.  The lags of
+        one turn of the loop go to the registry together, once the
+        turn's writes are done — one lock a turn, not one a frame."""
+        if self.metrics is None:
+            return
+        if not self._reply_lags:
+            self._loop.call_soon(self._flush_reply_lags)
+        self._reply_lags.append(lag_s)
+
+    def _flush_reply_lags(self) -> None:
+        lags, self._reply_lags = self._reply_lags, []
+        self.metrics.observe_bulk("transport_reply_lag_s", lags)
+
     async def _on_connection(self, reader: asyncio.StreamReader, writer):
         peer = writer.get_extra_info("peername") or ("?", 0)
         self._inbound.add(writer)
 
         def reply(payload: bytes) -> None:
+            t_called = time.perf_counter()
+
             def _w():
+                self._note_reply_lag(time.perf_counter() - t_called)
                 try:
                     if writer.transport.get_write_buffer_size() \
                             > self.REPLY_BUFFER_LIMIT:
@@ -201,8 +245,13 @@ class MessageTransport:
                 self.n_rcvd += 1
                 try:
                     self.handler(payload, peer, reply)
-                except Exception:
-                    pass  # handler errors must not kill the read loop
+                except Exception as exc:
+                    # handler errors must not kill the read loop — but the
+                    # frame is gone, and with it requests nobody will
+                    # answer: say so, once a kind of error
+                    gplog.warn_once(_LOG, type(exc).__name__,
+                                    "frame handler raised, frame dropped: "
+                                    "%r", exc)
         except (asyncio.IncompleteReadError, ConnectionError):
             pass
         except asyncio.CancelledError:

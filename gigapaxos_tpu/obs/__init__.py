@@ -14,8 +14,8 @@ package recreates all three for the array-world runtime:
   attribute check when disabled.
 * :mod:`.metrics` — a histogram-capable counter/gauge registry for the
   per-step engine aggregates (decisions, preempts, coordinator flips,
-  frontier stalls, blob bytes), complementing the EWMA-only
-  :class:`~gigapaxos_tpu.utils.profiler.DelayProfiler`.
+  frontier stalls, blob bytes) and the legs of a commit
+  (``commit_leg_*``: the request tracer's marks, aggregated).
 * :mod:`.spans` — the one span primitive: a timed phase observed into
   the node's registry (``phase_<phase>_s``, with the thread's CPU time
   beside it) and annotated onto the profiler's clock (``gp.<phase>``).

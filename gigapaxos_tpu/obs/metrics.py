@@ -1,12 +1,11 @@
 """Engine metrics registry: counters, gauges, histograms.
 
-Extends the EWMA-only :class:`~gigapaxos_tpu.utils.profiler.DelayProfiler`
-(the reference's string-keyed global) with the two things a serving stack
-needs that an EWMA can't give: exact monotonic counters reduced from the
-vectorized engine's per-step outputs (decisions executed, requests
-admitted, preempts, coordinator flips, ...) and latency DISTRIBUTIONS
-(log-spaced histogram buckets — an average engine-step time hides the
-p99 stall that actually wedges a tick loop).
+What a serving stack needs and an EWMA can't give: exact monotonic
+counters reduced from the vectorized engine's per-step outputs
+(decisions executed, requests admitted, preempts, coordinator flips,
+...) and latency DISTRIBUTIONS (log-spaced histogram buckets — an
+average engine-step time hides the p99 stall that actually wedges a
+tick loop).
 
 One registry per node (``PaxosManager.metrics``), surfaced three ways:
 
@@ -18,7 +17,11 @@ One registry per node (``PaxosManager.metrics``), surfaced three ways:
 Updates are per-STEP aggregates and per-phase spans (``obs/spans.py``),
 not per-request — a few numpy reductions and some thirty observations
 per tick — so the registry stays on unconditionally; only per-request
-tracing is gated.
+tracing is gated.  The legs of a commit (``commit_leg_*``) are per
+request in what they count and per tick in what they cost: the manager
+gathers a tick's legs and hands each histogram ONE ``observe_bulk``.
+What is read from outside the hot path altogether (the threads' CPU
+clocks) comes through :meth:`MetricsRegistry.add_collector`.
 """
 
 from __future__ import annotations
@@ -26,7 +29,10 @@ from __future__ import annotations
 import gc
 import os
 import threading
-from typing import Dict, List, Optional, Sequence, Tuple
+from bisect import bisect_left, bisect_right
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
+
+import numpy as np
 
 # default bounds suit SECONDS-valued latencies (100us .. 10s, log-ish)
 DEFAULT_BOUNDS = (
@@ -60,19 +66,29 @@ class Histogram:
 
     def observe(self, x: float) -> None:
         x = float(x)
-        lo = 0
-        hi = len(self.bounds)
-        while lo < hi:  # bisect: first bound >= x
-            mid = (lo + hi) // 2
-            if x <= self.bounds[mid]:
-                hi = mid
-            else:
-                lo = mid + 1
-        self.buckets[lo] += 1
+        self.buckets[bisect_left(self.bounds, x)] += 1  # first bound >= x
         self.count += 1
         self.total += x
         self.min = x if self.min is None or x < self.min else self.min
         self.max = x if self.max is None or x > self.max else self.max
+
+    def observe_many(self, vals: Sequence[float]) -> None:
+        """Fold a list of samples without a call that gives up the
+        interpreter lock: sort them, then one bisect a bound."""
+        sv = sorted(vals)
+        n, below, buckets = len(sv), 0, self.buckets
+        for i, b in enumerate(self.bounds):
+            upto = bisect_right(sv, b, below)  # samples <= this bound
+            buckets[i] += upto - below
+            below = upto
+            if below == n:
+                break
+        buckets[-1] += n - below  # over the last bound
+        self.count += n
+        self.total += float(sum(sv))
+        lo, hi = float(sv[0]), float(sv[-1])
+        self.min = lo if self.min is None or lo < self.min else self.min
+        self.max = hi if self.max is None or hi > self.max else self.max
 
     def snapshot(self) -> Dict:
         # ALL buckets ship, zeros included: Prometheus histogram_quantile
@@ -100,6 +116,23 @@ class MetricsRegistry:
         self._counters: Dict[str, float] = {}
         self._gauges: Dict[str, float] = {}
         self._hists: Dict[str, Histogram] = {}
+        self._collectors: List[Callable[[], None]] = []
+
+    def add_collector(self, fn: Callable[[], None]) -> None:
+        """``fn`` runs at the head of every :meth:`snapshot` (and so of
+        :meth:`render`), outside the lock: it brings up to date what is
+        read only when somebody looks (a thread's CPU clock), so that
+        nothing on a hot path pays for it."""
+        self._collectors.append(fn)
+
+    def register_hist(self, key: str,
+                      bounds: Optional[Sequence[float]] = None) -> None:
+        """An EMPTY histogram under ``key``, as ``count(key, 0)`` gives a
+        counter at 0: a snapshot shows a leg that never ran apart from a
+        program that has no such leg."""
+        with self._lock:
+            if key not in self._hists:
+                self._hists[key] = Histogram(bounds)
 
     # ---- update -------------------------------------------------------
     def count(self, key: str, n: float = 1) -> None:
@@ -125,35 +158,37 @@ class MetricsRegistry:
     def observe_bulk(self, key: str, values,
                      bounds: Optional[Sequence[float]] = None) -> None:
         """Fold MANY histogram samples under one lock acquisition — the
-        stats-cadence face of :meth:`observe` for vectorized sources
-        (the ``group_heat`` pull hands over one value per active group;
-        taking the lock per group would make the stats tick O(G) lock
-        traffic).  Bucketing is vectorized via numpy when available;
-        ``bounds`` is first-wins exactly like :meth:`observe`."""
-        vals = list(values) if not hasattr(values, "__len__") else values
-        if len(vals) == 0:
+        face of :meth:`observe` for sources that come many at a time
+        (the ``group_heat`` pull hands over one value per active group,
+        a tick the legs of every commit it answered; taking the lock
+        per sample would make either O(n) lock traffic).  A numpy array
+        is bucketed by numpy; a list or a tuple in the interpreter
+        (:meth:`Histogram.observe_many`) — on a tick thread, every
+        tick, numpy's sort and ``searchsorted`` each give up the
+        interpreter lock whatever the size, and getting it back is what
+        a tick pays for (PERF.md section 6, PR 37).  ``bounds`` is
+        first-wins exactly like :meth:`observe`."""
+        if len(values) == 0:
             return
+        arr = values if isinstance(values, np.ndarray) else None
         with self._lock:
             h = self._hists.get(key)
             if h is None:
                 h = self._hists[key] = Histogram(bounds)
-            try:
-                import numpy as np
-
-                arr = np.asarray(vals, np.float64)
-                idx = np.searchsorted(
-                    np.asarray(h.bounds, np.float64), arr, side="left"
-                )
-                for i, n in zip(*np.unique(idx, return_counts=True)):
-                    h.buckets[int(i)] += int(n)
-                h.count += int(arr.size)
-                h.total += float(arr.sum())
-                lo, hi = float(arr.min()), float(arr.max())
-                h.min = lo if h.min is None or lo < h.min else h.min
-                h.max = hi if h.max is None or hi > h.max else h.max
-            except ImportError:
-                for x in vals:
-                    h.observe(x)
+            if arr is None:
+                h.observe_many(values)
+                return
+            arr = arr.astype(np.float64, copy=False)
+            idx = np.searchsorted(
+                np.asarray(h.bounds, np.float64), arr, side="left"
+            )
+            for i, n in zip(*np.unique(idx, return_counts=True)):
+                h.buckets[int(i)] += int(n)
+            h.count += int(arr.size)
+            h.total += float(arr.sum())
+            lo, hi = float(arr.min()), float(arr.max())
+            h.min = lo if h.min is None or lo < h.min else h.min
+            h.max = hi if h.max is None or hi > h.max else h.max
 
     def remove(self, key: str) -> None:
         """Retire a metric series (e.g. a per-node gauge of a removed
@@ -177,6 +212,8 @@ class MetricsRegistry:
 
     def snapshot(self) -> Dict:
         """JSON-safe structured dump (the ``stats`` admin-op body)."""
+        for collect in self._collectors:
+            collect()
         with self._lock:
             return {
                 "node": self.node,

@@ -33,9 +33,16 @@ PHASE_LABELS = {
     ("send", "recv"): "client-wire",
     ("recv", "propose"): "ingress",
     ("recv", "respond-cached"): "cached-answer",
+    # the commit legs' marks (manager.py:COMMIT_LEGS), per request: for a
+    # traced request a hop's dticks are what its node's commit_leg_*
+    # histograms were handed for it
+    ("propose", "admit"): "admission-queue",
     ("propose", "forward-out"): "admission-queue",
     ("forward-out", "forward-in"): "forward-wire",
+    ("forward-out", "decide"): "away",
     ("forward-in", "propose"): "re-propose",
+    ("forward-in", "admit"): "admission-queue",
+    ("admit", "decide"): "consensus",
     ("propose", "decide"): "consensus",
     ("decide", "decide"): "exchange",
     ("decide", "execute"): "execute-gate",

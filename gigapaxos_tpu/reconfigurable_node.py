@@ -117,6 +117,7 @@ class ActiveReplicaServer(PaxosServer):
                 ),
                 overloaded=self.manager.overloaded,
                 metrics=self.manager.metrics.render,
+                stats=self.manager.metrics.snapshot,
             )
         except OSError:
             pass  # HTTP port taken: binary protocol still fully serves

@@ -48,6 +48,13 @@ from .paxos_config import PC
 from .utils.config import Config
 
 
+# counters the registry's collector advances (seconds): the CPU clock of
+# this node's tick thread and of its transport loop(s), the process's,
+# and the wall clock they are read beside
+THREAD_CLOCKS = ("thread_cpu_tick_s", "thread_cpu_transport_s",
+                 "process_cpu_s", "thread_wall_s")
+
+
 class PaxosServer:
     def __init__(
         self,
@@ -134,8 +141,10 @@ class PaxosServer:
         for key in ("blob_frames_received", "blob_frames_replaced_unread",
                     "blob_base_mismatch", "ticks", "ticks_noprog",
                     "ticks_inflight_noprog", "ticks_without_fresh_blob",
-                    "crash_emulations", "frames_dropped_while_crashed"):
+                    "crash_emulations", "frames_dropped_while_crashed",
+                    *THREAD_CLOCKS):
             self.manager.metrics.count(key, 0)  # present from the start
+        self.manager.metrics.register_hist("commit_leg_flush_s")
         # the emulated crash (upstream's TESTPaxosConfig.crash/isCrashed:
         # a crashed node's traffic is dropped): until this moment on the
         # monotonic clock the node takes nothing in, sends nothing out
@@ -190,7 +199,10 @@ class PaxosServer:
         # pipeline boundary — ingress handlers only buffer, so one
         # syscall carries every completion a cycle produced for a peer
         self._resp_lock = threading.Lock()
-        self._resp_buf: Dict[Tuple[int, bool], Tuple[Callable, list, bool]] = {}
+        # (connection, binary) -> (reply, items, binary, when the first
+        # of this cycle's items was buffered: commit_leg_flush_s)
+        self._resp_buf: Dict[Tuple[int, bool],
+                             Tuple[Callable, list, bool, float]] = {}
         # connections that spoke the binary 'R' request frame get binary
         # 'S' response frames; weak so short-lived client connections
         # don't accumulate (the reply closure dies with its connection)
@@ -224,6 +236,44 @@ class PaxosServer:
         self._thread = threading.Thread(
             target=self._run, name=f"paxos-server-{my_id}", daemon=True
         )
+        # who has the interpreter: this node's threads' CPU clocks, read
+        # when somebody looks at the registry and at no other time
+        self._clocks_lock = threading.Lock()
+        # as THREAD_CLOCKS: tick thread, loops, process, wall
+        self._clocks_seen = (0.0, 0.0, time.process_time(), time.monotonic())
+        self.manager.metrics.add_collector(self._collect_thread_clocks)
+
+    def _collect_thread_clocks(self) -> None:
+        """Advance the four THREAD_CLOCKS counters by their growth since
+        the last look (the first: since this node was built).  Any
+        thread may read another's CPU clock (``pthread_getcpuclockid``);
+        where the platform has none, before the threads run and once the
+        node is asked to stop (a thread's clock dies with it) nothing
+        grows.  CPU spent in C with the interpreter lock released counts
+        as the thread's own."""
+        threads = [self._thread, self.transport._thread] + (
+            [self.client_transport._thread]
+            if self.client_transport is not None else [])
+        if self._stop.is_set() or not all(t.is_alive() for t in threads) \
+                or not hasattr(time, "pthread_getcpuclockid"):
+            return
+        with self._clocks_lock:  # two looks at once count once
+            try:
+                cpu = [
+                    time.clock_gettime(time.pthread_getcpuclockid(t.ident))
+                    for t in threads]
+            except OSError:
+                return
+            now = (cpu[0], sum(cpu[1:]), time.process_time(),
+                   time.monotonic())
+            tick, loops, process, wall = (
+                n - s for n, s in zip(now, self._clocks_seen))
+            self._clocks_seen = now
+        mx = self.manager.metrics
+        mx.count("thread_cpu_tick_s", tick)
+        mx.count("thread_cpu_transport_s", loops)
+        mx.count("process_cpu_s", process)
+        mx.count("thread_wall_s", wall)
 
     # ---- lifecycle -----------------------------------------------------
     def start(self) -> None:
@@ -522,7 +572,8 @@ class PaxosServer:
             key = (id(reply), binary)
             ent = self._resp_buf.get(key)
             if ent is None:
-                self._resp_buf[key] = (reply, [item], binary)
+                self._resp_buf[key] = (reply, [item], binary,
+                                       time.perf_counter())
             else:
                 ent[1].append(item)
 
@@ -546,7 +597,8 @@ class PaxosServer:
         tcm = m.trace_ctx
         tick = m._tick_no
         n_items = 0
-        for reply, items, binary in bufs.values():
+        waited = []  # per frame: first item buffered -> handed to reply()
+        for reply, items, binary, t_first in bufs.values():
             for item in items:
                 rid = item.get("request_id")
                 tc = tcm.get(rid) if tcm else None
@@ -574,6 +626,8 @@ class PaxosServer:
                 reply(encode_json(
                     "client_response_batch", self.my_id, {"resps": items}
                 ))
+            waited.append(time.perf_counter() - t_first)
+        mx.observe_bulk("commit_leg_flush_s", waited)
         if n_items:
             mx.count("responses_flushed", n_items)
             mx.count("response_frames_sent", len(bufs))

@@ -1,4 +1,3 @@
 from .config import Config
-from .profiler import DelayProfiler
 
-__all__ = ["Config", "DelayProfiler"]
+__all__ = ["Config"]

@@ -59,7 +59,8 @@ from typing import Iterator, Set, Tuple
 
 PACKAGE = "gigapaxos_tpu"
 EXEMPT_TOP_DIRS = ("obs",)
-METRIC_METHODS = ("count", "gauge", "observe", "observe_bulk")
+METRIC_METHODS = ("count", "gauge", "observe", "observe_bulk",
+                  "register_hist")
 METRICS_DOC = "METRICS.md"
 
 # Pseudo-leaf for the group-heat accumulator pull: `pull_group_heat()`

@@ -295,7 +295,7 @@ def test_rc_http_stats_and_metrics_under_tls(tmp_path):
         off = Config.get_int(PC.HTTP_PORT_OFFSET)
         for port, want in (
             (ports[1] + off, "placement"),   # RC front
-            (ports[0] + off, "stats"),       # AR front
+            (ports[0] + off, "counters"),    # AR front: its registry
         ):
             with urllib.request.urlopen(
                 f"https://127.0.0.1:{port}/stats", timeout=10,
@@ -308,7 +308,7 @@ def test_rc_http_stats_and_metrics_under_tls(tmp_path):
                 context=ctx,
             ) as resp:
                 text = resp.read().decode()
-            assert "# delayprofiler" in text
+            assert text.startswith("gp_")  # the node's registry, rendered
         # the RC /metrics carries its engine registry; the process
         # gauges land there at the stats cadence (refreshed by the tick
         # loop) — poll briefly rather than assume the cadence fired

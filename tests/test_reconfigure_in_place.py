@@ -247,7 +247,7 @@ def test_a_write_decided_behind_the_stop_executes_once_in_the_next_epoch():
             m.vid_meta[write_vid] = (1, 777_001)
             with m._state_lock:
                 m.pending_exec.setdefault(row, {}).update(
-                    {slot: stop_vid, slot + 1: write_vid})
+                    {slot: (stop_vid, None), slot + 1: (write_vid, None)})
                 m._drain_pending_exec()
         mgrs[1].outstanding.put(
             777_001, lambda rid, resp: answered.append(resp), 0)

@@ -1,10 +1,9 @@
-"""Unit tests for the config/flag system and profiler (mirrors the
+"""Unit tests for the config/flag system (mirrors the
 reference's utils self-tests, e.g. ``utils/UtilTest.java``)."""
 
 import enum
 
 from gigapaxos_tpu.utils.config import Config, parse_properties
-from gigapaxos_tpu.utils.profiler import DelayProfiler
 
 
 class Flags(enum.Enum):
@@ -39,15 +38,6 @@ def test_three_tiers(tmp_path):
 def test_parse_properties():
     props = parse_properties("a=1\nb: two\n!ignored\n\nc = 3 ")
     assert props == {"a": "1", "b": "two", "c": "3"}
-
-
-def test_profiler():
-    DelayProfiler.clear()
-    DelayProfiler.update_mov_avg("lat", 1.0)
-    DelayProfiler.update_count("reqs", 5)
-    assert DelayProfiler.get("lat") == 1.0
-    assert DelayProfiler.get("reqs") == 5
-    assert "lat" in DelayProfiler.get_stats()
 
 
 def test_flags_reach_the_framework(tmp_path):
