@@ -296,6 +296,16 @@ def _wait_for_elections(servers):
     return time.perf_counter() - t0
 
 
+def _entry_is_coordinator(ars, names):
+    """A configuration of ONE name runs in one of two modes, which a race
+    in set-up chooses (PERF.md section 7): per active, in the order of the
+    traffic's targets, whether it leads the name's row by its own state
+    (name 0 enters at target 0: true there means nothing is forwarded).
+    Read after the drain, so no judged number pays for it."""
+    return [s.manager.coordinator_of_row(s.manager.names[names[0]])
+            == s.manager.my_id for s in ars]
+
+
 def _create(client, names):
     """The names in batches of ``CREATE_BATCH``, one after the other, each
     sent once under its batch id: a batch sent again under the same id
@@ -478,6 +488,9 @@ def run_cell(config, traffic, metric_specs, end_to_end_names, seed, seconds,
             correct = correct and ok
             say(check=what, value=value, limit=limit, ok=ok,
                 **({"examples": examples} if not ok else {}))
+        # each number compared beside its limit: the result's last key
+        numbers = {what: {"value": value, "limit": limit}
+                   for what, value, limit, _ in compared}
 
         e2e, n_acks, lat = end_to_end(
             loop.reqs, t_start, t_end, float(traffic["fail_after_s"]),
@@ -500,7 +513,9 @@ def run_cell(config, traffic, metric_specs, end_to_end_names, seed, seconds,
                                             "coordinator_flips"),
             host_peak_rss_bytes=resource.getrusage(
                 resource.RUSAGE_SELF).ru_maxrss * 1024,
-            end_to_end={k: v[0] for k, v in e2e.items()})
+            end_to_end={k: v[0] for k, v in e2e.items()},
+            entry_is_coordinator=_entry_is_coordinator(ars, names)
+            if n_names == 1 else None)
 
         device = {
             "platform": devices[0].platform,
@@ -521,6 +536,7 @@ def run_cell(config, traffic, metric_specs, end_to_end_names, seed, seconds,
                 k: {"value": e2e[k][0], "unit": e2e[k][1]}
                 for k in end_to_end_names
             }
+            result["compared"] = numbers
             return result
         reduced = trace_reduce.reduce_dir(traced)
         say(trace={k: reduced[k] for k in ("planes", "busy_s", "window_s",
@@ -543,6 +559,7 @@ def run_cell(config, traffic, metric_specs, end_to_end_names, seed, seconds,
             "device_ops": reduced["top_ops"][:10],
             "idle_gaps": reduced["idle_gaps"][:10],
         }
+        result["compared"] = numbers
         return result
     finally:
         if client is not None:
@@ -589,6 +606,9 @@ def main():
     result = run_cell(config, traffic, metric_specs, e2e_names, args.seed,
                       args.seconds, bool(args.trace), "tpu",
                       chips=cell["chips"], t_process_start=_T0)
+    for what, n in result["compared"].items():  # standard error's last lines
+        print(f"compared {what} {n['value']} limit {n['limit']}",
+              file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

@@ -243,11 +243,11 @@ def test_one_active_goes_dark_in_the_window_and_the_run_is_correct(
         {s["name"] for s in specs} - set(got)
     assert 1000.0 <= got["failover.detect_ms.crs"]["value"] < 2500.0
     for name in ("failover.election_ms.crs", "failover.unserved_ms.crs",
-                 "failover.catchup_ms.crs", "tick.elections.crs"):
+                 "failover.catchup_ms.crs", "tick.elections.sat"):
         assert got[name]["value"] > 0, name
     # a fallback ran: a whole frame, a whole vector up
-    assert got["transport.blob_delta_share.crs"]["value"] < 100.0
-    assert got["tick.gather_scatter_share.crs"]["value"] < 100.0
+    assert got["transport.blob_delta_share.sat"]["value"] < 100.0
+    assert got["tick.gather_scatter_share.sat"]["value"] < 100.0
 
 
 def test_a_node_that_does_not_allow_the_crash_ends_the_run_in_set_up(tiny):

@@ -129,6 +129,8 @@ def test_the_schedule_runs_on_the_wall_clock_beside_the_writes(capsys):
         loop.poll()
         time.sleep(0.01)
     loop.stop()
+    # 50 a second for 0.5 s, or for as long as a busy box made of it
+    ran_s = time.perf_counter() - loop.t_start
     client.deliver(len(client.sends))
     for _ in range(100):
         if not loop.outstanding():
@@ -136,7 +138,7 @@ def test_the_schedule_runs_on_the_wall_clock_beside_the_writes(capsys):
         time.sleep(0.01)
     assert loop.outstanding() == 0
     loop.fail_outstanding()
-    assert 15 <= len(loop.reconfs) <= 27        # 50 a second for 0.5 s
+    assert 15 <= len(loop.reconfs) <= 50 * ran_s + 2
     due = [rc.t_due - loop.t_start for rc in loop.reconfs]
     assert due == pytest.approx([(k + 1) / 50.0 for k in range(len(due))])
     assert not loop.errors and len(loop.reqs) > len(NAMES)

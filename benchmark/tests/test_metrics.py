@@ -116,7 +116,6 @@ def test_names_units_and_files_agree_with_benchmark_json():
     assert bench["paths"] == ["benchmark"]
     configs = {c["name"]: c for c in bench["configs"]}
     cells = {w["name"] for w in bench["workloads"]}
-    e2e = {m["name"]: m for m in bench["end_to_end"]}
     for c in configs.values():
         body = json.load(open(os.path.join(ROOT, c["file"])))
         assert body["name"] == c["name"] and body["source"] == c["source"]
@@ -143,13 +142,126 @@ def test_names_units_and_files_agree_with_benchmark_json():
                                      "layer", "moves")} == \
             {k: m[k] for k in ("name", "unit", "better", "source",
                                "layer", "moves")}
-        assert spec["cells"] == m["workloads"]
         assert spec["reader"] in run.READERS
-        # every cell that reports the metric reports what it moves
-        for cell in m["workloads"]:
-            assert cell in e2e[m["moves"]].get("workloads", cells)
     for path, _, names in os.walk(BENCH):
         if "__pycache__" in path:
             continue
         for f in names:
             assert re.match(r"^[A-Za-z0-9_.\-]+$", f), f
+
+
+PER_LAYER_MAX = 128     # the driver's contract for BENCHMARK.json
+
+
+def _body(spec):
+    """What a metric IS: its file less what names and describes it and
+    where it is read.  ``moves`` is part of it: the same reader under
+    ``.sat`` and ``.lat`` is two metrics, judged by two end-to-end ones."""
+    return json.dumps({k: v for k, v in spec.items()
+                       if k not in ("name", "cells", "what", "twin_of")},
+                      sort_keys=True)
+
+
+def per_layer_faults(bench, specs):
+    """``per_layer`` was full at 128 (PR 37) because every new cell brought
+    a suffixed copy of each common metric.  A metric is ONE entry for a
+    reader and the end-to-end metric it moves, with the list of the cells
+    that report it.  A PR that may edit no file and so has to bring a copy
+    for its new cell says so in the copy's file (``"twin_of"``: the entry
+    whose list the next ``benchmark`` PR lengthens by the copy's cells)."""
+    faults = []
+    cells = {w["name"] for w in bench["workloads"]}
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    twins = sorted(n for n, s in specs.items() if "twin_of" in s)
+    n = len(bench["per_layer"])
+    if n > PER_LAYER_MAX:
+        faults.append(
+            f"per_layer holds {n} entries, {n - PER_LAYER_MAX} over the "
+            f"{PER_LAYER_MAX} the driver admits; merging the declared twins "
+            f"frees {len(twins)}: {twins}")
+    heads, read_in = {}, {}
+    for m in bench["per_layer"]:
+        name, spec = m["name"], specs[m["name"]]
+        body = _body(spec)
+        if spec["cells"] != m["workloads"]:
+            faults.append(f"{name}: workloads {m['workloads']} is not its "
+                          f"file's cells {spec['cells']}")
+        for cell in spec["cells"]:
+            if cell not in e2e[spec["moves"]].get("workloads", cells):
+                faults.append(f"{name}: {cell} does not report "
+                              f"{spec['moves']}")
+            other = read_in.setdefault((body, cell), name)
+            if other != name:
+                faults.append(f"{cell} reads {other} again as {name}")
+        if "twin_of" not in spec:
+            other = heads.setdefault(body, name)
+            if other != name:
+                faults.append(
+                    f"{name} is {other} again: one entry, with both lists "
+                    f"(or \"twin_of\": \"{other}\" in the file of a PR "
+                    "that may edit none)")
+    for name in twins:
+        if heads.get(_body(specs[name])) != specs[name]["twin_of"]:
+            faults.append(f"{name} is no copy of {specs[name]['twin_of']}")
+    return faults
+
+
+def test_one_entry_a_reader_and_judged_metric():
+    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    specs = {m["name"]: json.load(open(os.path.join(
+        BENCH, "layer_metrics", m["name"] + ".json")))
+        for m in bench["per_layer"]}
+    assert per_layer_faults(bench, specs) == []
+
+
+def _tiny_bench(*entries):
+    """Two cells judged by ``rps`` and one by ``p50``; ``entries`` are
+    (name, moves, cells, further keys of the file)."""
+    specs = {name: {"name": name, "moves": moves, "cells": list(cells),
+                    "reader": "stats_hist", "hist": "tick_s",
+                    "what": name + " in words", **more}
+             for name, moves, cells, more in entries}
+    return {"workloads": [{"name": c} for c in ("a", "b", "c")],
+            "end_to_end": [{"name": "rps", "workloads": ["a", "b"]},
+                           {"name": "p50", "workloads": ["c"]},
+                           {"name": "setup_s"}],
+            "per_layer": [{"name": n, "workloads": list(s["cells"])}
+                          for n, s in specs.items()]}, specs
+
+
+@pytest.mark.parametrize("entries, fault", [
+    # one reader under two judged metrics is two metrics
+    ([("t.sat", "rps", "ab", {}), ("t.lat", "p50", "c", {})], None),
+    # the copy a new cell used to bring: one entry with both cells instead
+    ([("t.sat", "rps", "a", {}), ("t.new", "rps", "b", {})],
+     "t.new is t.sat again"),
+    # ... unless its file says whose copy it is (a PR that may edit no file)
+    ([("t.sat", "rps", "a", {}), ("t.new", "rps", "b", {"twin_of": "t.sat"})],
+     None),
+    ([("t.sat", "rps", "a", {}), ("t.new", "rps", "b", {"twin_of": "t.lat"})],
+     "t.new is no copy of t.lat"),
+    ([("t.sat", "rps", "ab", {}), ("t.new", "rps", "b", {"twin_of": "t.sat"})],
+     "b reads t.sat again as t.new"),
+    ([("t.sat", "rps", "a", {}), ("t.n1", "rps", "b", {"twin_of": "t.sat"}),
+      ("t.n2", "rps", "b", {"twin_of": "t.sat"})],
+     "b reads t.n1 again as t.n2"),
+    # every cell of an entry reports what the entry moves
+    ([("t.sat", "rps", "ac", {})], "t.sat: c does not report rps"),
+    ([(f"t{i}.sat", "rps", "a", {"hist": f"h{i}"})
+      for i in range(PER_LAYER_MAX + 1)],
+     "per_layer holds 129 entries, 1 over the 128"),
+])
+def test_the_rule_that_keeps_per_layer_from_filling_up(entries, fault):
+    bench, specs = _tiny_bench(*entries)
+    faults = per_layer_faults(bench, specs)
+    if fault is None:
+        assert faults == []
+    else:
+        assert len(faults) == 1 and faults[0].startswith(fault), faults
+
+
+def test_workloads_is_the_files_cells():
+    bench, specs = _tiny_bench(("t.sat", "rps", "ab", {}))
+    bench["per_layer"][0]["workloads"] = ["a"]
+    assert per_layer_faults(bench, specs)[0].startswith(
+        "t.sat: workloads ['a'] is not its file's cells")

@@ -46,6 +46,10 @@ def test_a_sound_traced_run_is_correct_and_reports_its_metrics(tiny, tmp_path):
     json.dumps(result)
     assert result["correct"] is True and result["failed"] == 0
     assert result["attempted"] > 8
+    # each number compared beside its limit, as the result's last key
+    assert list(result)[-1] == "compared" and len(result["compared"]) == 6
+    assert all(n == {"value": 0, "limit": 0}
+               for n in result["compared"].values())
     assert result["device"]["busy_s"] > 0
     assert 0 < result["device"]["window_s"] < 3.0
     got = result["metrics"]
@@ -75,12 +79,17 @@ def test_a_broken_guarantee_comes_out_as_not_correct(tiny, workload, fault,
                               expect_platform="cpu")
     assert result["correct"] is False
     assert set(result["metrics"]) == set(e2e)
-    checks = [json.loads(line) for line in capsys.readouterr().out.splitlines()
-              if line.startswith('{"check"')]
-    failed = {c["check"] for c in checks if not c["ok"]}
+    assert list(result)[-1] == "compared"
+    assert result["compared"]["replica_total_mismatches"]["value"] > 0
+    lines = [json.loads(line) for line in capsys.readouterr().out.splitlines()]
+    failed = {c["check"] for c in lines if "check" in c and not c["ok"]}
     assert "replica_total_mismatches" in failed
     if fault != "replica_behind":
         assert "ack_value_mismatches" in failed
+    # a configuration of one name says which of its two modes the run was in
+    info, = [c for c in lines if "boot_s" in c]
+    assert info["entry_is_coordinator"] is None if config["names"] > 1 \
+        else sorted(info["entry_is_coordinator"]) == [False, False, True]
 
 
 def test_the_wrong_platform_comes_out_as_not_correct(tiny):
