@@ -78,7 +78,9 @@ from .net.mirror import PublishMirror, news_blocks
 from .parallel.spmd import make_step
 from .obs import gplog
 from .obs.flight import FlightRecorder
-from .obs.metrics import ROW_BOUNDS, TICK_BOUNDS, MetricsRegistry
+from .obs.metrics import (
+    BATCH_BOUNDS, ROW_BOUNDS, TICK_BOUNDS, MetricsRegistry,
+)
 from .obs.reqtrace import RequestTracer
 from .dedup import ExecutedIds
 from .obs.spans import observe_interval, span
@@ -328,7 +330,9 @@ class PaxosManager:
                     "step_digest_overflows", "gather_updates_scattered",
                     "gather_updates_whole", "gather_upload_bytes",
                     "commit_requests_answered", "commit_requests_forwarded",
-                    "commit_legs_untiled"):
+                    "commit_legs_untiled", "requests_admitted",
+                    "requests_staged", "requests_coalesced",
+                    "window_full_rows"):
             self.metrics.count(key, 0)
         # ... and so are the legs of a commit: a leg that never ran in a
         # run (nothing forwarded) reads as a share of 0, not as nothing
@@ -337,6 +341,10 @@ class PaxosManager:
         for leg in COMMIT_LEGS:
             self.metrics.register_hist("commit_leg_" + leg + "_ticks",
                                        TICK_BOUNDS)
+        # ... and what admission does with a row's queue (METRICS.md):
+        # requests a proposal when it is first staged, rows a dispatch
+        self.metrics.register_hist("proposal_requests", BATCH_BOUNDS)
+        self.metrics.register_hist("admission_rows", ROW_BOUNDS)
         # black-box flight recorder (obs/flight.py): always-on bounded
         # rings of per-step engine summaries + last-K decided
         # (group, slot, ballot, vid), dumped on divergence/exception/
@@ -484,8 +492,10 @@ class PaxosManager:
         self._heat_dev = jnp.zeros((G,), jnp.int32)
         self._heat_host = np.zeros(G, np.int64)
         # vids staged into the device request ring by the LAST dispatch
-        # (the device_queue_depth gauge)
+        # (the device_queue_depth gauge), and how many on which row: a
+        # row's queue may have grown by the time the step completes
         self._last_ring_depth = 0
+        self._last_ring_rows: Dict[int, int] = {}
         # test/emulation modes (PaxosManager.java:1731-1778): UNREPLICATED
         # answers at the entry replica without consensus (isolates app+wire
         # cost); LAZY_PROPAGATION additionally still drives consensus but
@@ -3435,7 +3445,8 @@ class PaxosManager:
         G, K = self.cfg.n_groups, self.cfg.req_lanes
         depth = K * n_steps
         req = np.full((n_steps, G, K), NULL, np.int32)
-        staged = 0
+        staged_on: Dict[int, int] = {}  # row -> vids staged on it
+        first: List[int] = []  # requests in each vid staged the first time
         bal = self._np("bal")
         tick = self._tick_no  # in which whatever leaves the queue does
         stamps, leave = self.vid_stamp, self.outstanding.leave
@@ -3540,22 +3551,29 @@ class PaxosManager:
             for off in range(0, len(take), K):
                 slab = take[off:off + K]
                 req[off // K, row, : len(slab)] = slab
-            staged += len(take)
+            staged_on[row] = len(take)
             for vid in take:
                 st = stamps.get(vid)
                 if st is None or st[0] is None:
-                    self._first_staged_locked(row, vid, st, tick)
-        self._last_ring_depth = staged
+                    first.append(
+                        self._first_staged_locked(row, vid, st, tick))
+        self._last_ring_depth = sum(staged_on.values())
+        self._last_ring_rows = staged_on
+        if first:
+            self.metrics.observe_bulk("proposal_requests", first)
+            self.metrics.count("requests_coalesced",
+                               sum(n for n in first if n > 1))
         return req
 
     def _first_staged_locked(self, row: int, vid: int,
-                             st: Optional[Tuple], tick: int) -> None:
+                             st: Optional[Tuple], tick: int) -> int:
         """``vid`` goes into the ring for the first time (a vid staged
         and not admitted, or preempted, is staged again and comes here no
         more): its requests that wait here have left the queue, its
         requests taken in from a forward have left the coordinator's,
         and the vid's own stamp starts its consensus leg.  ``st`` None:
-        a request of this node's own, alone."""
+        a request of this node's own, alone.  Returns the number of
+        client requests in the vid (``proposal_requests``)."""
         if st is None:
             rids = (self.vid_meta.get(vid, (None, vid))[1],)
         else:
@@ -3573,6 +3591,7 @@ class PaxosManager:
                     node=self.my_id, vid=vid, row=row, tick=tick,
                     force=tc is not None, **self._tc_detail(tc),
                 )
+        return len(rids)
 
     def tick_host(
         self,
@@ -4063,6 +4082,11 @@ class PaxosManager:
         # rows per DISPATCH, never per-request host work
         if n_dec:
             mx.count("decisions_executed", n_dec)
+        if self._last_ring_depth:
+            # what this dispatch staged beside what of it got in: the rest
+            # the window or the prefix rule turned back (requeue below)
+            mx.count("requests_staged", self._last_ring_depth)
+            mx.observe("admission_rows", len(self._last_ring_rows))
         if n_admit:
             mx.count("requests_admitted", n_admit)
         if preempt_requeue:
@@ -4163,6 +4187,8 @@ class PaxosManager:
         K = self.cfg.req_lanes
         payload_delta: Dict[int, str] = {}
         meta_delta: Dict[int, Tuple[int, int]] = {}
+        staged_on = self._last_ring_rows
+        turned_back = 0  # rows that got fewer lanes in than were staged
         for row, vids in list(self.queues.items()):
             if not vids:
                 continue
@@ -4173,12 +4199,16 @@ class PaxosManager:
                 na = int(o.n_admitted[row])
                 admitted += slab[:na]
                 rest += slab[na:]
+            # (what was proposed since the dispatch is in `rest` too)
+            turned_back += len(admitted) < staged_on.get(row, 0)
             rest += vids[n_sub * K:]
             self.queues[row] = rest
             for vid in admitted:
                 payload_delta[vid] = self.arena.get(vid, "")
                 if vid in self.vid_meta:
                     meta_delta[vid] = self.vid_meta[vid]
+        if turned_back:
+            mx.count("window_full_rows", turned_back)
         for row, vid in preempt_requeue:
             self.queues.setdefault(row, []).append(vid)
 

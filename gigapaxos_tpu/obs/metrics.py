@@ -44,6 +44,9 @@ TICK_BOUNDS = (1, 2, 3, 4, 6, 8, 12, 16, 24, 32, 64)
 # bounds for histograms whose unit is engine ROWS (blob_delta_rows): none,
 # one, and powers of four up to the deployed 65,536
 ROW_BOUNDS = (0, 1, 4, 16, 64, 256, 1024, 4096, 16384, 65536)
+# bounds for histograms whose unit is client REQUESTS in one proposal
+# (proposal_requests): one, and powers of two up to MAX_BATCH_SIZE's 2,000
+BATCH_BOUNDS = (1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024, 2048)
 
 
 class Histogram:
