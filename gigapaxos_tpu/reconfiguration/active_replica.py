@@ -25,6 +25,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 from ..obs.metrics import MetricsRegistry
 from ..obs.spans import observe_interval, span
 from ..protocoltask import ProtocolExecutor, ProtocolTask
+from .chash import ConsistentHashing
 from .coordinator import AbstractReplicaCoordinator
 
 Addr = Tuple[str, int]  # ("AR"|"RC", node id)
@@ -101,6 +102,12 @@ class ActiveReplica:
         # reconfigurator ids for Deactivator pause suggestions (any RC
         # forwards to the name's primary); empty = no sweeps from here
         self.rc_ids = list(rc_ids or [])
+        # the reconfigurators' own ring (reconfigurator.py builds the same
+        # one over the same ids): a name's demand goes straight to the
+        # ring's first server for it.  An active has no view of which
+        # reconfigurator is up or of a ring that changed at run time; one
+        # that is not the live primary sends the entry on
+        self._rc_ring = ConsistentHashing(self.rc_ids)
         self._last_sweep = time.time()
         # flag snapshots — tick runs every ~10ms and must not contend on
         # the global Config lock
@@ -152,6 +159,10 @@ class ActiveReplica:
         self.metrics.count("epochs_dropped", 0)
         self.metrics.count("wake_requests_sent", 0)
         self.metrics.count("pause_evictions", 0)
+        # the demand plane: frames sent and the (name, epoch, count)
+        # entries they carried — names a frame = how far a flush is batched
+        self.metrics.count("demand_report_frames", 0)
+        self.metrics.count("demand_report_names", 0)
         # residency's rounds — ``pause_epoch``, and ``start_epoch`` with
         # ``resume`` and no previous epoch to fetch — that arrived since
         # the last drain, as (kind, body, arrival): a sweep's burst of
@@ -267,17 +278,25 @@ class ActiveReplica:
         self._last_demand_flush = now
         inst = sum(c for c, _e in drained.values()) / dt
         self._load_rps = 0.7 * self._load_rps + 0.3 * inst
-        # the load summary rides every report: the record's primary RC
-        # aggregates {names hosted, request rate} per active for the
-        # placement policies (ProximateBalance's load-balance signal)
-        load = self.load_summary()
+        if not drained:
+            return
+        # ONE frame a reconfigurator a flush: each name's (name, epoch,
+        # count) goes to the ring's first server for it, in the order the
+        # names were first counted.  The load summary rides every frame,
+        # once: the record's primary RC aggregates {names hosted, request
+        # rate} per active for the placement policies (ProximateBalance's
+        # load-balance signal)
+        by_rc: Dict[int, List] = {}
         for name, (count, epoch) in drained.items():
-            self.send(("RC", self.rc_ids[hash(name) % len(self.rc_ids)]),
-                      "demand_report", {
-                          "name": name, "epoch": epoch,
-                          "count": count, "from": self.my_id,
-                          "load": load,
-                      })
+            by_rc.setdefault(self._rc_ring.get_node(name), []).append(
+                [name, epoch, count])
+        load = self.load_summary()
+        for rc, reports in by_rc.items():
+            self.send(("RC", rc), "demand_report", {
+                "from": self.my_id, "load": load, "reports": reports,
+            })
+        self.metrics.count("demand_report_frames", len(by_rc))
+        self.metrics.count("demand_report_names", len(drained))
 
     # ---- Deactivator sweep (PaxosManager.java:2931,2786) ---------------
     def sweep_stats(self, now: Optional[float] = None) -> Dict:

@@ -517,6 +517,8 @@ class Reconfigurator:
         # for an epoch change, rc.create_to_complete for a create; the
         # stop and start rounds inside are timed by their tasks)
         self.metrics = rc_manager.metrics
+        # demand entries sent on to another reconfigurator
+        self.metrics.count("demand_reports_forwarded", 0)
         self._epoch_change_t0: Dict[str, float] = {}
         self._create_t0: Dict[str, float] = {}
         # epochs whose drop expired with unreached stragglers: re-dropped
@@ -1292,19 +1294,47 @@ class Reconfigurator:
 
     # ---- demand (handleDemandReport, Reconfigurator.java:311) ----------
     def _handle_demand_report(self, body: Dict) -> None:
-        name = body["name"]
-        if not self.is_primary(name):
-            self.send(("RC", self.primary_of(name)), "demand_report", body)
-            return
-        rec = self.rc_app.get_record(name)
-        if rec is None or rec.deleted:
-            self.demand.pop(name)
-            self.placement.note_name_gone(name)
-            return
-        # the report's load summary feeds the placement plane even when
-        # no migration follows (every active's rate/names view matters)
-        self.placement.note_report(body)
-        prof = self.demand.combine(name, body)
+        """One flush of one active's demand counts, as far as this
+        reconfigurator was the ring's first server for them:
+        ``{"from", "load", "reports": [[name, epoch, count], ...]}``.
+        The entries whose live primary is another reconfigurator (a dead
+        primary, a ring that changed) go on as ONE frame to each."""
+        src, load = body.get("from"), body.get("load")
+        elsewhere: Dict[int, List] = {}
+        noted = False
+        for entry in body["reports"]:
+            name, epoch, count = entry
+            primary = self.primary_of(name)
+            if primary != self.my_id:
+                elsewhere.setdefault(primary, []).append(entry)
+                continue
+            rec = self.rc_app.get_record(name)
+            if rec is None or rec.deleted:
+                self.demand.pop(name)
+                self.placement.note_name_gone(name)
+                continue
+            if not noted:
+                # the frame's load summary feeds the placement plane even
+                # when no migration follows (every active's rate/names
+                # view matters): once a frame that holds a live name
+                self.placement.note_report(body)
+                noted = True
+            # what a demand profile is told of one name: the fields of
+            # DemandReport.java, the frame's sender and load with them
+            self._note_demand(name, rec, {
+                "name": name, "epoch": epoch, "count": count,
+                "from": src, "load": load,
+            })
+        for rc, entries in elsewhere.items():
+            self.metrics.count("demand_reports_forwarded", len(entries))
+            self.send(("RC", rc), "demand_report", {
+                "from": src, "load": load, "reports": entries,
+            })
+
+    def _note_demand(self, name: str, rec, report: Dict) -> None:
+        """Fold one name's report into its profile and let the profile,
+        then the placement policy, ask for a move."""
+        prof = self.demand.combine(name, report)
         if rec.state is not RCState.READY:
             return
         target = prof.reconfigure(list(rec.actives), sorted(self.ar_ids))
