@@ -332,7 +332,8 @@ class PaxosManager:
                     "commit_requests_answered", "commit_requests_forwarded",
                     "commit_legs_untiled", "requests_admitted",
                     "requests_staged", "requests_coalesced",
-                    "window_full_rows"):
+                    "window_full_rows", "decisions_detected",
+                    "accepts_at_detection"):
             self.metrics.count(key, 0)
         # ... and so are the legs of a commit: a leg that never ran in a
         # run (nothing forwarded) reads as a share of 0, not as nothing
@@ -3813,7 +3814,11 @@ class PaxosManager:
                 self._np_cache_locked().update(fresh)
             digests = []
             for i, row in enumerate(digest_np):
-                digest, n_busy = split_digest_vec(row, self.cfg)
+                digest, n_busy, (decisions, accepts) = split_digest_vec(
+                    row, self.cfg)
+                # how many accepts a decision waited for (METRICS.md)
+                mx.count("decisions_detected", decisions)
+                mx.count("accepts_at_detection", accepts)
                 mx.count("step_digest_dispatches")
                 mx.observe("step_digest_rows", n_busy, bounds=ROW_BOUNDS)
                 if n_busy > self._digest_rows:

@@ -378,7 +378,7 @@ def _mix(h, vid):
     return (h * jnp.int32(31) + vid) ^ (vid << 7)
 
 
-def step(
+def step_counted(
     state: EngineState,
     g: Blob,                 # gathered COMPACT blobs, every leaf with leading [R] axis
     heard: jnp.ndarray,      # [R] bool — which peers' blobs are live
@@ -389,10 +389,20 @@ def step(
 ):
     """One vectorized consensus step for all G groups. Pure function.
 
-    Returns (state', StepOutputs).  The caller journals the accepted-window
-    delta of state' *before* publishing blob(state') — that preserves the
-    reference's log-before-send rule (``AbstractPaxosLogger.logAndMessage``,
-    ``AbstractPaxosLogger.java:157``).
+    Returns (state', StepOutputs, quorum_sums).  The caller journals the
+    accepted-window delta of state' *before* publishing blob(state') — that
+    preserves the reference's log-before-send rule
+    (``AbstractPaxosLogger.logAndMessage``, ``AbstractPaxosLogger.java:157``).
+
+    ``quorum_sums`` is two int32 sums over this replica's own rows: the lanes
+    it FIRST saw decided by its own count of matching accepts in this
+    step (a slot its decision ring takes from the count and did not hold:
+    one it learned from a peer's ring before its count got there is not
+    among them, and a slot is counted once), and the sum of that count
+    (``n_match``: the live members, itself among them, whose accepted
+    (slot, ballot) is the lane's) over them.  Their quotient says how many
+    accepts a decision waited for: the majority where it went ahead
+    without the slowest replicas, the group's size where it did not.
     """
     G, W, K, R = cfg.n_groups, cfg.window, cfg.req_lanes, cfg.n_replicas
     if W <= 0 or W & (W - 1):
@@ -554,6 +564,12 @@ def step(
             state.dec_vid,
         )
         dec_slot = jnp.where(have, best, state.dec_slot)
+        # the count's own news: c0_s == c2_s is a decision already held
+        first = have & (c2_s == best) & (c2_s < c0_s) & i_member[:, None]
+        quorum_sums = jnp.stack([
+            first.sum(dtype=jnp.int32),
+            jnp.where(first, n_match, 0).sum(dtype=jnp.int32),
+        ])
 
     # ---- 4. execute: advance the in-order frontier (EEC analog,
     # PaxosInstanceStateMachine.extractExecuteAndCheckpoint:1511-1593) ----
@@ -785,7 +801,13 @@ def step(
         bal_new=(new_state.bal != state.bal).astype(jnp.int32),
         preempted_vid=jnp.where(m2, preempted_vid, NULL),
     )
-    return new_state, outputs
+    return new_state, outputs, quorum_sums
+
+
+def step(state: EngineState, g: Blob, heard, req_vid, want_coord, my_id,
+         cfg: EngineConfig):
+    """:func:`step_counted` less its quorum sums: (state', StepOutputs)."""
+    return step_counted(state, g, heard, req_vid, want_coord, my_id, cfg)[:2]
 
 
 # ---------------------------------------------------------------------------
@@ -1017,12 +1039,14 @@ def split_blob_vec(vec: np.ndarray, cfg: EngineConfig) -> Blob:
 # [G, W] planes only the BUSY rows come — those with a commit, a newly
 # accepted lane or a preempted proposal — each with its lanes of the NEW
 # state's accept columns (the journal's log-before-send rows), plus one
-# flag: does any row still hold consensus work.  A dispatch with more busy
-# rows than the digest holds reports its count, and the host pulls the
-# whole planes for that one (``digest_from_planes``).
+# flag: does any row still hold consensus work, and the substep's two
+# quorum sums (``step_counted``).  A dispatch with more busy rows than the
+# digest holds reports its count, and the host pulls the whole planes for
+# that one (``digest_from_planes``).
 #
 # Vector layout (int32): the six [G] leaves in StepOutputs order, n_busy,
-# live, rows [M], then the six [M, W] planes in StepDigest order.
+# live, the two quorum sums, rows [M], then the six [M, W] planes in
+# StepDigest order.
 # ---------------------------------------------------------------------------
 
 class StepDigest(NamedTuple):
@@ -1048,6 +1072,7 @@ class StepDigest(NamedTuple):
 
 
 _DIGEST_G_LEAVES = StepDigest._fields[:6]
+_DIGEST_HEAD = 4  # n_busy, live, decisions detected, accepts at detection
 _DIGEST_OUT_PLANES = ("exec_vid", "acc_new", "preempted_vid")
 _DIGEST_STATE_PLANES = ("acc_slot", "acc_bal", "acc_vid")
 
@@ -1061,7 +1086,7 @@ def digest_rows(cfg: EngineConfig) -> int:
 @functools.lru_cache(maxsize=None)
 def digest_vec_len(cfg: EngineConfig) -> int:
     M = digest_rows(cfg)
-    return 6 * cfg.n_groups + 2 + M + 6 * M * cfg.window
+    return 6 * cfg.n_groups + _DIGEST_HEAD + M + 6 * M * cfg.window
 
 
 def work_in_flight(state: EngineState) -> jnp.ndarray:
@@ -1087,10 +1112,11 @@ def _busy_rows(out) -> "jnp.ndarray | np.ndarray":
 _DIGEST_CHUNK = 256  # rows gathered per pass of make_digest's loop
 
 
-def make_digest(out: StepOutputs, state: EngineState,
-                cfg: EngineConfig) -> jnp.ndarray:
-    """Inside jit: the digest vector of one substep's ``out`` against
-    the dispatch's NEW ``state``.  The device's work follows the busy
+def make_digest(out: StepOutputs, state: EngineState, cfg: EngineConfig,
+                quorum_sums: jnp.ndarray) -> jnp.ndarray:
+    """Inside jit: the digest vector of one substep's ``out`` and
+    ``quorum_sums`` (``step_counted``'s third result) against the
+    dispatch's NEW ``state``.  The device's work follows the busy
     rows: one sort of [G] keys names them, and their lanes are gathered
     a chunk of rows at a time, as many chunks as hold them (rows of the
     planes past the last chunk stay 0; the host reads none of them)."""
@@ -1116,7 +1142,10 @@ def make_digest(out: StepOutputs, state: EngineState,
         0, (jnp.minimum(n_busy, M) + C - 1) // C, gather_chunk,
         jnp.zeros((6, M, W), jnp.int32),
     )
-    head = jnp.stack([n_busy, work_in_flight(state).astype(jnp.int32)])
+    head = jnp.concatenate([
+        jnp.stack([n_busy, work_in_flight(state).astype(jnp.int32)]),
+        quorum_sums,
+    ])
     return jnp.concatenate(
         [getattr(out, f) for f in _DIGEST_G_LEAVES]
         + [head, rows, jnp.ravel(planes)]
@@ -1125,18 +1154,21 @@ def make_digest(out: StepOutputs, state: EngineState,
 
 def split_digest_vec(vec: np.ndarray, cfg: EngineConfig):
     """Host-side: one transferred digest vector -> (:class:`StepDigest`
-    of np views, n_busy).  Where ``n_busy`` exceeds the digest's rows
-    the planes hold only the first of them: the caller pulls the whole
-    planes instead (``digest_from_planes``)."""
+    of np views, n_busy, the substep's quorum sums as two ints: decisions
+    first detected, accepts counted at their detection).  Where ``n_busy``
+    exceeds the digest's rows the planes hold only the first of them: the
+    caller pulls the whole planes instead (``digest_from_planes``)."""
     G, W, M = cfg.n_groups, cfg.window, digest_rows(cfg)
     vec = np.asarray(vec)
     g_leaves = vec[:6 * G].reshape(6, G)
-    n_busy, live = int(vec[6 * G]), bool(vec[6 * G + 1])
+    n_busy, live, decisions, accepts = (
+        int(x) for x in vec[6 * G:6 * G + _DIGEST_HEAD])
     n = min(n_busy, M)
-    off = 6 * G + 2
+    off = 6 * G + _DIGEST_HEAD
     rows = vec[off:off + n]
     planes = vec[off + M:].reshape(6, M, W)[:, :n]
-    return StepDigest(*g_leaves, live, rows, *planes), n_busy
+    return (StepDigest(*g_leaves, bool(live), rows, *planes), n_busy,
+            (decisions, accepts))
 
 
 def digest_from_planes(out: StepOutputs, acc_slot: np.ndarray,

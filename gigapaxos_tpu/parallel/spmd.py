@@ -87,6 +87,7 @@ from ..ops.engine import (
     scatter_update,
     stack_blob,
     step,
+    step_counted,
     unpack_out,
     with_my_row,
 )
@@ -254,7 +255,7 @@ def _build_packed(cfg: EngineConfig, mesh: Optional[Mesh], n_steps: int,
             state = _constrain(mesh, state, GROUP_AXIS)
             stack = with_my_row(
                 scatter_update(stack, upd, cfg), state, my_id)
-            new_state, out = step(
+            new_state, out, quorum_sums = step_counted(
                 state, stack_blob(stack), heard, req_ring[0], want_coord,
                 my_id, cfg=cfg,
             )
@@ -267,7 +268,7 @@ def _build_packed(cfg: EngineConfig, mesh: Optional[Mesh], n_steps: int,
             return (
                 _constrain(mesh, new_state, GROUP_AXIS), stack,
                 out_rings, pack_blob(blob), heat_acc,
-                make_digest(out, new_state, cfg)[None],
+                make_digest(out, new_state, cfg, quorum_sums)[None],
                 make_news(blob, published, cfg),
             )
     else:
@@ -277,9 +278,10 @@ def _build_packed(cfg: EngineConfig, mesh: Optional[Mesh], n_steps: int,
             heat_acc = _constrain(mesh, heat_acc, GROUP_AXIS)
             stack = scatter_update(stack, upd, cfg)
             out0 = jnp.zeros((n_steps, M), jnp.int32)
+            sums0 = jnp.zeros((n_steps, 2), jnp.int32)
 
             def body(i, carry):
-                st, g, outs, ht = carry
+                st, g, outs, sums, ht = carry
                 # every substep takes MY row from the advancing state;
                 # peers' rows stay frozen for the whole dispatch —
                 # exactly N serial ticks during which no peer frame lands
@@ -288,23 +290,27 @@ def _build_packed(cfg: EngineConfig, mesh: Optional[Mesh], n_steps: int,
                     req_ring, i, axis=0, keepdims=False
                 )
                 want_i = want_coord & (i == 0)
-                st, out = step(st, stack_blob(g), heard, req_i, want_i,
-                               my_id, cfg=cfg)
+                st, out, quorum_sums = step_counted(
+                    st, stack_blob(g), heard, req_i, want_i, my_id, cfg=cfg)
                 outs = lax.dynamic_update_index_in_dim(
                     outs, _pack_out(out), i, axis=0
                 )
+                sums = lax.dynamic_update_index_in_dim(
+                    sums, quorum_sums, i, axis=0
+                )
                 ht = ht + out.n_committed + out.n_admitted
-                return st, g, outs, ht
+                return st, g, outs, sums, ht
 
-            new_state, stack, out_rings, heat_acc = lax.fori_loop(
-                0, n_steps, body, (state, stack, out0, heat_acc)
+            new_state, stack, out_rings, sums, heat_acc = lax.fori_loop(
+                0, n_steps, body, (state, stack, out0, sums0, heat_acc)
             )
             blob = make_blob(new_state)
             # one digest row per substep, each against the dispatch's
             # final state: the journal values an accepted lane from it
             digests = jax.vmap(
-                lambda row: make_digest(unpack_out(row, cfg), new_state, cfg)
-            )(out_rings)
+                lambda row, quorum_sums: make_digest(
+                    unpack_out(row, cfg), new_state, cfg, quorum_sums)
+            )(out_rings, sums)
             return (
                 _constrain(mesh, new_state, GROUP_AXIS), stack, out_rings,
                 pack_blob(blob), _constrain(mesh, heat_acc, GROUP_AXIS),

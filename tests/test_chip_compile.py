@@ -81,16 +81,16 @@ def _dispatch_bytes(compiled) -> int:
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
 
 
-@pytest.mark.parametrize("G,W,K", [
-    (65_536, 16, 8),       # the deployed default (what chip_smoke serves)
-    (1_048_576, 32, 16),   # the headline shape
+@pytest.mark.parametrize("G,W,K,R", [
+    (65_536, 16, 8, 3),       # the deployed default (what chip_smoke serves)
+    (1_048_576, 32, 16, 3),   # the headline shape
+    (65_536, 16, 8, 5),       # five replicas a name (the cell g1k-r5-lat)
 ])
-def test_packed_host_step_compiles_and_fits_one_chip(one_chip, G, W, K):
+def test_packed_host_step_compiles_and_fits_one_chip(one_chip, G, W, K, R):
     """The step the manager dispatches (state, gathered stack and heat
     donated; the tick's news as one fixed-shape update): one replica's
     dispatch must stay under one chip's 16 GiB, and the stack comes back
     in the buffers it went in."""
-    R = 3
     cfg = EngineConfig(G, W, K, R)
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(
         shape, dtype, sharding=one_chip

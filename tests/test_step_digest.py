@@ -54,7 +54,7 @@ def _watch(m, seen):
         out_rows = np.asarray(pend["out_vec"])
         planes = _state_np(m, "acc_slot", "acc_bal", "acc_vid")
         for i, row in enumerate(digest_np):
-            got, n_busy = split_digest_vec(row, m.cfg)
+            got, n_busy, _quorum = split_digest_vec(row, m.cfg)
             out = split_out_vec(out_rows[i], m.cfg)
             want = digest_from_planes(out, *planes, _old_work_in_flight(m))
             assert n_busy == len(want.rows) <= digest_rows(m.cfg)
@@ -264,12 +264,13 @@ def test_digest_gathers_every_busy_row_chunk_by_chunk(n_busy):
     )
     state = init_state(cfg)._replace(
         acc_slot=plane(), acc_bal=plane(), acc_vid=plane())
-    vec = jax.jit(lambda o, s: make_digest(o, s, cfg))(
+    vec = jax.jit(lambda o, s: make_digest(o, s, cfg, np.array([3, 11])))(
         StepOutputs(*out), EngineState(*state))
-    got, n = split_digest_vec(np.asarray(vec), cfg)
+    got, n, quorum = split_digest_vec(np.asarray(vec), cfg)
     want = digest_from_planes(
         out, *(np.asarray(getattr(state, f))
                for f in ("acc_slot", "acc_bal", "acc_vid")), got.live)
     assert n == n_busy and np.array_equal(want.rows, np.flatnonzero(busy))
+    assert quorum == (3, 11)  # the step's two sums ride beside the flag
     for f in StepDigest._fields:
         assert np.array_equal(getattr(got, f), getattr(want, f)), f
