@@ -188,6 +188,32 @@ def decode_batch(payload: str) -> List[Tuple[int, int, str]]:
     return [(int(r), int(e), v) for r, e, v in json.loads(payload)]
 
 
+# what JSON puts around the strings of a forward frame, rounded up (ids
+# reach 2^62: 19 digits): around a frame's entries, an entry's requests, a
+# request's value, and one trace context
+_FRAME_OVERHEAD = 64
+_ENTRY_OVERHEAD = 64
+_REQ_OVERHEAD = 48
+_TC_BYTES = 96
+_DROP_ESCAPED = str.maketrans("", "", '"\\')  # JSON writes these as two
+
+
+def _forward_entry_bytes(body: Dict) -> int:
+    """About what one forward entry (name, epoch, reqs, tc) comes to on
+    the wire, from the lengths of its strings and without encoding it: a
+    quote or a backslash is written as two bytes, a character outside
+    ASCII as a six-byte escape.  An estimate (control characters and
+    characters beyond the basic plane come to more): a frame that passes
+    the cap all the same is chunked like any other."""
+    n = _ENTRY_OVERHEAD + 6 * len(body["name"]) \
+        + _TC_BYTES * len(body.get("tc", ()))
+    for _rid, _entry, value, _stop in body["reqs"]:
+        n += _REQ_OVERHEAD + (
+            2 * len(value) - len(value.translate(_DROP_ESCAPED))
+            if value.isascii() else 6 * len(value))
+    return n
+
+
 class SlimRequest(RequestPacket):
     """Hot-path request object for decided-slot execution.
 
@@ -3253,55 +3279,14 @@ class PaxosManager:
                 entry_replica=body.get("entry", None),
                 trace_ctx=tc,
             )
-        elif kind == "forward_batch":
-            # a peer forwards a whole queue run (one frame, many
-            # proposals).  Same staleness guard as singleton forwards.
-            # FIFO within the run is preserved: requests accumulated
-            # before a stop flush BEFORE the stop is proposed (proposing
-            # the stop first would decide it ahead of requests that
-            # preceded it, and the epoch bump would drop them as stale).
-            name = body["name"]
-            cur = self.current_epoch(name)
-            if cur != int(body["epoch"]):
-                # across an epoch change only the writes come along
-                writes = [r for r in body["reqs"] if not r[3]]
-                if not self._write_crosses_epoch_locked(
-                        name, cur, int(body["epoch"]), len(writes)):
-                    return
-                body = dict(body, reqs=writes)
-            tcs = body.get("tc") or {}
-
-            def _tc_of(rid):
-                tc = tcs.get(str(rid))
-                return None if not tc else (
-                    int(tc[0]), int(tc[1]), int(tc[2])
-                )
-
-            tr_on = self.tracer.enabled
-            if tr_on or tcs:
-                for rid, entry, _v, _s in body["reqs"]:
-                    tc = _tc_of(rid)
-                    if tr_on or tc is not None:
-                        self.tracer.note(rid, "forward-in", name=name,
-                                         node=self.my_id, entry=entry,
-                                         tick=self._tick_no,
-                                         force=tc is not None,
-                                         **self._tc_detail(tc))
-            items = []
-            for rid, entry, value, stop in body["reqs"]:
-                if stop:
-                    if items:
-                        self.propose_batch(items)
-                        items = []
-                    self.propose(
-                        name, value, stop=True, request_id=rid,
-                        entry_replica=entry, trace_ctx=_tc_of(rid),
-                    )
-                else:
-                    items.append((name, value, rid, None, entry,
-                                  _tc_of(rid)))
-            if items:
-                self.propose_batch(items)
+        elif kind == "forward_rows":
+            # what a peer's tick forwards to me, one frame for all the
+            # names I lead: each entry is taken in by itself, so one the
+            # epoch guard turns away does not touch its neighbours
+            for row in body["rows"]:
+                self._on_forward_batch_locked(row)
+        elif kind == "forward_batch":  # one entry of such a frame, alone
+            self._on_forward_batch_locked(body)
         elif kind == "state_request":  # checkpoint-transfer pull
             self._serve_state_request(body)
         elif kind == "state_reply":
@@ -3319,6 +3304,56 @@ class PaxosManager:
                 self.forward_out.append(
                     (sync.node_id, "payloads", {"arena": have, "meta": meta})
                 )
+
+    def _on_forward_batch_locked(self, body: Dict) -> None:
+        """A peer forwards one name's queue run (many proposals).  Same
+        staleness guard as singleton forwards.  FIFO within the run is
+        preserved: requests accumulated before a stop flush BEFORE the
+        stop is proposed (proposing the stop first would decide it ahead
+        of requests that preceded it, and the epoch bump would drop them
+        as stale)."""
+        name = body["name"]
+        cur = self.current_epoch(name)
+        if cur != int(body["epoch"]):
+            # across an epoch change only the writes come along
+            writes = [r for r in body["reqs"] if not r[3]]
+            if not self._write_crosses_epoch_locked(
+                    name, cur, int(body["epoch"]), len(writes)):
+                return
+            body = dict(body, reqs=writes)
+        tcs = body.get("tc") or {}
+
+        def _tc_of(rid):
+            tc = tcs.get(str(rid))
+            return None if not tc else (
+                int(tc[0]), int(tc[1]), int(tc[2])
+            )
+
+        tr_on = self.tracer.enabled
+        if tr_on or tcs:
+            for rid, entry, _v, _s in body["reqs"]:
+                tc = _tc_of(rid)
+                if tr_on or tc is not None:
+                    self.tracer.note(rid, "forward-in", name=name,
+                                     node=self.my_id, entry=entry,
+                                     tick=self._tick_no,
+                                     force=tc is not None,
+                                     **self._tc_detail(tc))
+        items = []
+        for rid, entry, value, stop in body["reqs"]:
+            if stop:
+                if items:
+                    self.propose_batch(items)
+                    items = []
+                self.propose(
+                    name, value, stop=True, request_id=rid,
+                    entry_replica=entry, trace_ctx=_tc_of(rid),
+                )
+            else:
+                items.append((name, value, rid, None, entry,
+                              _tc_of(rid)))
+        if items:
+            self.propose_batch(items)
 
     # ------------------------------------------------------------------
     # the tick
@@ -3478,11 +3513,12 @@ class PaxosManager:
                     vids.clear()
                     continue
                 epoch_now = int(self._np("version")[row])
-                # ONE forward_batch frame per row per tick (at capacity a
-                # per-request forward frame was one json encode + syscall
-                # + decode + singleton propose EACH — the non-coordinator
-                # entry's whole budget); the coordinator re-proposes the
-                # list under one lock acquisition
+                # ONE entry per row per tick (at capacity a per-request
+                # forward frame was one json encode + syscall + decode +
+                # singleton propose EACH — the non-coordinator entry's
+                # whole budget); drain_forward_out puts a tick's entries
+                # for one coordinator into one forward_rows frame, which
+                # that coordinator takes in under one lock acquisition
                 reqs = []
                 for vid in vids:
                     # _filter_stale_vids (just above, same lock) guarantees
@@ -5106,13 +5142,45 @@ class PaxosManager:
         })
         self._slots_since_ckpt = 0
 
-    def drain_forward_out(self) -> List[Tuple[int, str, Dict]]:
+    def drain_forward_out(
+        self, max_frame_bytes: Optional[int] = None,
+    ) -> List[Tuple[int, str, Dict]]:
         """Atomically take the pending outbound host-channel messages.
         An unlocked swap could lose a message appended by a transport
-        thread between the load and the store."""
+        thread between the load and the store.
+
+        The wire unit of a forward is a DESTINATION, not a row: the
+        ``forward_batch`` entries the ring build staged come out as one
+        ``(dst, "forward_rows", {"rows": [body, ...]})`` a coordinator,
+        in the place of the first of them, bodies in the order staged
+        and as staged.  Every frame is a hand-over of the interpreter on
+        the sender's tick thread and a decode and a hold of
+        ``_state_lock`` on the receiver's transport loop, and a saturated
+        tick stages a dozen rows for two peers.  Every other kind passes
+        through untouched and in order.  A group that would pass
+        ``max_frame_bytes`` (a node's frame cap; a stepped harness has
+        none) goes on in a second frame: forwards are consensus traffic
+        and must not take the paced chunk path of a state transfer."""
         with self._state_lock:
             out, self.forward_out = self.forward_out, []
-        return out
+        if not out:
+            return out
+        cap = float("inf") if max_frame_bytes is None \
+            else max_frame_bytes - _FRAME_OVERHEAD
+        grouped: List[Tuple[int, str, Dict]] = []
+        open_frames: Dict[int, Tuple[list, int]] = {}  # dst -> rows, bytes
+        for dst, kind, body in out:
+            if kind != "forward_batch":
+                grouped.append((dst, kind, body))
+                continue
+            size = _forward_entry_bytes(body)
+            rows, used = open_frames.get(dst, (None, 0))
+            if rows is None or used + size > cap:
+                rows, used = [], 0
+                grouped.append((dst, "forward_rows", {"rows": rows}))
+            rows.append(body)
+            open_frames[dst] = (rows, used + size)
+        return grouped
 
     def blob_vec(self) -> np.ndarray:
         """Packed publish vector for the current state (the wire body of

@@ -42,8 +42,8 @@ _DHDR = struct.Struct(">cIQQI")  # kind, sender, tick, base tick, rows
 
 # cross-node trace context (Dapper-style, obs/reqtrace.py): an OPTIONAL
 # ``"tc": [trace_id, origin_node, hop]`` field on J-frame request bodies
-# (client_request[_batch] items, forward/forward_batch, payload gossip).
-# Absent = untraced; bodies without it are byte-identical to the
+# (client_request[_batch] items, forward / forward_rows entries, payload
+# gossip).  Absent = untraced; bodies without it are byte-identical to the
 # pre-trace wire format.  The binary R/S frames carry the same triple in
 # a fixed 13-byte layout (net/hot_codec.py).
 TRACE_KEY = "tc"

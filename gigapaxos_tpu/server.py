@@ -142,6 +142,7 @@ class PaxosServer:
                     "blob_base_mismatch", "ticks", "ticks_noprog",
                     "ticks_inflight_noprog", "ticks_without_fresh_blob",
                     "crash_emulations", "frames_dropped_while_crashed",
+                    "forward_frames_sent", "forward_rows_sent",
                     *THREAD_CLOCKS):
             self.manager.metrics.count(key, 0)  # present from the start
         self.manager.metrics.register_hist("commit_leg_flush_s")
@@ -321,8 +322,9 @@ class PaxosServer:
     # them (found by the first g1k-crash on the chip, PR 35: no election
     # in 14 s of darkness)
     MESH_KINDS = frozenset((
-        "payloads", "forward", "forward_batch", "need_payloads",
-        "state_request", "state_reply", "fd_ping", "blob_resync",
+        "payloads", "forward", "forward_batch", "forward_rows",
+        "need_payloads", "state_request", "state_reply", "fd_ping",
+        "blob_resync",
     ))
 
     def _on_client_plane_message(
@@ -466,8 +468,8 @@ class PaxosServer:
         """JSON-frame dispatch; subclasses extend (ReconfigurableNode roles
         layer epoch-plane kinds on the same demux — the reference's
         precedePacketDemultiplexer chaining).  Returns True if handled."""
-        if k in ("payloads", "forward", "forward_batch", "need_payloads",
-                 "state_request", "state_reply"):
+        if k in ("payloads", "forward", "forward_batch", "forward_rows",
+                 "need_payloads", "state_request", "state_reply"):
             self.manager.on_host_message(k, body)
         elif k == "chunk":
             self._on_chunk(sender, body, reply)
@@ -1206,7 +1208,9 @@ class PaxosServer:
             "delta": delta if (
                 delta["arena"] or delta.get("app_exec")
             ) else None,
-            "fwd": m.drain_forward_out(),
+            # a tick's forwards leave as one frame a coordinator, cut
+            # before the size at which a frame is chunked and paced
+            "fwd": m.drain_forward_out(self.max_frame_bytes),
         }
 
     def _drain_self_msgs(self) -> None:
@@ -1246,9 +1250,13 @@ class PaxosServer:
                     self.transport.send_to_id(r, frame)
         if not pub["fwd"]:
             return
+        fwd_frames = fwd_rows = 0
         with self.manager._span("forward"):
             for dst, k, body in pub["fwd"]:
                 frame = encode_json(k, self.my_id, body)
+                if k == "forward_rows":
+                    fwd_frames += 1
+                    fwd_rows += len(body["rows"])
                 # send_frame_to_id streams oversize frames (a multi-MB
                 # state_reply must not monopolize the link)
                 if dst == -1:
@@ -1260,6 +1268,10 @@ class PaxosServer:
                     self._self_msgs.append((k, body))
                 else:
                     self.send_frame_to_id(dst, frame)
+        if fwd_frames:
+            mx = self.manager.metrics
+            mx.count("forward_frames_sent", fwd_frames)
+            mx.count("forward_rows_sent", fwd_rows)
 
     def _heat_stats(self) -> Dict:
         """Group-heat block for the ``stats`` op — degrades to an empty

@@ -299,7 +299,7 @@ def main() -> int:
     # of a loaded 1-core host before this fast path)
     # route each name's traffic at its COORDINATOR (initial coord =
     # members[row % |members|], the create-time rule): a non-coordinator
-    # entry must forward_batch every proposal — one extra frame encode/
+    # entry must forward every proposal — one extra frame encode/
     # decode + two extra latency legs per request for 2/3 of the
     # traffic.  Smart clients route at the leader; elections can move it
     # (the forward path still handles that correctly, it just costs).
