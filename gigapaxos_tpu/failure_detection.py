@@ -32,6 +32,7 @@ class FailureDetector:
         my_id: int,
         node_ids: Iterable[int],
         timeout_s: Optional[float] = None,
+        metrics=None,
     ):
         self.my_id = int(my_id)
         if timeout_s is None:
@@ -46,9 +47,15 @@ class FailureDetector:
         )
         now = time.time()
         self.last_heard: Dict[int, float] = {int(n): now for n in node_ids}
-        # want_coord's last inputs (as bytes) and its answer to them
+        # want_coord's standing answer, who was up and long dead when
+        # it was made, and — where a caller handed the inputs over with
+        # no word on what moved — the inputs' bytes
         self._want_key = None
+        self._want_bytes = None
         self._want: Optional[np.ndarray] = None
+        # where ``want_coord_full`` / ``want_coord_patched_rows`` count
+        # (a node's registry; None: nowhere)
+        self.metrics = metrics
 
     @property
     def ping_period_s(self) -> float:
@@ -78,31 +85,72 @@ class FailureDetector:
         return float("inf") if t is None else time.time() - t
 
     # ---- vectorized election trigger ----------------------------------
+    # numpy keeps the interpreter lock through a loop of at most 500
+    # elements: more changed rows than that are not worth patching
+    PATCH_ROWS_MAX = 500
+
     def want_coord(
         self,
         bal: np.ndarray,          # [G] promised ballots (packed)
         member_mask: np.ndarray,  # [G]
         n_replicas: int,
+        changed: Optional[np.ndarray] = None,
     ) -> np.ndarray:
         """[G] bool: should THIS node start an election for each group.
         The answer is a function of who is up, the ballots and the
         memberships, which stand still from tick to tick: while they do,
         the last answer is handed back — the SAME array, read-only —
         and the thirty [G] passes below do not run (each gives up the
-        interpreter lock and queues for it again)."""
+        interpreter lock and queues for it again).
+
+        ``changed`` is the caller's word on the inputs: the rows in
+        which ``bal`` or ``member_mask`` may differ from the last call's
+        (manager.py:election_inputs keeps them: a lifecycle operation's
+        rows, a step's ballot rises).  While who is up stands, the
+        answer is made anew at those rows alone, and is a new array
+        only where it came out otherwise.  None: no word, and the
+        inputs are compared whole, by their bytes."""
         R = n_replicas
         up = np.array([self.is_node_up(r) for r in range(R)], bool)
         long_dead = np.array(
             [self.dead_for(r) > self.timeout_s * self.long_dead_factor
              for r in range(R)], bool,
         )
-        key = (up.tobytes(), long_dead.tobytes(),
-               np.asarray(bal).tobytes(), np.asarray(member_mask).tobytes())
+        key = (up.tobytes(), long_dead.tobytes())
+        whole = None if changed is not None else (
+            np.asarray(bal).tobytes(), np.asarray(member_mask).tobytes())
         if key == self._want_key:
-            return self._want
-        self._want_key, self._want = key, self._want_coord(
-            up, long_dead, bal, member_mask, R)
+            if changed is None:
+                if whole == self._want_bytes:
+                    return self._want
+            elif changed.size <= self.PATCH_ROWS_MAX:
+                self._want_bytes = None
+                return self._patch_want(
+                    up, long_dead, bal, member_mask, R, changed)
+        self._want_key, self._want_bytes = key, whole
+        self._want = self._want_coord(up, long_dead, bal, member_mask, R)
         self._want.setflags(write=False)
+        if self.metrics is not None:
+            self.metrics.count("want_coord_full")
+        return self._want
+
+    def _patch_want(self, up, long_dead, bal, member_mask, R,
+                    rows: np.ndarray) -> np.ndarray:
+        """The standing answer with ``rows`` made anew (one
+        :meth:`_want_coord` over the rows' ballots and masks): the same
+        array where nothing came out otherwise, else a fresh read-only
+        one."""
+        if not rows.size:
+            return self._want
+        at = self._want_coord(
+            up, long_dead, np.asarray(bal)[rows],
+            np.asarray(member_mask)[rows], R)
+        if self.metrics is not None:
+            self.metrics.count("want_coord_patched_rows", int(rows.size))
+        if not np.array_equal(at, self._want[rows]):
+            self._want = self._want.copy()
+            self._want[rows] = at
+            self._want.setflags(write=False)
         return self._want
 
     def _want_coord(self, up, long_dead, bal, member_mask, R) -> np.ndarray:

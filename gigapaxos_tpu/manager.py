@@ -32,6 +32,7 @@ The tick cycle (one call to :meth:`tick`):
 
 from __future__ import annotations
 
+import bisect
 import contextlib
 import functools
 import itertools
@@ -85,10 +86,18 @@ from .obs.reqtrace import RequestTracer
 from .dedup import ExecutedIds
 from .obs.spans import observe_interval, span
 from .ops.lifecycle import (
+    KILL_WROTE,
+    ROW_LEAVES,
+    ROW_PLANES,
     create_groups,
+    create_wrote,
     jump_rows,
+    jump_wrote,
     kill_groups,
     restore_paused_rows,
+    restore_wrote,
+    split_rows,
+    take_rows,
 )
 from .storage.logger import PaxosLogger
 
@@ -142,6 +151,13 @@ def _padded_chunks(n: int, size: int):
     to it twice changes nothing."""
     for i in range(0, n, size):
         yield np.minimum(np.arange(i, i + size), n - 1)
+
+
+def _with(arr: np.ndarray, j: int, value) -> np.ndarray:
+    """A copy of ``arr`` with ``value`` at ``j``."""
+    out = arr.copy()
+    out[j] = value
+    return out
 
 
 def _mix32(h: int, vid: int) -> int:
@@ -811,48 +827,145 @@ class PaxosManager:
         # (whole copies only where the whole vector came down), which the
         # leaf cache then shows for the new state
         self._bal_exec: Optional[Dict[str, np.ndarray]] = None
+        # rows of that pair a lifecycle operation has written since the
+        # last completion (_replace_state_locked): a step that makes no
+        # news of such a row has left it as PUBLISHED, not as written
+        self._bal_exec_written: set = set()
         # the rows that hold a name (``member_mask`` non-zero, current
         # and old epochs alike), ascending, in blocks of ROWS_A_PASS,
         # each with its members as [R, n] bits: what every per-tick pass
-        # of the post-step runs over.  Derived anew when the cached
-        # ``member_mask`` ARRAY is another one — the step carries the
-        # array across its swap (_carried_leaves), a lifecycle op
-        # replaces the state and with it the array — so one [G] pass a
-        # lifecycle operation
+        # of the post-step runs over.  Derived anew (one [G] pass) when
+        # the cached ``member_mask`` ARRAY is another one: the step
+        # carries the array across its swap (_carried_leaves), and a
+        # lifecycle op writes its rows into the array and into this
+        # index alike (_replace_state_locked)
         self._member_rows: Tuple[Optional[np.ndarray], List] = (None, [])
+        # the rows in which ``bal`` or ``member_mask`` may differ from
+        # what :meth:`election_inputs` last handed the failure detector
+        # (lifecycle operations' rows, steps' ballot rises); None: more
+        # than rows may have moved
+        self._election_rows: Optional[set] = None
+        # row -> its words as _row_words_locked gathered them from the
+        # CURRENT state: a step's swap drops them all, a lifecycle
+        # operation those of its rows
+        self._row_words: Dict[int, Dict[str, Any]] = {}
+        for key in ("host_leaf_pulls", "host_leaf_pull_bytes",
+                    "host_leaf_carried", "host_leaf_patched_rows",
+                    "lifecycle_row_reads"):
+            self.metrics.count(key, 0)  # present from the start
         self.state: EngineState = init_state(cfg)
         self._recover()
 
     def _np(self, leaf: str) -> np.ndarray:
         """Cached host copy of an engine leaf for the CURRENT state object
-        (one transfer per leaf per state version, not per accessor call).
+        (one transfer per leaf, not per accessor call; the step's swap
+        and the lifecycle operations carry the copies across the state's
+        replacement, :meth:`_carried_leaves` and
+        :meth:`_replace_state_locked`).
         Takes the state lock: an unlocked reader racing the tick thread's
         state replacement could otherwise store an OLD state's array under
         the NEW state's cache and poison every later reader.
 
-        The returned array is a PRIVATE copy when np.asarray would be a
-        zero-copy view of the device buffer (`.base` set — the CPU
-        backend): the dispatch step donates the state, so a view held by
-        a transport thread past its lock region would read buffers a
-        later tick overwrites in place.  Device backends already transfer
-        into a fresh host buffer (`.base` None)."""
+        The returned array is the manager's own: a lifecycle operation
+        writes its rows into it, a completion writes the step's news
+        into ``bal`` / ``exec_slot`` — copy what you keep."""
         with self._state_lock:
             cache = self._np_cache_locked()
             arr = cache.get(leaf)
             if arr is None:
-                arr = np.asarray(getattr(self.state, leaf))
-                if arr.base is not None:
-                    arr = arr.copy()
-                cache[leaf] = arr
+                arr = cache[leaf] = self._pull_leaf(self.state, leaf)
             return arr
+
+    def _pull_leaf(self, state: EngineState, leaf: str) -> np.ndarray:
+        """A whole leaf from the device: a sync behind whatever is in
+        flight there (``host_leaf_pulls`` / ``host_leaf_pull_bytes``).
+        A PRIVATE copy when np.asarray would be a zero-copy view of the
+        device buffer (`.base` set — the CPU backend): the dispatch step
+        donates the state, so a view held past the lock region would
+        read buffers a later tick overwrites in place.  Device backends
+        already transfer into a fresh host buffer (`.base` None)."""
+        arr = np.asarray(getattr(state, leaf))
+        if arr.base is not None:
+            arr = arr.copy()
+        self.metrics.count("host_leaf_pulls")
+        self.metrics.count("host_leaf_pull_bytes", arr.nbytes)
+        return arr
 
     def _np_cache_locked(self) -> Dict[str, np.ndarray]:
         """Lock held: the host cache of the CURRENT state object's
-        leaves (emptied when the state was replaced since)."""
+        leaves.  Whoever replaced the state without carrying the cache
+        across (a whole-state build: recovery, a test) finds it emptied
+        here, and with it the index of member rows and what the failure
+        detector was told stands."""
         if self._np_cache_state is not self.state:
             self._np_cache = {}
             self._np_cache_state = self.state
+            self._member_rows = (None, [])
+            self._election_rows = None
+            self._row_words = {}
         return self._np_cache
+
+    def _replace_state_locked(self, new_state: EngineState, rows,
+                              wrote: Dict[str, Any]) -> None:
+        """Lock held, no step in flight: ``new_state`` is ``self.state``
+        after a lifecycle program wrote ``rows`` of it.  The host's
+        copies follow by rows: ``wrote`` (ops/lifecycle.py, beside each
+        program) gives per written leaf the values the host already
+        holds, which go into the cached copy at ``rows``, in place (a
+        private copy first where the array is the device's read-only
+        buffer) — or None, and THAT leaf alone is dropped.  A leaf the
+        program did not write keeps its array.  ``bal`` / ``exec_slot``
+        in the cache are the publish mirror's pair, which follows the
+        steps' NEWS: the rows written here are noted, and the next
+        completion reads them again from what was published
+        (:meth:`_fresh_bal_exec`).  The index of member rows and the
+        failure detector's standing answer follow at the same rows
+        (:meth:`election_inputs`)."""
+        rows = np.asarray(rows, np.int64)
+        cache = self._np_cache_locked()
+        indexed = cache.get("member_mask")  # what _member_rows may be of
+        n_patched = 0
+        for leaf, values in wrote.items():
+            arr = cache.get(leaf)
+            if arr is None:
+                continue
+            if values is None:
+                del cache[leaf]
+                continue
+            if not arr.flags.writeable:  # the device's own buffer: once
+                arr = cache[leaf] = arr.copy()
+            arr[rows] = values
+            n_patched += 1
+        self.state = new_state
+        self._np_cache_state = new_state
+        if "member_mask" in wrote and indexed is not None \
+                and self._member_rows[0] is indexed:
+            mask = cache["member_mask"]
+            self._member_rows = (mask, self._patched_member_rows(
+                self._member_rows[1], mask, rows))
+        touched = set(rows.tolist())
+        self._bal_exec_written |= touched
+        for row in touched:
+            self._row_words.pop(row, None)
+        if self._election_rows is not None:
+            self._election_rows |= touched
+        self.metrics.count("host_leaf_carried", len(cache))
+        self.metrics.count("host_leaf_patched_rows", n_patched * rows.size)
+
+    def election_inputs(
+        self,
+    ) -> Tuple[np.ndarray, np.ndarray, Optional[np.ndarray]]:
+        """What the failure detector's election mask is made of
+        (failure_detection.py:want_coord): ``bal``, ``member_mask`` and
+        the rows in which either may differ from what the last call
+        handed out — the rows of the lifecycle operations and of the
+        steps' ballot rises since — or None where more than rows moved
+        (the first call; a state built whole)."""
+        with self._state_lock:
+            bal, mask = self._np("bal"), self._np("member_mask")
+            rows, self._election_rows = self._election_rows, set()
+            return bal, mask, (None if rows is None else np.fromiter(
+                rows, np.int64, len(rows)))
 
     # numpy keeps the interpreter lock through a loop of at most 500
     # elements and gives it up, to queue for it again, around every
@@ -863,10 +976,10 @@ class PaxosManager:
     # block at a time, and no pass over a block lets go of the lock
     ROWS_A_PASS = 500
 
-    def _member_rows_locked(self) -> List[Tuple[np.ndarray, np.ndarray]]:
+    def _member_rows_locked(self) -> List[Tuple[np.ndarray, Tuple]]:
         """Lock held: the rows that hold a name in the CURRENT state,
-        ascending, as blocks of (rows [n], their members [R, n] bool)
-        with n at most ``ROWS_A_PASS``."""
+        ascending, as blocks of (rows [n], their members: per replica
+        [n] bool) with n at most ``ROWS_A_PASS``."""
         mask = self._np("member_mask")
         if mask is not self._member_rows[0]:
             self._member_rows = (mask, self._index_member_rows(mask))
@@ -874,16 +987,71 @@ class PaxosManager:
 
     def _index_member_rows(
         self, mask: np.ndarray
-    ) -> List[Tuple[np.ndarray, np.ndarray]]:
+    ) -> List[Tuple[np.ndarray, Tuple]]:
         """The one pass over ``[G]`` the post-step's index costs."""
         rows = np.flatnonzero(mask)
-        rids = np.arange(self.cfg.n_replicas)[:, None]
         return [
-            (block, ((mask[block][None, :] >> rids) & 1) == 1)
+            (block, self._member_bits(mask[block]))
             for block in (
                 rows[i:i + self.ROWS_A_PASS]
                 for i in range(0, rows.size, self.ROWS_A_PASS))
         ]
+
+    def _member_bits(self, masks: np.ndarray) -> Tuple[np.ndarray, ...]:
+        """``[n]`` member masks as their bits: per replica ``[n]`` bool."""
+        return tuple(((masks >> r) & 1) == 1
+                     for r in range(self.cfg.n_replicas))
+
+    def _patched_member_rows(
+        self, blocks: List[Tuple[np.ndarray, Tuple]],
+        mask: np.ndarray, rows: np.ndarray,
+    ) -> List[Tuple[np.ndarray, Tuple]]:
+        """``blocks`` with ``rows`` as ``mask`` now has them: a row
+        joins the ascending list, leaves it or changes its members.  A
+        NEW list of new blocks where one changed (the post-step may
+        still hold the old one), and no array operation over more than
+        ``ROWS_A_PASS`` elements (a lifecycle call holds the state
+        lock: it hands the interpreter over to nobody): a full block is
+        halved before a row joins it, and a list that has crumbled
+        into more than twice the blocks its rows need is indexed
+        anew."""
+        blocks = list(blocks)
+        for row in sorted(set(rows.tolist())):
+            # the first block that ends at or past the row
+            i = bisect.bisect_left(blocks, row, key=lambda b: int(b[0][-1]))
+            at = min(i, max(len(blocks) - 1, 0))
+            block, bits = blocks[at] if blocks else (
+                np.zeros(0, np.int64),
+                (np.zeros(0, bool),) * self.cfg.n_replicas)
+            j = int(np.searchsorted(block, row))
+            held = j < block.size and int(block[j]) == row
+            members = [bool((int(mask[row]) >> r) & 1)
+                       for r in range(self.cfg.n_replicas)]
+            if held and not mask[row]:
+                new = [(np.delete(block, j),
+                        tuple(np.delete(b, j) for b in bits))]
+            elif held:
+                new = [(block, tuple(
+                    _with(b, j, m) for b, m in zip(bits, members)))]
+            elif mask[row]:
+                half = block.size // 2 if block.size >= self.ROWS_A_PASS \
+                    else 0
+                parts = [(block[:half], tuple(b[:half] for b in bits)),
+                         (block[half:], tuple(b[half:] for b in bits))]
+                k = j >= half  # the half the row joins
+                rows_k, bits_k = parts[k]
+                parts[k] = (
+                    np.insert(rows_k, j - half * k, row),
+                    tuple(np.insert(b, j - half * k, m)
+                          for b, m in zip(bits_k, members)))
+                new = parts
+            else:
+                continue
+            blocks[at:at + 1] = [part for part in new if part[0].size]
+        n_rows = sum(block.size for block, _bits in blocks)
+        if len(blocks) > 2 * (n_rows // self.ROWS_A_PASS + 1):
+            return self._index_member_rows(mask)
+        return blocks
 
     # ------------------------------------------------------------------
     # recovery (initiateRecovery analog, PaxosManager.java:1832-2035)
@@ -1112,7 +1280,10 @@ class PaxosManager:
             tags[r] = _instance_tag(nm, int(versions[r]))
         for (nm, e), r in self.old_epochs.items():
             tags[r] = _instance_tag(nm, int(e))
-        self.state = self.state._replace(tag=jnp.asarray(tags))
+        rows = [*self.names.values(), *self.old_epochs.values()]
+        self._replace_state_locked(
+            self.state._replace(tag=jnp.asarray(tags)), rows,
+            {"tag": tags[rows]})
         # ---- lazy hydration plan (recovery plane) ---------------------
         # Checkpoint-domain names not already restored above go COLD:
         # their rows gate out of admission/execution/reads until the
@@ -1245,8 +1416,12 @@ class PaxosManager:
             scratch = restore_paused_rows(
                 scratch, z[:n], z[:n], z[:n], z[:n], z[:n],
                 nullw, nullw, nullw, nullw, nullw)
-        # a sweep's burst of pauses frees its rows PAUSE_CHUNK at a time
-        kill_groups(scratch, np.zeros(self.PAUSE_CHUNK, np.int32))
+        # a sweep's burst of pauses reads and frees its rows PAUSE_CHUNK
+        # at a time; a single pause reads and frees one
+        zp = np.zeros(self.PAUSE_CHUNK, np.int32)
+        kill_groups(scratch, zp)
+        for n in (1, self.PAUSE_CHUNK):
+            take_rows(scratch, zp[:n])
         # a straggler's state pull (a node back after a while) jumps its
         # rows JUMP_CHUNK at a time
         zj = np.zeros(self.JUMP_CHUNK, np.int32)
@@ -1463,7 +1638,7 @@ class PaxosManager:
                 # confirmed (unpended) or executed row must refuse as a
                 # collision so the RC's probe converges back to this row.
                 if cur_row not in self.pending_rows or \
-                        int(self._np("n_execd")[cur_row]):
+                        self._row_word_locked(cur_row, "n_execd"):
                     raise RuntimeError(
                         f"row move for {name!r} v{version} refused: row "
                         f"{cur_row} is confirmed or already executed"
@@ -1475,7 +1650,7 @@ class PaxosManager:
                 # row stays resident under (name, old_epoch) until the
                 # reconfigurator drops it; the name re-maps to the new row
                 # (PaxosManager's paxosID+version instance keying analog).
-                if not int(self._np("stopped")[cur_row]):
+                if not self._row_word_locked(cur_row, "stopped"):
                     return False  # old epoch must stop before the next starts
                 if row is not None and int(row) in self.row_name:
                     # the probed row is taken: refuse BEFORE the name lets
@@ -1524,9 +1699,8 @@ class PaxosManager:
                 # epoch upgrade supersedes a cold row's checkpoint state
                 # (the new epoch restores from the stop-time final state)
                 self.hydrating_rows.discard(cur_row)
-                self.app_exec_slot[cur_row] = int(
-                    self._np("exec_slot")[cur_row]
-                )
+                self.app_exec_slot[cur_row] = self._row_word_locked(
+                    cur_row, "exec_slot")
         row = self.default_row_for(name) if row is None else int(row)
         if row in self.row_name:
             # collision-NACK path: the name (if it was re-homed above) is
@@ -1548,11 +1722,14 @@ class PaxosManager:
         for m in members:
             mask |= 1 << m
         coord0 = members[row % len(members)]
-        self.state = create_groups(
-            self.state, np.array([row]), np.array([mask]),
-            np.array([coord0]), my_id=self.my_id, version=version,
-            tag=_instance_tag(name, version),
-        )
+        tag = _instance_tag(name, version)
+        self._replace_state_locked(
+            create_groups(
+                self.state, np.array([row]), np.array([mask]),
+                np.array([coord0]), my_id=self.my_id, version=version,
+                tag=tag,
+            ), [row],
+            create_wrote([mask], [coord0], self.my_id, version, tag))
         # the implicit initial ballot (0, coord0) is known host-side:
         # seed the decide-attribution view without touching the device
         self._bal_host[row] = encode_ballot(0, coord0)
@@ -1666,11 +1843,15 @@ class PaxosManager:
             if not fresh:
                 return 0
             rows_np = np.array(rows, np.int32)
-            self.state = create_groups(
-                self.state, rows_np, np.full(len(rows), mask, np.int32),
-                np.array(coords, np.int32), my_id=self.my_id, version=0,
-                tag=np.array(tags, np.int32),
-            )
+            masks_np = np.full(len(rows), mask, np.int32)
+            coords_np = np.array(coords, np.int32)
+            tags_np = np.array(tags, np.int32)
+            self._replace_state_locked(
+                create_groups(
+                    self.state, rows_np, masks_np, coords_np,
+                    my_id=self.my_id, version=0, tag=tags_np,
+                ), rows_np,
+                create_wrote(masks_np, coords_np, self.my_id, 0, tags_np))
             self.app_exec_slot[rows_np] = 0
             self._stall_since[rows_np] = -1
             self._stall_slot[rows_np] = -1
@@ -1682,10 +1863,8 @@ class PaxosManager:
                 self.pending_exec.pop(row, None)
             if self.logger:
                 self.logger.log_create(
-                    rows_np, np.full(len(rows), mask, np.int32),
-                    np.zeros(len(rows), np.int32),
-                    np.array(coords, np.int32),
-                    names=fresh,
+                    rows_np, masks_np, np.zeros(len(rows), np.int32),
+                    coords_np, names=fresh,
                     inits=[initial_states.get(n) for n in fresh],
                 )
             if self.my_id in members:
@@ -1743,6 +1922,11 @@ class PaxosManager:
         for vid in self.queues.pop(row, None) or []:
             self._release_vid(vid)
 
+    def _kill_rows_locked(self, rows: np.ndarray) -> None:
+        """The device side of freeing ``rows``, and the host's copies."""
+        self._replace_state_locked(
+            kill_groups(self.state, rows), rows, KILL_WROTE)
+
     def kill(self, name: str) -> bool:
         with self._state_lock:
             self._await_step_lifecycle_locked()
@@ -1766,7 +1950,7 @@ class PaxosManager:
         self._stall_since[row] = -1
         self._stall_slot[row] = -1
         self._needs_state.discard(row)
-        self.state = kill_groups(self.state, np.array([row]))
+        self._kill_rows_locked(np.array([row]))
         if self.logger:
             self.logger.log_kill(np.array([row]))
         if release_queue:
@@ -1806,7 +1990,7 @@ class PaxosManager:
                     return False
                 if int(self._np("version")[cur]) != epoch:
                     return False
-                if not int(self._np("stopped")[cur]):
+                if not self._row_word_locked(cur, "stopped"):
                     return False  # never kill a live, unstopped group
                 return self._kill_locked(name)
             del self.row_name[row]
@@ -1816,7 +2000,7 @@ class PaxosManager:
             self._stall_since[row] = -1
             self._stall_slot[row] = -1
             self._needs_state.discard(row)
-            self.state = kill_groups(self.state, np.array([row]))
+            self._kill_rows_locked(np.array([row]))
             if self.logger:
                 self.logger.log_kill(np.array([row]))
             self._release_row_queue(row)
@@ -1935,16 +2119,17 @@ class PaxosManager:
                 return "ok" if (name, int(epoch)) in self.paused else "unknown"
             if int(self._np("version")[row]) != int(epoch):
                 return "unknown"
-            if int(self._np("stopped")[row]):
+            words = self._row_words_locked([row])[0]
+            if int(words["stopped"]):
                 return "busy"  # stopping group: the delete path owns it
             if row in self.hydrating_rows:
                 # a pause record snapshots app state — un-hydrated, the
                 # snapshot would capture the pre-restore blank.  Busy is
                 # transient: background hydration clears it
                 return "busy"
-            if not force and not self._quiescent_locked(row):
+            if not force and not self._quiescent_locked(row, words):
                 return "busy"
-            rec = self._extract_record(name, int(epoch), row)
+            rec = self._extract_record(name, int(epoch), row, words)
             held = list(self.queues.get(row, []))
             if held:
                 # unadmitted requests survive the pause in the record's
@@ -1968,33 +2153,68 @@ class PaxosManager:
                 self.metrics.count("pause_evictions")
             return "ok"
 
-    def _quiescent_locked(self, row: int) -> bool:
+    def _quiescent_locked(self, row: int, words: Dict) -> bool:
         """Nothing queued, decided-unexecuted or accepted past the
-        frontier on ``row``: a pause there loses no work in flight."""
-        exec_now = int(self._np("exec_slot")[row])
+        frontier on ``row`` (``words``: :meth:`_row_words_locked` of
+        it): a pause there loses no work in flight."""
+        exec_now = int(words["exec_slot"])
         return (
             not self.queues.get(row)
             and not self.pending_exec.get(row)
             and int(self.app_exec_slot[row]) == exec_now
-            and int(self._np("acc_slot")[row].max()) < exec_now
+            and int(words["acc_slot"].max()) < exec_now
         )
+
+    def _row_word_locked(self, row: int, leaf: str) -> int:
+        """Lock held: one word of one row (a leaf of ``ROW_LEAVES``)."""
+        return int(self._row_words_locked([row], (leaf,))[0][leaf])
+
+    def _row_words_locked(self, rows, leaves=ROW_LEAVES + ROW_PLANES
+                          ) -> List[Dict[str, Any]]:
+        """Lock held: per row of ``rows`` its word of each [G] leaf and
+        its ``[W]`` lanes of each plane in ``leaves`` (of ``ROW_LEAVES``
+        and ``ROW_PLANES``).  From the host's copies where every leaf
+        asked for is cached; else from the rows' own words, brought
+        down by ONE device gather of the rows not held yet over all ten
+        leaves (ops/lifecycle.py:take_rows, one row alone or
+        ``PAUSE_CHUNK`` a program, a row repeated to fill) and one pull
+        of ``[n, 5 + 5W]`` words, and held while the state stands (a
+        caller that asks about a row again goes to the device once) —
+        no reader pulls a whole leaf for a row
+        (``lifecycle_row_reads``: rows gathered)."""
+        rows = np.asarray(rows, np.int32)
+        cache = self._np_cache_locked()
+        if all(leaf in cache for leaf in leaves):
+            return [{leaf: cache[leaf][row] for leaf in leaves}
+                    for row in rows]
+        held = self._row_words
+        new = np.array([row for row in dict.fromkeys(rows.tolist())
+                        if row not in held], np.int32)
+        if new.size:
+            chunks = [np.zeros(1, np.int64)] if new.size == 1 \
+                else _padded_chunks(new.size, self.PAUSE_CHUNK)
+            took = split_rows(np.concatenate(jax.device_get([
+                take_rows(self.state, new[pad]) for pad in chunks
+            ])), self.cfg.window)
+            for i, row in enumerate(new.tolist()):
+                held[row] = {leaf: col[i] for leaf, col in took.items()}
+            self.metrics.count("lifecycle_row_reads", int(new.size))
+        return [held[row] for row in rows.tolist()]
 
     def pause_group_batch(
         self, items: List[Tuple[str, int]]
     ) -> Dict[Tuple[str, int], str]:
         """The pause rounds of a sweep's burst, together: per (name,
         epoch) what :meth:`pause_group` answers without ``force`` ("ok",
-        "unknown", "busy"), at one wait for the step in flight, one pull
-        of each leaf for all the rows (every single pause makes a new
-        state, and the leaf cache would then fetch each [G, W] plane —
-        21 MB at 65,536 rows — again for the next name), one pass over
-        the response cache for their dedup entries, and the rows freed
-        ``PAUSE_CHUNK`` at a time.  Each record is journaled before its
-        row is freed."""
+        "unknown", "busy"), at one wait for the step in flight, one
+        gather of all the rows' words (:meth:`_row_words_locked`), one
+        pass over the response cache for their dedup entries, and the
+        rows freed ``PAUSE_CHUNK`` at a time.  Each record is journaled
+        before its row is freed."""
         out: Dict[Tuple[str, int], str] = {}
         with self._state_lock:
             self._await_step_lifecycle_locked()
-            jobs: List[Tuple[str, int, int]] = []
+            hosted: List[Tuple[str, int, int]] = []
             for name, epoch in items:
                 epoch = int(epoch)
                 row = self.names.get(name)
@@ -2003,26 +2223,31 @@ class PaxosManager:
                         "ok" if (name, epoch) in self.paused else "unknown")
                 elif int(self._np("version")[row]) != epoch:
                     out[(name, epoch)] = "unknown"
-                elif int(self._np("stopped")[row]) \
-                        or row in self.hydrating_rows \
-                        or not self._quiescent_locked(row):
-                    out[(name, epoch)] = "busy"
                 elif (name, epoch) not in out:
+                    out[(name, epoch)] = "busy"  # until found otherwise
+                    hosted.append((name, epoch, row))
+            jobs: List[Tuple[str, int, int, Dict]] = []
+            for (name, epoch, row), words in zip(
+                    hosted, self._row_words_locked(
+                        [row for _n, _e, row in hosted])):
+                if not int(words["stopped"]) \
+                        and row not in self.hydrating_rows \
+                        and self._quiescent_locked(row, words):
                     out[(name, epoch)] = "ok"
-                    jobs.append((name, epoch, row))
+                    jobs.append((name, epoch, row, words))
             if not jobs:
                 return out
-            for name, epoch, row in jobs:
-                rec = self._extract_record(name, epoch, row)
+            for name, epoch, row, words in jobs:
+                rec = self._extract_record(name, epoch, row, words)
                 if self.logger:
                     self.logger.log_pause(rec)
                 self._paused_put((name, epoch), rec)
-            rows = np.array([row for _n, _e, row in jobs], np.int32)
+            rows = np.array([job[2] for job in jobs], np.int32)
             for pad in _padded_chunks(len(rows), self.PAUSE_CHUNK):
-                self.state = kill_groups(self.state, rows[pad])
+                self._kill_rows_locked(rows[pad])
             if self.logger:
                 self.logger.log_kill(rows)
-            for name, _epoch, row in jobs:
+            for name, _epoch, row, _words in jobs:
                 self._forget_row_locked(name, row)
             self.metrics.count("pause_evictions", len(jobs))
         return out
@@ -2043,20 +2268,18 @@ class PaxosManager:
         self.queues.pop(row, None)
         self.pending_exec.pop(row, None)
 
-    def _extract_record(self, name: str, epoch: int, row: int) -> Dict:
-        """Snapshot one row for pause/re-home (HotRestoreInfo analog).
-        Reads go through the ``_np`` leaf cache — one host transfer per
-        leaf per state version, not per paused name (the old per-call
-        ``np.asarray(leaf)`` copied whole [G, W] planes per pause; a
-        density sweep pays extraction thousands of times per state)."""
-        exec_now = int(self._np("exec_slot")[row])
+    def _extract_record(self, name: str, epoch: int, row: int,
+                        words: Dict) -> Dict:
+        """Snapshot one row for pause/re-home (HotRestoreInfo analog)
+        from its ``words`` (:meth:`_row_words_locked`: the row's own
+        words, never a whole leaf — five [G, W] planes of 4 MB each came
+        down for sixteen lanes when the leaf cache was read here)."""
+        exec_now = int(words["exec_slot"])
         acc = []
         dec = []
-        acc_slot = self._np("acc_slot")[row]
-        acc_bal = self._np("acc_bal")[row]
-        acc_vid = self._np("acc_vid")[row]
-        dec_slot = self._np("dec_slot")[row]
-        dec_vid = self._np("dec_vid")[row]
+        acc_slot, acc_bal, acc_vid = (
+            words["acc_slot"], words["acc_bal"], words["acc_vid"])
+        dec_slot, dec_vid = words["dec_slot"], words["dec_vid"]
         for lane in range(self.cfg.window):
             if int(acc_slot[lane]) >= exec_now:
                 acc.append([int(acc_slot[lane]), int(acc_bal[lane]),
@@ -2066,9 +2289,9 @@ class PaxosManager:
         return {
             "name": name, "epoch": epoch,
             "exec": exec_now,
-            "bal": int(self._np("bal")[row]),
-            "app_hash": int(self._np("app_hash")[row]),
-            "n_execd": int(self._np("n_execd")[row]),
+            "bal": int(words["bal"]),
+            "app_hash": int(words["app_hash"]),
+            "n_execd": int(words["n_execd"]),
             "app_state": self.app.checkpoint(name),
             "app_exec": int(self.app_exec_slot[row]),
             "acc": acc, "dec": dec,
@@ -2207,10 +2430,11 @@ class PaxosManager:
                 lane = slot % W
                 dec_slot[i, lane] = slot
                 dec_vid[i, lane] = vid
-        self.state = restore_paused_rows(
-            self.state, rows, exec_, bal, app_hash, n_execd,
-            acc_bal, acc_vid, acc_slot, dec_vid, dec_slot,
-        )
+        words = (exec_, bal, app_hash, n_execd,
+                 acc_bal, acc_vid, acc_slot, dec_vid, dec_slot)
+        self._replace_state_locked(
+            restore_paused_rows(self.state, rows, *words), rows,
+            restore_wrote(*words))
 
     def _resume_record_host_locked(
         self, r: int, rec: Dict, name: str, epoch: int
@@ -2384,11 +2608,15 @@ class PaxosManager:
                     vers_np = np.array(vers, np.int32)
                     tags_np = np.array(tags, np.int32)
                     for pad in _padded_chunks(len(batch), self.RESUME_CHUNK):
-                        self.state = create_groups(
-                            self.state, rows_np[pad], masks_np[pad],
-                            coords_np[pad], my_id=self.my_id,
-                            version=vers_np[pad], tag=tags_np[pad],
-                        )
+                        self._replace_state_locked(
+                            create_groups(
+                                self.state, rows_np[pad], masks_np[pad],
+                                coords_np[pad], my_id=self.my_id,
+                                version=vers_np[pad], tag=tags_np[pad],
+                            ), rows_np[pad],
+                            create_wrote(masks_np[pad], coords_np[pad],
+                                         self.my_id, vers_np[pad],
+                                         tags_np[pad]))
                     if self.logger:
                         self.logger.log_create(
                             rows_np, masks_np, vers_np, coords_np,
@@ -2453,8 +2681,9 @@ class PaxosManager:
         return True
 
     def hibernate_batch(self, names: List[str]) -> int:
-        """Hibernate MANY names: one batched extract off the cached host
-        leaves, ONE fused ``kill_groups`` scatter, one sequential spill
+        """Hibernate MANY names: one batched extract (the rows' words,
+        or off the whole leaves for a long tail), ONE fused
+        ``kill_groups`` scatter, one sequential spill
         run.  Per-name :meth:`hibernate` costs a device kill dispatch per
         name — putting a 1M-name cold tail to sleep that way is minutes
         of pure dispatch overhead (the density campaign's boot path).
@@ -2477,8 +2706,15 @@ class PaxosManager:
                 return 0
             rows_l: List[int] = []
             keys: List[Tuple[str, int]] = []
-            for name, epoch, row in jobs:
-                rec = self._extract_record(name, epoch, row)
+            if len(jobs) > self.PAUSE_CHUNK:
+                # a cold tail of many: each leaf whole, once, is less
+                # than a gather every PAUSE_CHUNK rows
+                for leaf in ROW_LEAVES + ROW_PLANES:
+                    self._np(leaf)
+            for (name, epoch, row), words in zip(
+                    jobs, self._row_words_locked(
+                        [row for _n, _e, row in jobs])):
+                rec = self._extract_record(name, epoch, row, words)
                 held = list(self.queues.get(row, []))
                 if held:
                     rec["held_vids"] = held
@@ -2492,7 +2728,7 @@ class PaxosManager:
                 rows_l.append(row)
                 keys.append((name, epoch))
             rows_np = np.array(rows_l, np.int32)
-            self.state = kill_groups(self.state, rows_np)
+            self._kill_rows_locked(rows_np)
             if self.logger:
                 self.logger.log_kill(rows_np)
             for name, _epoch, row in jobs:
@@ -2790,7 +3026,7 @@ class PaxosManager:
             row = self.names.get(name)
             if row is None:
                 return False
-            return bool(int(self._np("stopped")[row]))
+            return bool(self._row_word_locked(row, "stopped"))
 
     def app_caught_up(self, name: str) -> bool:
         """Host app cursor == device frontier for the name's current row:
@@ -2809,9 +3045,8 @@ class PaxosManager:
                 # not reflect ANY executed decision yet — serving it as a
                 # consistent snapshot would hand out the pre-restore blank
                 return False
-            return int(self.app_exec_slot[row]) == int(
-                self._np("exec_slot")[row]
-            )
+            return int(self.app_exec_slot[row]) == \
+                self._row_word_locked(row, "exec_slot")
 
     # ------------------------------------------------------------------
     # cross-node trace plumbing (obs/reqtrace.py)
@@ -3720,6 +3955,7 @@ class PaxosManager:
             self._heat_dev = new_heat
             self._np_cache = carried
             self._np_cache_state = new_state
+            self._row_words = {}
             del old_state
         # the mirror is behind the device's published vector from here
         # until this dispatch's completion has patched it
@@ -3794,16 +4030,14 @@ class PaxosManager:
         sync and re-serialize exactly what the pipeline exists to
         overlap.  Copies are taken BEFORE the jit call: the step donates
         old_state's buffers."""
-        carry: Dict[str, np.ndarray] = {}
-        if self._np_cache_state is old_state:
-            for leaf in ("version", "member_mask", "majority", "tag"):
-                arr = self._np_cache.get(leaf)
-                if arr is not None:
-                    carry[leaf] = arr
+        cache = self._np_cache_locked()  # ``old_state`` is the current one
+        carry = {
+            leaf: cache[leaf]
+            for leaf in ("version", "member_mask", "majority", "tag")
+            if leaf in cache}
         for leaf in ("version", "member_mask"):
             if leaf not in carry:
-                arr = np.asarray(getattr(old_state, leaf))
-                carry[leaf] = arr.copy() if arr.base is not None else arr
+                carry[leaf] = self._pull_leaf(old_state, leaf)
         return carry
 
     def _device_wait(self, pend: Dict):
@@ -3966,6 +4200,7 @@ class PaxosManager:
         the mirror shows it: the manager's own pair (the mirror is
         patched in place, and later), the news' rows written into it."""
         cfg, names = self.cfg, ("bal", "exec_slot")
+        written, self._bal_exec_written = self._bal_exec_written, set()
         if whole is not None:
             blob = split_blob_vec(whole, cfg)
             self._bal_exec = {
@@ -3973,7 +4208,14 @@ class PaxosManager:
         else:
             news = split_blob_vec(
                 body, cfg._replace(n_groups=update_rows(cfg)))
+            # a row a lifecycle operation wrote and this step made no
+            # news of reads as it was published (the mirror, not yet
+            # patched with this step's news): the step may have moved
+            # it back there from what was written
+            at = np.fromiter(written, np.int64, len(written))
+            published = split_blob_vec(self.mirror.vec, cfg)
             for name in names:
+                self._bal_exec[name][at] = getattr(published, name)[at]
                 self._bal_exec[name][rows] = getattr(news, name)[:rows.size]
         return self._bal_exec
 
@@ -4104,6 +4346,8 @@ class PaxosManager:
         # the rows whose promised ballot some substep raised: the flips
         # below, and the journal's promises further down
         pg_m = np.concatenate(risen)
+        if len(pg_m) and self._election_rows is not None:
+            self._election_rows.update(pg_m.tolist())
         if n_admit or n_dec or len(pg_m) \
                 or any(o.acc_new.any() for o in outs):
             self.last_progress_tick = self._tick_no
@@ -4879,8 +5123,7 @@ class PaxosManager:
         # donor snapshots pair device frontier with the app cursor: an
         # in-flight step would advance one but not (yet) the other
         self._await_step_locked()
-        exec_np = self._np("exec_slot")
-        states = []
+        asked = []
         for ent in body["rows"]:
             g, name = int(ent["row"]), ent["name"]
             if self.names.get(name) != g:
@@ -4894,18 +5137,23 @@ class PaxosManager:
                 # "heal" the requester into blankness too
             if int(self._np("version")[g]) != int(ent["version"]):
                 continue
-            frontier = int(exec_np[g])
+            asked.append((g, name, int(ent["version"])))
+        states = []
+        for (g, name, version), words in zip(
+                asked, self._row_words_locked(
+                    [g for g, _n, _v in asked], ROW_LEAVES)):
+            frontier = int(words["exec_slot"])
             if int(self.app_exec_slot[g]) != frontier:
                 continue  # app cursor lags the device: snapshot inconsistent
-            bal = int(self._np("bal")[g])
+            bal = int(words["bal"])
             states.append(StatePacket(
-                paxos_id=name, version=int(ent["version"]),
+                paxos_id=name, version=version,
                 ballot_num=int(ballot_num(bal)),
                 ballot_coord=int(ballot_coord(bal)),
                 slot=frontier, row=g,
-                app_hash=int(self._np("app_hash")[g]),
-                n_execd=int(self._np("n_execd")[g]),
-                stopped=int(self._np("stopped")[g]),
+                app_hash=int(words["app_hash"]),
+                n_execd=int(words["n_execd"]),
+                stopped=int(words["stopped"]),
                 state=self.app.checkpoint(name),
             ).to_json())
         if states:
@@ -4990,7 +5238,12 @@ class PaxosManager:
                     for k in ("row", "exec", "bal", "app_hash", "n_execd",
                               "stopped")]
             for pad in _padded_chunks(len(jumps), self.JUMP_CHUNK):
-                self.state = jump_rows(self.state, *[c[pad] for c in cols])
+                rows, *words = [c[pad] for c in cols]
+                bal_np = self._np_cache_locked().get("bal")
+                self._replace_state_locked(
+                    jump_rows(self.state, rows, *words), rows,
+                    jump_wrote(*words, bal_before=(
+                        None if bal_np is None else bal_np[rows])))
         # install the donor's dedup entries ONLY for names whose state
         # was actually ADOPTED here: an entry is sound exactly when it is
         # paired with a state that contains its execution.  Installing a

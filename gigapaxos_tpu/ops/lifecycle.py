@@ -10,7 +10,7 @@ gather updates on :class:`~gigapaxos_tpu.ops.engine.EngineState`.
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +18,12 @@ import numpy as np
 
 from .ballot import NULL, encode_ballot
 from .engine import ACTIVE, IDLE, EngineState
+
+
+# What a pause record, a donor's snapshot and the lifecycle checks read
+# of a ROW: the [G] leaves first, one word each, then the [G, W] planes.
+ROW_LEAVES = ("stopped", "bal", "exec_slot", "app_hash", "n_execd")
+ROW_PLANES = ("acc_bal", "acc_vid", "acc_slot", "dec_vid", "dec_slot")
 
 
 def _popcount32(x: jnp.ndarray) -> jnp.ndarray:
@@ -100,6 +106,31 @@ def create_groups(
     )
 
 
+# The manager keeps host copies of state leaves and writes a lifecycle
+# program's rows into them instead of pulling the leaves again
+# (manager.py:_replace_state_locked).  Beside each program, what it
+# writes at ``idx`` as the host knows it from the program's ARGUMENTS:
+# leaf -> a word, ``[N]`` words or ``[N, W]`` lanes — or None where the
+# value depends on what the device held.  A leaf a program writes and
+# its ``*_wrote`` leaves out is a stale copy on the host
+# (tests/test_host_leaf_carry.py holds every leaf to the device's).
+def create_wrote(member_mask, coord0, my_id, version=0, tag=0) -> Dict:
+    """What :func:`create_groups` writes at ``idx``."""
+    member_mask = np.asarray(member_mask, np.int32)
+    coord0 = np.asarray(coord0, np.int32)
+    bal0 = encode_ballot(np.zeros_like(coord0), coord0)
+    mine = coord0 == my_id
+    members = sum((member_mask >> b) & 1 for b in range(32))
+    wrote = dict.fromkeys(ROW_PLANES + ("c_prop_vid", "c_prop_slot"), NULL)
+    wrote.update(
+        member_mask=member_mask, majority=members // 2 + 1,
+        version=version, stopped=0, tag=tag, bal=bal0, exec_slot=0,
+        app_hash=0, n_execd=0, c_phase=np.where(mine, ACTIVE, IDLE),
+        c_bal=np.where(mine, bal0, NULL), c_next_slot=0,
+    )
+    return wrote
+
+
 @jax.jit
 def kill_groups(state: EngineState, idx: jnp.ndarray) -> EngineState:
     """Batched kill: rows become inert (the Cremator analog,
@@ -116,6 +147,11 @@ def kill_groups(state: EngineState, idx: jnp.ndarray) -> EngineState:
         c_phase=state.c_phase.at[idx].set(IDLE),
         c_bal=state.c_bal.at[idx].set(NULL),
     )
+
+
+# What :func:`kill_groups` writes at ``idx``.
+KILL_WROTE = dict(member_mask=0, majority=2 ** 30, stopped=0, tag=0,
+                  bal=NULL, c_phase=IDLE, c_bal=NULL)
 
 
 @jax.jit
@@ -175,6 +211,22 @@ def jump_rows(
     )
 
 
+def jump_wrote(exec_slot, bal, app_hash, n_execd, stopped,
+               bal_before=None) -> Dict:
+    """What :func:`jump_rows` writes at ``idx``.  The ballot is the
+    larger of the donor's and the row's own, so ``bal_before`` is the
+    host's copy of ``bal[idx]`` (None: it has none); which window lanes
+    it keeps only the device can say."""
+    wrote = dict.fromkeys(ROW_PLANES)
+    wrote.update(
+        bal=None if bal_before is None else np.maximum(bal_before, bal),
+        exec_slot=exec_slot, app_hash=app_hash, n_execd=n_execd,
+        stopped=stopped, c_phase=IDLE, c_bal=NULL, c_next_slot=exec_slot,
+        c_prop_vid=NULL, c_prop_slot=NULL,
+    )
+    return wrote
+
+
 @jax.jit
 def restore_paused_rows(
     state: EngineState,
@@ -218,13 +270,35 @@ def restore_paused_rows(
     )
 
 
-def extract_rows(state: EngineState, idx) -> Tuple:
-    """Gather full rows for pause-to-disk (HotRestoreInfo analog)."""
-    idx = jnp.asarray(idx, jnp.int32)
-    return tuple(leaf[idx] for leaf in state)
+def restore_wrote(exec_slot, bal, app_hash, n_execd, acc_bal, acc_vid,
+                  acc_slot, dec_vid, dec_slot) -> Dict:
+    """What :func:`restore_paused_rows` writes at ``idx``: its arguments."""
+    return dict(
+        exec_slot=exec_slot, bal=bal, app_hash=app_hash, n_execd=n_execd,
+        c_next_slot=exec_slot, acc_bal=acc_bal, acc_vid=acc_vid,
+        acc_slot=acc_slot, dec_vid=dec_vid, dec_slot=dec_slot,
+    )
 
 
-def restore_rows(state: EngineState, idx, rows: Tuple) -> EngineState:
-    """Scatter previously extracted rows back (unpause)."""
+@jax.jit
+def take_rows(state: EngineState, idx: jnp.ndarray) -> jnp.ndarray:
+    """``[N, len(ROW_LEAVES) + len(ROW_PLANES) * W]``: the words of
+    ``ROW_LEAVES`` and then the lanes of ``ROW_PLANES`` of rows ``idx``,
+    as ONE device value — a reader of N rows pulls N * (5 + 5W) words,
+    not ten whole leaves (:func:`split_rows` is its host side).  One
+    compile per (state shape, N): the manager asks for N = 1 and
+    N = ``PAUSE_CHUNK`` only, and warms both."""
     idx = jnp.asarray(idx, jnp.int32)
-    return EngineState(*(leaf.at[idx].set(row) for leaf, row in zip(state, rows)))
+    return jnp.concatenate(
+        [getattr(state, leaf)[idx][:, None] for leaf in ROW_LEAVES]
+        + [getattr(state, leaf)[idx] for leaf in ROW_PLANES], axis=1)
+
+
+def split_rows(words: np.ndarray, window: int) -> Dict[str, np.ndarray]:
+    """:func:`take_rows`' result on the host, as leaf -> ``[N]`` words or
+    ``[N, W]`` lanes."""
+    out = {leaf: words[:, i] for i, leaf in enumerate(ROW_LEAVES)}
+    base = len(ROW_LEAVES)
+    for i, leaf in enumerate(ROW_PLANES):
+        out[leaf] = words[:, base + i * window:base + (i + 1) * window]
+    return out

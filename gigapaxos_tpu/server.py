@@ -106,7 +106,10 @@ class PaxosServer:
                 listen_port=int(port) + Config.get_int(PC.CLIENT_PORT_OFFSET),
                 ssl_server_context=c_srv, ssl_client_context=c_cli,
             )
-        self.fd = FailureDetector(my_id, node_config.get_node_ids(), fd_timeout_s)
+        self.fd = FailureDetector(my_id, node_config.get_node_ids(),
+                                  fd_timeout_s, metrics=self.manager.metrics)
+        for key in ("want_coord_full", "want_coord_patched_rows"):
+            self.manager.metrics.count(key, 0)  # present from the start
         self.tick_interval = (
             Config.get_float(PC.TICK_INTERVAL_S)
             if tick_interval is None else tick_interval
@@ -1028,10 +1031,7 @@ class PaxosServer:
             return True
         if time.monotonic() - self._last_full_tick > self.IDLE_REPUBLISH_S:
             return True
-        want = self.fd.want_coord(
-            m._np("bal"), m._np("member_mask"), self.cfg.n_replicas
-        )
-        return want is not None and bool(np.asarray(want).any())
+        return bool(self._want_coord().any())
 
     def idle_once(self) -> None:
         """Host housekeeping between engine ticks: FD pings, layered
@@ -1104,17 +1104,19 @@ class PaxosServer:
         # an unheard peer's row of the stack holds what it held: the
         # step masks it by ``heard`` (tests/test_gather_device.py)
         heard[self.my_id] = True
-        want = self.fd.want_coord(
-            self.manager._np("bal"),
-            self.manager._np("member_mask"),
-            R,
-        )
+        want = self._want_coord()
         if want is not self._want_seen:
             self._want_seen = want
             self._note_want(want)
         if frontier is not None:
             self.manager.begin_catchup(self._back_t0, frontier)
         return update, heard, want
+
+    def _want_coord(self) -> np.ndarray:
+        """The failure detector's election mask over the manager's
+        ballots and memberships, told which rows of them moved."""
+        bal, mask, changed = self.manager.election_inputs()
+        return self.fd.want_coord(bal, mask, self.cfg.n_replicas, changed)
 
     def _note_want(self, want) -> None:
         """A new answer of the failure detector's (it hands back the same

@@ -169,9 +169,8 @@ class ManagerCluster:
             update = self._news[i].drain(self._held[i])
             want = want_coord.get(i)
             if want is None:
-                want = self._fds[i].want_coord(
-                    m._np("bal"), m._np("member_mask"), R
-                )
+                bal, mask, changed = m.election_inputs()
+                want = self._fds[i].want_coord(bal, mask, R, changed)
             if self.pipelined:
                 pend = m.step_dispatch(update, heard, want)
                 _tick, _state, delta = m.step_complete(pend)

@@ -95,10 +95,13 @@ HOT_NP_ALLOW = {
     ("manager.py", "_whole_planes_locked"): frozenset(
         {"acc_slot", "acc_bal", "acc_vid"}
     ),
-    ("server.py", "_should_tick"): frozenset({"bal", "member_mask"}),
+    # the election mask's inputs come through manager.election_inputs,
+    # which reads the carried copies (a lifecycle operation writes its
+    # rows into them: no pull follows one)
+    ("server.py", "_should_tick"): frozenset(),
     ("server.py", "_tick_once_inner"): frozenset(),
     # no blob vector is pulled or copied here: the stack is the device's
-    ("server.py", "_gather"): frozenset({"bal", "member_mask"}),
+    ("server.py", "_gather"): frozenset(),
     ("server.py", "_finish_tick"): frozenset(),
     # stats-cadence hook: the ONE sanctioned group-heat drain (runs at
     # STATS_LOG_PERIOD_S inside the tick loop, not per tick)

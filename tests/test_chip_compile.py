@@ -173,6 +173,27 @@ def test_state_pull_jump_compiles_at_deployed_rows(one_chip):
     assert _dispatch_bytes(compiled) < HBM_BYTES
 
 
+@pytest.mark.parametrize("n", [1, 64])
+def test_row_gather_compiles_at_deployed_rows(one_chip, n):
+    """take_rows (a pause record's, a donor snapshot's and the lifecycle
+    checks' read of their rows) over a 65,536-row state at the two
+    shapes ``warm_engine`` compiles at boot: one row, and
+    ``PaxosManager.PAUSE_CHUNK``; what comes down is ``[n, 5 + 5W]``."""
+    from gigapaxos_tpu.manager import PaxosManager
+    from gigapaxos_tpu.ops.lifecycle import ROW_LEAVES, ROW_PLANES, take_rows
+
+    assert n in (1, PaxosManager.PAUSE_CHUNK)
+    cfg = EngineConfig(65_536, 16, 8, 3)
+    rows = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+    compiled = take_rows.lower(_state_shapes(cfg, one_chip), rows).compile()
+    words = len(ROW_LEAVES) + len(ROW_PLANES) * cfg.window
+    assert compiled.output_shardings is not None
+    out, = jax.tree_util.tree_leaves(jax.eval_shape(
+        take_rows, _state_shapes(cfg, one_chip), rows))
+    assert out.shape == (n, words) and out.dtype == jnp.int32
+    assert _dispatch_bytes(compiled) < HBM_BYTES
+
+
 def test_group_sharded_has_no_collectives_on_four_chips(topo):
     """The ``('g',)``-sharded step on the four described devices
     (``chip_smoke.py --chips 4``): groups are independent, so the

@@ -69,7 +69,8 @@ def test_accessors_do_not_transfer_per_call():
     cfg = EngineConfig(n_groups=131_072, window=8, req_lanes=4, n_replicas=3)
     m = PaxosManager(0, NoopPaxosApp(), cfg)
     m.create_paxos_instance("svc", [0, 1, 2], row=7)
-    m.coordinator_of_row(7)  # prime the mirror
+    m.coordinator_of_row(7)  # prime the mirror...
+    m.is_stopped("svc")  # ...and the row's own words (one gather a state)
     t0 = time.perf_counter()
     for _ in range(10_000):
         m.coordinator_of_row(7)
