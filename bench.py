@@ -39,12 +39,6 @@ Modes (env):
   harness runs on a virtual CPU mesh
   (``XLA_FLAGS=--xla_force_host_platform_device_count=8`` is forced)
   with ``platform`` marked in the artifact.
-* ``BENCH_DISPATCH_ABLATION=1`` — the host-boundary residency ablation:
-  N=1 vs N=8 ``steps_per_dispatch`` under identical offered load and an
-  identical total substep budget.  Asserts per-step engine parity is
-  bit-exact across N, counts host dispatches for the same decided work
-  (~8x fewer at N=8), and measures end-to-end throughput for both arms.
-  Emits ``BENCH_r06.json`` (override: ``BENCH_DISPATCH_OUT``).
 """
 
 import json
@@ -192,7 +186,7 @@ def _run_group_sharded_point(n_devices: int, g_per_dev: int, W: int, K: int,
         np.full((R, G, K), NULL, np.int32), np.zeros((R, G), bool),
     )
     Gp = _req0.shape[1]
-    step_fn = make_step(cfg, mesh, 1)
+    step_fn = make_step(cfg, mesh)
     vids = jnp.arange(1, K + 1, dtype=jnp.int32)
     CHUNK = 10
 
@@ -245,171 +239,6 @@ def _run_group_sharded_point(n_devices: int, g_per_dev: int, W: int, K: int,
         "warmup_s": round(warmup_s, 2),
         "wall_s": round(dt, 2),
     }
-
-
-def _dispatch_arm(n_steps: int, G: int, W: int, K: int, R: int,
-                  substeps: int) -> dict:
-    """Time one ablation arm: the unified step at ``n_steps`` rounds per
-    host dispatch, over ``substeps`` total engine steps of identical
-    offered load.  The host touches the packed outputs once per dispatch
-    (the decided-count reduction), exactly like the deployed post-step
-    cycle's single transfer."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from gigapaxos_tpu.ops.engine import EngineConfig
-    from gigapaxos_tpu.parallel.spmd import build_replica_states, make_step
-
-    cfg = EngineConfig(n_groups=G, window=W, req_lanes=K, n_replicas=R)
-    states = build_replica_states(cfg)
-    step_fn = make_step(cfg, None, n_steps)
-    vids = jnp.arange(1, K + 1, dtype=jnp.int32)
-    if n_steps == 1:
-        ring = jnp.broadcast_to(vids[None, None, :], (R, G, K))
-    else:
-        ring = jnp.broadcast_to(
-            vids[None, None, None, :], (n_steps, R, G, K)
-        )
-    want = jnp.zeros((R, G), bool)
-    dispatches = substeps // n_steps
-    # warmup: compile + steady-state fill — timed into its own field so
-    # compile cost never leaks into (or hides inside) the steady rate
-    tw = time.perf_counter()
-    for _ in range(2):
-        states, out = step_fn(states, ring, want)
-    jax.block_until_ready(out.n_committed)
-    warmup_s = time.perf_counter() - tw
-
-    t0 = time.perf_counter()
-    decided = 0
-    for _ in range(dispatches):
-        states, out = step_fn(states, ring, want)
-        # ONE host touch per dispatch: the packed reduction syncs the
-        # device and is the only per-dispatch host<->device traffic
-        decided += int(np.asarray(out.n_committed)[..., 0, :].sum())
-    dt = time.perf_counter() - t0
-    return {
-        "steps_per_dispatch": n_steps,
-        "host_dispatches": dispatches,
-        "substeps": dispatches * n_steps,
-        "decided": decided,
-        "warmup_s": round(warmup_s, 3),
-        "wall_s": round(dt, 3),
-        "decided_per_s": round(decided / dt, 1),
-        "dispatch_amortized_us": round(1e6 * dt / dispatches / n_steps, 1),
-    }
-
-
-def _dispatch_parity(G: int, W: int, K: int, R: int, substeps: int) -> dict:
-    """Bit-exact check: N=8 residency vs 8x sequential N=1 from the same
-    initial states — every state leaf and every StepOutputs field."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from gigapaxos_tpu.ops.engine import EngineConfig
-    from gigapaxos_tpu.parallel.spmd import build_replica_states, make_step
-
-    N = 8
-    cfg = EngineConfig(n_groups=G, window=W, req_lanes=K, n_replicas=R)
-    fn1 = make_step(cfg, None, 1, donate=False)
-    fn8 = make_step(cfg, None, N, donate=False)
-    vids = jnp.arange(1, K + 1, dtype=jnp.int32)
-    req = jnp.broadcast_to(vids[None, None, :], (R, G, K))
-    ring = jnp.broadcast_to(vids[None, None, None, :], (N, R, G, K))
-    want = jnp.zeros((R, G), bool)
-
-    s1 = build_replica_states(cfg)
-    s8 = build_replica_states(cfg)
-    dec1 = dec8 = 0
-    bit_exact = True
-    for _ in range(substeps // N):
-        outs1 = []
-        for _i in range(N):
-            s1, o = fn1(s1, req, want)
-            outs1.append(o)
-        s8, o8 = fn8(s8, ring, want)
-        dec1 += int(sum(int(np.asarray(o.n_committed)[0].sum())
-                        for o in outs1))
-        dec8 += int(np.asarray(o8.n_committed)[:, 0].sum())
-        for i, o in enumerate(outs1):
-            for a, b in zip(o, jax.tree.map(lambda x: x[i], o8)):
-                if not (np.asarray(a) == np.asarray(b)).all():
-                    bit_exact = False
-        for a, b in zip(s1, s8):
-            if not (np.asarray(a) == np.asarray(b)).all():
-                bit_exact = False
-    return {
-        "substeps": substeps - substeps % N,
-        "bit_exact": bit_exact,
-        "decided_n1": dec1,
-        "decided_n8": dec8,
-    }
-
-
-def dispatch_ablation_main() -> int:
-    """BENCH_DISPATCH_ABLATION=1: the steps_per_dispatch residency
-    ablation — N=1 vs N=8 under identical load (see module docstring)."""
-    t_start = time.perf_counter()
-    platform = require_backend()
-
-    on_cpu = platform.startswith("cpu")
-    # CPU default sits in the dispatch-bound regime (small per-step device
-    # time, like a TPU step over sharded groups) — that is the regime the
-    # residency amortization targets; at compute-bound shapes the host
-    # dispatch cost is noise either way
-    G = int(os.environ.get("BENCH_G", 512 if on_cpu else 262_144))
-    W = int(os.environ.get("BENCH_W", 8 if on_cpu else 32))
-    K = int(os.environ.get("BENCH_K", 4 if on_cpu else 16))
-    R = 3
-    substeps = int(os.environ.get("BENCH_DISPATCH_SUBSTEPS", "480"))
-
-    parity = _dispatch_parity(
-        G, W, K, R, int(os.environ.get("BENCH_DISPATCH_PARITY_SUBSTEPS",
-                                       "32")),
-    )
-    # best-of-trials, arms interleaved: the signal (dispatch overhead
-    # amortization) is a few percent on CPU, below run-to-run OS noise
-    trials = int(os.environ.get("BENCH_DISPATCH_TRIALS", "5"))
-    arm1 = arm8 = None
-    for _ in range(trials):
-        a1 = _dispatch_arm(1, G, W, K, R, substeps)
-        a8 = _dispatch_arm(8, G, W, K, R, substeps)
-        if arm1 is None or a1["wall_s"] < arm1["wall_s"]:
-            arm1 = a1
-        if arm8 is None or a8["wall_s"] < arm8["wall_s"]:
-            arm8 = a8
-    result = {
-        "metric": "dispatch_ablation",
-        "platform": platform,
-        "shape": {"G": G, "W": W, "K": K, "R": R},
-        "arms": {"n1": arm1, "n8": arm8},
-        "dispatch_count_ratio": round(
-            arm1["host_dispatches"] / arm8["host_dispatches"], 2
-        ),
-        "throughput_ratio_n8_vs_n1": round(
-            arm8["decided_per_s"] / arm1["decided_per_s"], 3
-        ),
-        "parity": parity,
-        "provenance": bench_provenance(donate=True),
-        "wall_s": round(time.perf_counter() - t_start, 1),
-    }
-    out_path = os.environ.get("BENCH_DISPATCH_OUT") or os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), "BENCH_r06.json"
-    )
-    tmp = out_path + ".tmp"
-    with open(tmp, "w") as f:
-        json.dump(result, f, indent=2)
-        f.write("\n")
-    os.replace(tmp, out_path)
-    print(json.dumps(result))
-    ok = (
-        parity["bit_exact"]
-        and parity["decided_n1"] == parity["decided_n8"]
-        and result["dispatch_count_ratio"] >= 7.5
-    )
-    return 0 if ok else 1
 
 
 def multichip_main() -> int:
@@ -509,8 +338,6 @@ def multichip_main() -> int:
 def main() -> None:
     if os.environ.get("BENCH_MULTICHIP", "") not in ("", "0"):
         return multichip_main()
-    if os.environ.get("BENCH_DISPATCH_ABLATION", "") not in ("", "0"):
-        sys.exit(dispatch_ablation_main())
     t_start = time.perf_counter()
     platform = require_backend()
     import jax
@@ -544,7 +371,7 @@ def main() -> None:
     # (essential under failover churn: a new leader must find requests)
     req = jnp.broadcast_to(vids[None, None, :], (R, G, K))
     want = jnp.zeros((R, G), dtype=bool)
-    step_fn = make_step(cfg, None, 1)
+    step_fn = make_step(cfg)
 
     # BENCH_MODE=failover (BASELINE config 5): continuous ballot
     # contention — leadership of every group is forced to rotate around

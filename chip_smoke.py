@@ -170,7 +170,7 @@ class _ReplicaArm:
         self.whole += len(update.whole)
         self.scattered += update.n_scattered
         upd = put(self.no_rows if update.rows is None else update.rows)
-        ring = put(req[None])
+        ring = put(req)
         outs, blobs = [], []
         for r in range(R):
             for peer, vec in update.whole:
@@ -302,8 +302,8 @@ def phase_engine_parity(n_groups: int, window: int, req_lanes: int,
     from gigapaxos_tpu.parallel.spmd import make_step
 
     cfg = EngineConfig(n_groups, window, req_lanes, n_replicas)
-    step_fn = make_step(cfg, None, 1, donate=True, io="packed_host")
-    ref_fn = make_step(cfg, None, 1, donate=False, io="packed_host")
+    step_fn = make_step(cfg, donate=True, io="packed_host")
+    ref_fn = make_step(cfg, donate=False, io="packed_host")
     arm = _ReplicaArm(cfg, device, step_fn)
     ref = _ReplicaArm(cfg, reference_device, ref_fn)
     decided = admitted = news_rows = 0
@@ -332,10 +332,10 @@ def phase_engine_parity(n_groups: int, window: int, req_lanes: int,
         for r, (got, exp) in enumerate(zip(arm.news, ref.news)):
             _assert_same(f"step {t} r{r}.news", got, exp)
         news_rows += arm.check_news(t)
-        out0 = split_out_vec(outs_np[0][0], cfg)
+        out0 = split_out_vec(outs_np[0], cfg)
         decided += int(out0.n_committed.sum())
         admitted += sum(
-            int(split_out_vec(o[0], cfg).n_admitted.sum()) for o in outs_np
+            int(split_out_vec(o, cfg).n_admitted.sum()) for o in outs_np
         )
     n_leaves = 0
     for (name, got), (_, exp) in zip(arm.leaves(), ref.leaves()):
@@ -704,8 +704,8 @@ def _dispatch_bytes(step_fn, cfg) -> int:
 def phase_four_chips(n_groups: int, window: int, req_lanes: int,
                      n_replicas: int, n_steps: int, seed: int,
                      n_devices: int = 4) -> dict:
-    """``make_step(cfg, make_group_mesh(n), 1)`` against
-    ``make_step(cfg, None, 1)`` on the first of the devices, same seeded
+    """``make_step(cfg, make_group_mesh(n))`` against
+    ``make_step(cfg)`` on the first of the devices, same seeded
     trace, every output of every step and every final state leaf
     compared on the device, bit for bit.  ``n_groups`` is halved until
     the unsharded arm fits the device by the compiler's account."""
@@ -732,7 +732,7 @@ def phase_four_chips(n_groups: int, window: int, req_lanes: int,
     requested = n_groups
     while True:
         cfg = EngineConfig(n_groups, window, req_lanes, n_replicas)
-        single = make_step(cfg, None, 1)
+        single = make_step(cfg)
         need = _dispatch_bytes(single, cfg)
         # the comparison also keeps a copy of the sharded arm's state
         # and outputs on this device: count the dispatch twice
@@ -740,7 +740,7 @@ def phase_four_chips(n_groups: int, window: int, req_lanes: int,
             break
         n_groups //= 2
     mesh = make_group_mesh(n_devices, devices=devs)
-    sharded = make_step(cfg, mesh, 1)
+    sharded = make_step(cfg, mesh)
 
     R, G, K = cfg.n_replicas, cfg.n_groups, cfg.req_lanes
     one = jax.sharding.SingleDeviceSharding(devs[0])

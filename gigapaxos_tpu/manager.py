@@ -34,7 +34,6 @@ from __future__ import annotations
 
 import bisect
 import contextlib
-import functools
 import itertools
 import json
 import threading
@@ -118,7 +117,7 @@ _publish_vec_jit = jax.jit(lambda state: pack_blob(make_blob(state)))
 
 def _committed_rows(digest: StepDigest) -> List[Tuple[int, int, int]]:
     """(index into the digest's planes, row, slots committed) of the rows
-    that executed something in this substep, rows ascending."""
+    that executed something in this step, rows ascending."""
     n_committed = digest.n_committed[digest.rows]
     return [
         (int(k), int(digest.rows[k]), int(n_committed[k]))
@@ -126,22 +125,13 @@ def _committed_rows(digest: StepDigest) -> List[Tuple[int, int, int]]:
     ]
 
 
-def _accepted_lanes(digests: List[StepDigest]):
-    """The lanes some substep of the dispatch newly accepted, each once,
-    rows then lanes ascending, with their slot, ballot and value in the
-    dispatch-final state: (rows, acc_slot, acc_bal, acc_vid), all [n].
-    Every digest carries the FINAL state's accept columns, so a row that
-    several substeps touched reads the same from each."""
-    rows = np.unique(np.concatenate([d.rows for d in digests]))
-    W = digests[0].acc_new.shape[1]
-    acc_any = np.zeros((len(rows), W), bool)
-    cols = np.empty((3, len(rows), W), np.int32)
-    for d in digests:
-        at = np.searchsorted(rows, d.rows)
-        acc_any[at] |= d.acc_new != 0
-        cols[:, at] = (d.acc_slot, d.acc_bal, d.acc_vid)
-    ks, lanes = np.nonzero(acc_any)
-    return (rows[ks].astype(np.int32),) + tuple(cols[:, ks, lanes])
+def _accepted_lanes(digest: StepDigest):
+    """The lanes the step newly accepted, rows then lanes ascending, with
+    their slot, ballot and value in the new state: (rows, acc_slot,
+    acc_bal, acc_vid), all [n]."""
+    ks, lanes = np.nonzero(digest.acc_new)
+    return (digest.rows[ks].astype(np.int32), digest.acc_slot[ks, lanes],
+            digest.acc_bal[ks, lanes], digest.acc_vid[ks, lanes])
 
 
 def _padded_chunks(n: int, size: int):
@@ -470,23 +460,15 @@ class PaxosManager:
         # minimum queued requests before coalescing bothers minting a batch
         # (MIN_PP_BATCH_SIZE gate analog, PaxosConfig.java:852)
         self.min_batch_trigger = max(2, Config.get_int(PC.MIN_PP_BATCH_SIZE))
-        # multi-step device residency: N consensus rounds per host
-        # dispatch over device-resident request/response rings — one
-        # Python dispatch + sync + post-step cycle per N engine steps
-        self.steps_per_dispatch = max(
-            1, Config.get_int(PC.ENGINE_STEPS_PER_DISPATCH)
-        )
         # the ONE unified step (parallel/spmd.py:make_step), packed-host
-        # flavor; instances are memoized by (cfg, N, donate), so the jit
+        # flavor; instances are memoized by (cfg, donate), so the jit
         # cache is shared across managers with the same shape.  It
         # threads the [G] device-resident activity accumulator through
-        # every dispatch (decisions + admissions per group, folded
-        # across substeps inside the device loop); the host pulls it
-        # only at the stats cadence (pull_group_heat), never per tick
+        # every dispatch (decisions + admissions per group); the host
+        # pulls it only at the stats cadence (pull_group_heat), never
+        # per tick
         self._dispatch_step = make_step(
-            cfg, None, self.steps_per_dispatch, donate=True,
-            io="packed_host",
-        )
+            cfg, donate=True, io="packed_host")
         # retrace sentinel bookkeeping (obs/device.py): the sentinel is
         # SHARED across managers of the same shape, so per-node metrics
         # count deltas against the last totals this manager saw; it is
@@ -496,7 +478,7 @@ class PaxosManager:
         self._compile_seen = 0
         self._retrace_seen = 0
         # what the post-step reads of a dispatch is the step's digest
-        # (ops/engine.py:make_digest); a substep with more busy rows than
+        # (ops/engine.py:make_digest); a step with more busy rows than
         # the digest holds has its whole planes pulled instead
         self._digest_rows = digest_rows(cfg)
         # the gathered stack, and the update of a tick without a row
@@ -524,8 +506,8 @@ class PaxosManager:
         # changes).  Each upload spared is a call that gives up the
         # interpreter lock and queues for it again
         self._my_id_dev = jnp.int32(my_id)
-        self._null_ring = jnp.asarray(np.full(
-            (self.steps_per_dispatch, G, cfg.req_lanes), NULL, np.int32))
+        self._null_ring = jnp.asarray(
+            np.full((G, cfg.req_lanes), NULL, np.int32))
         self._heard_dev = (None, None)
         self._want_dev = (None, None)
         # the work-in-flight flag of the last completed step's new state
@@ -554,7 +536,7 @@ class PaxosManager:
         self.row_name: Dict[int, str] = {}     # occupancy: row -> name (or name@vE)
         # rows created by a start-epoch whose COMPLETE hasn't been confirmed
         # yet: proposals are accepted and QUEUED but never admitted to
-        # consensus (build_requests skips pending rows), so nothing can
+        # consensus (build_request_ring skips pending rows), so nothing can
         # commit on a row the reconfigurator's probe may still move — the
         # recreate in _create_locked is only safe because of this gate, and
         # the held queue follows the name to the new row
@@ -1427,9 +1409,7 @@ class PaxosManager:
         zj = np.zeros(self.JUMP_CHUNK, np.int32)
         jump_rows(scratch, zj, zj, zj, zj, zj, zj)
         _publish_vec_jit(scratch)
-        req = np.full(
-            (self.steps_per_dispatch, G, cfg.req_lanes), NULL, np.int32
-        )
+        req = np.full((G, cfg.req_lanes), NULL, np.int32)
         stack = set_peer_rows(
             init_stack(cfg),
             jnp.asarray(np.zeros(blob_vec_len(cfg), np.int32)),
@@ -1443,9 +1423,6 @@ class PaxosManager:
             jnp.zeros((blob_vec_len(cfg),), jnp.int32),
         )
         jax.block_until_ready(out)
-        # a substep whose busy rows overflow the digest has its whole
-        # planes pulled through this slice: its program too
-        np.asarray(out[2][0])
         return time.monotonic() - t0
 
     def mesh_info(self) -> Dict[str, Any]:
@@ -3520,8 +3497,6 @@ class PaxosManager:
             # epoch guard turns away does not touch its neighbours
             for row in body["rows"]:
                 self._on_forward_batch_locked(row)
-        elif kind == "forward_batch":  # one entry of such a frame, alone
-            self._on_forward_batch_locked(body)
         elif kind == "state_request":  # checkpoint-transfer pull
             self._serve_state_request(body)
         elif kind == "state_reply":
@@ -3703,19 +3678,14 @@ class PaxosManager:
         flush()
         return out
 
-    def build_requests(self) -> np.ndarray:
-        """Single-step [G, K] lanes (the n_steps=1 face of the ring)."""
-        return self.build_request_ring(1)[0]
-
-    def build_request_ring(self, n_steps: int) -> np.ndarray:
-        """Drain queues into the [n_steps, G, K] device request ring —
-        slab i feeds dispatch substep i, so one host admission pass
-        covers N engine steps; forward non-coordinated groups' requests
-        to their believed coordinator.  Records the staged vid count for
-        the ``device_queue_depth`` gauge."""
+    def build_request_ring(self) -> np.ndarray:
+        """Drain queues into the [G, K] device request ring — a row's
+        first K vids, the rest keep their order for the next dispatch;
+        forward non-coordinated groups' requests to their believed
+        coordinator.  Records the staged vid count for the
+        ``device_queue_depth`` gauge."""
         G, K = self.cfg.n_groups, self.cfg.req_lanes
-        depth = K * n_steps
-        req = np.full((n_steps, G, K), NULL, np.int32)
+        req = np.full((G, K), NULL, np.int32)
         staged_on: Dict[int, int] = {}  # row -> vids staged on it
         first: List[int] = []  # requests in each vid staged the first time
         bal = self._np("bal")
@@ -3812,17 +3782,15 @@ class PaxosManager:
                 vids.clear()
                 continue
             if self.batching_enabled and len(vids) > max(
-                depth, self.min_batch_trigger - 1
+                K, self.min_batch_trigger - 1
             ):
                 name = self.row_name.get(row)
                 if name is not None:
                     vids = self.queues[row] = self._coalesce_row_queue(
                         row, name, int(self._np("version")[row]), vids
                     )
-            take = vids[:depth]
-            for off in range(0, len(take), K):
-                slab = take[off:off + K]
-                req[off // K, row, : len(slab)] = slab
+            take = vids[:K]
+            req[row, : len(take)] = take
             staged_on[row] = len(take)
             for vid in take:
                 st = stamps.get(vid)
@@ -3927,11 +3895,11 @@ class PaxosManager:
         news (whole vectors through the whole-row program, rows with the
         step) and fire the step without waiting for the device.
         Returns the pending handle of device values (``out_vec`` stays
-        on the device unless a substep's digest overflows, ``blob_vec``
+        on the device unless the step's digest overflows, ``blob_vec``
         unless its news does) with ``self.state`` already the in-flight
         result."""
         with self._span("step.ring_build", cpu=False):
-            req = self.build_request_ring(self.steps_per_dispatch)
+            req = self.build_request_ring()
             old_state = self.state
             carried = self._carried_leaves(old_state)
         with self._span("step.dispatch"):
@@ -4041,7 +4009,7 @@ class PaxosManager:
         return carry
 
     def _device_wait(self, pend: Dict):
-        """The step's digests and the news of its blob on the host: two
+        """The step's digest and the news of its blob on the host: two
         transfers asked for together, the first forces the sync; the
         whole blob only where its news does not fit (or the mirror has
         none to patch).  Its annotation wraps JAX's own host events, so
@@ -4063,7 +4031,7 @@ class PaxosManager:
 
     def _complete_locked(self, pend: Dict, digest_np, news_np,
                          whole) -> Dict:
-        """Lock held, digests and the blob's news on the host: close the
+        """Lock held, digest and the blob's news on the host: close the
         ``engine_step_s`` envelope, run the post-step host cycle, and
         only then let the mirror show what the step made (the journal is
         written before a peer can be sent the rows it covers)."""
@@ -4082,22 +4050,19 @@ class PaxosManager:
             fresh = self._fresh_bal_exec(rows, body, whole)
             if pend["state"] is self.state:
                 self._np_cache_locked().update(fresh)
-            digests = []
-            for i, row in enumerate(digest_np):
-                digest, n_busy, (decisions, accepts) = split_digest_vec(
-                    row, self.cfg)
-                # how many accepts a decision waited for (METRICS.md)
-                mx.count("decisions_detected", decisions)
-                mx.count("accepts_at_detection", accepts)
-                mx.count("step_digest_dispatches")
-                mx.observe("step_digest_rows", n_busy, bounds=ROW_BOUNDS)
-                if n_busy > self._digest_rows:
-                    mx.count("step_digest_overflows")
-                    digest = self._whole_planes_locked(
-                        pend["out_vec"][i], digest.live)
-                digests.append(digest)
-            self._work_in_flight = digests[-1].live
-            host_delta = self._post_step_locked(digests)
+            digest, n_busy, (decisions, accepts) = split_digest_vec(
+                digest_np, self.cfg)
+            # how many accepts a decision waited for (METRICS.md)
+            mx.count("decisions_detected", decisions)
+            mx.count("accepts_at_detection", accepts)
+            mx.count("step_digest_dispatches")
+            mx.observe("step_digest_rows", n_busy, bounds=ROW_BOUNDS)
+            if n_busy > self._digest_rows:
+                mx.count("step_digest_overflows")
+                digest = self._whole_planes_locked(
+                    pend["out_vec"], digest.live)
+            self._work_in_flight = digest.live
+            host_delta = self._post_step_locked(digest)
             if whole is not None:
                 self.mirror.replace(whole)
             else:
@@ -4219,12 +4184,12 @@ class PaxosManager:
                 self._bal_exec[name][rows] = getattr(news, name)[:rows.size]
         return self._bal_exec
 
-    def _whole_planes_locked(self, out_vec_row, live: bool) -> StepDigest:
-        """A substep whose busy rows overflowed the device's digest: its
+    def _whole_planes_locked(self, out_vec, live: bool) -> StepDigest:
+        """A step whose busy rows overflowed the device's digest: its
         whole output planes and the new state's accept columns, pulled
         and reduced on the host to the same form."""
         return digest_from_planes(
-            split_out_vec(out_vec_row, self.cfg), self._np("acc_slot"),
+            split_out_vec(out_vec, self.cfg), self._np("acc_slot"),
             self._np("acc_bal"), self._np("acc_vid"), live,
         )
 
@@ -4312,20 +4277,12 @@ class PaxosManager:
         self._fire(fired)
         return self.mirror.tick, pend["state"], host_delta
 
-    def _post_step_locked(self, outs) -> Dict:
+    def _post_step_locked(self, out: StepDigest) -> Dict:
         """Shared post-engine host work (requeue, watermarks, journaling,
         execution, state pulls, gossip delta) of a completed dispatch.
 
-        ``outs`` is the dispatch's LIST of per-substep StepDigests: the
-        [G] output leaves whole, the [G, W] planes as their busy rows
-        only, in row order — one
-        host cycle per dispatch covers all N device-resident substeps:
-        per-substep work (decision logging, execution, preempt requeue)
-        runs in substep order; per-dispatch work (ballot flips,
-        watermarks, checkpoint cadence, gossip delta) runs once against
-        the final state."""
-        last = outs[-1]
-        n_sub = len(outs)
+        ``out`` is the step's StepDigest: the [G] output leaves whole,
+        the [G, W] planes as their busy rows only, in row order."""
         self._tick_no += 1
         # every pass below runs over the rows that hold a name, a block
         # at a time, not over [G]: a row that admits, commits or raises
@@ -4339,29 +4296,25 @@ class PaxosManager:
         n_admit = n_dec = 0
         risen = [np.zeros(0, np.int64)]
         for rows, _members in blocks:
-            n_admit += sum(int(o.n_admitted[rows].sum()) for o in outs)
-            n_dec += sum(int(o.n_committed[rows].sum()) for o in outs)
-            risen.append(rows[np.flatnonzero(functools.reduce(
-                np.bitwise_or, [o.bal_new[rows] for o in outs]))])
-        # the rows whose promised ballot some substep raised: the flips
+            n_admit += int(out.n_admitted[rows].sum())
+            n_dec += int(out.n_committed[rows].sum())
+            risen.append(rows[np.flatnonzero(out.bal_new[rows])])
+        # the rows whose promised ballot the step raised: the flips
         # below, and the journal's promises further down
         pg_m = np.concatenate(risen)
         if len(pg_m) and self._election_rows is not None:
             self._election_rows.update(pg_m.tolist())
-        if n_admit or n_dec or len(pg_m) \
-                or any(o.acc_new.any() for o in outs):
+        if n_admit or n_dec or len(pg_m) or out.acc_new.any():
             self.last_progress_tick = self._tick_no
         # re-propose preempted requests at a fresh slot (PREEMPTED
-        # analog), in substep order; appended AFTER the ring requeue
-        # below so a vid preempted at substep i cannot collide with the
-        # slab bookkeeping of substeps > i
+        # analog); appended AFTER the ring requeue below, behind what
+        # the ring turned back
         preempt_requeue = []
-        for o in outs:
-            pre_k, pre_l = np.nonzero(o.preempted_vid != NULL)
-            for k_, l_ in zip(pre_k, pre_l):
-                vid = int(o.preempted_vid[k_, l_])
-                if vid in self.arena and vid not in self.retained:
-                    preempt_requeue.append((int(o.rows[k_]), vid))
+        pre_k, pre_l = np.nonzero(out.preempted_vid != NULL)
+        for k_, l_ in zip(pre_k, pre_l):
+            vid = int(out.preempted_vid[k_, l_])
+            if vid in self.arena and vid not in self.retained:
+                preempt_requeue.append((int(out.rows[k_]), vid))
         # per-step engine metrics: aggregate counters reduced from the
         # vectorized step outputs — a few numpy sums over the member
         # rows per DISPATCH, never per-request host work
@@ -4403,10 +4356,8 @@ class PaxosManager:
         mx.gauge("inflight_requests", len(self.inflight))
         mx.gauge("arena_payloads", len(self.arena))
         mx.observe("engine_step_s", self.last_engine_step_s)
-        # residency plane: steps amortized per host dispatch and the
-        # staged device-ring depth
+        # residency plane: the staged device-ring depth
         mx.count("host_dispatches")
-        mx.gauge("dispatch_steps_per_host", n_sub)
         mx.gauge("device_queue_depth", self._last_ring_depth)
         # retrace sentinel: fold the shared sentinel's totals into this
         # node's counters as deltas (attribute reads only — no device
@@ -4454,7 +4405,7 @@ class PaxosManager:
             for r in range(self.cfg.n_replicas)
         ]
         for rows, members in blocks:
-            horizon = last.maj_exec[rows].astype(np.int64) \
+            horizon = out.maj_exec[rows].astype(np.int64) \
                 - self.jump_horizon
             lowest = np.full(rows.size, np.iinfo(np.int64).max)
             ok = np.zeros(rows.size, bool)
@@ -4465,11 +4416,9 @@ class PaxosManager:
                     eligible, np.minimum(lowest, cursor), lowest)
                 ok |= eligible
             self._min_exec[rows[ok]] = lowest[ok]
-        # requeue what wasn't admitted: the ring staged queue slab i into
-        # substep i's lanes, and the engine admits a contiguous prefix
-        # per slab — admitted = union of slab prefixes, leftovers keep
-        # their order ahead of the unstaged tail
-        K = self.cfg.req_lanes
+        # requeue what wasn't admitted: the ring staged a row's first K
+        # vids and the engine admits a contiguous prefix of them — the
+        # leftovers keep their order ahead of the unstaged tail
         payload_delta: Dict[int, str] = {}
         meta_delta: Dict[int, Tuple[int, int]] = {}
         staged_on = self._last_ring_rows
@@ -4477,18 +4426,11 @@ class PaxosManager:
         for row, vids in list(self.queues.items()):
             if not vids:
                 continue
-            admitted: List[int] = []
-            rest: List[int] = []
-            for i, o in enumerate(outs):
-                slab = vids[i * K:(i + 1) * K]
-                na = int(o.n_admitted[row])
-                admitted += slab[:na]
-                rest += slab[na:]
-            # (what was proposed since the dispatch is in `rest` too)
-            turned_back += len(admitted) < staged_on.get(row, 0)
-            rest += vids[n_sub * K:]
-            self.queues[row] = rest
-            for vid in admitted:
+            na = int(out.n_admitted[row])
+            # (what was proposed since the dispatch stays queued too)
+            turned_back += na < staged_on.get(row, 0)
+            self.queues[row] = vids[na:]
+            for vid in vids[:na]:
                 payload_delta[vid] = self.arena.get(vid, "")
                 if vid in self.vid_meta:
                     meta_delta[vid] = self.vid_meta[vid]
@@ -4500,8 +4442,8 @@ class PaxosManager:
         # log-before-send: persist the promise + accept delta before the
         # blob leaves (bare promises too — a ballot that rose with no
         # accept must survive a crash, ADVICE r1 high / handlePrepare's
-        # LogMessagingTask rule).  The whole tick's blocks (every
-        # substep's decision log included) leave as ONE group commit
+        # LogMessagingTask rule).  The whole tick's blocks (the
+        # decision log included) leave as ONE group commit
         # (BatchedLogger analog) — written before anything executes and
         # before this function returns, so log-before-send still holds
         # for the published blob, and the `journal` span holds the write.
@@ -4511,22 +4453,14 @@ class PaxosManager:
                     bal_np = self._np("bal")
                     self.logger.log_promises(
                         pg_m.astype(np.int32), bal_np[pg_m])
-                # accept lanes changed by ANY substep, valued from the
-                # dispatch-final state: a lane overwritten by a LATER
-                # substep's accept implies its earlier slot was decided
-                # within this dispatch, and that decision is journaled
-                # per substep by _execute below — so the final lane view
-                # plus the per-substep decision log loses nothing
-                gs, acc_slot, acc_bal, acc_vid = _accepted_lanes(outs)
+                gs, acc_slot, acc_bal, acc_vid = _accepted_lanes(out)
                 if len(gs):
                     self.logger.log_accepts(gs, acc_slot, acc_bal, acc_vid)
                 if payload_delta:
                     self.logger.log_payloads(payload_delta, meta=meta_delta)
-                for o in outs:
-                    self._log_decisions(o)
-        for o in outs:
-            self._execute(o)
-        self._maybe_request_state(last)
+                self._log_decisions(out)
+        self._execute(out)
+        self._maybe_request_state(out)
         self.outstanding.gc()
         if self._tick_no % 64 == 0 and self.inflight:
             # entries whose vid left vid_meta (forwarded to a coordinator /
@@ -4542,7 +4476,7 @@ class PaxosManager:
                 waiting = self.outstanding._map
                 self._forwarded = {r: v for r, v in self._forwarded.items()
                                    if r in waiting}
-        self._maybe_checkpoint(last)
+        self._maybe_checkpoint(out)
         self._observe_legs_locked()
 
         # periodic full-baseline refresh: a dropped gossip frame must not
@@ -4573,7 +4507,7 @@ class PaxosManager:
     # execution (EEC analog, PaxosInstanceStateMachine.java:1511-1734)
     # ------------------------------------------------------------------
     def _log_decisions(self, out_np: StepDigest) -> None:
-        """One substep's decisions into the open journal batch."""
+        """The step's decisions into the open journal batch."""
         rows, slots, vids = [], [], []
         for k, g, n in _committed_rows(out_np):
             base = int(out_np.exec_base[g])
@@ -4590,7 +4524,7 @@ class PaxosManager:
 
     def _execute(self, out_np: StepDigest) -> None:
         committed = _committed_rows(out_np)
-        seen = self._tick_no  # in which this substep's slots show decided
+        seen = self._tick_no  # in which this step's slots show decided
         if committed:
             rows = [g for _k, g, _n in committed]
             now = time.time()

@@ -1014,11 +1014,6 @@ def gathered_matrix(state: EngineState, stack: Blob, upd: jnp.ndarray,
     )
 
 
-def unpack_out(vec: jnp.ndarray, cfg: EngineConfig) -> StepOutputs:
-    """[M] packed step outputs -> StepOutputs (inside jit)."""
-    return _unpack(vec, StepOutputs._fields, cfg, StepOutputs, batched=False)
-
-
 def split_out_vec(vec: np.ndarray, cfg: EngineConfig) -> StepOutputs:
     """Host-side: one transferred [M] vector -> StepOutputs of np views."""
     return _unpack(
@@ -1039,7 +1034,7 @@ def split_blob_vec(vec: np.ndarray, cfg: EngineConfig) -> Blob:
 # [G, W] planes only the BUSY rows come — those with a commit, a newly
 # accepted lane or a preempted proposal — each with its lanes of the NEW
 # state's accept columns (the journal's log-before-send rows), plus one
-# flag: does any row still hold consensus work, and the substep's two
+# flag: does any row still hold consensus work, and the step's two
 # quorum sums (``step_counted``).  A dispatch with more busy rows than the
 # digest holds reports its count, and the host pulls the whole planes for
 # that one (``digest_from_planes``).
@@ -1050,7 +1045,7 @@ def split_blob_vec(vec: np.ndarray, cfg: EngineConfig) -> Blob:
 # ---------------------------------------------------------------------------
 
 class StepDigest(NamedTuple):
-    """One substep's results as the host reads them: the [G] leaves of
+    """One step's results as the host reads them: the [G] leaves of
     :class:`StepOutputs` whole, ``rows`` the busy rows in ascending order,
     and every plane [len(rows), W] — row ``k`` of a plane is row
     ``rows[k]`` of the [G, W] plane it was gathered from."""
@@ -1114,7 +1109,7 @@ _DIGEST_CHUNK = 256  # rows gathered per pass of make_digest's loop
 
 def make_digest(out: StepOutputs, state: EngineState, cfg: EngineConfig,
                 quorum_sums: jnp.ndarray) -> jnp.ndarray:
-    """Inside jit: the digest vector of one substep's ``out`` and
+    """Inside jit: the digest vector of one step's ``out`` and
     ``quorum_sums`` (``step_counted``'s third result) against the
     dispatch's NEW ``state``.  The device's work follows the busy
     rows: one sort of [G] keys names them, and their lanes are gathered
@@ -1154,7 +1149,7 @@ def make_digest(out: StepOutputs, state: EngineState, cfg: EngineConfig,
 
 def split_digest_vec(vec: np.ndarray, cfg: EngineConfig):
     """Host-side: one transferred digest vector -> (:class:`StepDigest`
-    of np views, n_busy, the substep's quorum sums as two ints: decisions
+    of np views, n_busy, the step's quorum sums as two ints: decisions
     first detected, accepts counted at their detection).  Where ``n_busy``
     exceeds the digest's rows the planes hold only the first of them: the
     caller pulls the whole planes instead (``digest_from_planes``)."""

@@ -13,23 +13,18 @@ ONE factory — :func:`make_step` — builds every execution shape of the pure
   under different ``NamedSharding``/``PartitionSpec`` constraints, so the
   engine's all-int32 arithmetic is bit-identical across partitionings.
 
-* ``steps_per_dispatch`` (N >= 1) runs N consensus rounds **per host
-  call** over device-resident request/response rings: admission gating,
-  dedup lookup, and response selection all happen inside a
-  ``lax.fori_loop``, and the host touches one packed request ring
-  ``[N, ...]`` going in and one response ring coming out — one Python
-  dispatch, one sync, per N engine steps.  N == 1 compiles the exact
-  legacy single-step program (no loop machinery), so the default path is
-  bit-for-bit the pre-factory step.
+* a host call is ONE consensus round: within a call the peers' rows are
+  what the host last gathered, so a second round in the same call would
+  hear no new accept and no new decision from anybody else (a commit is
+  counted in exchanges, not in steps).
 
 Two I/O flavors:
 
-* ``io="stacked"`` — the SPMD/bench face: states are the stacked
-  ``[R, G, ...]`` global layout, requests ``[R, G, K]`` (or
-  ``[N, R, G, K]`` for N > 1), outputs :class:`StepOutputs` of
-  ``[R, ...]`` (or ``[N, R, ...]``) leaves.  Every replica advances each
-  substep and the blob exchange is re-read from the advancing states, so
-  N stacked substeps are exactly N sequential stacked calls.
+* ``io="stacked"`` — the SPMD/bench face and the tests' reference:
+  states are the stacked ``[R, G, ...]`` global layout, requests
+  ``[R, G, K]``, outputs :class:`StepOutputs` of ``[R, ...]`` leaves.
+  Every replica advances and the blob exchange is read from the states
+  the call was given.
 
 * ``io="packed_host"`` — the deployed-runtime face: one replica's state,
   the gathered STACK (every peer's blob as a device-resident ``Blob`` of
@@ -37,26 +32,24 @@ Two I/O flavors:
   donated and handed back), the tick's news as one
   fixed-shape update (``ops/engine.py:scatter_update``: the rows the
   peers' ``d`` frames named, scattered into the stack before it is
-  read), a donated ``[G]`` int32 activity accumulator, and as the
-  trailing argument the PUBLISHED vector: the packed blob the host last
-  received from this node's step, donated too.  Returns ``(state',
-  stack', out_rings [N, M], blob_vec, heat', digests [N, L], news)`` —
-  ``heat'`` is the accumulator plus
-  ``n_committed + n_admitted`` of every substep (the host pulls it at
-  the stats cadence, never per tick), ``digests`` per substep what the
+  read), the ``[G, K]`` request ring, a donated ``[G]`` int32 activity
+  accumulator, and as the trailing argument the PUBLISHED vector: the
+  packed blob the host last received from this node's step, donated
+  too.  Returns ``(state', stack', out_vec [M], blob_vec, heat',
+  digest [L], news)`` — ``heat'`` is the accumulator plus
+  ``n_committed + n_admitted`` of the step (the host pulls it at
+  the stats cadence, never per tick), ``digest`` what the
   host's post-step reads (``ops/engine.py:make_digest``: the [G] output
   leaves, the busy rows of the [G, W] planes with their accept lanes of
-  the new state, a work-in-flight flag), so that ``out_rings`` can stay
+  the new state, a work-in-flight flag), so that ``out_vec`` can stay
   on the device; ``news`` the rows in which ``blob_vec`` differs from
   the published vector (``ops/engine.py:make_news``), so that
   ``blob_vec`` stays there too: it is the next dispatch's published
   vector, and the host pulls it only when more rows changed than the
   news holds.
-  Row ``my_id`` of the stack is never sent up: every substep takes it
-  from the state it steps (at entry that is the row a host would have
-  gathered, after a lifecycle operation included); peers' rows stay
-  frozen for the dispatch — the semantics of N serial host ticks during
-  which no new peer frame lands.
+  Row ``my_id`` of the stack is never sent up: the step takes it
+  from the state it steps (that is the row a host would have
+  gathered, after a lifecycle operation included).
 
 Global array convention for SPMD: every state leaf gets a leading replica
 axis -> ``[R, G, ...]``; a ``(g, r)`` mesh constrains ``P('r', 'g')``, a
@@ -75,20 +68,16 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.engine import (
-    _G_LEAVES,
     EngineConfig,
     EngineState,
-    StepOutputs,
     make_blob,
     make_digest,
     make_news,
-    out_vec_len,
     pack_blob,
     scatter_update,
     stack_blob,
     step,
     step_counted,
-    unpack_out,
     with_my_row,
 )
 from .mesh import GROUP_AXIS, REPLICA_AXIS
@@ -151,8 +140,7 @@ def _constrain(mesh: Optional[Mesh], tree, *lead):
 # ---------------------------------------------------------------------------
 
 
-def _build_stacked(cfg: EngineConfig, mesh: Optional[Mesh], n_steps: int,
-                   donate: bool):
+def _build_stacked(cfg: EngineConfig, mesh: Optional[Mesh], donate: bool):
     R = cfg.n_replicas
 
     def _exchange_step(states, req_vid, want_coord, h):
@@ -183,141 +171,44 @@ def _build_stacked(cfg: EngineConfig, mesh: Optional[Mesh], n_steps: int,
             jnp.asarray(heard, bool) | jnp.eye(R, dtype=bool)
         )
 
-    if n_steps == 1:
-        @partial(jax.jit, donate_argnums=(0,) if donate else ())
-        def run(states, req_vid, want_coord, heard=None):
-            h = _heard(heard)
-            states = _constrain(mesh, states, REPLICA_AXIS, GROUP_AXIS)
-            new_states, outs = _exchange_step(
-                states, req_vid, want_coord, h
-            )
-            return (
-                _constrain(mesh, new_states, REPLICA_AXIS, GROUP_AXIS),
-                _constrain(mesh, outs, REPLICA_AXIS, GROUP_AXIS),
-            )
-
-        return run
-
     @partial(jax.jit, donate_argnums=(0,) if donate else ())
-    def run_n(states, req_ring, want_coord, heard=None):
-        # req_ring [N, R, G, K]: slab i feeds substep i.  want_coord
-        # fires only at substep 0 (an election pulse is a host decision;
-        # replaying it every substep would re-bump ballots N times).
-        # heard is frozen for the dispatch — the host's delivery view
-        # cannot change mid-dispatch by construction.
+    def run(states, req_vid, want_coord, heard=None):
         h = _heard(heard)
         states = _constrain(mesh, states, REPLICA_AXIS, GROUP_AXIS)
-        G = int(states.bal.shape[1])
-        W = cfg.window
-        outs0 = StepOutputs(*[
-            jnp.zeros(
-                (n_steps, R) + ((G,) if f in _G_LEAVES else (G, W)),
-                jnp.int32,
-            )
-            for f in StepOutputs._fields
-        ])
-
-        def body(i, carry):
-            st, outs = carry
-            req_i = lax.dynamic_index_in_dim(
-                req_ring, i, axis=0, keepdims=False
-            )
-            want_i = want_coord & (i == 0)
-            st, out = _exchange_step(st, req_i, want_i, h)
-            outs = jax.tree.map(
-                lambda acc, o: lax.dynamic_update_index_in_dim(
-                    acc, o, i, axis=0
-                ),
-                outs, out,
-            )
-            return st, outs
-
-        new_states, outs = lax.fori_loop(0, n_steps, body, (states, outs0))
+        new_states, outs = _exchange_step(
+            states, req_vid, want_coord, h
+        )
         return (
             _constrain(mesh, new_states, REPLICA_AXIS, GROUP_AXIS),
-            _constrain(mesh, outs, None, REPLICA_AXIS, GROUP_AXIS),
+            _constrain(mesh, outs, REPLICA_AXIS, GROUP_AXIS),
         )
 
-    return run_n
+    return run
 
 
-def _build_packed(cfg: EngineConfig, mesh: Optional[Mesh], n_steps: int,
-                  donate: bool):
-    M = out_vec_len(cfg)
-
-    def _pack_out(out):
-        return jnp.concatenate([jnp.ravel(leaf) for leaf in out])
-
-    if n_steps == 1:
-        # one scatter of the tick's news, one step, three downloads
-        def run_heat(state, stack, upd, heard, req_ring, want_coord, my_id,
-                     heat_acc, published):
-            state = _constrain(mesh, state, GROUP_AXIS)
-            stack = with_my_row(
-                scatter_update(stack, upd, cfg), state, my_id)
-            new_state, out, quorum_sums = step_counted(
-                state, stack_blob(stack), heard, req_ring[0], want_coord,
-                my_id, cfg=cfg,
-            )
-            heat_acc = _constrain(
-                mesh, heat_acc + out.n_committed + out.n_admitted,
-                GROUP_AXIS,
-            )
-            out_rings = _pack_out(out)[None]
-            blob = make_blob(new_state)
-            return (
-                _constrain(mesh, new_state, GROUP_AXIS), stack,
-                out_rings, pack_blob(blob), heat_acc,
-                make_digest(out, new_state, cfg, quorum_sums)[None],
-                make_news(blob, published, cfg),
-            )
-    else:
-        def run_heat(state, stack, upd, heard, req_ring, want_coord, my_id,
-                     heat_acc, published):
-            state = _constrain(mesh, state, GROUP_AXIS)
-            heat_acc = _constrain(mesh, heat_acc, GROUP_AXIS)
-            stack = scatter_update(stack, upd, cfg)
-            out0 = jnp.zeros((n_steps, M), jnp.int32)
-            sums0 = jnp.zeros((n_steps, 2), jnp.int32)
-
-            def body(i, carry):
-                st, g, outs, sums, ht = carry
-                # every substep takes MY row from the advancing state;
-                # peers' rows stay frozen for the whole dispatch —
-                # exactly N serial ticks during which no peer frame lands
-                g = with_my_row(g, st, my_id)
-                req_i = lax.dynamic_index_in_dim(
-                    req_ring, i, axis=0, keepdims=False
-                )
-                want_i = want_coord & (i == 0)
-                st, out, quorum_sums = step_counted(
-                    st, stack_blob(g), heard, req_i, want_i, my_id, cfg=cfg)
-                outs = lax.dynamic_update_index_in_dim(
-                    outs, _pack_out(out), i, axis=0
-                )
-                sums = lax.dynamic_update_index_in_dim(
-                    sums, quorum_sums, i, axis=0
-                )
-                ht = ht + out.n_committed + out.n_admitted
-                return st, g, outs, sums, ht
-
-            new_state, stack, out_rings, sums, heat_acc = lax.fori_loop(
-                0, n_steps, body, (state, stack, out0, sums0, heat_acc)
-            )
-            blob = make_blob(new_state)
-            # one digest row per substep, each against the dispatch's
-            # final state: the journal values an accepted lane from it
-            digests = jax.vmap(
-                lambda row, quorum_sums: make_digest(
-                    unpack_out(row, cfg), new_state, cfg, quorum_sums)
-            )(out_rings, sums)
-            return (
-                _constrain(mesh, new_state, GROUP_AXIS), stack, out_rings,
-                pack_blob(blob), _constrain(mesh, heat_acc, GROUP_AXIS),
-                digests,
-                # the dispatch's news, once: the final state's blob
-                make_news(blob, published, cfg),
-            )
+def _build_packed(cfg: EngineConfig, mesh: Optional[Mesh], donate: bool):
+    # one scatter of the tick's news, one step, three downloads
+    def run_heat(state, stack, upd, heard, req_ring, want_coord, my_id,
+                 heat_acc, published):
+        state = _constrain(mesh, state, GROUP_AXIS)
+        stack = with_my_row(
+            scatter_update(stack, upd, cfg), state, my_id)
+        new_state, out, quorum_sums = step_counted(
+            state, stack_blob(stack), heard, req_ring, want_coord,
+            my_id, cfg=cfg,
+        )
+        heat_acc = _constrain(
+            mesh, heat_acc + out.n_committed + out.n_admitted,
+            GROUP_AXIS,
+        )
+        blob = make_blob(new_state)
+        return (
+            _constrain(mesh, new_state, GROUP_AXIS), stack,
+            jnp.concatenate([jnp.ravel(leaf) for leaf in out]),
+            pack_blob(blob), heat_acc,
+            make_digest(out, new_state, cfg, quorum_sums),
+            make_news(blob, published, cfg),
+        )
 
     # the gathered stack, the accumulator and the published vector ride
     # the dispatch like state leaves (donated alongside them); the
@@ -327,15 +218,13 @@ def _build_packed(cfg: EngineConfig, mesh: Optional[Mesh], n_steps: int,
 
 
 @functools.lru_cache(maxsize=None)
-def _make_step_cached(cfg, mesh, steps_per_dispatch, donate, io):
+def _make_step_cached(cfg, mesh, donate, io):
     from ..obs.device import StepSentinel
 
-    if steps_per_dispatch < 1:
-        raise ValueError("steps_per_dispatch must be >= 1")
     if io == "stacked":
-        fn = _build_stacked(cfg, mesh, steps_per_dispatch, donate)
+        fn = _build_stacked(cfg, mesh, donate)
     elif io == "packed_host":
-        fn = _build_packed(cfg, mesh, steps_per_dispatch, donate)
+        fn = _build_packed(cfg, mesh, donate)
     else:
         raise ValueError(f"unknown io flavor: {io!r}")
     # every factory instance leaves through the retrace/compile sentinel
@@ -346,17 +235,16 @@ def _make_step_cached(cfg, mesh, steps_per_dispatch, donate, io):
         f"{k}{v}" for k, v in mesh.shape.items()
     ) if mesh is not None else "none"
     label = (
-        f"make_step[{io} N={steps_per_dispatch} donate={donate} "
+        f"make_step[{io} donate={donate} "
         f"mesh={mesh_tag} G={cfg.n_groups} "
         f"R={cfg.n_replicas} W={cfg.window} K={cfg.req_lanes}]"
     )
     return StepSentinel(fn, label=label)
 
 
-def make_step(cfg: EngineConfig, mesh: Optional[Mesh] = None,
-              steps_per_dispatch: int = 1, *, donate: bool = True,
-              io: str = "stacked"):
-    """Build THE consensus step: mesh-parameterized, N-steps-resident.
+def make_step(cfg: EngineConfig, mesh: Optional[Mesh] = None, *,
+              donate: bool = True, io: str = "stacked"):
+    """Build THE consensus step: mesh-parameterized, one round a call.
 
     Parameters
     ----------
@@ -365,10 +253,6 @@ def make_step(cfg: EngineConfig, mesh: Optional[Mesh] = None,
         :class:`jax.sharding.Mesh` to pin the GSPMD partitioning (the
         program is the same; only the auto-partitioning changes, so
         results are bit-identical across meshes — all-int32 arithmetic).
-    steps_per_dispatch : N >= 1 consensus rounds per host call over
-        device-resident request/response rings (``ENGINE_STEPS_PER_
-        DISPATCH``).  N == 1 compiles the exact legacy single-step
-        program.
     donate : alias the caller's old state buffers into the new state
         (halves state HBM — the G=2M capacity lever); pass ``False``
         when input states must stay valid across calls.
@@ -376,15 +260,13 @@ def make_step(cfg: EngineConfig, mesh: Optional[Mesh] = None,
         (one replica + the device-resident gathered stack — the deployed
         runtime's face; see the module docstring for signatures).
 
-    Instances are memoized: the same (cfg, mesh, N, donate, io)
+    Instances are memoized: the same (cfg, mesh, donate, io)
     returns the same callable, so jit caches are shared across
     managers.  Every instance is wrapped in a
     :class:`gigapaxos_tpu.obs.device.StepSentinel`, so compiles and
     retraces are recorded process-wide.
     """
-    return _make_step_cached(
-        cfg, mesh, int(steps_per_dispatch), bool(donate), str(io),
-    )
+    return _make_step_cached(cfg, mesh, bool(donate), str(io))
 
 
 # ---------------------------------------------------------------------------

@@ -52,15 +52,6 @@ class PC(FlagEnum):
     # worker w of a node listens at node_port + this + w (mesh), with the
     # usual CLIENT_PORT_OFFSET split layered on top inside the worker
     SERVING_WORKER_PORT_OFFSET = 500
-    # multi-step device residency: consensus rounds the unified step
-    # (parallel/spmd.py:make_step) runs per host dispatch, over
-    # device-resident request/response rings.  1 (default) = one step per
-    # dispatch, the exact legacy program; N > 1 amortizes the Python
-    # dispatch + sync + post-step host cycle over N engine steps (higher
-    # throughput under sustained load, +N-1 steps of decide latency for
-    # a request arriving mid-dispatch).  The request ring holds
-    # K * N staged vids per group per dispatch
-    ENGINE_STEPS_PER_DISPATCH = 1
 
     # ---- durability (ref: PaxosConfig.java:240,314,334,410) -----------
     ENABLE_JOURNALING = True

@@ -325,7 +325,7 @@ class PaxosServer:
     # them (found by the first g1k-crash on the chip, PR 35: no election
     # in 14 s of darkness)
     MESH_KINDS = frozenset((
-        "payloads", "forward", "forward_batch", "forward_rows",
+        "payloads", "forward", "forward_rows",
         "need_payloads", "state_request", "state_reply", "fd_ping",
         "blob_resync",
     ))
@@ -471,7 +471,7 @@ class PaxosServer:
         """JSON-frame dispatch; subclasses extend (ReconfigurableNode roles
         layer epoch-plane kinds on the same demux — the reference's
         precedePacketDemultiplexer chaining).  Returns True if handled."""
-        if k in ("payloads", "forward", "forward_batch", "forward_rows",
+        if k in ("payloads", "forward", "forward_rows",
                  "need_payloads", "state_request", "state_reply"):
             self.manager.on_host_message(k, body)
         elif k == "chunk":

@@ -269,9 +269,8 @@ def settle_and_audit(c: ReconfigurableCluster, names, step,
 
     # record agreement across RCs — poll-bounded like the READY-align
     # and RSM checks below: settle gates on RC0's records only, and a
-    # sibling RC executing its paxos log in dispatch-sized bursts
-    # (ENGINE_STEPS_PER_DISPATCH > 1) can be one exchange behind at the
-    # instant settle flips.  A real fork never converges and still
+    # sibling RC can be one exchange behind at the instant settle
+    # flips.  A real fork never converges and still
     # lands here; a replica mid-catch-up is not end state.
     for nm in names:
         agree_deadline = time.time() + 30

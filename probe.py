@@ -81,12 +81,6 @@ def main() -> int:
     ap.add_argument("--max-rounds", type=int, default=12)
     ap.add_argument("--cpu", action="store_true",
                     help="pin the JAX backend to CPU")
-    ap.add_argument("--steps-per-dispatch", type=int, default=1,
-                    metavar="N",
-                    help="ENGINE_STEPS_PER_DISPATCH for the booted nodes "
-                         "(N>1 = multi-step device residency; run twice "
-                         "with --label steps_n1 / steps_n8 into "
-                         "--capacity-out for the residency ablation)")
     ap.add_argument("--unreplicated", action="store_true",
                     help="EMULATE_UNREPLICATED attribution mode "
                          "(PaxosManager.java:1731): answer at the entry "
@@ -157,9 +151,6 @@ def main() -> int:
     if args.unreplicated:
         Config.set("EMULATE_UNREPLICATED", "true")
         os.environ["GP_EMULATE_UNREPLICATED"] = "true"  # child processes
-    if args.steps_per_dispatch > 1:
-        Config.set("ENGINE_STEPS_PER_DISPATCH",
-                   str(args.steps_per_dispatch))
     node_names = [f"{r}{i}" for r in ("AR", "RC") for i in range(3)]
     nodes = []
     procs = []
@@ -212,10 +203,6 @@ def main() -> int:
             props.write(f"reconfigurator.RC{i}=127.0.0.1:{ports[3 + i]}\n")
         props.write(f"ENGINE_ROWS={max(64, args.groups * 2)}\n")
         props.write("SLOT_WINDOW=16\n")
-        if args.steps_per_dispatch > 1:
-            props.write(
-                f"ENGINE_STEPS_PER_DISPATCH={args.steps_per_dispatch}\n"
-            )
         # NOTE: child RCs use the node's default rc_cfg (64 rows, window
         # SLOT_WINDOW); the in-process mode mirrors that below so the two
         # modes differ only in process topology
@@ -496,12 +483,9 @@ def main() -> int:
         print(json.dumps(summary), flush=True)
         if args.capacity_out:
             label = args.label or (
-                f"steps_n{args.steps_per_dispatch}"
-                if args.steps_per_dispatch > 1
-                else "unreplicated" if args.unreplicated
+                "unreplicated" if args.unreplicated
                 else ("durable" if args.durable else "in_process")
             )
-            record_steps = args.steps_per_dispatch
             try:
                 with open(args.capacity_out) as f:
                     doc = json.load(f)
@@ -522,7 +506,6 @@ def main() -> int:
                 ),
             }
             doc[label] = {
-                "steps_per_dispatch": record_steps,
                 "capacity_rps": summary["value"],
                 "min_rps": summary["capacity_min_rps"],
                 "max_rps": summary["capacity_max_rps"],

@@ -91,7 +91,7 @@ HOT_NP_ALLOW = {
     ("manager.py", "_log_decisions"): frozenset(),
     ("manager.py", "engine_work_in_flight"): frozenset(),
     # the ONE place a [G, W] leaf crosses to the host on the tick path: a
-    # substep whose busy rows overflowed the step's digest
+    # step whose busy rows overflowed the step's digest
     ("manager.py", "_whole_planes_locked"): frozenset(
         {"acc_slot", "acc_bal", "acc_vid"}
     ),

@@ -10,15 +10,10 @@ Usage:
     python scripts/footprint_probe.py [--groups G] [--window W]
                                       [--req-lanes K] [--replicas R]
                                       [--sharded N]
-                                      [--steps-per-dispatch N]
 
-``--steps-per-dispatch N`` adds the device-resident I/O ring bytes of
-the unified step at ENGINE_STEPS_PER_DISPATCH=N (``parallel/spmd.py:
-make_step``): the request ring stages N x [R, G, K] vid slabs and the
-response ring holds N packed [R, out_vec_len] rows per dispatch.  Ring
-bytes scale with N but are additive I/O buffers — the per-group blob
-budget (the exchange plane) is independent of N, and the sharded-mode
-assert proves it stays at the compact budget.
+``device_queue`` is the device-resident I/O of a deployed node's step
+(``parallel/spmd.py:make_step``, packed-host flavor): the [G, K] request
+ring going up and the packed out_vec it leaves on the device.
 
 Defaults are the headline bench shape (G=1,048,576, W=32, K=16, R=3).
 
@@ -93,17 +88,16 @@ def probe(G: int, W: int, K: int, R: int) -> dict:
     }
 
 
-def device_queue(G: int, W: int, K: int, R: int, n_steps: int) -> dict:
-    """Device-resident I/O ring bytes for a deployed node at
-    ENGINE_STEPS_PER_DISPATCH=n_steps (the unified step's packed-host
-    flavor): N [G, K] request slabs in, N packed out_vec rows back."""
+def device_queue(G: int, W: int, K: int, R: int) -> dict:
+    """Device-resident I/O ring bytes for a deployed node (the unified
+    step's packed-host flavor): the [G, K] request ring in, the packed
+    out_vec back."""
     from gigapaxos_tpu.ops.engine import EngineConfig, out_vec_len
 
     cfg = EngineConfig(n_groups=G, window=W, req_lanes=K, n_replicas=R)
-    req_b = 4 * n_steps * G * K
-    out_b = 4 * n_steps * out_vec_len(cfg)
+    req_b = 4 * G * K
+    out_b = 4 * out_vec_len(cfg)
     return {
-        "steps_per_dispatch": n_steps,
         "request_ring_bytes": req_b,
         "response_ring_bytes": out_b,
         "total_ring_bytes": req_b + out_b,
@@ -206,10 +200,6 @@ def main() -> int:
     ap.add_argument("--sharded", "-N", type=int, default=0, metavar="N",
                     help="add group-sharded arithmetic for an N-device "
                          "mesh and assert the per-group blob budget")
-    ap.add_argument("--steps-per-dispatch", type=int, default=1,
-                    metavar="N",
-                    help="device-resident I/O ring bytes at "
-                         "ENGINE_STEPS_PER_DISPATCH=N")
     ap.add_argument("--paused", type=int, default=0, metavar="N",
                     help="add paused-tail arithmetic for N paused names "
                          "(packed spill store) and assert the "
@@ -220,9 +210,7 @@ def main() -> int:
     args = ap.parse_args()
     out = probe(args.groups, args.window, args.req_lanes, args.replicas)
     out["device_queue"] = device_queue(
-        args.groups, args.window, args.req_lanes, args.replicas,
-        max(1, args.steps_per_dispatch),
-    )
+        args.groups, args.window, args.req_lanes, args.replicas)
     if args.sharded > 0:
         out["sharded"] = probe_sharded(
             args.groups, args.window, args.req_lanes, args.replicas,

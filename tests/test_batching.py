@@ -174,7 +174,7 @@ def test_batch_survives_crash_recovery(tmp_path):
 
 def test_forward_batch_preserves_fifo_around_stop():
     """A non-coordinator entry forwards its whole queue run as ONE
-    forward_batch frame; requests queued BEFORE a stop must commit
+    entry of a forward_rows frame; requests queued BEFORE a stop must commit
     before it (proposing the stop first would bump the epoch and drop
     them as stale — review find on the batched forward path)."""
     cfg = small_cfg()

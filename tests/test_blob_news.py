@@ -31,7 +31,6 @@ from gigapaxos_tpu.ops.engine import (
     update_vec_len,
 )
 from gigapaxos_tpu.testing.cluster import DELIVER, DROP, ManagerCluster
-from gigapaxos_tpu.utils.config import Config
 
 CFG = EngineConfig(n_groups=64, window=8, req_lanes=4, n_replicas=3)
 NAMES = [f"bn{i}" for i in range(8)]
@@ -117,8 +116,8 @@ class _Follower:
 
 
 # ---- (a) parity over a run with lifecycle operations between steps ----
-@pytest.mark.parametrize("steps", [1, 4])
-def test_mirror_equals_the_steps_vector_after_every_step(steps):
+@pytest.mark.parametrize("seed", [20260930, 20260933])
+def test_mirror_equals_the_steps_vector_after_every_step(seed):
     """Admits, accepts, decisions, election pulses, dropped links — and
     between steps names created, killed, paused and restored, and a state
     replaced behind the manager's back: after EVERY step the mirror is
@@ -126,8 +125,7 @@ def test_mirror_equals_the_steps_vector_after_every_step(steps):
     the whole vectors finds."""
     import jax.numpy as jnp
 
-    Config.set("ENGINE_STEPS_PER_DISPATCH", str(steps))
-    rng = np.random.default_rng(20260929 + steps)
+    rng = np.random.default_rng(seed)
     c = ManagerCluster(CFG, HashChainApp)
     seen = {"steps": 0, "rows": 0, "max": 0}
     R, G = CFG.n_replicas, CFG.n_groups
@@ -277,7 +275,7 @@ def test_a_completion_that_ended_before_its_patch_is_healed_by_a_pull():
         m = c.managers[0]
         orig = m._post_step_locked
 
-        def boom(digests):
+        def boom(digest):
             m._post_step_locked = orig
             raise RuntimeError("the journal's disk is gone")
 

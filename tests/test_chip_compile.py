@@ -97,10 +97,10 @@ def test_packed_host_step_compiles_and_fits_one_chip(one_chip, G, W, K, R):
     )
     state = _state_shapes(cfg, one_chip)
     stack, stack_bytes = _stack_shapes(cfg, one_chip)
-    step = make_step(cfg, None, 1, donate=True, io="packed_host")
+    step = make_step(cfg, donate=True, io="packed_host")
     args = (
         sds((update_vec_len(cfg),), jnp.int32), sds((R,), jnp.bool_),
-        sds((1, G, K), jnp.int32), sds((G,), jnp.bool_),
+        sds((G, K), jnp.int32), sds((G,), jnp.bool_),
         sds((), jnp.int32), sds((G,), jnp.int32),
         sds((blob_vec_len(cfg),), jnp.int32),
     )
@@ -211,7 +211,7 @@ def test_group_sharded_has_no_collectives_on_four_chips(topo):
         ),
         jax.eval_shape(lambda: init_state(cfg)),
     )
-    compiled = make_step(cfg, mesh, 1).lower(
+    compiled = make_step(cfg, mesh).lower(
         state,
         jax.ShapeDtypeStruct((R, G, K), jnp.int32, sharding=by_rank[3]),
         jax.ShapeDtypeStruct((R, G), jnp.bool_, sharding=by_rank[2]),

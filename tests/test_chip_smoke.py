@@ -67,7 +67,7 @@ def test_engine_parity_trace_pauses_rows_and_resumes_them_in_one_batch(smoke):
     # carry on from their frontier
     cfg = EngineConfig(256, 16, 8, 3)
     arm = smoke._ReplicaArm(cfg, cpu[0], make_step(
-        cfg, None, 1, donate=False, io="packed_host"))
+        cfg, donate=False, io="packed_host"))
     rows = np.array([5, 42], np.int32)
     trace = list(smoke.make_trace(cfg, 12, 3))
     for req, want, heard in trace[:6]:

@@ -7,7 +7,7 @@ Covers the three tentpole instruments end to end:
   deployed hot dispatch compiles exactly once across a multi-tick
   loopback run;
 * group-heat telemetry — the on-device ``[G]`` accumulator bit-matches
-  a longhand host recount of every substep's decided+admitted counts
+  a longhand host recount of every step's decided+admitted counts
   over a chaos-seeded ManagerCluster run, and the bulk histogram fold
   bit-matches scalar observes;
 * cost attribution — ``step_cost`` AOT split, provenance JSON
@@ -169,28 +169,26 @@ def test_group_heat_bitmatches_host_recount_chaos_run():
     """The on-device heat accumulator is exact, not approximate: over a
     chaos-seeded stepped run (random proposals, random link drops, an
     election kick), every manager's pulled heat equals a longhand host
-    recount of per-substep ``n_committed + n_admitted``."""
+    recount of per-step ``n_committed + n_admitted``."""
     from gigapaxos_tpu.models.apps import HashChainApp
-    from gigapaxos_tpu.ops.engine import EngineConfig, StepOutputs
+    from gigapaxos_tpu.ops.engine import EngineConfig
     from gigapaxos_tpu.testing.cluster import DELIVER, DROP, ManagerCluster
 
     cfg = EngineConfig(n_groups=8, window=4, req_lanes=2, n_replicas=3)
     R, G = cfg.n_replicas, cfg.n_groups
     c = ManagerCluster(cfg, HashChainApp)
     try:
-        # longhand recount: intercept every dispatch's StepOutputs list
-        # BEFORE the engine's own post-step work consumes it
+        # longhand recount: intercept every dispatch's digest BEFORE
+        # the engine's own post-step work consumes it
         expected = [np.zeros(G, np.int64) for _ in range(R)]
 
         def _wrap(m, exp):
             orig = m._post_step_locked
 
-            def wrapped(outs):
-                lst = [outs] if isinstance(outs, StepOutputs) else outs
-                for o in lst:
-                    exp[:] += np.asarray(o.n_committed).astype(np.int64)
-                    exp[:] += np.asarray(o.n_admitted).astype(np.int64)
-                return orig(outs)
+            def wrapped(out):
+                exp[:] += np.asarray(out.n_committed).astype(np.int64)
+                exp[:] += np.asarray(out.n_admitted).astype(np.int64)
+                return orig(out)
 
             m._post_step_locked = wrapped
 

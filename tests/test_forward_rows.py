@@ -130,12 +130,12 @@ def test_one_row_gives_a_one_row_frame_through_the_same_code(h):
 
 
 # ---- (b) FIFO around a stop inside one entry --------------------------------
-@pytest.mark.parametrize("kind", ["forward_rows", "forward_batch"])
-def test_fifo_around_a_stop_inside_one_entry_of_a_frame(h, kind):
+@pytest.mark.parametrize("frames", ["one_of_many_rows", "one_a_row"])
+def test_fifo_around_a_stop_inside_one_entry_of_a_frame(h, frames):
     """The case of test_batching.py's
     ``test_forward_batch_preserves_fifo_around_stop`` as one entry among
-    others: what was queued BEFORE the stop commits before it.  Under the
-    old kind the entries reach the same function one a frame."""
+    others: what was queued BEFORE the stop commits before it — in one
+    frame of many rows, and in frames of one row each."""
     coord = h.coord(NAMES[0])
     mine = h.led_by(coord)
     assert len(mine) >= 3
@@ -145,21 +145,22 @@ def test_fifo_around_a_stop_inside_one_entry_of_a_frame(h, kind):
     pre = [h.submit(stopped, 10 + i, entry) for i in range(5)]
     h.managers[entry].propose(stopped, "", stop=True)
     h.submit(others[1], 4, entry)
-    if kind == "forward_rows":
+    if frames == "one_of_many_rows":
         h.c.step_all()
         (_j, _k, body), = [f for f in h.frames_in_flight()
                            if f[0] == coord]
     else:
-        # the entries as the ring build stages them, handed over one by
-        # one under the old kind
+        # the entries as the ring build stages them, handed over one a
+        # frame
         m = h.managers[entry]
         with m._state_lock:
-            m.build_request_ring(1)
+            m.build_request_ring()
             staged, m.forward_out = m.forward_out, []
         assert {k for _d, k, _b in staged} == {"forward_batch"}
         body = {"rows": [b for d, _k, b in staged if d == coord]}
         for row in body["rows"]:
-            h.managers[coord].on_host_message("forward_batch", row)
+            h.managers[coord].on_host_message(
+                "forward_rows", {"rows": [row]})
     assert [r["name"] for r in body["rows"]] == mine[:3]
     assert [q[3] for q in body["rows"][1]["reqs"]] == [False] * 5 + [True]
     h.c.run(20)

@@ -26,22 +26,26 @@ def stepped_cluster(pipelined: bool) -> ManagerCluster:
     return c
 
 
-@pytest.mark.parametrize("steps_per_dispatch, whole_planes", [
-    (1, False), (4, False),
-    (1, True),   # the pipelined side pulls the whole planes every dispatch
+@pytest.mark.parametrize("whole_planes, deep_queue", [
+    (False, False),
+    (True, False),   # the pipelined side pulls the whole planes every dispatch
+    (False, True),   # a row holds 3K vids: K a dispatch, the rest in order
 ])
-def test_pipeline_state_parity(steps_per_dispatch, whole_planes):
+def test_pipeline_state_parity(whole_planes, deep_queue):
     """Identical schedule through serial and pipelined dispatch: every
     engine leaf equal after every cluster step, and identical client
-    responses — with one substep a dispatch and with four (each
-    substep's digest read in turn), and with the donated, pipelined side
-    forced down the digest's overflow path."""
-    Config.set("ENGINE_STEPS_PER_DISPATCH", str(steps_per_dispatch))
+    responses — also with the donated, pipelined side forced down the
+    digest's overflow path, and with 3K requests queued on one row at
+    its coordinator (coalescing off): the ring stages the row's first K
+    a dispatch and the rest keep their order, so the twelve execute in
+    the order they were proposed."""
+    if deep_queue:
+        Config.set("BATCHING_ENABLED", "false")
     serial, piped = stepped_cluster(False), stepped_cluster(True)
-    assert serial.managers[0].steps_per_dispatch == steps_per_dispatch
     if whole_planes:
         for m in piped.managers:
             m._digest_rows = -1
+    deep = 3 * CFG.req_lanes
     try:
         resp_s, resp_p = [], []
         names = ["pa", "pb", "pc"]
@@ -70,6 +74,24 @@ def test_pipeline_state_parity(steps_per_dispatch, whole_planes):
                         ),
                         request_id=rid + 0,
                     )
+                if deep_queue and step_no == 10:
+                    row = c.managers[0].names[names[1]]
+                    lead = c.managers[c.managers[0].coordinator_of_row(row)]
+                    for i in range(deep):
+                        lead.propose(
+                            names[1], f"deep{i}",
+                            callback=(
+                                lambda r, x, _o=resp:
+                                _o.append(("deep", r, x))
+                            ),
+                            request_id=rid + 1000 + i,
+                        )
+                    queued = list(lead.queues[row])
+                    c.step_all()
+                    # K staged and admitted, the rest as they stood
+                    assert lead._last_ring_rows[row] == CFG.req_lanes
+                    assert lead.queues[row] == queued[CFG.req_lanes:]
+                    continue
                 c.step_all()
             # step-for-step: EVERY leaf of EVERY replica identical
             for ms, mp in zip(serial.managers, piped.managers):
@@ -84,9 +106,93 @@ def test_pipeline_state_parity(steps_per_dispatch, whole_planes):
                 ), (step_no, ms.my_id)
         assert sorted(resp_s, key=str) == sorted(resp_p, key=str)
         assert len(resp_s) >= 10  # the schedule actually decided things
+        if deep_queue:
+            for resp in (resp_s, resp_p):
+                assert [r for tag, r, _x in resp if tag == "deep"] == [
+                    rid + 1000 + i for i in range(deep)]
     finally:
         serial.close()
         piped.close()
+
+
+def _pipelined_rounds(c, landing, n_rounds, on_round=None):
+    """Drive ``c``'s managers as three nodes that tick at one cadence:
+    a round is every node's ``step_dispatch``, then every node's
+    ``step_complete``.  What the peers published at the end of a round
+    LANDS at each receiver (``GatherNews.hear``: it is there for the
+    next ``drain``) at one of three moments of the next round —
+    ``before_dispatch`` (the stepped harness's lockstep),
+    ``in_flight`` (after the receiver's dispatch has read its stack,
+    before its completion) or ``after_completion``."""
+    R = c.cfg.n_replicas
+    heard = np.ones(R, bool)
+
+    def land(vecs):
+        for i in range(R):
+            for j in range(R):
+                if i != j:
+                    c._held[i][j] = c._news[i].hear(j, vecs[j], c._held[i][j])
+
+    for t in range(n_rounds):
+        if on_round is not None:
+            on_round(t)
+        landed = list(c.vecs)  # what the last round published
+        if landing == "before_dispatch":
+            land(landed)
+        pends = [m.step_dispatch(c._news[i].drain(c._held[i]), heard)
+                 for i, m in enumerate(c.managers)]
+        if landing == "in_flight":
+            land(landed)
+        deltas = [m.step_complete(pend)[2]
+                  for m, pend in zip(c.managers, pends)]
+        c.vecs = [m.mirror.vec.copy() for m in c.managers]
+        if landing == "after_completion":
+            land(landed)
+        for i, delta in enumerate(deltas):  # payloads, at once
+            for j in range(R):
+                if j != i and delta["arena"]:
+                    c.managers[j].on_host_message("payloads", delta)
+
+
+@pytest.mark.parametrize("landing, ticks", [
+    ("before_dispatch", 3),   # the floor: stage, two exchanges
+    ("in_flight", 5),         # the dispatch in flight has read its stack:
+    ("after_completion", 5),  # ... folded as late as a blob after it
+])
+def test_ticks_from_staged_to_decided_by_when_a_peers_blob_lands(
+        landing, ticks):
+    """ROADMAP S4's yardstick: the coordinator's consensus leg, in its
+    own ticks, from the dispatch that first stages a request to the
+    completion that shows it decided.  A blob that lands while the
+    receiver's step is in flight is folded by the NEXT dispatch — one
+    dispatch late at each end of each of the two exchanges — exactly as
+    one that lands after the completion: what the tree does today, and
+    the number a cure of the receiver's half has to move."""
+    c = ManagerCluster(CFG, HashChainApp)
+    try:
+        row = c.create("s4")
+        lead = c.managers[c.managers[0].coordinator_of_row(row)]
+        staged, decided = {}, {}
+
+        def on_round(t):
+            if t >= 6 and t % 8 == 6:  # one request at a time, alone
+                rid = (1 << 56) + t
+                staged[rid] = lead._tick_no  # the dispatch of this round
+                lead.propose(
+                    "s4", f"v{t}", request_id=rid,
+                    callback=lambda r, x: decided.setdefault(
+                        r, lead._tick_no))
+
+        _pipelined_rounds(c, landing, 30, on_round)
+        assert len(staged) == 3 and decided.keys() == staged.keys()
+        assert [decided[r] - staged[r] for r in staged] == [ticks] * 3
+        # the manager's own account of the leg agrees
+        hist = lead.metrics.snapshot()["hists"]["commit_leg_consensus_ticks"]
+        assert (hist["count"], hist["sum"]) == (3, 3 * ticks)
+        for m in c.managers:  # and everybody executed all three
+            assert m.app_exec_slot[row] == 3
+    finally:
+        c.close()
 
 
 def test_stepped_cluster_and_served_node_share_one_step_instance():
@@ -96,9 +202,9 @@ def test_stepped_cluster_and_served_node_share_one_step_instance():
     from gigapaxos_tpu.parallel.spmd import make_step
 
     cfg = EngineConfig(n_groups=12, window=8, req_lanes=4, n_replicas=3)
-    sentinel = make_step(cfg, None, 1, donate=True, io="packed_host")
+    sentinel = make_step(cfg, donate=True, io="packed_host")
     with pytest.raises(TypeError):
-        make_step(cfg, None, 1, io="packed_host", heat=True)
+        make_step(cfg, io="packed_host", heat=True)
     c = ManagerCluster(cfg, HashChainApp)
     m = PaxosManager(0, HashChainApp(), cfg)
     try:
@@ -114,6 +220,44 @@ def test_stepped_cluster_and_served_node_share_one_step_instance():
         assert sentinel.n_retraces == 0, sentinel.stats()
     finally:
         c.close()
+        m.close()
+
+
+def test_after_warm_engine_the_tick_path_compiles_nothing():
+    """``warm_engine`` warms the shapes a dispatch has: after it, a tick
+    with nothing staged (the standing null ring), one with a request
+    (a ring sent up) and one whose digest overflows (the whole planes
+    pulled) hand XLA no program to compile — by JAX's own account of
+    its backend compiles, not by the sentinel's."""
+    import jax.monitoring
+
+    cfg = EngineConfig(n_groups=24, window=8, req_lanes=4, n_replicas=3)
+    m = PaxosManager(0, HashChainApp(), cfg)
+    compiled = []
+
+    def listener(event, _secs, **_kw):
+        if "backend_compile" in event:
+            compiled.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        m.warm_engine()
+        m.create_paxos_instance("w", [0])
+        assert compiled  # the listener hears a compile when there is one
+        del compiled[:]
+        heard, done = np.array([True, False, False]), []
+        m.tick_host(None, heard)
+        m.propose("w", "v1", callback=lambda r, x: done.append(x))
+        m.step_complete(m.step_dispatch(None, heard))
+        m._digest_rows = -1
+        m.propose("w", "v2", callback=lambda r, x: done.append(x))
+        for _ in range(3):
+            m.step_complete(m.step_dispatch(None, heard))
+        assert len(done) == 2
+        assert m.metrics.get("step_digest_overflows") == 3
+        assert compiled == []
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
         m.close()
 
 

@@ -129,13 +129,13 @@ def _hold_to_dense(m, seen):
     the program runs them."""
     post, detect = m._post_step_locked, m._maybe_request_state
 
-    def post_step(outs):
-        want = dense_min_exec(m, outs[-1], seen)
-        dec = sum(int(o.n_committed.sum()) for o in outs)
-        adm = sum(int(o.n_admitted.sum()) for o in outs)
+    def post_step(out):
+        want = dense_min_exec(m, out, seen)
+        dec = int(out.n_committed.sum())
+        adm = int(out.n_admitted.sum())
         before = (m.metrics.get("decisions_executed"),
                   m.metrics.get("requests_admitted"))
-        result = post(outs)
+        result = post(out)
         assert np.array_equal(m._min_exec, want), (m.my_id, m._tick_no)
         assert (m.metrics.get("decisions_executed") - before[0],
                 m.metrics.get("requests_admitted") - before[1]) == (dec, adm)
@@ -270,15 +270,14 @@ class _Run:
         c.republish()
 
 
-@pytest.mark.parametrize("G,n_names,steps,block", [
-    (64, 64, 1, 500),    # every row a member, one block
-    (64, 64, 4, 16),     # ... in four blocks
-    (4096, 30, 1, 8),    # 30 members of 4,096 rows, in four blocks
-    (4096, 30, 4, 500),  # ... in one
+@pytest.mark.parametrize("G,n_names,block,seed", [
+    (64, 64, 500, 3600065),    # every row a member, one block
+    (64, 64, 16, 3600068),     # ... in four blocks
+    (4096, 30, 8, 3604097),    # 30 members of 4,096 rows, in four blocks
+    (4096, 30, 500, 3604100),  # ... in one
 ])
 def test_member_rows_equal_the_dense_pass_after_every_step(
-        G, n_names, steps, block, monkeypatch):
-    Config.set("ENGINE_STEPS_PER_DISPATCH", str(steps))
+        G, n_names, block, seed, monkeypatch):
     monkeypatch.setattr(PaxosManager, "ROWS_A_PASS", block)
     Config.set("BATCHING_ENABLED", "false")  # a slot a request
     # the triggers at a tenth of their ticks, for both forms alike
@@ -286,7 +285,7 @@ def test_member_rows_equal_the_dense_pass_after_every_step(
     monkeypatch.setattr(PaxosManager, "PAYLOAD_BLOCKED_TICKS", 6)
     monkeypatch.setattr(PaxosManager, "STATE_REQ_INTERVAL", 4)
     cfg = EngineConfig(n_groups=G, window=8, req_lanes=4, n_replicas=3)
-    rng = np.random.default_rng(3600000 + G + steps)
+    rng = np.random.default_rng(seed)
     c = ManagerCluster(cfg, HashChainApp)
     seen = Counter()
     try:
@@ -465,10 +464,10 @@ def _turns_taken_during_post_steps(monkeypatch, block):
 
     post = m._post_step_locked
 
-    def post_step(outs):
+    def post_step(out):
         before = turns[0]
         try:
-            return post(outs)
+            return post(out)
         finally:
             inside[0] += turns[0] - before
 

@@ -23,7 +23,6 @@ from gigapaxos_tpu.ops.engine import (
     split_out_vec,
 )
 from gigapaxos_tpu.testing.cluster import DELIVER, DROP, ManagerCluster
-from gigapaxos_tpu.utils.config import Config
 
 CFG = EngineConfig(n_groups=16, window=8, req_lanes=4, n_replicas=3)
 NAMES = [f"dg{i}" for i in range(6)]
@@ -46,30 +45,28 @@ def _old_work_in_flight(m) -> bool:
 
 
 def _watch(m, seen):
-    """Hold every substep's digest against the whole planes of the same
+    """Hold every step's digest against the whole planes of the same
     dispatch, at the moment the post-step is handed it."""
     orig = m._complete_locked
 
     def wrapped(pend, digest_np, *news):
-        out_rows = np.asarray(pend["out_vec"])
         planes = _state_np(m, "acc_slot", "acc_bal", "acc_vid")
-        for i, row in enumerate(digest_np):
-            got, n_busy, _quorum = split_digest_vec(row, m.cfg)
-            out = split_out_vec(out_rows[i], m.cfg)
-            want = digest_from_planes(out, *planes, _old_work_in_flight(m))
-            assert n_busy == len(want.rows) <= digest_rows(m.cfg)
-            for f in StepDigest._fields:
-                assert np.array_equal(getattr(got, f), getattr(want, f)), (
-                    m.my_id, m._tick_no, i, f)
-            # the order the post-step walks is np.nonzero's over the planes
-            k, lane = np.nonzero(got.acc_new)
-            g_full, lane_full = np.nonzero(out.acc_new)
-            assert np.array_equal(got.rows[k], g_full)
-            assert np.array_equal(lane, lane_full)
-            seen["substeps"] += 1
-            seen["busy"] += n_busy
-            seen["multi_slot"] += int((out.n_committed > 1).sum())
-            seen["live"].add(got.live)
+        got, n_busy, _quorum = split_digest_vec(digest_np, m.cfg)
+        out = split_out_vec(np.asarray(pend["out_vec"]), m.cfg)
+        want = digest_from_planes(out, *planes, _old_work_in_flight(m))
+        assert n_busy == len(want.rows) <= digest_rows(m.cfg)
+        for f in StepDigest._fields:
+            assert np.array_equal(getattr(got, f), getattr(want, f)), (
+                m.my_id, m._tick_no, f)
+        # the order the post-step walks is np.nonzero's over the planes
+        k, lane = np.nonzero(got.acc_new)
+        g_full, lane_full = np.nonzero(out.acc_new)
+        assert np.array_equal(got.rows[k], g_full)
+        assert np.array_equal(lane, lane_full)
+        seen["steps"] += 1
+        seen["busy"] += n_busy
+        seen["multi_slot"] += int((out.n_committed > 1).sum())
+        seen["live"].add(got.live)
         result = orig(pend, digest_np, *news)
         assert m.engine_work_in_flight() == _old_work_in_flight(m)
         return result
@@ -122,27 +119,26 @@ def _counter(c, key):
                for m in c.managers)
 
 
-@pytest.mark.parametrize("steps", [1, 4])
-def test_digest_equals_whole_planes(steps):
+@pytest.mark.parametrize("seed", [20260928, 20261005])
+def test_digest_equals_whole_planes(seed):
     """(a) and (c): over a seeded run with elections, preempts and
     multi-slot commits, every field the post-step reads from the digest
     equals the whole planes', and the flag equals the old expression."""
-    Config.set("ENGINE_STEPS_PER_DISPATCH", str(steps))
     c = ManagerCluster(CFG, HashChainApp)
-    seen = {"substeps": 0, "busy": 0, "multi_slot": 0, "live": set()}
+    seen = {"steps": 0, "busy": 0, "multi_slot": 0, "live": set()}
     try:
         for nm in NAMES:
             c.create(nm)
         for m in c.managers:
             _watch(m, seen)
-        done = _drive(c, 20260928, 60)
+        done = _drive(c, seed, 60)
         assert len(done) > 40
-        assert seen["substeps"] == 72 * 3 * steps and seen["busy"] > 100
+        assert seen["steps"] == 72 * 3 and seen["busy"] > 100
         assert seen["live"] == {True, False}
         assert seen["multi_slot"] > 0
         assert _counter(c, "preempts") > 0
         assert _counter(c, "coordinator_flips") > 0
-        assert _counter(c, "step_digest_dispatches") == seen["substeps"]
+        assert _counter(c, "step_digest_dispatches") == seen["steps"]
         assert _counter(c, "step_digest_overflows") == 0
         _assert_replicas_agree(c)
     finally:
@@ -159,12 +155,11 @@ def _journal_bytes(log_dir):
     return out
 
 
-@pytest.mark.parametrize("steps", [1, 4])
-def test_journal_cannot_tell_digest_from_whole_planes(tmp_path, steps):
+@pytest.mark.parametrize("seed", [7, 47])
+def test_journal_cannot_tell_digest_from_whole_planes(tmp_path, seed):
     """(a): two clusters driven identically, one reading the digest and
     one forced down the whole-plane path on every dispatch, write
     byte-identical journals and answer identically."""
-    Config.set("ENGINE_STEPS_PER_DISPATCH", str(steps))
     runs = {}
     for arm in ("digest", "planes"):
         dirs = [str(tmp_path / f"{arm}{r}") for r in range(3)]
@@ -174,10 +169,10 @@ def test_journal_cannot_tell_digest_from_whole_planes(tmp_path, steps):
             for m in c.managers:
                 m._rid_nonce = 1 << 20  # a batch's id is minted from it
                 if arm == "planes":
-                    m._digest_rows = -1  # no substep fits: always overflow
+                    m._digest_rows = -1  # no step fits: always overflow
             for nm in NAMES:
                 c.create(nm)
-            done = _drive(c, 7, 50)
+            done = _drive(c, seed, 50)
             overflows = _counter(c, "step_digest_overflows")
             dispatches = _counter(c, "step_digest_dispatches")
             assert overflows == (dispatches if arm == "planes" else 0)
